@@ -24,13 +24,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main():
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    from bench import _peak_flops, enable_compilation_cache
+    from bench import _peak_flops
+    from paddle_tpu.framework.device import platform, require_tpu
+    from paddle_tpu.utils.xla_cache import enable_compilation_cache
 
     enable_compilation_cache()
-    smoke = "--smoke" in sys.argv or jax.default_backend() == "cpu"
-    print(f"ernie_bench: backend={jax.default_backend()} smoke={smoke}",
+    smoke = "--smoke" in sys.argv
+    if not smoke:
+        require_tpu("ernie_bench")
+    print(f"ernie_bench: platform={platform()} smoke={smoke}",
           file=sys.stderr, flush=True)
 
     import paddle_tpu as pt

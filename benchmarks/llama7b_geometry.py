@@ -33,19 +33,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main() -> int:
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    from bench import _peak_flops, _probe_backend, enable_compilation_cache
+    from bench import _peak_flops
+    from paddle_tpu.framework.device import require_tpu
+    from paddle_tpu.utils.xla_cache import enable_compilation_cache
 
     enable_compilation_cache()
     smoke = "--smoke" in sys.argv
     if not smoke:
-        try:
-            smoke = _probe_backend() == "cpu"
-        except RuntimeError as e:
-            print(f"llama7b_geometry: backend unavailable: {e}",
-                  file=sys.stderr)
-            return 2
+        require_tpu("llama7b_geometry")
     print(f"llama7b_geometry: smoke={smoke}", flush=True)
 
     import paddle_tpu as pt
@@ -91,7 +86,9 @@ def main() -> int:
     final = float(np.asarray(loss.numpy()))  # sync
     dt = time.perf_counter() - t0
     tps = batch * seq * steps / dt
-    mfu = (tps * model.flops_per_token(seq) / _peak_flops(jax.devices()[0]))
+    # MFU is a statement about the chip: a CPU smoke has none
+    mfu = 0.0 if smoke else (tps * model.flops_per_token(seq)
+                             / _peak_flops(jax.devices()[0]))
     rec = {"metric": "llama7b_geometry_tokens_per_sec_per_chip",
            "value": round(tps, 1), "unit": "tokens/s",
            "mfu": round(mfu, 4), "layers": layers, "batch": batch,

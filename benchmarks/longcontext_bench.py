@@ -91,26 +91,19 @@ def main():
     ap.add_argument("--seqs", default="4096,8192,16384")
     args = ap.parse_args()
 
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    from bench import enable_compilation_cache
+    from paddle_tpu.framework.device import require_tpu
+    from paddle_tpu.utils.xla_cache import enable_compilation_cache
 
     enable_compilation_cache()
-    smoke = args.smoke or jax.default_backend() == "cpu"
-    if smoke and not args.smoke:
-        print("longcontext_bench: no TPU — smoke mode", flush=True)
+    smoke = args.smoke
+    if not smoke:
+        require_tpu("longcontext_bench")
 
-    # same pre-flight as bench.py: a kernel that cannot lower must cost
-    # perf, not the run
+    # same pre-flight as bench.py: a kernel that cannot lower fails the
+    # run
     from paddle_tpu.ops import pallas as _pallas
 
-    try:
-        _pallas.check_tpu_lowering()
-    except Exception as e:  # noqa: BLE001
-        _pallas.disable()
-        print(f"longcontext_bench: pallas disabled: {e}", flush=True)
+    _pallas.check_tpu_lowering()
 
     for seq in (int(s) for s in args.seqs.split(",")):
         bench_seq(seq, smoke)

@@ -1,29 +1,18 @@
-"""Version shims over jax API surfaces that moved between releases.
+"""The few JAX surfaces this repo reaches through one name.
 
-Two surfaces this repo depends on changed addresses across jax versions:
+Written for the installed JAX (0.9.0) only — no branch for another
+release. Each entry is either a plain alias or a one-line spelling kept
+here so that the next JAX move touches one file:
 
-- ``shard_map``: new jax exposes ``jax.shard_map`` (kwargs ``check_vma``,
-  ``axis_names``); jax 0.4.x only has
-  ``jax.experimental.shard_map.shard_map`` (kwargs ``check_rep``,
-  ``auto``).  :func:`shard_map` below accepts the NEW spelling and
-  translates down when running on the experimental API.
-- ``jax.export``: public module since jax 0.4.30 but NOT imported by
-  ``import jax`` on 0.4.x — attribute access ``jax.export.export`` raises
-  ``AttributeError`` unless something imported the submodule first.  The
-  ``export`` name below is the resolved module (falling back to
-  ``jax.experimental.export`` on trees that predate the move).
-
-- executable serialization: ``jax.experimental.serialize_executable``
-  (pickle-able AOT-compiled executables — the on-disk tier of
-  ``jit/exec_cache.py``) has lived at the same address for a while but is
-  experimental; :func:`serialize_executable` /
-  :func:`deserialize_executable` below are the one indirection point for
-  when it moves.
-
-Callers (``distributed/collective.py``, ``ops/ring_attention.py``,
-``jit/__init__.py``, ``jit/exec_cache.py``) import from here instead of
-touching ``jax.*`` directly, so a jax upgrade needs exactly one file to
-change.
+- ``shard_map`` / ``export``: ``jax.shard_map`` and the ``jax.export``
+  module.
+- ``pvary``: ``jax.lax.pcast(..., to="varying")`` (``jax.lax.pvary`` is
+  deprecated).
+- ``tpu_compiler_params``: ``pltpu.CompilerParams``, imported lazily
+  (Pallas is heavy and optional).
+- ``serialize_executable`` / ``deserialize_executable``:
+  ``jax.experimental.serialize_executable`` — the on-disk tier of
+  ``jit/exec_cache.py``.
 """
 from __future__ import annotations
 
@@ -32,94 +21,46 @@ import jax
 __all__ = ["shard_map", "export", "pvary", "tpu_compiler_params",
            "serialize_executable", "deserialize_executable"]
 
+shard_map = jax.shard_map
+export = jax.export
+
 
 def tpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params across the ``TPUCompilerParams`` →
-    ``CompilerParams`` rename (lazy import: pallas is heavy and optional)."""
+    """Pallas-TPU compiler params (``pltpu.CompilerParams``)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-# -- shard_map ---------------------------------------------------------------
-
-_native_shard_map = getattr(jax, "shard_map", None)
-if _native_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-else:
-    _exp_shard_map = None
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None, check_rep=None,
-              axis_names=None):
-    """``jax.shard_map`` with the new-API signature on every jax.
-
-    ``check_vma``/``check_rep`` are aliases (new/old name for the same
-    replication check); pass either.  ``axis_names`` (the manual-axes
-    subset) is dropped on the old API: its equivalent ``auto`` set raises
-    ``NotImplementedError`` in the old eager impl, and binding the extra
-    mesh axes manually is semantically equivalent for bodies that only
-    address their spec'd axes (unspec'd axes stay replicated).
-    """
-    check = check_vma if check_vma is not None else check_rep
-    if _native_shard_map is not None:
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if check is not None:
-            kwargs["check_vma"] = check
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return _native_shard_map(f, **kwargs)
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check is not None:
-        kwargs["check_rep"] = bool(check)
-    return _exp_shard_map(f, **kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def pvary(x, axis_names):
-    """Mark ``x`` varying over ``axis_names`` inside shard_map.
+    """Mark ``x`` varying over ``axis_names`` inside shard_map (literals
+    that feed varying outputs need the cast under ``check_vma``)."""
+    return jax.lax.pcast(x, tuple(axis_names), to="varying")
 
-    New jax tracks a varying-mask (vma) per value and needs literals that
-    feed varying outputs cast explicitly (``jax.lax.pcast``/``pvary``).
-    Old shard_map has no vma system — its ``check_rep`` inference handles
-    replicated literals itself — so this is the identity there.
-    """
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, tuple(axis_names), to="varying")
-    pv = getattr(jax.lax, "pvary", None)
-    if pv is not None:
-        return pv(x, tuple(axis_names))
-    return x
-
-
-# -- executable serialization ------------------------------------------------
 
 def serialize_executable(compiled):
-    """``(payload, in_tree, out_tree)`` for a ``jax.stages.Compiled`` —
-    the persistable form of an AOT-compiled executable (lazy import: the
-    module drags in pickle glue callers may never need)."""
+    """``(payload, in_tree, out_tree, device_ids)`` for a
+    ``jax.stages.Compiled`` — the persistable form of an AOT-compiled
+    executable (lazy import: the module drags in pickle glue callers may
+    never need). ``device_ids`` are the devices the executable was
+    compiled for: ``deserialize_and_load`` otherwise loads it across
+    EVERY device of the backend, and a one-device program then refuses
+    its arguments ("expected 8 shards") on a host that shows eight."""
     from jax.experimental import serialize_executable as _se
 
-    return _se.serialize(compiled)
+    payload, in_tree, out_tree = _se.serialize(compiled)
+    devices = compiled._executable._unloaded_executable.device_list
+    return payload, in_tree, out_tree, tuple(d.id for d in devices)
 
 
-def deserialize_executable(payload, in_tree, out_tree):
+def deserialize_executable(payload, in_tree, out_tree, device_ids):
     """Rehydrate :func:`serialize_executable` output into a loaded,
-    callable executable on the current backend. Raises on any
-    payload/topology mismatch — callers treat that as a cache miss."""
+    callable executable on the same device ids of the current backend.
+    Raises on any payload/topology mismatch — callers treat that as a
+    cache miss."""
     from jax.experimental import serialize_executable as _se
 
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
-
-
-# -- jax.export --------------------------------------------------------------
-
-export = getattr(jax, "export", None)
-if export is None:
-    try:
-        # module exists on 0.4.30+ but isn't loaded by `import jax`
-        import jax.export as export  # noqa: F401
-    except ImportError:  # pragma: no cover — pre-0.4.30 trees
-        from jax.experimental import export  # noqa: F401
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])

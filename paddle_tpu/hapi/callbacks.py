@@ -221,7 +221,7 @@ class LRSchedulerCallback(Callback):
 class MonitorCallback(Callback):
     """Stream per-step runtime telemetry to a JSONL sink
     (`paddle_tpu.monitor.StepLogger`): one line per train batch with loss,
-    ips, and the counter diff (retraces, tunnel syncs, collective bytes...)
+    ips, and the counter diff (retraces, sync fences, collective bytes...)
     attributable to that step. Auto-added by `config_callbacks` when the
     monitor is enabled (``PT_MONITOR=1``); sink path from ``path`` or
     ``PT_MONITOR_SINK``. Step ids are monotonic across epochs."""

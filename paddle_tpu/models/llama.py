@@ -114,8 +114,12 @@ class LlamaConfig:
 
     @classmethod
     def llama2_7b(cls, **kw):
-        return cls(hidden_size=4096, intermediate_size=11008,
-                   num_hidden_layers=32, num_attention_heads=32, **kw)
+        """Llama-2-7B's published widths; ``kw`` overrides (a depth cut
+        to fit one chip is ``num_hidden_layers=N`` — chip_smoke.py)."""
+        base = dict(hidden_size=4096, intermediate_size=11008,
+                    num_hidden_layers=32, num_attention_heads=32)
+        base.update(kw)
+        return cls(**base)
 
     @classmethod
     def tiny(cls, **kw):

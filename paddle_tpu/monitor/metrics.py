@@ -3,7 +3,7 @@
 Reference parity: the role of Paddle's profiler statistic collectors
 (`python/paddle/profiler/profiler_statistic.py`) and the C++ host event
 counters, rebuilt as process-wide typed metrics so the *runtime* itself
-(dispatch, retraces, tunnel syncs, collectives) is observable — not just
+(dispatch, retraces, sync fences, collectives) is observable — not just
 user-scoped host events.
 
 Design: metrics are cheap enough to sit on hot paths when monitoring is ON

@@ -23,11 +23,15 @@ def check_tpu_lowering():
     """Lower every registered Pallas kernel for the TPU platform.
 
     Runs on any host (no chip needed): ``jax.export(platforms=['tpu'])``
-    performs the full Mosaic lowering, including the block-mapping checks
-    that interpret-mode skips. Raises on the first kernel that would fail
-    on real hardware — wired into ``__graft_entry__.entry()`` and the
-    bench pre-flight so a kernel regression fails loudly *before* it can
-    zero a hardware run (the round-2 failure mode).
+    lowers each kernel to its Mosaic module, including the block-mapping
+    checks that interpret mode skips, and raises on the first kernel
+    that fails. It is the CHEAP in-process pre-flight
+    (``__graft_entry__.entry()``, the bench): it stops before the
+    Mosaic / XLA-TPU compile, so it cannot see a layout the compiler
+    refuses or a kernel that overflows scoped VMEM. Those are caught by
+    compiling the same shapes for a described ``v5e:2x2`` topology —
+    ``tests/test_chip_compile.py``, never in a process that holds the
+    chip.
 
     Coverage is registry-driven: each kernel registers a
     ``check_lowering`` self-check attribute alongside itself, so new
@@ -44,13 +48,3 @@ def check_tpu_lowering():
                 f"Pallas kernel {name!r} registered without a "
                 f"check_lowering self-check; attach one in its register()")
         check()
-
-
-def disable():
-    """Drop every Pallas override so ops fall back to the XLA composite
-    path — the bench pre-flight's containment action when a kernel fails
-    to lower (a kernel bug must cost MFU, not the run)."""
-    from .. import registry
-
-    for name, _ in registry.platform_kernels("tpu"):
-        registry.deregister_kernel(name, "tpu")

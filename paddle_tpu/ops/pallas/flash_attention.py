@@ -663,6 +663,15 @@ def flash_attention_kernel(q, k, v, *rest, causal=False, dropout=0.0,
     from . import autotune as _tune
 
     scale = 1.0 / math.sqrt(d)
+    # Under a multi-device mesh the kernel runs once per shard (see
+    # _mesh_partition): the engagement keys below see the LOCAL batch
+    # and head counts, the shapes the kernel will really be built for.
+    part = _mesh_partition(b, h, h_kv)
+    b_l, h_l, hkv_l = part[3:] if part is not None else (b, h, h_kv)
+    seed = None
+    if dropout > 0.0:
+        seed = jax.lax.bitcast_convert_type(
+            jnp.asarray(dkey).reshape(2), jnp.int32)
     if not interpret:
         # head-BATCHED variant (head_flash.py — no transpose pair):
         # exact-key measured engagement only, from the search harness's
@@ -670,18 +679,18 @@ def flash_attention_kernel(q, k, v, *rest, causal=False, dropout=0.0,
         # mask calls disengaged until their own rows exist
         from . import head_flash as _hb
 
-        hb_key = _hb.shape_key(b, sq, sk, h, h_kv, d, causal,
+        hb_key = _hb.shape_key(b_l, sq, sk, h_l, hkv_l, d, causal,
                                dropout > 0.0, kadd is not None)
         if _search.engaged("flash_headbatch", hb_key):
             cfg = _search.best_config("flash_headbatch", hb_key) or {}
-            hb_seed = None
-            if dropout > 0.0:
-                hb_seed = jax.lax.bitcast_convert_type(
-                    jnp.asarray(dkey).reshape(2), jnp.int32)
             _search.note_engaged("flash_headbatch")
-            return _hb.hb_flash(q, k, v, hb_seed, kadd, causal, scale,
-                                False, cfg.get("block_q"),
-                                cfg.get("block_k"), 0, dropout)
+
+            def hb_local(q, k, v, kadd, seed):
+                return _hb.hb_flash(q, k, v, seed, kadd, causal, scale,
+                                    False, cfg.get("block_q"),
+                                    cfg.get("block_k"), 0, dropout)
+
+            return _per_shard(hb_local, part, q, k, v, kadd, seed)
 
     bq_t = bk_t = None
     if not interpret:
@@ -708,21 +717,77 @@ def flash_attention_kernel(q, k, v, *rest, causal=False, dropout=0.0,
             return fallback(dropout)
         bq_t, bk_t = _tune.best_blocks(sq, sk, d, causal)
     _search.note_engaged("flash")
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h_kv, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h_kv, sk, d)
-    seed = None
-    if dropout > 0.0:
-        seed = jax.lax.bitcast_convert_type(
-            jnp.asarray(dkey).reshape(2), jnp.int32)
-    kmask = None
-    if kadd is not None:
-        # [b, 1, sk] -> per-query-head rows [bh, 1, sk]
-        kmask = jnp.broadcast_to(kadd[:, None],
-                                 (b, h, 1, sk)).reshape(b * h, 1, sk)
-    out = _flash_call(qt, kt, vt, seed, kmask, causal, scale, interpret,
-                      bq_t, bk_t, 0, dropout)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+
+    def bhsd_local(q, k, v, kadd, seed):
+        b, _, h, _ = q.shape
+        h_kv = k.shape[2]
+        qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+        kt = k.transpose(0, 2, 1, 3).reshape(b * h_kv, sk, d)
+        vt = v.transpose(0, 2, 1, 3).reshape(b * h_kv, sk, d)
+        kmask = None
+        if kadd is not None:
+            # [b, 1, sk] -> per-query-head rows [bh, 1, sk]
+            kmask = jnp.broadcast_to(kadd[:, None],
+                                     (b, h, 1, sk)).reshape(b * h, 1, sk)
+        out = _flash_call(qt, kt, vt, seed, kmask, causal, scale,
+                          interpret, bq_t, bk_t, 0, dropout)
+        return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+
+    return _per_shard(bhsd_local, part, q, k, v, kadd, seed)
+
+
+def _mesh_partition(b, h, h_kv):
+    """How one attention call splits over the active mesh, or None on a
+    single device: ``(mesh, batch_axis, head_axis, b_local, h_local,
+    h_kv_local)``. XLA cannot partition a Mosaic kernel by itself ("Mosaic
+    kernels cannot be automatically partitioned"), so under a mesh the
+    call is wrapped in a ``shard_map`` whose specs are the ones the
+    model already constrains q/k/v to (``models/llama.py``: batch over
+    'dp', heads over 'mp'). A dimension its axis does not divide stays
+    whole — every device of that axis then computes all of it, which is
+    redundant but right."""
+    from ...distributed import env as env_mod
+
+    e = env_mod.get_env()
+    if e is None or e.mesh.size == 1:
+        return None
+    dp, mp = e.degree("dp"), e.degree("mp")
+    bax = "dp" if dp > 1 and b % dp == 0 else None
+    hax = "mp" if mp > 1 and h % mp == 0 and h_kv % mp == 0 else None
+    return (e.mesh, bax, hax, b // dp if bax else b,
+            h // mp if hax else h, h_kv // mp if hax else h_kv)
+
+
+def _per_shard(local, part, q, k, v, kadd, seed):
+    """Run ``local(q, k, v, kadd, seed)`` on [b, s, h, d] operands —
+    directly on one device, once per (batch, head) shard under a mesh."""
+    if part is None:
+        return local(q, k, v, kadd, seed)
+    from jax.sharding import PartitionSpec as P
+
+    from ...framework.jax_compat import shard_map
+
+    mesh, bax, hax = part[:3]
+    qkv = P(bax, None, hax, None)
+
+    def body(q, k, v, kadd, seed):
+        if seed is not None and (bax or hax):
+            # the in-kernel mask hashes LOCAL (head, row, col): fold the
+            # shard's coordinates into the seed so shards draw
+            # different masks
+            shard = jnp.int32(0)
+            for ax in (bax, hax):
+                if ax is not None:
+                    shard = shard * mesh.shape[ax] + jax.lax.axis_index(ax)
+            seed = seed.at[1].add(shard)
+        return local(q, k, v, kadd, seed)
+
+    return shard_map(
+        body, mesh=mesh,
+        in_specs=(qkv, qkv, qkv,
+                  None if kadd is None else P(bax, None, None),
+                  None if seed is None else P()),
+        out_specs=qkv, check_vma=False)(q, k, v, kadd, seed)
 
 
 def _key_padding_additive(mask, q_shape, k_shape):
@@ -747,78 +812,71 @@ def _key_padding_additive(mask, q_shape, k_shape):
     return jnp.maximum(m.astype(jnp.float32), NEG_INF)
 
 
-def check_lowering():
-    """Mosaic-lower fwd+bwd for platform 'tpu' at the kernel's contract
-    shapes (BERT-base d=64, Llama d=128, cross-length) — runs on any host
-    via jax.export, no chip needed."""
-    shapes = [(8, 1024, 1024, 64), (8, 1024, 1024, 128), (4, 512, 1024, 128)]
-    for bh, sq, sk, d in shapes:
-        q = jnp.zeros((bh, sq, d), jnp.bfloat16)
-        kv = jnp.zeros((bh, sk, d), jnp.bfloat16)
-        scale = 1.0 / math.sqrt(d)
+def lowering_cases():
+    """``(label, fn, arg_specs)`` for every shape the kernel answers
+    for: fwd+bwd (one ``jax.grad`` — its program holds the forward
+    kernel and both backward kernels) at the main path's widths
+    (Llama-2-7B training b4 x 32 heads at s1024/d128 causal, at the
+    default and at the tuned 1024 blocks; BERT-base b64 x 12 heads at
+    s512/d64 non-causal), d=64 causal, cross-length, and the sliding
+    window, key-mask and dropout variants. :func:`check_lowering`
+    lowers them with ``jax.export``; ``tests/test_chip_compile.py``
+    compiles the same list for a described v5e."""
+    bf16 = jnp.bfloat16
 
-        def fwd(q, k, v, _s=scale):
-            return _flash_bhsd(q, k, v, True, _s, False)
-
-        def bwd(q, k, v, _s=scale):
+    def grad_of(f):
+        def g(q, k, v, *rest):
             return jax.grad(
-                lambda *a: fwd(*a, _s=_s).astype(jnp.float32).sum(),
+                lambda *a: f(*a, *rest).astype(jnp.float32).sum(),
                 argnums=(0, 1, 2))(q, k, v)
+        return g
 
-        _jax_export.export(jax.jit(fwd), platforms=["tpu"])(q, kv, kv)
-        _jax_export.export(jax.jit(bwd), platforms=["tpu"])(q, kv, kv)
+    def qkv(bh, sq, sk, d):
+        return (jax.ShapeDtypeStruct((bh, sq, d), bf16),
+                jax.ShapeDtypeStruct((bh, sk, d), bf16),
+                jax.ShapeDtypeStruct((bh, sk, d), bf16))
 
-    # sliding-window variant (window bands engage the tile-skip path)
-    q = jnp.zeros((8, 1024, 128), jnp.bfloat16)
-    kv = jnp.zeros((8, 1024, 128), jnp.bfloat16)
-
-    def swa(q, k, v):
-        return _flash_bhsd(q, k, v, True, 1.0 / math.sqrt(128.0), False,
-                           None, None, 256)
-
-    def swa_bwd(q, k, v):
-        return jax.grad(
-            lambda *a: swa(*a).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-
-    _jax_export.export(jax.jit(swa), platforms=["tpu"])(q, kv, kv)
-    _jax_export.export(jax.jit(swa_bwd), platforms=["tpu"])(q, kv, kv)
-
-    # in-kernel key-padding mask variant
-    q = jnp.zeros((8, 1024, 128), jnp.bfloat16)
-    kv = jnp.zeros((8, 1024, 128), jnp.bfloat16)
-    km = jnp.zeros((8, 1, 1024), jnp.float32)
+    def plain(causal, d, bq=None, bk=None, window=0):
+        return lambda q, k, v: _flash_bhsd(
+            q, k, v, causal, 1.0 / math.sqrt(d), False, bq, bk, window)
 
     def masked(q, k, v, km):
         return _flash_call(q, k, v, None, km, False,
                            1.0 / math.sqrt(128.0), False, None, None, 0,
                            0.0)
 
-    def masked_bwd(q, k, v, km):
-        return jax.grad(
-            lambda *a: masked(*a, km).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-
-    _jax_export.export(jax.jit(masked), platforms=["tpu"])(q, kv, kv, km)
-    _jax_export.export(jax.jit(masked_bwd), platforms=["tpu"])(q, kv, kv,
-                                                              km)
-
-    # in-kernel dropout variant (counter-hash mask; uint32 VPU ops)
-    seed = jnp.zeros((2,), jnp.int32)
-
     def drop(q, k, v, seed):
         return _flash_bhsd_drop(q, k, v, seed, True,
                                 1.0 / math.sqrt(128.0), False, None, None,
                                 0, 0.1)
 
-    def drop_bwd(q, k, v, seed):
-        return jax.grad(
-            lambda *a: drop(*a, seed).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
+    return [
+        ("bh128_s1024_d128_causal", grad_of(plain(True, 128)),
+         qkv(128, 1024, 1024, 128)),
+        ("bh128_s1024_d128_causal_blk1024",
+         grad_of(plain(True, 128, 1024, 1024)), qkv(128, 1024, 1024, 128)),
+        ("bh768_s512_d64_full", grad_of(plain(False, 64)),
+         qkv(768, 512, 512, 64)),
+        ("bh8_s1024_d64_causal", grad_of(plain(True, 64)),
+         qkv(8, 1024, 1024, 64)),
+        ("bh4_s512x1024_d128_causal", grad_of(plain(True, 128)),
+         qkv(4, 512, 1024, 128)),
+        ("bh8_s1024_d128_window256",
+         grad_of(plain(True, 128, window=256)), qkv(8, 1024, 1024, 128)),
+        ("bh8_s1024_d128_keymask", grad_of(masked),
+         qkv(8, 1024, 1024, 128)
+         + (jax.ShapeDtypeStruct((8, 1, 1024), jnp.float32),)),
+        ("bh8_s1024_d128_dropout", grad_of(drop),
+         qkv(8, 1024, 1024, 128)
+         + (jax.ShapeDtypeStruct((2,), jnp.int32),)),
+    ]
 
-    _jax_export.export(jax.jit(drop), platforms=["tpu"])(q, kv, kv, seed)
-    _jax_export.export(jax.jit(drop_bwd), platforms=["tpu"])(q, kv, kv,
-                                                            seed)
+
+def check_lowering():
+    """Mosaic-lower every :func:`lowering_cases` entry for platform
+    'tpu' — runs on any host via jax.export, no chip needed."""
+    for _label, fn, specs in lowering_cases():
+        _jax_export.export(jax.jit(fn), platforms=["tpu"])(*specs)
 
 
 def register(platform="tpu", interpret=False):
@@ -829,5 +887,6 @@ def register(platform="tpu", interpret=False):
     # the lowering self-check travels with the kernel so the pre-flight
     # (ops.pallas.check_tpu_lowering) covers every registered kernel
     fn.check_lowering = check_lowering
+    fn.lowering_cases = lowering_cases
     registry.register_kernel("flash_attention", platform)(fn)
     return fn

@@ -32,6 +32,23 @@ elements for one seed), and the additive key-padding mask (``[b,1,sk]``
 Parity is proven in interpret mode against the XLA composite
 (tests/test_head_flash.py) and the dropout variant against the bhsd
 kernel's identical mask.
+
+What the chip's compiler takes (v5e, compiled for a described topology
+— tests/test_chip_compile.py; ``jax.export`` alone does not see either
+limit):
+
+- ``head_dim % 128 == 0`` only. A d=64 head slice of the all-heads
+  block is half a lane tile and Mosaic refuses the per-head store
+  (``unsupported shape cast vector<256x64xbf16> ->
+  vector<1x256x1x64xbf16>``), so the BERT-shape encoder is out of this
+  family; :func:`hb_flash` raises on it instead of handing Mosaic a
+  kernel it cannot build.
+- every head's state is resident and the per-head loop is unrolled, so
+  scoped VMEM (16 MiB) grows with the head count whatever the block:
+  256-wide blocks overflow from 12 heads, 64-wide ones at 32 heads.
+  The default block is 128 (12 heads x s1024 compiles); the 32-head
+  7B geometry fits only at 32-wide blocks, which starve the MXU, and
+  is not in the family's search shapes.
 """
 from __future__ import annotations
 
@@ -43,6 +60,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...framework.device import on_tpu
 from ...framework.jax_compat import export as _jax_export, tpu_compiler_params
 from .. import registry
 from . import search
@@ -51,7 +69,13 @@ from .flash_attention import (
     _unpack,
 )
 
-__all__ = ["hb_flash", "shape_key", "check_lowering", "register"]
+__all__ = ["hb_flash", "shape_key", "lowering_cases", "check_lowering",
+           "register"]
+
+
+# default (block_q, block_k) target: 256 overflows scoped VMEM in the
+# dk/dv kernel from 12 heads up (module docstring)
+_DEFAULT_BLOCK = 128
 
 
 def _hb_fwd_kernel(*refs, causal, scale, offset, n_kb, h, h_kv, window=0,
@@ -285,8 +309,8 @@ def _hb_fwd(q, k, v, causal, scale, interpret, block_q=None,
     Returns (out [b, sq, h, d], lse [b, sq, h, _LANES])."""
     b, sq, h, d = q.shape
     sk, h_kv = k.shape[1], k.shape[2]
-    block_q = block_q or _pick_block(sq, 256)
-    block_k = block_k or _pick_block(sk, 256)
+    block_q = block_q or _pick_block(sq, _DEFAULT_BLOCK)
+    block_k = block_k or _pick_block(sk, _DEFAULT_BLOCK)
     n_kb = sk // block_k
     grid = (b, sq // block_q, n_kb)
     kernel = functools.partial(
@@ -343,8 +367,8 @@ def _hb_bwd_impl(q, k, v, out, lse, g_out, causal, scale, interpret,
                  block_q, block_k, window, seed, dropout, kmask=None):
     b, sq, h, d = q.shape
     sk, h_kv = k.shape[1], k.shape[2]
-    block_q = block_q or _pick_block(sq, 256)
-    block_k = block_k or _pick_block(sk, 256)
+    block_q = block_q or _pick_block(sq, _DEFAULT_BLOCK)
+    block_k = block_k or _pick_block(sk, _DEFAULT_BLOCK)
     n_qb = sq // block_q
     n_kb = sk // block_k
     offset = sk - sq
@@ -475,6 +499,11 @@ def hb_flash(q, k, v, seed=None, kmask=None, causal=False, scale=None,
     layout transposes anywhere."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if not interpret and q.shape[-1] % _LANES:
+        raise ValueError(
+            f"hb_flash: head_dim {q.shape[-1]} is not a multiple of "
+            f"{_LANES}; Mosaic cannot store a per-head slice narrower "
+            f"than a lane tile (use flash_attention_kernel's bhsd path)")
     return _hb_call(q, k, v, seed, kmask, causal, scale, interpret,
                     block_q, block_k, window, dropout)
 
@@ -523,16 +552,14 @@ class HeadBatchFlashFamily(search.KernelFamily):
     vmem_budget = 12 * 2 ** 20  # leave headroom of the ~16 MB VMEM
 
     def shapes(self):
-        # (b, sq, sk, h, h_kv, d, causal): the bench-relevant geometries
-        # — headline 0.44B Llama, 7B-geometry legs, BERT-base encoder
-        return [
-            (8, 1024, 1024, 12, 12, 128, True),
-            (4, 1024, 1024, 32, 32, 128, True),
-            (64, 512, 512, 12, 12, 64, False),
-        ]
+        # (b, sq, sk, h, h_kv, d, causal): the 0.44B Llama proxy. The
+        # 32-head 7B geometry and the d=64 BERT encoder are NOT here:
+        # the chip's compiler refuses them (module docstring), so the
+        # search must never be able to write a row for them
+        return [(8, 1024, 1024, 12, 12, 128, True)]
 
     def smoke_shapes(self):
-        return [(2, 64, 64, 4, 2, 32, True)]
+        return [(2, 64, 64, 4, 2, 128, True)]
 
     def key(self, shape):
         b, sq, sk, h, h_kv, d, causal = shape
@@ -544,8 +571,15 @@ class HeadBatchFlashFamily(search.KernelFamily):
                 "d": d, "causal": causal}
 
     def candidates(self, shape):
+        """The VMEM prune is a cheap pre-filter, not a proof: a config
+        the chip's compiler still refuses fails the search's timing
+        step, and the harness writes rows only for configs it timed.
+        Nothing is appended when nothing fits — an empty space is an
+        error there, never a guess."""
         b, sq, sk, h, h_kv, d, causal = shape
         out = []
+        if d % _LANES:
+            return out
         for bq in (64, 128, 256, 512):
             if bq > sq or sq % bq:
                 continue
@@ -555,8 +589,6 @@ class HeadBatchFlashFamily(search.KernelFamily):
                 cand = {"block_q": bq, "block_k": bk}
                 if vmem_bytes(shape, cand) <= self.vmem_budget:
                     out.append(cand)
-        if not out:
-            out.append({"block_q": min(sq, 64), "block_k": min(sk, 64)})
         return out
 
     def _inputs(self, shape, dtype):
@@ -605,7 +637,7 @@ class HeadBatchFlashFamily(search.KernelFamily):
 
         if _tune.kernel_beats_composite(sq, sk, d, causal):
             bq, bk = _tune.best_blocks(sq, sk, d, causal)
-            interpret = jax.default_backend() == "cpu"
+            interpret = not on_tpu()
 
             def composite(q, k, v):
                 qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
@@ -637,39 +669,18 @@ search.register_family(HeadBatchFlashFamily())
 
 # -- lowering self-check + registry hookup ------------------------------------
 
-def check_lowering():
-    """Mosaic-lower fwd+bwd for platform 'tpu' at the contract shapes
-    (head-batched blocks: MHA d=128, GQA, BERT-shape d=64, and the
-    dropout + key-mask variants) — runs on any host via jax.export, no
-    chip needed. The round-5 negative result was exactly a lowering
-    failure this check exists to catch before a hardware run."""
-    shapes = [
-        (2, 512, 512, 8, 8, 128, True),
-        (2, 512, 512, 8, 4, 128, True),   # GQA in-tile grouping
-        (2, 512, 512, 12, 12, 64, False),  # BERT-base head_dim
-    ]
-    for b, sq, sk, h, h_kv, d, causal in shapes:
-        q = jnp.zeros((b, sq, h, d), jnp.bfloat16)
-        kv = jnp.zeros((b, sk, h_kv, d), jnp.bfloat16)
-        scale = 1.0 / math.sqrt(d)
-
-        def fwd(q, k, v, _c=causal, _s=scale):
-            return hb_flash(q, k, v, causal=_c, scale=_s)
-
-        def bwd(q, k, v, _c=causal, _s=scale):
-            return jax.grad(
-                lambda *a: hb_flash(*a, causal=_c, scale=_s).astype(
-                    jnp.float32).sum(),
-                argnums=(0, 1, 2))(q, k, v)
-
-        _jax_export.export(jax.jit(fwd), platforms=["tpu"])(q, kv, kv)
-        _jax_export.export(jax.jit(bwd), platforms=["tpu"])(q, kv, kv)
-
-    # key-padding mask + in-kernel dropout variants
-    q = jnp.zeros((2, 512, 8, 128), jnp.bfloat16)
-    kv = jnp.zeros((2, 512, 8, 128), jnp.bfloat16)
-    km = jnp.zeros((2, 1, 512), jnp.float32)
-    seed = jnp.zeros((2,), jnp.int32)
+def lowering_cases():
+    """``(label, fn, arg_specs)``: fwd+bwd (one ``jax.grad`` each — its
+    program holds the forward, dq and dk/dv kernels) for the two ends of
+    the feature set, so every in-kernel path lowers once: MHA +
+    additive key mask, non-causal; GQA in-tile grouping + in-kernel
+    dropout, causal. Two cases, not one per feature: each compiles for
+    the described chip in ~6 s (the per-head loop is unrolled), against
+    ~1 s for the other families' cases. :func:`check_lowering` lowers
+    them with ``jax.export``; ``tests/test_chip_compile.py`` compiles
+    the same list for a described v5e."""
+    sds = jax.ShapeDtypeStruct
+    bf16 = jnp.bfloat16
     scale = 1.0 / math.sqrt(128.0)
 
     def masked_bwd(q, k, v, km):
@@ -678,16 +689,30 @@ def check_lowering():
                                 scale=scale).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    def drop_bwd(q, k, v, seed):
+    def gqa_drop_bwd(q, k, v, seed):
         return jax.grad(
             lambda *a: hb_flash(*a, seed, causal=True, scale=scale,
                                 dropout=0.1).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    _jax_export.export(jax.jit(masked_bwd), platforms=["tpu"])(q, kv, kv,
-                                                               km)
-    _jax_export.export(jax.jit(drop_bwd), platforms=["tpu"])(q, kv, kv,
-                                                             seed)
+    q = sds((2, 512, 8, 128), bf16)
+    return [
+        ("b2_s512_h8_d128_keymask", masked_bwd,
+         (q, q, q, sds((2, 1, 512), jnp.float32))),
+        ("b2_s512_h8kv4_d128_causal_dropout", gqa_drop_bwd,
+         (q, sds((2, 512, 4, 128), bf16), sds((2, 512, 4, 128), bf16),
+          sds((2,), jnp.int32))),
+    ]
+
+
+def check_lowering():
+    """Mosaic-lower every :func:`lowering_cases` entry for platform
+    'tpu' — runs on any host via jax.export, no chip needed. It stops
+    before the Mosaic compile, which is where this family's two limits
+    (module docstring) show: the described-topology compile is the
+    check that counts."""
+    for _label, fn, specs in lowering_cases():
+        _jax_export.export(jax.jit(fn), platforms=["tpu"])(*specs)
 
 
 def register(platform="tpu"):
@@ -697,5 +722,6 @@ def register(platform="tpu"):
     op name."""
     fn = hb_flash
     fn.check_lowering = check_lowering
+    fn.lowering_cases = lowering_cases
     registry.register_kernel("flash_attention_headbatch", platform)(fn)
     return fn

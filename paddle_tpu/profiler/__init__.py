@@ -246,7 +246,7 @@ class Profiler:
 
     def _emit_monitor_counters(self):
         """Runtime-telemetry counters (`paddle_tpu.monitor`) as chrome-trace
-        ``ph:"C"`` counter events, so retraces / tunnel syncs / collective
+        ``ph:"C"`` counter events, so retraces / sync fences / collective
         bytes render as counter tracks on the same Perfetto timeline as the
         host events. No-op when the monitor is disabled."""
         from ..monitor import enabled as _mon_enabled, snapshot as _mon_snap

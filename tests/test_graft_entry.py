@@ -1,8 +1,11 @@
 """Driver-contract tests: __graft_entry__.entry / dryrun_multichip."""
+import os
 import sys
 
 import jax
 import pytest
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -14,7 +17,7 @@ def _clean_env():
 
 
 def _graft():
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, _ROOT)
     import __graft_entry__ as g
 
     return g
@@ -35,18 +38,19 @@ def test_dryrun_multichip_8():
 
 def test_bench_smoke_emits_one_json_line():
     """Driver contract: bench.py prints exactly one parseable JSON line
-    with the required keys, even in CPU smoke mode."""
+    with the required keys in an explicit CPU run (JAX_PLATFORMS=cpu),
+    and the line says which device it ran on."""
     import json
-    import os
     import subprocess
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
-        [sys.executable, "/root/repo/bench.py"], env=env, cwd="/root/repo",
-        capture_output=True, text=True, timeout=900)
+        [sys.executable, os.path.join(_ROOT, "bench.py")], env=env,
+        cwd=_ROOT, capture_output=True, text=True, timeout=900)
     lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
     assert len(lines) == 1, proc.stdout + proc.stderr
     rec = json.loads(lines[0])
     assert {"metric", "value", "unit", "vs_baseline"} <= set(rec)
     assert rec["value"] > 0
+    assert rec["platform"] == "cpu" and rec["device_kind"]

@@ -226,10 +226,15 @@ class TestCandidates:
             <= max(c["block_q"] for c in few)
         assert len(many) < len(few)
 
-    def test_headbatch_space_never_empty(self):
+    def test_headbatch_space_never_guesses(self):
+        """What the VMEM prune (or the d % 128 contract) rules out
+        leaves an EMPTY space — the search then refuses the shape; it is
+        never handed a config nobody checked (the old behaviour appended
+        a minimal one, which the chip's compiler refused at 32 heads)."""
         fam = search.FAMILIES["flash_headbatch"]
-        cands = fam.candidates((1, 64, 64, 64, 64, 128, True))
-        assert cands  # fallback minimal config survives any h
+        assert fam.candidates((1, 64, 64, 64, 64, 128, True)) == []
+        assert fam.candidates((64, 512, 512, 12, 12, 64, False)) == []
+        assert fam.candidates((8, 1024, 1024, 12, 12, 128, True))
 
     def test_paged_candidates_are_dead_strategies(self):
         fam = search.FAMILIES["paged_attention"]

@@ -2,12 +2,12 @@
 
 Covers the zero-overhead-when-off contract (no monitor callables on the
 dispatch hot path unless enabled), counter thread-safety under concurrent
-emit, instrumentation of jit retraces / tunnel syncs / collectives / RNG /
+emit, instrumentation of jit retraces / sync fences / collectives / RNG /
 AMP, the StepLogger JSONL sink (monotonic step ids, counter diffs), the
 hapi MonitorCallback, and the tools/monitor_report.py renderer — including
 the tier-1 smoke: PT_MONITOR-style 3-step training on the virtual 8-device
 mesh yields exactly 1 retrace for fixed shapes, 2 after a shape change, and
-zero tunnel syncs on CPU.
+zero sync fences on CPU.
 """
 import importlib.util
 import json
@@ -187,8 +187,8 @@ class TestInstrumentationSites:
 
         device_sync(jnp.ones((4,)))
         snap = monitor.snapshot()
-        assert snap["counters"]["tunnel/syncs"] == 1
-        assert snap["histograms"]["tunnel/sync_ms"]["count"] == 1
+        assert snap["counters"]["sync/fences"] == 1
+        assert snap["histograms"]["sync/fence_ms"]["count"] == 1
 
     def test_rng_key_splits(self, mon):
         from paddle_tpu.framework import random as rng
@@ -306,8 +306,8 @@ class TestStepLogger:
         end = lines[-1]
         assert end["event"] == "run_end" and end["steps"] == 3
         assert end["totals"]["counters"]["jit/retraces"] == 1
-        # CPU-only guard: no tunnel syncs during training
-        assert end["totals"]["counters"].get("tunnel/syncs", 0) == 0
+        # CPU-only guard: no sync fences during training
+        assert end["totals"]["counters"].get("sync/fences", 0) == 0
 
     def test_works_with_monitor_disabled(self, tmp_path):
         assert not monitor.enabled()
@@ -327,7 +327,7 @@ class TestStepLogger:
 class TestMeshSmoke:
     """Tier-1 smoke from the issue: PT_MONITOR-enabled 3-step training on
     the virtual 8-device mesh -> parseable JSONL, monotonic ids, 1 retrace
-    for fixed shapes (2 after a shape change), zero tunnel syncs; then the
+    for fixed shapes (2 after a shape change), zero sync fences; then the
     report CLI renders a summary from it."""
 
     @pytest.fixture
@@ -371,7 +371,7 @@ class TestMeshSmoke:
         assert sum(s.get("counters", {}).get("jit/retraces", 0)
                    for s in steps) == 2
         end = lines[-1]
-        assert end["totals"]["counters"].get("tunnel/syncs", 0) == 0
+        assert end["totals"]["counters"].get("sync/fences", 0) == 0
 
         report = _load_report_tool().main([path])
         assert "steps: 4" in report

@@ -593,8 +593,8 @@ class TestBenchHelpers:
         assert any(not np.array_equal(p1, p3) for (_, p1, _), (_, p3, _)
                    in zip(t1, t3))
 
-    def test_tunnel_probe_summarize(self):
-        probe = _load_by_path("ec_probe_t", "tools/exec_cache_tunnel_probe.py")
+    def test_chip_probe_summarize(self):
+        probe = _load_by_path("ec_probe_t", "tools/exec_cache_chip_probe.py")
         cold = {"metric": "m", "telemetry": {
             "compile_ms_total": 900.0, "exec_cache": {"serialized": 3}}}
         warm = {"metric": "m", "telemetry": {

@@ -12,7 +12,7 @@ Four proof layers:
   boundary, so the identity is exact, not approximate), preempted
   requests bill their off-lane time to ``preempted_ms``, and the
   attribution stays on with the monitor off.
-- **Span taxonomy** — queue-wait/prefill/round/finish spans land on the
+- **Span classes** — queue-wait/prefill/round/finish spans land on the
   ``req/<trace_id>`` and ``serve/rounds`` lanes with the documented
   cats; spec rollback rounds record exactly one COMPLETE verify span
   each (a rewound ``pool_len`` cannot leave an open span).
@@ -147,7 +147,7 @@ class TestAttribution:
             assert parts == pytest.approx(total, rel=1e-6, abs=1e-3)
 
 
-# -- span taxonomy ------------------------------------------------------------
+# -- span classes -------------------------------------------------------------
 
 class TestServingSpans:
     def test_request_lifecycle_spans(self, model, mon):

@@ -152,7 +152,7 @@ class TestInstrumentationSpans:
 
         device_sync(jnp.ones((4,)))
         spans = monitor.spans().snapshot()
-        syncs = [s for s in spans if s[0] == "tunnel/device_sync"]
+        syncs = [s for s in spans if s[0] == "sync/device_sync"]
         assert len(syncs) == 1
         assert syncs[0][1] == "sync" and syncs[0][2] == "sync_fences"
 
@@ -265,7 +265,7 @@ class TestFitTraceExport:
             hists = monitor.snapshot().get("histograms", {})
             blocked_ms = sum(
                 hists.get(h, {"sum": 0.0})["sum"]
-                for h in ("tunnel/sync_ms", "async/bound_wait_ms",
+                for h in ("sync/fence_ms", "async/bound_wait_ms",
                           "io/prefetch_wait_ms")
             ) + hists.get("jit/compile_ms", {"sum": 0.0})["sum"]
             assert blocked_ms > 0
@@ -320,7 +320,7 @@ class TestAttributionPass:
         trace = {"traceEvents": [
             ev("step/1", "step", 0, 20),
             ev("async/bound_wait", "fence_wait", 0, 10),
-            ev("tunnel/device_sync", "sync", 2, 8),
+            ev("sync/device_sync", "sync", 2, 8),
             ev("jit/step_dispatch", "dispatch", 12, 15),
         ]}
         path = str(tmp_path / "synt.json")
@@ -477,7 +477,7 @@ class TestWatchpoints:
         from paddle_tpu.utils.timing import device_sync
 
         fired = []
-        monitor.watchpoint("tunnel/syncs", 1, message="sync storm",
+        monitor.watchpoint("sync/fences", 1, message="sync storm",
                            callback=lambda n, v: fired.append(v))
         device_sync(jnp.ones((2,)))  # 1: at ceiling, no fire
         assert fired == []

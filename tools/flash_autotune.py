@@ -22,19 +22,11 @@ def main():
                     help="bh,sq,sk,d,causal tuples")
     args = ap.parse_args()
 
-    import jax
+    from paddle_tpu.framework.device import require_tpu
+    from paddle_tpu.utils.xla_cache import enable_compilation_cache
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    from bench import enable_compilation_cache
-
+    require_tpu("flash_autotune")  # wall-clock tuning needs the chip
     enable_compilation_cache()
-    backend = jax.default_backend()
-    print(f"flash_autotune: backend={backend}", flush=True)
-    if backend == "cpu":
-        print("flash_autotune: no TPU — tuning wall-clock on CPU is "
-              "meaningless; exiting", flush=True)
-        return 1
 
     from paddle_tpu.ops.pallas import autotune
 

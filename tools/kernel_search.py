@@ -55,22 +55,17 @@ def main():
             tempfile.mkdtemp(prefix="kernel_search_smoke_"),
             "kernel_tune.json")
 
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    from bench import enable_compilation_cache
+    from paddle_tpu.framework.device import platform, require_tpu
+    from paddle_tpu.utils.xla_cache import enable_compilation_cache
 
     enable_compilation_cache()
-    backend = jax.default_backend()
-    smoke = args.smoke or backend == "cpu"
-    print(f"kernel_search: backend={backend} smoke={smoke}",
+    smoke = args.smoke
+    if not smoke:
+        # wall-clock search on a CPU is meaningless: --smoke is the
+        # pipeline proof, anything else needs the chip
+        require_tpu("kernel_search")
+    print(f"kernel_search: platform={platform()} smoke={smoke}",
           file=sys.stderr, flush=True)
-    if backend == "cpu" and not args.smoke:
-        print("kernel_search: no TPU — wall-clock search on CPU is "
-              "meaningless; run with --smoke for the pipeline proof",
-              file=sys.stderr, flush=True)
-        return 1
 
     import paddle_tpu.ops.pallas  # noqa: F401 — registers the families
     from paddle_tpu.ops.pallas import search
