@@ -1,0 +1,7 @@
+"""90th percentile over requests of the mean gap between a request's
+output tokens."""
+from chiplib.common import request_quantile
+
+
+def read(obs):
+    return request_quantile(obs, "open", "tpot_ms", 0.9)
