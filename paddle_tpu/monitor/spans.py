@@ -14,12 +14,14 @@ Zero-overhead-when-off: instrumented modules carry a module-global
 unless :func:`paddle_tpu.monitor.enable` installed the recorder — off, the
 hot path pays one ``is None`` check and no recorder code runs.
 
-Clock contract: span timestamps are ``time.perf_counter()`` seconds — the
+Clock contract: ring timestamps are ``time.perf_counter()`` seconds — the
 same epoch the profiler's host events and ``ph:"C"`` counter tracks use
 (`profiler/__init__.py:_HostEventRecorder.emit`), so a merged chrome trace
 (`Profiler.export` or :func:`paddle_tpu.monitor.export_spans`) lines spans
-up with the op timeline and with xplane device traces captured in the same
-process.
+up with the op timeline. That is NOT the clock of an xplane device trace
+(``jax.profiler`` numbers its events from the start of its session):
+device alignment comes from :class:`Phase`, whose
+``jax.profiler.TraceAnnotation`` lands in the device trace's own file.
 
 Categories double as host-blocked-time attribution buckets
 (`tools/monitor_report.py --spans`): ``sync`` (transfer fences),
@@ -36,7 +38,9 @@ import os
 import threading
 import time
 
-__all__ = ["SpanRecorder", "ATTRIBUTION_CATEGORIES"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["SpanRecorder", "Phase", "ATTRIBUTION_CATEGORIES"]
 
 # the buckets tools/monitor_report.py --spans decomposes host time into;
 # order is the nesting priority (earlier wins an overlapping slice: a
@@ -76,6 +80,55 @@ class _Span:
         self._rec.record(self._name, self._cat, self._t0,
                          time.perf_counter(), lane=self._lane,
                          args=self._args)
+        return False
+
+
+class Phase:
+    """One phase boundary of an always-on host loop (the serving engine's
+    step), as ONE ``with`` per site that feeds three sinks:
+
+    - a ``jax.profiler.TraceAnnotation(name, **args)``, ALWAYS: it records
+      only while a profiler session is on, into the device trace's own
+      file and on its clock — that is what "tracing on" means for these
+      spans (``args`` have to be known when the phase opens);
+    - ``acc[key] += wall seconds``, always: plain floats a caller reads as
+      deltas with no profiler at all (``ServingEngine.counters``);
+    - ``ring.record(...)`` of the same interval when the module's
+      ``_spans`` slot is filled (``PT_MONITOR``), so the flight recorder
+      and the blackbox dump hold the phases too. The body may add what
+      it only knows at the end to ``.args`` for the ring record.
+
+    ``t0``/``t1`` are the ``perf_counter`` stamps, for a caller that needs
+    the boundary itself (the round's token-fetch end is every lane's
+    attribution mark)."""
+
+    __slots__ = ("_ann", "_acc", "_key", "_ring", "_name", "_cat",
+                 "_lane", "args", "t0", "t1")
+
+    def __init__(self, name, acc, key, ring=None, cat="phase", lane=None,
+                 **args):
+        self._ann = TraceAnnotation(name, **args)
+        self._acc = acc
+        self._key = key
+        self._ring = ring
+        self._name = name
+        self._cat = cat
+        self._lane = lane
+        self.args = args
+        self.t0 = self.t1 = None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._acc[self._key] += self.t1 - self.t0
+        if self._ring is not None:
+            self._ring.record(self._name, self._cat, self.t0, self.t1,
+                              lane=self._lane, args=self.args or None)
         return False
 
 

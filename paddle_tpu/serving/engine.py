@@ -53,12 +53,18 @@ callable is ever invoked; the always-on plain-int
 ``ServingEngine.counters`` and per-request latency attribution
 (``Request.queue_ms``/``prefill_ms``/``decode_ms``/``preempted_ms``,
 telescoped at the phase boundaries the engine already timestamps) feed
-the serving bench instead. With ``PT_MONITOR=1`` every request's
-journey lands in the flight recorder on its own ``req/<trace_id>``
-lane — queue/requeue waits (scheduler-side), prefill chunks with their
-prefix-cache hit/miss split, decode/verify rounds with draft/accept
-counts, preemptions, and a whole-journey finish span carrying the
-attribution breakdown (docs/OBSERVABILITY.md). On an engine raise the
+the serving bench instead. Every phase of :meth:`ServingEngine.step`
+(admit, prefill, first-token fetch, grow, draft, pack, dispatch, token
+fetch, emit) is one ``monitor/spans.Phase``: always a
+``jax.profiler.TraceAnnotation`` (recorded only while a profiler
+session is on, in the device trace's own file) and a float of wall
+seconds in ``counters``; a constant number a round, none per lane,
+token or chunk. With ``PT_MONITOR=1`` the phases land in the flight
+recorder too, next to every request's journey on its own
+``req/<trace_id>`` lane — queue/requeue waits (scheduler-side), prefill
+chunks with their prefix-cache hit/miss split, preemptions, and a
+whole-journey finish span carrying the attribution breakdown
+(docs/OBSERVABILITY.md). On an engine raise the
 blackbox postmortem (``monitor/blackbox.py``) serializes the last
 spans + scheduler state to ``serving_blackbox.json`` before the error
 propagates.
@@ -85,6 +91,7 @@ from ..models.generation import (
 from ..monitor import _register as _monitor_register
 from ..monitor import blackbox as _blackbox
 from ..monitor import live as _live_telemetry
+from ..monitor.spans import Phase
 from .kv_cache import BlockPool, blocks_needed
 from .scheduler import RUNNING, FCFSScheduler, Request
 from .speculative import NgramDrafter
@@ -501,7 +508,13 @@ class ServingEngine:
             "prefix_hit_tokens": 0, "prefix_miss_tokens": 0,
             "kv_read_tokens": 0, "kv_dense_read_tokens": 0,
             "kv_quant_writes": 0, "kv_quant_tokens": 0,
-            "decode_wall_s": 0.0,
+            # wall seconds per phase of step() (monitor/spans.Phase):
+            # they telescope to step_s up to the statements between
+            # them; dispatch_s + fetch_s is a round's launch-to-tokens
+            "step_s": 0.0, "admit_s": 0.0, "prefill_s": 0.0,
+            "first_fetch_s": 0.0, "grow_s": 0.0, "draft_s": 0.0,
+            "pack_s": 0.0, "dispatch_s": 0.0, "fetch_s": 0.0,
+            "emit_s": 0.0,
         }
         # postmortem hook: on an engine raise (or an external crash
         # site) the blackbox dump snapshots scheduler + request state
@@ -671,29 +684,41 @@ class ServingEngine:
                                  error=exc)
             raise
 
+    def _phase(self, name, key, cat="serving_phase", lane="serve/rounds",
+               **args):
+        """One phase of :meth:`step` (``monitor/spans.Phase``): a
+        ``serving/<name>`` annotation in any live ``jax.profiler``
+        session, its wall seconds in ``counters[key]``, and the ring
+        when ``PT_MONITOR`` filled the slot."""
+        return Phase("serving/" + name, self.counters, key, _spans, cat,
+                     lane, **args)
+
     def _step(self) -> bool:
         self._ensure_compiled()
         worked = False
-        while True:
-            admitted = self.scheduler.admit(limit=1)
-            if not admitted:
-                break
-            req = admitted[0]
-            worked = True
-            self.counters["admits"] += 1
-            m = _monitor
-            if m is not None:
-                now = time.perf_counter()
-                m.on_serving_admit(
-                    (now - req.t_submit) * 1e3 if req.t_submit else 0.0)
-            self._prefill(req)
-        if self.scheduler.has_running():
-            self._decode_round()
-            worked = True
-        lv = _live
-        if lv is not None:
-            # one engine step = one live window: roll + SLO watchdog
-            lv.on_engine_step()
+        with self._phase("step", "step_s"):
+            while True:
+                with self._phase("admit", "admit_s"):
+                    admitted = self.scheduler.admit(limit=1)
+                if not admitted:
+                    break
+                req = admitted[0]
+                worked = True
+                self.counters["admits"] += 1
+                m = _monitor
+                if m is not None:
+                    now = time.perf_counter()
+                    m.on_serving_admit(
+                        (now - req.t_submit) * 1e3 if req.t_submit
+                        else 0.0)
+                self._prefill(req)
+            if self.scheduler.has_running():
+                self._decode_round()
+                worked = True
+            lv = _live
+            if lv is not None:
+                # one engine step = one live window: roll + SLO watchdog
+                lv.on_engine_step()
         return worked
 
     def run(self) -> dict:
@@ -745,88 +770,93 @@ class ServingEngine:
         toks = req.prefill_tokens
         ctx = int(toks.size)
         cached = int(req.cached_len)
-        C = self.config.prefill_chunk
-        table = jnp.asarray(self._table_row(req))
-        sp = _spans
-        p_t0 = req._t_mark  # admission stamped it just before this call
-        nchunks = 0
-        tok = None
-        for start in range(cached, ctx, C):
-            c_t0 = time.perf_counter() if sp is not None else 0.0
-            piece = toks[start:start + C]
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, :piece.size] = piece
-            last_idx = ctx - 1 - start if start + C >= ctx else 0
-            (tok, self._kpool, self._vpool, self._kscale,
-             self._vscale) = self._prefill_exec(
-                self._params, self._kpool, self._vpool, self._kscale,
-                self._vscale, table, jnp.asarray(chunk),
-                jnp.int32(start), jnp.int32(ctx), jnp.int32(last_idx))
-            nchunks += 1
-            if sp is not None:
-                # enqueue wall only (no per-chunk host sync — the one
-                # sync per admission stays the first-token fetch below)
-                sp.record("serving/prefill_chunk", "serving_prefill",
-                          c_t0, time.perf_counter(),
-                          lane=f"req/{req.trace_id}",
-                          args={"request": req.request_id,
-                                "start": start,
-                                "tokens": min(C, ctx - start)})
-        req.pool_len = ctx
-        self.scheduler.publish_prefix(req)
-        self.counters["prefill_chunks"] += nchunks
-        self.counters["prefix_hit_tokens"] += cached
-        self.counters["prefix_miss_tokens"] += ctx - cached
-        if self.config.kv_int8:
-            # quantize-on-write accounting: program launches that
-            # quantized + the real (non-pad) tokens they wrote
-            self.counters["kv_quant_writes"] += nchunks
-            self.counters["kv_quant_tokens"] += ctx - cached
-        m = _monitor
-        if m is not None:
-            m.on_serving_prefill(nchunks)
-            pool = self.scheduler.pool
-            m.on_serving_prefix(cached, ctx - cached,
-                                pool.shared_count, pool.cold_count)
+        with self._phase("prefill", "prefill_s", "serving_prefill",
+                         f"req/{req.trace_id}", request=req.trace_id,
+                         hit_tokens=cached, miss_tokens=ctx - cached) as ph:
+            C = self.config.prefill_chunk
+            table = jnp.asarray(self._table_row(req))
+            sp = _spans
+            p_t0 = req._t_mark  # admission stamped it just before this call
+            nchunks = 0
+            tok = None
+            for start in range(cached, ctx, C):
+                c_t0 = time.perf_counter() if sp is not None else 0.0
+                piece = toks[start:start + C]
+                chunk = np.zeros((1, C), np.int32)
+                chunk[0, :piece.size] = piece
+                last_idx = ctx - 1 - start if start + C >= ctx else 0
+                (tok, self._kpool, self._vpool, self._kscale,
+                 self._vscale) = self._prefill_exec(
+                    self._params, self._kpool, self._vpool, self._kscale,
+                    self._vscale, table, jnp.asarray(chunk),
+                    jnp.int32(start), jnp.int32(ctx), jnp.int32(last_idx))
+                nchunks += 1
+                if sp is not None:
+                    # enqueue wall only (no per-chunk host sync — the one
+                    # sync per admission stays the first-token fetch below)
+                    sp.record("serving/prefill_chunk", "serving_prefill",
+                              c_t0, time.perf_counter(),
+                              lane=f"req/{req.trace_id}",
+                              args={"request": req.request_id,
+                                    "start": start,
+                                    "tokens": min(C, ctx - start)})
+            req.pool_len = ctx
+            self.scheduler.publish_prefix(req)
+            self.counters["prefill_chunks"] += nchunks
+            self.counters["prefix_hit_tokens"] += cached
+            self.counters["prefix_miss_tokens"] += ctx - cached
             if self.config.kv_int8:
-                m.on_serving_kv_quant(nchunks, ctx - cached,
-                                      self.kv_pool_bytes)
-        # recompute-refund: cached tokens on a re-admission are context
-        # the preemption forced us to rebuild but the prefix cache
-        # served back for free
-        refund = cached if req.output else 0
-        req.prefill_refunded_tokens += refund
-        first_tok = None
-        if not req.output:
-            first_tok = int(np.asarray(tok)[0])  # the TTFT host sync
-        end = time.perf_counter()
-        if p_t0 is not None:
-            req.prefill_ms += (end - p_t0) * 1e3
-            req._t_mark = end
-        if sp is not None:
-            sp.record("serving/prefill", "serving_prefill",
-                      p_t0 if p_t0 is not None else end, end,
-                      lane=f"req/{req.trace_id}",
-                      args={"request": req.request_id, "chunks": nchunks,
-                            "hit_tokens": cached,
-                            "miss_tokens": ctx - cached,
-                            "refunded_tokens": refund,
-                            "recompute": bool(req.output)})
-        if req.output:
-            return  # recompute path: the pending token is output[-1]
-        self._emit(req, first_tok, end)
+                # quantize-on-write accounting: program launches that
+                # quantized + the real (non-pad) tokens they wrote
+                self.counters["kv_quant_writes"] += nchunks
+                self.counters["kv_quant_tokens"] += ctx - cached
+            m = _monitor
+            if m is not None:
+                m.on_serving_prefill(nchunks)
+                pool = self.scheduler.pool
+                m.on_serving_prefix(cached, ctx - cached,
+                                    pool.shared_count, pool.cold_count)
+                if self.config.kv_int8:
+                    m.on_serving_kv_quant(nchunks, ctx - cached,
+                                          self.kv_pool_bytes)
+            # recompute-refund: cached tokens on a re-admission are context
+            # the preemption forced us to rebuild but the prefix cache
+            # served back for free
+            refund = cached if req.output else 0
+            req.prefill_refunded_tokens += refund
+            if sp is not None:  # what the ring's rollup span adds at its end
+                ph.args.update(chunks=nchunks, refunded_tokens=refund,
+                               recompute=bool(req.output))
+            if req.output:
+                end = time.perf_counter()
+            else:
+                with self._phase("first_token_fetch", "first_fetch_s") as f:
+                    first_tok = int(np.asarray(tok)[0])  # the TTFT host sync
+                end = f.t1
+            if p_t0 is not None:
+                req.prefill_ms += (end - p_t0) * 1e3
+                req._t_mark = end
+            if req.output:
+                return  # recompute path: the pending token is output[-1]
+            self._emit(req, first_tok, end)
 
     def _decode_round(self) -> None:
         sched = self.scheduler
-        # growth walks FCFS order so older requests claim blocks first;
-        # a victim preempted mid-walk is skipped by the state check
-        for req in sched.running():
-            if req.state == RUNNING:
-                sched.ensure_capacity(req, on_preempt=self._note_preempt)
-        act = sched.running()
+        with self._phase("grow", "grow_s"):
+            # growth walks FCFS order so older requests claim blocks
+            # first; a victim preempted mid-walk is skipped by the state
+            # check
+            for req in sched.running():
+                if req.state == RUNNING:
+                    sched.ensure_capacity(req,
+                                          on_preempt=self._note_preempt)
+            act = sched.running()
         if not act:
             return
-        drafts = self._draft(act) if self.spec_active else {}
+        drafts = {}
+        if self.spec_active:
+            with self._phase("draft", "draft_s", lanes=len(act)):
+                drafts = self._draft(act)
         if any(d.size for d in drafts.values()):
             self._verify_round(act, drafts)
         else:
@@ -857,6 +887,19 @@ class ServingEngine:
             drafts[id(req)] = d
         return drafts
 
+    def _launch(self, kind, program, operands, lanes):
+        """Run the round's program over the pools and fetch its tokens:
+        returns them as numpy with the stamp of the fetch's end — the
+        round's ONE host sync, and every lane's attribution mark."""
+        with self._phase("dispatch", "dispatch_s", kind=kind, lanes=lanes):
+            (out, self._kpool, self._vpool, self._kscale,
+             self._vscale) = program(
+                self._params, self._kpool, self._vpool, self._kscale,
+                self._vscale, *operands)
+        with self._phase("token_fetch", "fetch_s") as fetch:
+            out = np.asarray(out)
+        return out, fetch.t1
+
     def _verify_round(self, act, drafts) -> None:
         """One [L, k+1] verify step for every occupied lane: score the
         pending token + draft, accept each lane's longest prefix that
@@ -867,28 +910,29 @@ class ServingEngine:
         accepted write overwrites it."""
         L, M = self.config.max_lanes, self.blocks_per_lane
         K = self.config.spec_k
-        tables = np.zeros((L, M), np.int32)
-        cur = np.zeros((L,), np.int32)
-        toks = np.zeros((L, K + 1), np.int32)
-        wlim = np.zeros((L,), np.int32)
-        for req in act:
-            d = drafts.get(id(req), _EMPTY_DRAFT)
-            tables[req.lane, :len(req.blocks)] = req.blocks
-            cur[req.lane] = req.pool_len
-            toks[req.lane, 0] = req.output[-1]
-            if d.size:
-                toks[req.lane, 1:1 + d.size] = d
-            wlim[req.lane] = req.pool_len + 1 + d.size
-        t0 = time.perf_counter()
-        (pred, self._kpool, self._vpool, self._kscale,
-         self._vscale) = self._verify_exec(
-            self._params, self._kpool, self._vpool, self._kscale,
-            self._vscale, jnp.asarray(tables), jnp.asarray(cur),
-            jnp.asarray(toks), jnp.asarray(wlim))
-        preds = np.asarray(pred)  # the round's ONE host sync
-        now = time.perf_counter()
+        with self._phase("pack", "pack_s"):
+            tables = np.zeros((L, M), np.int32)
+            cur = np.zeros((L,), np.int32)
+            toks = np.zeros((L, K + 1), np.int32)
+            wlim = np.zeros((L,), np.int32)
+            for req in act:
+                d = drafts.get(id(req), _EMPTY_DRAFT)
+                tables[req.lane, :len(req.blocks)] = req.blocks
+                cur[req.lane] = req.pool_len
+                toks[req.lane, 0] = req.output[-1]
+                if d.size:
+                    toks[req.lane, 1:1 + d.size] = d
+                wlim[req.lane] = req.pool_len + 1 + d.size
+            operands = (jnp.asarray(tables), jnp.asarray(cur),
+                        jnp.asarray(toks), jnp.asarray(wlim))
+        preds, now = self._launch("verify", self._verify_exec, operands,
+                                  len(act))
+        with self._phase("emit", "emit_s") as ph:
+            self._accept(act, drafts, preds, now, ph)
+
+    def _accept(self, act, drafts, preds, now, ph) -> None:
+        M = self.blocks_per_lane
         c = self.counters
-        c["decode_wall_s"] += now - t0
         c["verify_steps"] += 1
         proposed = accepted = bonus = emitted = 0
         for req in act:
@@ -963,64 +1007,55 @@ class ServingEngine:
         lv = _live
         if lv is not None and proposed:
             lv.on_accept_rate(proposed, accepted)
-        sp = _spans
-        if sp is not None:
-            # recorded COMPLETE, after rollbacks/releases settled — a
-            # rewound pool_len can never leave an open round span
-            sp.record("serving/verify_round", "serving_decode", t0, now,
-                      lane="serve/rounds",
-                      args={"lanes": len(act), "proposed": proposed,
-                            "accepted": accepted, "bonus": bonus,
-                            "emitted": emitted})
+        if _spans is not None:
+            ph.args.update(lanes=len(act), proposed=proposed,
+                           accepted=accepted, bonus=bonus, emitted=emitted)
 
     def _plain_decode_round(self, act) -> None:
         L, M = self.config.max_lanes, self.blocks_per_lane
-        tables = np.zeros((L, M), np.int32)
-        cur = np.zeros((L,), np.int32)
-        last = np.zeros((L,), np.int32)
-        for req in act:
-            tables[req.lane, :len(req.blocks)] = req.blocks
-            cur[req.lane] = req.pool_len
-            last[req.lane] = req.output[-1]
-        t0 = time.perf_counter()
-        (tok, self._kpool, self._vpool, self._kscale,
-         self._vscale) = self._decode_exec(
-            self._params, self._kpool, self._vpool, self._kscale,
-            self._vscale, jnp.asarray(tables), jnp.asarray(cur),
-            jnp.asarray(last))
-        toks = np.asarray(tok)  # the round's ONE host sync
-        now = time.perf_counter()
-        c = self.counters
-        c["decode_wall_s"] += now - t0
-        c["decode_steps"] += 1
-        c["decoded_tokens"] += len(act)
-        # live-prefix KV slots the paged kernel reads this round vs the
-        # full-table slots the dense gather reads — the roofline byte
-        # model's inputs (benchmarks/serving_bench.py hbm_util delta)
-        c["kv_read_tokens"] += sum(r.pool_len + 1 for r in act)
-        c["kv_dense_read_tokens"] += len(act) * M * self.config.block_size
-        if self.config.kv_int8:
-            c["kv_quant_writes"] += 1
-            c["kv_quant_tokens"] += len(act)
-        m = _monitor
-        if m is not None:
-            # allocatable = free list + revivable cold LRU — the
-            # pre-sharing meaning of "free" (cold blocks are spare
-            # capacity, not occupancy)
-            m.on_serving_decode(len(act), self.scheduler.pool.allocatable)
+        with self._phase("pack", "pack_s"):
+            tables = np.zeros((L, M), np.int32)
+            cur = np.zeros((L,), np.int32)
+            last = np.zeros((L,), np.int32)
+            for req in act:
+                tables[req.lane, :len(req.blocks)] = req.blocks
+                cur[req.lane] = req.pool_len
+                last[req.lane] = req.output[-1]
+            operands = (jnp.asarray(tables), jnp.asarray(cur),
+                        jnp.asarray(last))
+        toks, now = self._launch("decode", self._decode_exec, operands,
+                                 len(act))
+        with self._phase("emit", "emit_s") as ph:
+            if _spans is not None:
+                ph.args.update(lanes=len(act), emitted=len(act))
+            c = self.counters
+            c["decode_steps"] += 1
+            c["decoded_tokens"] += len(act)
+            # live-prefix KV slots the paged kernel reads this round vs
+            # the full-table slots the dense gather reads — the roofline
+            # byte model's inputs (benchmarks/serving_bench.py hbm_util
+            # delta)
+            c["kv_read_tokens"] += sum(r.pool_len + 1 for r in act)
+            c["kv_dense_read_tokens"] += \
+                len(act) * M * self.config.block_size
             if self.config.kv_int8:
-                m.on_serving_kv_quant(1, len(act), self.kv_pool_bytes)
-        sp = _spans
-        if sp is not None:
-            sp.record("serving/decode_round", "serving_decode", t0, now,
-                      lane="serve/rounds",
-                      args={"lanes": len(act), "emitted": len(act)})
-        for req in act:
-            if req._t_mark is not None:
-                req.decode_ms += (now - req._t_mark) * 1e3
-                req._t_mark = now
-            req.pool_len += 1
-            self._emit(req, int(toks[req.lane]), now)
+                c["kv_quant_writes"] += 1
+                c["kv_quant_tokens"] += len(act)
+            m = _monitor
+            if m is not None:
+                # allocatable = free list + revivable cold LRU — the
+                # pre-sharing meaning of "free" (cold blocks are spare
+                # capacity, not occupancy)
+                m.on_serving_decode(len(act),
+                                    self.scheduler.pool.allocatable)
+                if self.config.kv_int8:
+                    m.on_serving_kv_quant(1, len(act), self.kv_pool_bytes)
+            for req in act:
+                if req._t_mark is not None:
+                    req.decode_ms += (now - req._t_mark) * 1e3
+                    req._t_mark = now
+                req.pool_len += 1
+                self._emit(req, int(toks[req.lane]), now)
 
     def _emit(self, req, tok: int, now: float) -> None:
         req.output.append(tok)
