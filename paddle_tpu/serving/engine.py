@@ -245,7 +245,10 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
     lane's block at ``pos`` (writes at positions >= ``wlimit[b]`` — pad
     tail of a final prefill chunk, idle decode lanes — are redirected to
     null block 0 so they can never clobber live KV), then attend over
-    the lane's whole gathered table. Layer math is
+    the lane's whole gathered table: every table slot, live or not, read
+    in one gather from the stacked pool by (layer, block) — no value of
+    one layer's pool shape is produced (tests/test_chip_compile.py holds
+    the compiled programs to that). Layer math is
     ``models/generation.py:_block`` on the pooled layout.
 
     ``kscale``/``vscale`` are the int8 mode's paired fp32 scale pools
@@ -302,7 +305,9 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
         if paged and s == 1:
             # Pallas paged read: gather straight from the pool via the
             # block table, touching only each lane's live prefix — the
-            # dense kp[li][tables] gather below reads every table slot
+            # dense gather below reads every table slot.
+            # (kp[li] here still hands the kernel a copy of the layer's
+            # whole pool; no cell engages this branch — PERF.md 7)
             interp = not on_tpu()
             if quant:
                 from ..ops.pallas.paged_attention import \
@@ -321,15 +326,28 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
                     pos[:, 0], window=cfg.sliding_window,
                     dead=paged_dead, interpret=interp)[:, None]
         else:
-            kc = kp[li][tables].reshape(b, M * B, nkv, d)
-            vc = vp[li][tables].reshape(b, M * B, nkv, d)
+            # ONE gather per pool on the stacked pool, by (layer, block):
+            # kp[li][tables] makes the TPU materialise kp[li], the
+            # layer's whole pool, before every gather. Which of the two
+            # forms without it follows the pool's dtype, as the chip
+            # ran them (PERF.md section 6, PR 25): bf16 pools flattened
+            # over (layer, block), int8 pools and their scales indexed
+            # by the pair
             if quant:
                 from ..quantization import dequantize_kv
 
                 kc = dequantize_kv(
-                    kc, ks[li][tables].reshape(b, M * B, nkv), dt)
+                    kp[li, tables].reshape(b, M * B, nkv, d),
+                    ks[li, tables].reshape(b, M * B, nkv), dt)
                 vc = dequantize_kv(
-                    vc, vs[li][tables].reshape(b, M * B, nkv), dt)
+                    vp[li, tables].reshape(b, M * B, nkv, d),
+                    vs[li, tables].reshape(b, M * B, nkv), dt)
+            else:
+                rows = tables + li * kp.shape[1]
+                kc = kp.reshape(-1, B, nkv, d)[rows].reshape(
+                    b, M * B, nkv, d)
+                vc = vp.reshape(-1, B, nkv, d)[rows].reshape(
+                    b, M * B, nkv, d)
             out = _attend_lanes(q, kc, vc, pos, nh, nkv,
                                 sliding_window=cfg.sliding_window)
         x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])

@@ -8,15 +8,17 @@ that is described and not attached: every ``lowering_cases()`` entry of
 every registered kernel — the shapes each ``check_lowering`` lists,
 which include the main path's widths (Llama-2-7B training attention,
 the 7B engine's paged read, BERT-base) — is compiled for one chip of a
-``v5e:2x2`` topology, one parametrised case each. Nothing runs, so this
-says nothing about results or speed, and it is never reported as a chip
-run.
+``v5e:2x2`` topology, one parametrised case each. So are the serving
+engine's three programs, held to what their K/V read may compile to.
+Nothing runs, so this says nothing about results or speed, and it is
+never reported as a chip run.
 
 The persistent compile cache is off around these compiles: an entry
 written for a described chip cannot be read back without one, and the
 next run would warn and compile again.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
 
@@ -133,3 +135,78 @@ def test_headbatch_refuses_what_mosaic_refuses():
         head_flash.hb_flash(q, q, q, causal=False)
     fam = search.FAMILIES["flash_headbatch"]
     assert all(shape[5] % 128 == 0 for shape in fam.shapes())
+
+
+# -- the serving engine's programs --------------------------------------------
+
+_POOL = (37, 16, 2, 64)  # [num_blocks, block, kv_heads, head_dim]: no
+#                          other value of the programs has this shape
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """A 3-layer bf16 engine per pool dtype whose layer pool is ``_POOL``
+    (scales: its first three dims), built once — on the CPU, for its
+    shapes."""
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    nb, block, nkv, d = _POOL
+    model = LlamaForCausalLM(LlamaConfig.tiny(
+        num_hidden_layers=3, hidden_size=4 * d, num_attention_heads=4,
+        num_key_value_heads=nkv, intermediate_size=512, dtype="bfloat16"))
+    for p in model.parameters():  # fp32 init, served in bf16
+        p._data = p._data.astype("bfloat16")
+    model.eval()
+    return {kv_int8: ServingEngine(model, ServingConfig(
+        max_lanes=4, block_size=block, num_blocks=nb, prefill_chunk=32,
+        max_seq_len=5 * block, kv_int8=kv_int8))
+        for kv_int8 in (False, True)}
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+def test_engine_program_never_holds_one_layers_pool(
+        topo, engines, kind, kv_int8):
+    """The K/V read gathers from the STACKED pool by (layer, block).
+    Indexing the layer first (``kp[li][tables]``) compiles, for the
+    chip, to a fusion that writes out that layer's whole pool before the
+    gather reads it — in every layer of every call (PERF.md section 6,
+    PR 25). So: in the engine's programs as the chip's compiler leaves
+    them (lowered as ``ServingEngine._ensure_compiled`` lowers them on
+    the chip, pools donated), no instruction's result has one layer's
+    pool shape (nor, in int8 mode, one layer's scale-pool shape)."""
+    import paddle_tpu.serving.engine as E
+
+    eng = engines[kv_int8]
+    assert eng._kpool.shape[1:] == _POOL
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    cfg = eng.config
+    L, M = cfg.max_lanes, eng.blocks_per_lane
+    fn, rest = {
+        "decode": (E._decode_step, (i32(L, M), i32(L), i32(L))),
+        "verify": (E._verify_step,
+                   (i32(L, M), i32(L), i32(L, cfg.spec_k + 1), i32(L))),
+        "prefill": (E._prefill_chunk,
+                    (i32(1, M), i32(1, cfg.prefill_chunk), i32(), i32(),
+                     i32())),
+    }[kind]
+    pools = jax.tree_util.tree_map(spec, (
+        eng._params, eng._kpool, eng._vpool, eng._kscale, eng._vscale))
+    text = jax.jit(
+        fn, static_argnames=("cfg",),
+        donate_argnums=(1, 2, 3, 4) if kv_int8 else (1, 2),
+    ).lower(*pools, *rest, cfg=eng._gcfg).compile().as_text()
+    nb, block, nkv, d = _POOL
+    held = re.compile(rf"= \w+\[{nb},{block},{nkv}(,{d})?\]")
+    lines = [ln.strip()[:200] for ln in text.splitlines()
+             if held.search(ln)]
+    assert not lines, "\n".join(lines[:6])
+    assert f"[3,{nb},{block},{nkv},{d}]" in text  # the stacked pool is

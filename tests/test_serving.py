@@ -929,6 +929,115 @@ def test_engine_retires_collected_requests(model):
     assert list(out2) == ["r"] and len(out2["r"]) == 2
 
 
+def _parent_read_logits(params, kpool, vpool, tables, ids, pos, wlimit,
+                        cfg):
+    """Verify-style logits of `engine._pool_forward`'s layer math with
+    the K/V read as it was before ISSUE 25: the layer's pool sliced out
+    FIRST (``kp[li]``), then gathered by the block table."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import engine as E
+
+    nh, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    d = cfg.hidden_size // nh
+    b, s = ids.shape
+    B, M = kpool.shape[2], tables.shape[1]
+    x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
+    blk = jnp.take_along_axis(tables, jnp.minimum(pos // B, M - 1), axis=1)
+    ok = pos < wlimit[:, None]
+    blk, off = jnp.where(ok, blk, 0), jnp.where(ok, pos % B, 0)
+    for li in range(kpool.shape[0]):
+        lp = {k: params[k][li] for k in
+              ("ln1", "qkv", "o", "ln2", "gate_up", "down")}
+        qkv = E._mm(E._rms(x, lp["ln1"], cfg.rms_norm_eps), lp["qkv"])
+        q, k, v = jnp.split(qkv, [nh * d, nh * d + nkv * d], axis=-1)
+        q, k = E._rope_at(q.reshape(b, s, nh, d), k.reshape(b, s, nkv, d),
+                          pos, cfg.rope_theta)
+        kpool = kpool.at[li, blk, off].set(k)
+        vpool = vpool.at[li, blk, off].set(v.reshape(b, s, nkv, d))
+        out = E._attend_lanes(
+            q, kpool[li][tables].reshape(b, M * B, nkv, d),
+            vpool[li][tables].reshape(b, M * B, nkv, d), pos, nh, nkv)
+        x = x + E._mm(out.reshape(b, s, nh * d), lp["o"])
+        gate, up = jnp.split(E._mm(E._rms(x, lp["ln2"], cfg.rms_norm_eps),
+                                   lp["gate_up"]), 2, axis=-1)
+        x = x + E._mm(jax.nn.silu(gate) * up, lp["down"])
+    x = E._rms(x, params["norm"], cfg.rms_norm_eps)
+    return E._mm(x, params["lm_head"]).astype(jnp.float32)
+
+
+def test_engine_each_layer_reads_its_own_pool():
+    """The K/V read is ONE gather on the stacked pool by (layer, block)
+    (ISSUE 25). A 3-layer model whose layers' K/V differ by an order of
+    magnitude, a pool of 23 blocks (a multiple of nothing in the
+    [3, 7] table, the 4-token block or the depth): served tokens equal
+    generate()'s, and on a pool filled with per-layer-distinct values
+    the logits equal, bit for bit, those of the layer-first
+    ``kp[li][tables]`` read — which a read from a neighbouring layer or
+    block misses by far more than rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import engine as E
+
+    pt.seed(3)
+    m = LlamaForCausalLM(LlamaConfig.tiny(num_hidden_layers=3))
+    m.eval()
+    for blk, scale in zip(m.model.layers, (0.3, 1.0, 3.0)):
+        w = blk.self_attn.qkv_proj.weight
+        w._data = w._data * scale
+    eng = ServingEngine(m, ServingConfig(
+        max_lanes=3, block_size=4, num_blocks=23, prefill_chunk=8,
+        max_seq_len=28))
+    assert eng._kpool.shape[:3] == (3, 23, 4) and eng.blocks_per_lane == 7
+    rng = np.random.RandomState(25)
+    reqs = []
+    for _ in range(7):
+        plen, new = int(rng.randint(3, 14)), int(rng.randint(4, 13))
+        prompt = rng.randint(0, m.config.vocab_size,
+                             (plen,)).astype(np.int32)
+        reqs.append((eng.submit(prompt, max_new_tokens=new), prompt, new))
+    outs = eng.run()
+    for r, prompt, new in reqs:
+        np.testing.assert_array_equal(
+            outs[r.request_id], _reference(m, prompt, new),
+            err_msg=f"request {r.request_id} diverged from generate()")
+
+    # the same programs' forward on a pool no layer shares with another
+    shape = eng._kpool.shape
+    layer_of = np.arange(3, dtype=np.float32).reshape(3, 1, 1, 1, 1)
+    kpool = jnp.asarray(rng.randn(*shape).astype(np.float32)
+                        * (1 + 2 * layer_of) + layer_of)
+    vpool = jnp.asarray(rng.randn(*shape).astype(np.float32)
+                        * (1 + 2 * layer_of) - layer_of)
+    tables = jnp.asarray(
+        rng.permutation(np.arange(1, 23))[:21].reshape(3, 7), jnp.int32)
+    cur = jnp.asarray([0, 9, 22], jnp.int32)
+    toks = jnp.asarray(rng.randint(0, m.config.vocab_size, (3, 5)),
+                       jnp.int32)
+    pos = cur[:, None] + jnp.arange(5, dtype=jnp.int32)[None, :]
+    wlimit = cur + jnp.asarray([1, 5, 3], jnp.int32)
+    cfg, params = eng._gcfg, eng._params
+
+    def served(kp, vp, tb):
+        x, *_ = E._pool_forward(params, kp, vp, None, None, tb, toks, pos,
+                                wlimit, cfg)
+        x = E._rms(x, params["norm"], cfg.rms_norm_eps)
+        return E._mm(x, params["lm_head"]).astype(jnp.float32)
+
+    got = np.asarray(jax.jit(served)(kpool, vpool, tables))
+    want = np.asarray(jax.jit(_parent_read_logits, static_argnums=7)(
+        params, kpool, vpool, tables, toks, pos, wlimit, cfg))
+    np.testing.assert_array_equal(got, want)
+    # what the assertion above can see: the neighbouring layer's pool,
+    # or the neighbouring block, is a different answer altogether
+    for wrong in (served(jnp.roll(kpool, 1, axis=0),
+                         jnp.roll(vpool, 1, axis=0), tables),
+                  served(kpool, vpool, tables % 22 + 1)):
+        assert np.abs(np.asarray(wrong) - want).max() > 0.1
+
+
 def test_paged_kernel_parity_vs_attend_lanes():
     """The Pallas paged-attention read (interpret mode) reproduces the
     dense `_attend_lanes` gather over a ragged block pool — both dead-
