@@ -16,6 +16,13 @@ def _json(path):
         return json.load(f)
 
 
+def _module(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 class Files:
     """Where a run finds its files. The default is the repository's own
     BENCHMARK.json and this directory; a test passes its own."""
@@ -44,6 +51,26 @@ class Files:
         """The cell's comparison limits: ``limits/<workload>.json``."""
         return _json(os.path.join(self.data, "limits", workload + ".json"))
 
+    def path(self, kind, name):
+        """``<kind>/<name>.py``: this data directory's own (a test brings
+        its own), else the benchmark's."""
+        for base in (self.data, HERE):
+            path = os.path.join(base, kind, name + ".py")
+            if os.path.exists(path):
+                return path
+        raise SystemExit(f"no {kind}/{name}.py under {self.data} or {HERE}")
+
+    def arch(self, name):
+        """``arch/<name>.py``: what the harness knows of one architecture
+        (see ``arch/llama_dense.py`` for what it gives)."""
+        return _module(self.path("arch", name),
+                       "chip_arch_" + name.replace("-", "_"))
+
+    def reference(self, name):
+        """``reference/<name>.py``: a configuration's plain reference."""
+        return _module(self.path("reference", name),
+                       "chip_reference_" + name.replace("-", "_"))
+
 
 def cell(manifest, name):
     for w in manifest["workloads"]:
@@ -51,18 +78,6 @@ def cell(manifest, name):
             return w
     raise SystemExit(f"workload {name!r} is not in BENCHMARK.json "
                      f"({[w['name'] for w in manifest['workloads']]})")
-
-
-def _module(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def reference(name):
-    return _module(os.path.join(HERE, "reference", name + ".py"),
-                   "chip_reference_" + name.replace("-", "_"))
 
 
 def metric_reader(name):

@@ -10,19 +10,19 @@ import time
 
 import numpy as np
 
-from . import common, manifest, modelbuild
+from . import common, modelbuild
 from . import trace as trace_mod
 from . import traffic as traffic_mod
-from . import weights as W
 
 
-def build_engine(cfg, seed, hooks):
+def build_engine(arch, cfg, seed, hooks):
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
     s = cfg["serve"]
     layers = cfg["num_hidden_layers"]["serve"]
     model, specs, keys, _ = modelbuild.build(
-        cfg, layers, s["max_seq_len"], seed, **cfg.get("model_flags", {}))
+        arch, cfg, layers, s["max_seq_len"], seed,
+        **cfg.get("model_flags", {}))
     model.eval()
     # only what a deployer must choose is pinned; chunk, paged, spec,
     # spec_k and prefix_cache stay the program's defaults
@@ -263,21 +263,23 @@ def reference_gaps(ref, cfg, specs, keys, samples, control=False):
     position, from ONE float32 forward over prompt + served tokens, and
     the gap by which the served token's logit lies below the reference's
     best. The stack runs layer by layer, so one layer's float32 weights
-    are alive at a time. ``control``: the same positions through the
-    lower-precision reference, and the gap of ITS first choice."""
+    are alive at a time; each layer's leaves are the ones its
+    architecture's specs list for it, and the jitted walk compiles once
+    per distinct set of them (two kinds of layer: two programs).
+    ``control``: the same positions through the lower-precision
+    reference, and the gap of ITS first choice."""
     import jax
     import jax.numpy as jnp
 
     m = cfg["model"]
-    dtype = m["torch_dtype"]
     cap = cfg["serve"]["max_seq_len"]
-    spec_of = {(li, name): (i, shape, kind)
-               for i, (li, name, shape, kind) in enumerate(specs)}
+    names_of = {}
+    for i, (li, name, _, _) in enumerate(specs):
+        names_of.setdefault(li, {})[name] = i
 
-    def leaf(li, name):
-        i, shape, kind = spec_of[(li, name)]
-        return jax.jit(lambda k: W.make_leaf(k, shape, kind, dtype)
-                       .astype(jnp.float32))(jnp.uint32(keys[i]))
+    def leaves(li):
+        return {name: modelbuild.reference_leaf(cfg, specs[i], keys[i])
+                for name, i in names_of[li].items()}
 
     seqs = []
     for prompt, served in samples:
@@ -287,28 +289,24 @@ def reference_gaps(ref, cfg, specs, keys, samples, control=False):
         ids[: full.size] = full
         seqs.append({"ids": jnp.asarray(ids), "T": T,
                      "first": prompt.size - 1, "served": served})
-    layers = cfg["num_hidden_layers"]["serve"]
     with jax.default_matmul_precision("highest"):
-        embed = leaf(-1, "embed")
+        top = leaves(-1)
         chains = [False, True] if control else [False]
-        xs = {q: [embed[s["ids"]] for s in seqs] for q in chains}
-        del embed
-        fwd = {q: jax.jit(lambda x, lw, q=q: ref.layer_forward(
-            x, lw, m=m, quant=q)) for q in chains}
-        for li in range(layers):
-            lw = {name: leaf(li, name) for name in
-                  ("ln1", "qkv", "o", "ln2", "gate_up", "down")}
+        xs = {q: [top["embed"][s["ids"]] for s in seqs] for q in chains}
+        fwd = {q: jax.jit(lambda x, lw, li, q=q: ref.layer_forward(
+            x, lw, li=li, m=m, quant=q)) for q in chains}
+        for li in sorted(k for k in names_of if k >= 0):
+            lw = leaves(li)
             for q in chains:
-                xs[q] = [fwd[q](x, lw) for x in xs[q]]
+                xs[q] = [fwd[q](x, lw, jnp.int32(li)) for x in xs[q]]
             del lw
-        norm_w, head_w = leaf(-1, "norm"), leaf(-1, "lm_head")
 
-        def gaps_at(x, xq, rows, served, a, b):
+        def gaps_at(x, xq, rows, served, top):
             """At K positions: how far the served token's logit lies
             below the reference's best, and how far the control's own
             first choice does. Shapes are the buckets T and K alone, so
             a new seed compiles nothing new."""
-            logits = ref.head_logits(x[rows], a, b, m=m, quant=False)
+            logits = ref.head_logits(x[rows], top, m=m, quant=False)
             best = jnp.max(logits, -1)
 
             def below(tok):
@@ -317,7 +315,7 @@ def reference_gaps(ref, cfg, specs, keys, samples, control=False):
 
             if xq is None:
                 return below(served), None
-            lq = ref.head_logits(xq[rows], a, b, m=m, quant=True)
+            lq = ref.head_logits(xq[rows], top, m=m, quant=True)
             return below(served), below(jnp.argmax(lq, -1))
 
         gaps_at = jax.jit(gaps_at)
@@ -330,8 +328,7 @@ def reference_gaps(ref, cfg, specs, keys, samples, control=False):
             served[:n] = s["served"]
             gap, cgap = gaps_at(xs[False][si],
                                 xs[True][si] if control else None,
-                                jnp.asarray(rows), jnp.asarray(served),
-                                norm_w, head_w)
+                                jnp.asarray(rows), jnp.asarray(served), top)
             rec = {"gaps": np.asarray(gap)[:n], "n": n}
             if control:
                 rec["control_gaps"] = np.asarray(cgap)[:n]
@@ -353,7 +350,7 @@ def run(ctx):
     from paddle_tpu.distributed import env as env_mod
 
     env_mod.init_mesh(dp=1, devices=list(devices[:1]))
-    model, engine, specs, keys = build_engine(cfg, seed, hooks)
+    model, engine, specs, keys = build_engine(ctx["arch"], cfg, seed, hooks)
     jax.block_until_ready(jax.tree_util.tree_leaves(engine._params))  # ptlint: disable=PTL002
     items["model_weights_engine_s"] = time.perf_counter() - t
     items["bytes_in_use_after_engine"] = common.bytes_in_use(devices)
@@ -420,6 +417,7 @@ def run(ctx):
            "tokens_out": tokens, "requests": facts, "late_ms": late,
            "counters": counters, "rounds": rec.rounds if rec else [],
            "setup_s": setup_s, "model": cfg["model"], "lanes": lanes,
+           "arch": ctx["arch"],
            "layers": cfg["num_hidden_layers"]["serve"],
            "slo": mix.get("slo"), "device_kind": ctx["device"]["kind"],
            "read_path": ("pallas " + stats["paged_family"]
@@ -482,7 +480,7 @@ def run(ctx):
     env_mod.reset_env()
     common.drop_program_state()
     left = common.bytes_in_use(devices)
-    ref = manifest.reference(cfg["reference"])
+    ref = ctx["files"].reference(cfg["reference"])
     gaps = reference_gaps(ref, cfg, specs, keys, sample,
                           control=ctx.get("control", False))
     widest = max((float(g["gaps"].max()) for g in gaps), default=None)
@@ -499,7 +497,7 @@ def run(ctx):
     if ctx.get("control"):
         extra["control_gap"] = max(float(g["control_gaps"].max())
                                    for g in gaps)
-    common.note("compare", numbers=numbers,
+    common.note("compare", numbers=numbers, **ctx["compared_with"],
                 sampled_requests=len(sample),
                 served_tokens_compared=int(sum(g["n"] for g in gaps)),
                 longest_sequence=int(max((p.size + s.size
@@ -510,4 +508,5 @@ def run(ctx):
     obs["attempted"], obs["failed"] = len(facts), failed
     obs["memory_peak_bytes"] = peak
     obs["compare"] = {"widest": widest, **extra}
+    obs["compared"] = numbers
     return obs

@@ -8,7 +8,7 @@ import time
 
 import numpy as np
 
-from . import common, manifest, modelbuild
+from . import common, modelbuild
 from . import trace as trace_mod
 from . import weights as W
 
@@ -46,7 +46,7 @@ def init_mesh(devices):
     return 1
 
 
-def build_step(cfg, traffic, seed, hooks):
+def build_step(arch, cfg, traffic, seed, hooks):
     """The ONE object the check drives and the window times: model,
     optimizer and compiled step with its state."""
     import paddle_tpu as pt
@@ -54,7 +54,8 @@ def build_step(cfg, traffic, seed, hooks):
 
     layers = cfg["num_hidden_layers"]["train"]
     model, specs, keys, params = modelbuild.build(
-        cfg, layers, traffic["seq_len"], seed, **cfg.get("model_flags", {}))
+        arch, cfg, layers, traffic["seq_len"], seed,
+        **cfg.get("model_flags", {}))
     o = cfg["train"]
     opt = pt.optimizer.AdamW(
         learning_rate=o["learning_rate"], beta1=o["beta1"],
@@ -195,7 +196,8 @@ def run(ctx):
     t = time.perf_counter()
     replicas = init_mesh(devices)
     monitor.enable()  # set-up only: engagement and retrace counters
-    model, step, specs, keys, params = build_step(cfg, traffic, seed, hooks)
+    model, step, specs, keys, params = build_step(ctx["arch"], cfg, traffic,
+                                                  seed, hooks)
     jax.block_until_ready([p._data for p in params])  # ptlint: disable=PTL002
     items["model_and_weights_s"] = time.perf_counter() - t
     items["bytes_in_use_after_model"] = common.bytes_in_use(devices)
@@ -273,6 +275,7 @@ def run(ctx):
            "window_s": window_s, "step_ms": step_ms, "rows": rows,
            "seq": seq, "chips": len(devices), "setup_s": setup_s,
            "model": cfg["model"], "layers": cfg["num_hidden_layers"]["train"],
+           "arch": ctx["arch"],
            "device_kind": ctx["device"]["kind"], "traced_steps":
            min(n, traffic["traced_steps"]) if ctx["trace"] else 0}
     if ctx["trace"]:
@@ -286,7 +289,7 @@ def run(ctx):
     env_mod.reset_env()
     common.drop_program_state()
     left = common.bytes_in_use(devices)
-    ref = manifest.reference(cfg["reference"])
+    ref = ctx["files"].reference(cfg["reference"])
     refv = reference_first_steps(ref, cfg, specs, keys, tokens)
     rows_cmp = compare(prog, refv, ctx["limits"])
     if ctx.get("control"):
@@ -307,11 +310,12 @@ def run(ctx):
                 grad_norm_reference=dict(zip(names, refv[1])),
                 update_norm_by_step={"program": prog[3],
                                      "reference": refv[3]})
-    common.note("compare", numbers=rows_cmp + checks,
+    common.note("compare", numbers=rows_cmp + checks, **ctx["compared_with"],
                 program={"losses": prog[0]}, reference={"losses": refv[0]},
                 flash=flash, bytes_left_before_reference=left,
                 reference_s=time.perf_counter() - t)
     obs["correct"] = all(r["ok"] for r in rows_cmp + checks)
+    obs["compared"] = rows_cmp + checks
     obs["attempted"], obs["failed"] = n, 0
     obs["memory_peak_bytes"] = peak
     return obs

@@ -1,10 +1,12 @@
 """Seeded weights, made by the benchmark and handed to program and
 reference alike.
 
-A leaf is a counter-based hash of (key, element index): a murmur3
-finaliser over an iota, mapped to a uniform in [-a, a) with the leaf's
-standard deviation. No PRNG state and a dozen integer ops per element, so
-two billion bf16 weights take one short jitted call on the chip, and the
+Which leaves a model has, and of what shape, is its architecture's
+(``arch/<name>.py``: ``leaf_specs``). A leaf is a counter-based hash of
+(key, element index): a murmur3 finaliser over an iota, mapped to a
+uniform in [-a, a) with the leaf's standard deviation. No PRNG state and
+a dozen integer ops per element, so two billion bf16 weights take one
+short jitted call on the chip, and the
 reference can regenerate any single layer later (after the program's
 state is freed) and get the same numbers bit for bit, on any backend.
 
@@ -17,7 +19,7 @@ import zlib
 
 import numpy as np
 
-STD = 0.02  # every matrix; norm weights are 1 + small, see leaf_specs
+STD = 0.02  # every matrix; norm weights are 1 + small, see make_leaf
 _MASK = 0xFFFFFFFF
 
 
@@ -37,42 +39,18 @@ def leaf_key(seed: int, layer: int, name: str) -> int:
     return _mix(seed, layer + 1, zlib.crc32(name.encode()))
 
 
-def leaf_specs(model_cfg: dict, layers: int) -> list:
-    """(layer index or -1, name, shape, kind) for every leaf, in the
-    layout ``models/llama.py`` uses: W is [in, out]; q, k, v fused into
-    one ``qkv`` (q first), gate and up fused into ``gate_up`` (gate
-    first)."""
-    h = model_cfg["hidden_size"]
-    nh = model_cfg["num_attention_heads"]
-    nkv = model_cfg["num_key_value_heads"]
-    d = model_cfg["head_dim"]
-    ffn = model_cfg["intermediate_size"]
-    v = model_cfg["vocab_size"]
-    out = [(-1, "embed", (v, h), "matrix")]
-    for li in range(layers):
-        out += [
-            (li, "ln1", (h,), "norm"),
-            (li, "qkv", (h, (nh + 2 * nkv) * d), "matrix"),
-            (li, "o", (nh * d, h), "matrix"),
-            (li, "ln2", (h,), "norm"),
-            (li, "gate_up", (h, 2 * ffn), "matrix"),
-            (li, "down", (ffn, h), "matrix"),
-        ]
-    out += [(-1, "norm", (h,), "norm"), (-1, "lm_head", (h, v), "matrix")]
-    return out
-
-
 def hashed_uniform(key, shape):
-    """float32 uniform in [-1, 1) from (key, flat element index)."""
+    """float32 uniform in [-1, 1) from (key, row-major flat element
+    index), for a shape of any rank (experts stacked [E, in, out])."""
     import jax
     import jax.numpy as jnp
 
     n = int(np.prod(shape))
-    assert n < 2 ** 32, shape
-    idx = jax.lax.iota(jnp.uint32, n).reshape(shape) if len(shape) == 1 \
-        else (jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
-              * jnp.uint32(shape[1])
-              + jax.lax.broadcasted_iota(jnp.uint32, shape, 1))
+    assert n < 2 ** 32, shape  # the index is a uint32
+    idx = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+    for axis in range(1, len(shape)):
+        idx = idx * jnp.uint32(shape[axis]) \
+            + jax.lax.broadcasted_iota(jnp.uint32, shape, axis)
     x = idx ^ key
     x = (x ^ (x >> 16)) * jnp.uint32(0x85EBCA6B)
     x = (x ^ (x >> 13)) * jnp.uint32(0xC2B2AE35)

@@ -1,6 +1,7 @@
 """The decode round's share of its roofline: the least time the chip
 could take to read the weights once and every running lane's live K/V
-once (costs.decode_round_bytes at the published HBM rate), over the
+once (the architecture's ``decode_round_bytes`` at the published HBM
+rate: ``obs["arch"]``, the configuration's adapter), over the
 seconds in which an operation ran on the DEVICE during that round: the
 device events of the trace that fall inside the round's
 ``bench/engine_step`` annotation. Pure decode / verify rounds only;
@@ -9,7 +10,7 @@ scheduling, the token fetch) is not in it: that is ``decode_round_ms_p50``
 and the idle share."""
 import statistics
 
-from chiplib import costs, trace
+from chiplib import trace
 
 
 def read(obs):
@@ -26,7 +27,7 @@ def read(obs):
         if r["prefill_chunks"] or b <= 0 or not (r["decode_steps"]
                                                  + r["verify_steps"]):
             continue
-        least = costs.decode_round_bytes(
+        least = obs["arch"].decode_round_bytes(
             obs["model"], obs["layers"], r["live_kv_tokens"]) \
             / obs["peaks"]["hbm_bytes_per_s"]
         shares.append(100.0 * least / b)

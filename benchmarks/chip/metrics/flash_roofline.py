@@ -1,8 +1,9 @@
 """The flash-attention kernel's share of its roofline: the least time the
-chip could take for the calls' required FLOPs and bytes (costs.py; the
+chip could take for the calls' required FLOPs and bytes (the
+architecture's ``flash_fwd_bwd_flops`` / ``_bytes``, ``obs["arch"]``; the
 larger of the two bounds), over the kernel events' device time in the
 trace. Per device: each chip runs its own shard's calls."""
-from chiplib import costs, trace
+from chiplib import trace
 
 KERNEL = r"^\S+ custom-call( |$)"  # the op's own opcode, never an operand
 
@@ -15,11 +16,12 @@ def read(obs):
         return None
     steps = obs["traced_steps"]
     rows_per_chip = obs["rows"] / obs["chips"]
-    flops = (costs.flash_fwd_bwd_flops(obs["model"], obs["seq"],
-                                       rows_per_chip)
+    arch = obs["arch"]
+    flops = (arch.flash_fwd_bwd_flops(obs["model"], obs["seq"],
+                                      rows_per_chip)
              * obs["layers"] * steps)
-    nbytes = (costs.flash_fwd_bwd_bytes(obs["model"], obs["seq"],
-                                        rows_per_chip)
+    nbytes = (arch.flash_fwd_bwd_bytes(obs["model"], obs["seq"],
+                                       rows_per_chip)
               * obs["layers"] * steps)
     least = max(flops / obs["peaks"]["bf16_flops"],
                 nbytes / obs["peaks"]["hbm_bytes_per_s"])

@@ -140,10 +140,13 @@ def adamw(p, g, mom, var, step, o):
     return p, mom, var
 
 
-def train_step(params, mom, var, ids, labels, step, *, m, o, quant=False):
-    """One AdamW step. Returns (params, mom, var, loss, per-leaf gradient
-    norms)."""
-    loss, grads = jax.value_and_grad(loss_fn)(params, ids, labels, m, quant)
+def train_step(params, mom, var, ids, labels, step, *, m, o, quant=False,
+               loss=None):
+    """One AdamW step on ``loss`` (``loss_fn``, unless a reference that
+    builds on this one brings its own). Returns (params, mom, var, loss,
+    per-leaf gradient norms)."""
+    loss, grads = jax.value_and_grad(loss or loss_fn)(
+        params, ids, labels, m, quant)
     gnorm = jax.tree_util.tree_map(
         lambda g: jnp.sqrt(jnp.sum(g * g)), grads)
     out = jax.tree_util.tree_map(
@@ -154,12 +157,17 @@ def train_step(params, mom, var, ids, labels, step, *, m, o, quant=False):
     return pick(0), pick(1), pick(2), loss, gnorm
 
 
-def layer_forward(x, lw, *, m, quant=False):
-    """One layer on one sequence — the serving check runs the stack layer
-    by layer so that only one layer's float32 weights are alive."""
+def layer_forward(x, lw, *, li, m, quant=False):
+    """Layer ``li`` on one sequence — the serving check runs the stack
+    layer by layer so that only one layer's float32 weights are alive.
+    ``lw`` holds the leaves the specs list for that layer; ``li`` arrives
+    traced (every layer of this architecture is alike, so it is unused)."""
+    del li
     return block(x, lw, m, quant)
 
 
-def head_logits(x, norm_w, head_w, *, m, quant=False):
-    """Logits [K, vocab] of the rows x [K, hidden] (pre-final-norm)."""
-    return _mm(rms_norm(x, norm_w, m["rms_norm_eps"]), head_w, quant)
+def head_logits(x, top, *, m, quant=False):
+    """Logits [K, vocab] of the rows x [K, hidden] (pre-final-norm);
+    ``top`` holds the top-level leaves."""
+    return _mm(rms_norm(x, top["norm"], m["rms_norm_eps"]), top["lm_head"],
+               quant)

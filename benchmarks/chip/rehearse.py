@@ -42,19 +42,17 @@ def report(name, compiled):
                                   + ma.temp_size_in_bytes)}, flush=True)
 
 
-def reference_train(cfg, traffic, topo_devices):
+def reference_train(files, cfg, traffic, topo_devices):
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from chiplib import manifest
-    from chiplib import weights as W
-
-    ref = manifest.reference(cfg["reference"])
+    ref = files.reference(cfg["reference"])
     one = SingleDeviceSharding(topo_devices[0])
     layers = cfg["num_hidden_layers"]["train"]
     tree = {"layers": [{} for _ in range(layers)]}
-    for li, name, shape, _ in W.leaf_specs(cfg["model"], layers):
+    for li, name, shape, _ in files.arch(cfg["arch"]).leaf_specs(
+            cfg["model"], layers):
         s = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
         if li < 0:
             tree[name] = s
@@ -72,29 +70,27 @@ def reference_train(cfg, traffic, topo_devices):
                fn.lower(tree, tree, tree, ids, ids, n).compile())
 
 
-def reference_serve(cfg, topo_devices):
+def reference_serve(files, cfg, topo_devices):
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from chiplib import manifest
-    from chiplib import weights as W
-
-    ref = manifest.reference(cfg["reference"])
+    ref = files.reference(cfg["reference"])
     one = SingleDeviceSharding(topo_devices[0])
     m = cfg["model"]
     lw = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
-          for li, name, shape, _ in W.leaf_specs(m, 1) if li == 0}
+          for li, name, shape, _ in files.arch(cfg["arch"]).leaf_specs(m, 1)
+          if li == 0}
     T = cfg["serve"]["max_seq_len"]
     x = jax.ShapeDtypeStruct((T, m["hidden_size"]), jnp.float32,
                              sharding=one)
     with jax.default_matmul_precision("highest"):
         report(f"reference layer, one sequence of {T} (float32)",
-               jax.jit(lambda x, lw: ref.layer_forward(x, lw, m=m))
+               jax.jit(lambda x, lw: ref.layer_forward(x, lw, li=0, m=m))
                .lower(x, lw).compile())
 
 
-def program_train(cfg, traffic, topo_devices):
+def program_train(files, cfg, traffic, topo_devices):
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -108,7 +104,8 @@ def program_train(cfg, traffic, topo_devices):
 
     device_mod.platform = lambda: "tpu"  # the program's one rule, steered
     replicas = train.init_mesh(jax.devices())
-    model, step, specs, keys, params = train.build_step(cfg, traffic, 0, {})
+    model, step, specs, keys, params = train.build_step(
+        files.arch(cfg["arch"]), cfg, traffic, 0, {})
     step._ensure_state()
     # built on the CPU for its shapes; lowered against the described chip
     env_mod.reset_env()
@@ -159,11 +156,11 @@ def main():
     todo = args.only.split(",") if args.only else [
         "reference_train", "reference_serve", "program_train"]
     if "reference_train" in todo:
-        reference_train(cfg, traffic, topo.devices)
+        reference_train(files, cfg, traffic, topo.devices)
     if "reference_serve" in todo:
-        reference_serve(cfg, topo.devices)
+        reference_serve(files, cfg, topo.devices)
     if "program_train" in todo:
-        program_train(cfg, traffic, topo.devices)
+        program_train(files, cfg, traffic, topo.devices)
 
 
 if __name__ == "__main__":

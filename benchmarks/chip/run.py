@@ -6,7 +6,8 @@
 One process, no child. Without a TPU (or with fewer chips than the cell
 asks for) it exits non-zero and prints no result. The last line of
 standard output is the result object; earlier lines itemise set-up and
-print every number compared beside its limit.
+print every number compared beside its limit, which are also the result's
+last key (``compared``) and the last lines of standard error.
 
     python3 benchmarks/chip/run.py --workload <name> --check-seeds 1,2,3 \
         [--control 1] --seconds <s>
@@ -51,6 +52,7 @@ def run_cell(workload, seed, seconds, trace, *, files=None,
     cell = manifest.cell(man, workload)
     cfg = files.config(man, cell["config"])
     traffic = dict(files.traffic(cell["traffic"]), **(traffic_override or {}))
+    arch = files.arch(cfg["arch"])
     device, devices = common.device_info(cell["chips"], require_chip)
     cache_dir = common.enable_compile_cache()
     events = common.JaxEvents()
@@ -62,6 +64,13 @@ def run_cell(workload, seed, seconds, trace, *, files=None,
         "setup_items": {"imports_s": time.perf_counter()
                         - (t_start or T_START)},
         "t_start": t_start or T_START, "control": bool(control),
+        "files": files, "arch": arch,
+        # what `correct` rests on, printed in every run's compare line
+        "compared_with": {
+            "reference_file": os.path.relpath(
+                files.path("reference", cfg["reference"]), files.root),
+            "arch_file": os.path.relpath(files.path("arch", cfg["arch"]),
+                                    files.root)},
     }
     if traffic["kind"] == "train":
         from chiplib import train as job
@@ -114,6 +123,10 @@ def run_cell(workload, seed, seconds, trace, *, files=None,
                                 window_s=reduced["window_s"])
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+    # every number compared beside its limit, last in the line
+    result["compared"] = {r["name"]: {"value": r["value"],
+                                      "limit": r["limit"]}
+                          for r in obs["compared"]}
     return result
 
 
@@ -142,6 +155,9 @@ def main(argv=None):
         return checkmode.main(args)
     result = run_cell(args.workload, args.seed, args.seconds, args.trace)
     common.emit(result)
+    for name, r in result["compared"].items():  # standard error's last lines
+        print(f"compared {name} {r['value']} limit {r['limit']}",
+              file=sys.stderr)
     return 0
 
 

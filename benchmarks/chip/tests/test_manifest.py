@@ -12,6 +12,9 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# what a reference must have, by the kind of the cell's traffic
+PATH_NEEDS = {"train": ("train_step",),
+              "serve": ("layer_forward", "head_logits")}
 
 
 @pytest.fixture(scope="module")
@@ -102,30 +105,70 @@ def test_every_entry_resolves_to_files_by_name(man):
         cfg = files.config(man, c["name"])
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
         assert cfg["reduced"] == c["reduced"]
-        assert hasattr(manifest.reference(cfg["reference"]), "train_step")
+        # the reference's file is named where a reader can find it
+        assert cfg["reference_file"] == os.path.relpath(
+            files.path("reference", cfg["reference"]), manifest.ROOT)
+        assert any(cfg["reference_file"].startswith(p + "/")
+                   for p in man["paths"])
+        arch = files.arch(cfg["arch"])
+        for fn in ("build_model", "param_name", "leaf_specs"):
+            assert callable(getattr(arch, fn)), (cfg["arch"], fn)
     for w in man["workloads"]:
-        assert files.traffic(w["traffic"])["kind"] in ("train", "serve")
+        kind = files.traffic(w["traffic"])["kind"]
         assert files.limits(w["name"])
+        # a reference needs only the functions of the paths its
+        # configuration's cells use
+        cfg = files.config(man, w["config"])
+        ref = files.reference(cfg["reference"])
+        for fn in PATH_NEEDS[kind]:
+            assert callable(getattr(ref, fn)), (cfg["reference"], fn)
     for m in man["end_to_end"] + man["per_layer"]:
         if m["name"] != "setup_s":
             assert callable(manifest.metric_reader(m["name"]))
 
 
+WIDTHS = ("hidden_size", "intermediate", "latent", "state_size", "head_dim",
+          "_dim", "_rank", "experts_per_tok", "head_size", "expansion",
+          "proj")
+
+
+def check_widths(entry, cfg):
+    """No width is in ``reduced``, and every key that ``model`` (what the
+    chip runs) shares with the configuration's OWN ``published`` block
+    (the source's config.json) is equal unless ``reduced`` lists it;
+    what ``model`` adds to the published keys is listed in ``assumed``."""
+    assert len(entry["reduced"]) <= 16
+    for k in entry["reduced"]:
+        assert not any(w in k for w in WIDTHS), k
+    pub, m = cfg["published"], cfg["model"]
+    for k, v in m.items():
+        if k in pub:
+            assert v == pub[k] or k in entry["reduced"], (k, v, pub[k])
+        else:
+            assert k in cfg["assumed"], k
+    depth = cfg["num_hidden_layers"]
+    assert depth["published"] == pub["num_hidden_layers"]
+    assert all(v == depth["published"] for v in depth.values()) \
+        or "num_hidden_layers" in entry["reduced"]
+
+
 def test_no_width_is_reduced(man):
-    widths = ("hidden_size", "intermediate", "latent", "state_size",
-              "head_dim", "_dim", "_rank", "experts_per_tok", "head_size",
-              "expansion", "proj")
     for c in man["configs"]:
-        assert len(c["reduced"]) <= 16
-        for k in c["reduced"]:
-            assert not any(w in k for w in widths), k
-        cfg = manifest.Files().config(man, c["name"])
-        m = cfg["model"]  # Mistral-7B-v0.3's published config.json
-        assert (m["hidden_size"], m["intermediate_size"],
-                m["num_attention_heads"], m["num_key_value_heads"],
-                m["vocab_size"], m["rope_theta"]) == (
-                    4096, 14336, 32, 8, 32768, 1e6)
-        assert cfg["num_hidden_layers"]["published"] == 32
+        check_widths(c, manifest.Files().config(man, c["name"]))
+
+
+def test_a_changed_width_is_caught():
+    cfg = {"published": {"hidden_size": 8, "num_hidden_layers": 4},
+           "model": {"hidden_size": 8}, "assumed": {},
+           "num_hidden_layers": {"published": 4, "serve": 2}}
+    check_widths({"reduced": ["num_hidden_layers"]}, cfg)
+    with pytest.raises(AssertionError):
+        check_widths({"reduced": []}, cfg)  # a cut depth not listed
+    cfg["model"]["hidden_size"] = 4
+    with pytest.raises(AssertionError):
+        check_widths({"reduced": ["num_hidden_layers"]}, cfg)
+    with pytest.raises(AssertionError):  # and a width may not be listed
+        check_widths({"reduced": ["num_hidden_layers", "hidden_size"]}, cfg)
 
 
 def test_a_cell_is_added_by_files_and_one_entry_only(tmp_path):

@@ -54,7 +54,7 @@ def test_reference_rope_and_attention_by_hand():
 
     from chiplib import manifest
 
-    ref = manifest.reference("llama_dense")
+    ref = manifest.Files().reference("llama_dense")
     rng = np.random.default_rng(0)
     T, nh, nkv, d = 6, 4, 2, 8
     q = jnp.asarray(rng.normal(size=(T, nh, d)), jnp.float32)

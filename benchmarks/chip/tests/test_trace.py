@@ -105,7 +105,8 @@ def test_decode_roofline_is_over_device_time_inside_the_round():
     hbm = 1e6
     obs = {"job": "serve", "loop": "backlog", "rounds": rounds,
            "trace": {"devices": {0: dev}, "host": host}, "model": m,
-           "layers": 1, "peaks": {"hbm_bytes_per_s": hbm}}
+           "layers": 1, "peaks": {"hbm_bytes_per_s": hbm},
+           "arch": manifest.Files().arch("llama_dense")}
     read = manifest.metric_reader("decode_step_roofline")
     a = 100.0 * costs.decode_round_bytes(m, 1, 100) / hbm / 0.010
     b = 100.0 * costs.decode_round_bytes(m, 1, 300) / hbm / 0.020
