@@ -390,7 +390,7 @@ def main():
     # every pool as bf16; worker-mode routers hold no local pool, so
     # they derive the itemsize from the config they dispatched
     kv_int8 = bool(stats.get("kv_int8", False))
-    kpool = getattr(engine, "_kpool", None)
+    kpool = getattr(engine, "_pools", (None,))[0]
     kv_el_bytes = (int(kpool.dtype.itemsize) if kpool is not None
                    else 1 if kv_int8
                    else 2 if cfg.dtype == "bfloat16" else 4)
@@ -563,7 +563,7 @@ def main():
                    if r.t_first is not None]
         tok_bf, alloc_bf = kv_byte_model(
             cfg, st_bf["num_blocks"], st_bf["block_size"],
-            int(eng_bf._kpool.dtype.itemsize), 0)
+            int(eng_bf._pools[0].dtype.itemsize), 0)
         rec["kv_bf16"] = {
             "tokens_per_sec": round(toks_bf / wall_bf, 1)
             if wall_bf > 0 else 0.0,

@@ -402,7 +402,7 @@ class LogitRows:
         import jax.numpy as jnp
 
         from paddle_tpu.models import generation as G
-        from paddle_tpu.serving import engine as E
+        from paddle_tpu.serving.families import dense_gqa as E
 
         self.bucket = bucket
         cfg = G._GenCfg(model.config)

@@ -1,7 +1,7 @@
 """Flagship model families (the reference ships these via PaddleNLP/PaddleClas;
 the benchmark configs in BASELINE.md name Llama, BERT, ResNet, ERNIE —
 they live in-tree here so the framework is benchmarkable standalone)."""
-from . import bert, ernie, generation, llama  # noqa: F401
+from . import bert, ernie, generation, latent_moe, llama  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertForMaskedLM, BertForSequenceClassification, BertModel,
 )
@@ -10,6 +10,7 @@ from .ernie import (  # noqa: F401
     ErnieForSequenceClassification, ErnieModel,
 )
 from .generation import generate  # noqa: F401
+from .latent_moe import LatentMoEConfig, LatentMoEForCausalLM  # noqa: F401
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaForCausalLMPipe, LlamaModel,
 )
@@ -20,6 +21,7 @@ __all__ = [
     "bert", "BertConfig", "BertModel", "BertForMaskedLM",
     "BertForSequenceClassification",
     "generation", "generate",
+    "latent_moe", "LatentMoEConfig", "LatentMoEForCausalLM",
     "ernie", "ErnieConfig", "ErnieModel", "ErnieForPretraining",
     "ErnieForPretrainingPipe", "ErnieForSequenceClassification",
 ]

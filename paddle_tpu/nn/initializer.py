@@ -67,13 +67,29 @@ class Constant(Initializer):
 
 
 class Normal(Initializer):
+    """ONE compiled call draws, scales and casts: no float32 array of the
+    leaf's size is ever held, so a leaf of gigabytes (stacked expert
+    weights) can be born in its dtype beside a nearly full device. ``std``
+    0 fills with the mean at no cost (a model whose values a checkpoint or
+    a harness is about to replace)."""
+
     def __init__(self, mean=0.0, std=1.0):
         self.mean, self.std = mean, std
 
     def __call__(self, shape, dtype=None, key=None):
         d = dtype_mod.convert_dtype(dtype) if dtype else dtype_mod.get_default_dtype()
-        out = jax.random.normal(self._key(key), tuple(shape), jnp.float32)
-        return (out * self.std + self.mean).astype(d)
+        if not self.std:
+            return jnp.full(tuple(shape), self.mean, d)
+        return _normal(self._key(key), self.mean, self.std,
+                       shape=tuple(shape), dtype=jnp.dtype(d))
+
+
+def _normal_impl(key, mean, std, *, shape, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * std
+            + mean).astype(dtype)
+
+
+_normal = jax.jit(_normal_impl, static_argnames=("shape", "dtype"))
 
 
 class TruncatedNormal(Initializer):

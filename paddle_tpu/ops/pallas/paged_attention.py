@@ -371,7 +371,7 @@ class PagedAttentionFamily(search.KernelFamily):
         nh = nkv * g
 
         def composite(q, kpool, vpool, tables, pos):
-            from ...serving.engine import _attend_lanes
+            from ...serving.families.dense_gqa import _attend_lanes
 
             kc = kpool[tables].reshape(L, M * B, nkv, d)
             vc = vpool[tables].reshape(L, M * B, nkv, d)
@@ -425,7 +425,7 @@ class PagedAttentionInt8Family(PagedAttentionFamily):
 
         def composite(q, kpool, vpool, kscale, vscale, tables, pos):
             from ...quantization import dequantize_kv
-            from ...serving.engine import _attend_lanes
+            from ...serving.families.dense_gqa import _attend_lanes
 
             kc = dequantize_kv(
                 kpool[tables].reshape(L, M * B, nkv, d),

@@ -33,12 +33,12 @@ growth never retrace. Three compiled programs serve the whole lifetime:
 All three compile through :func:`paddle_tpu.jit.exec_cache.get_or_compile`
 (keyed on generation config, param avals, pool geometry, lane count and
 mesh), so a warm ``PT_EXEC_CACHE`` server start pays zero fresh XLA
-compiles. The attention/RoPE/MLP math reuses
-``models/generation.py``'s helpers (``_rms``/``_mm``/``_rope_at``) and
-mirrors its ``_attend`` line for line — engine outputs are
-token-identical to per-request ``generate()`` calls
-(tests/test_serving.py proves it, padding included, because masked
-slots contribute exactly-zero softmax weight).
+compiles. The programs themselves — the layer math, the cache a token
+takes in a layer, how the weights are collected — are the model's
+FAMILY's (``serving/families``: the dense grouped-query decoder whose
+outputs are token-identical to per-request ``generate()`` calls, the
+latent-attention sparse-expert decoder); this module is what every
+family shares and names no architecture.
 
 Reference lineage: the static-graph serving surface this replaces is
 `paddle_infer.Predictor` (`paddle/fluid/inference/api/
@@ -85,13 +85,11 @@ import numpy as np
 
 from ..framework.core import Tensor
 from ..framework.device import on_tpu
-from ..models.generation import (
-    _GenCfg, _collect_params, _mm, _rms, _rope_at,
-)
 from ..monitor import _register as _monitor_register
 from ..monitor import blackbox as _blackbox
 from ..monitor import live as _live_telemetry
 from ..monitor.spans import Phase
+from .families import family_for
 from .kv_cache import BlockPool, blocks_needed
 from .scheduler import RUNNING, FCFSScheduler, Request
 from .speculative import NgramDrafter
@@ -210,228 +208,6 @@ class ServingConfig:
                                  f"got {getattr(self, name)}")
 
 
-# -- compiled phases ----------------------------------------------------------
-
-def _attend_lanes(q, kc, vc, pos, nh, nkv, sliding_window=0):
-    """``models/generation.py:_attend`` with PER-TOKEN positions: q
-    [b, s, nh, d] against the gathered block slots kc/vc [b, L, nkv, d].
-    Slot ``l`` is visible to the query at absolute position ``p =
-    pos[b, t]`` iff ``l <= p`` — block tables lay a lane's positions out
-    in order, so slot index == absolute position for every allocated
-    slot, and unallocated/pad slots sit above every real ``p``. The math
-    (fp32 einsum, 1/sqrt(d), -1e30 mask, fp32 softmax/AV) mirrors
-    ``_attend`` exactly so masked slots carry exactly-zero weight and
-    engine outputs stay token-identical to ``generate()``."""
-    b, s, _, d = q.shape
-    L = kc.shape[1]
-    g = nh // nkv
-    qg = q.reshape(b, s, nkv, g, d)
-    logits = jnp.einsum("bskgd,blkd->bskgl", qg.astype(jnp.float32),
-                        kc.astype(jnp.float32)) / np.sqrt(d)
-    vis = jnp.arange(L)[None, None, :] <= pos[:, :, None]  # [b, s, L]
-    if sliding_window > 0:
-        vis &= jnp.arange(L)[None, None, :] > pos[:, :, None] \
-            - sliding_window
-    logits = jnp.where(vis[:, :, None, None, :], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bskgl,blkd->bskgd", p, vc.astype(jnp.float32))
-    return out.reshape(b, s, nh, d).astype(q.dtype)
-
-
-def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
-                  pos, wlimit, cfg, paged=False, paged_dead="clamp"):
-    """Forward ``ids`` [b, s] at absolute positions ``pos`` [b, s]
-    against the block pool: per layer, write each token's K/V into its
-    lane's block at ``pos`` (writes at positions >= ``wlimit[b]`` — pad
-    tail of a final prefill chunk, idle decode lanes — are redirected to
-    null block 0 so they can never clobber live KV), then attend over
-    the lane's whole gathered table: every table slot, live or not, read
-    in one gather from the stacked pool by (layer, block) — no value of
-    one layer's pool shape is produced (tests/test_chip_compile.py holds
-    the compiled programs to that). Layer math is
-    ``models/generation.py:_block`` on the pooled layout.
-
-    ``kscale``/``vscale`` are the int8 mode's paired fp32 scale pools
-    (``[layers, num_blocks, block_size, kv_heads]``; None in bf16 mode
-    — None is an empty pytree, so the bf16 jaxpr is byte-identical to
-    the pre-int8 program): writes quantize K/V per position through the
-    shared `quantization.quantize_kv` (scale writes ride the same
-    null-redirected ``blk``/``off``, null block included), reads
-    dequantize the gathered blocks before the same fp32 attention —
-    identical ops to ``generate(kv_int8=True)``'s round-trip, so the
-    two paths stay bit-equal. Returns
-    (x [b, s, hidden], kpool, vpool, kscale, vscale)."""
-    b, s = ids.shape
-    nh = cfg.num_attention_heads
-    nkv = cfg.num_key_value_heads or nh
-    d = cfg.hidden_size // nh
-    B = kpool.shape[2]
-    M = tables.shape[1]
-    dt = jnp.dtype(cfg.dtype)
-    quant = kscale is not None
-    x = params["embed"][ids].astype(dt)
-    idx = jnp.minimum(pos // B, M - 1)  # pad pos can run past the table
-    blk = jnp.take_along_axis(tables, idx, axis=1)
-    ok = pos < wlimit[:, None]
-    blk = jnp.where(ok, blk, 0)
-    off = jnp.where(ok, pos % B, 0)
-    n_layers = params["ln1"].shape[0]
-
-    def body(carry, li):
-        if quant:
-            x, kp, vp, ks, vs = carry
-        else:
-            x, kp, vp = carry
-            ks = vs = None
-        layer_p = {k: jax.tree_util.tree_map(lambda a: a[li], params[k])
-                   for k in
-                   ("ln1", "qkv", "o", "ln2", "gate_up", "down")}
-        h = _rms(x, layer_p["ln1"], cfg.rms_norm_eps)
-        qkv = _mm(h, layer_p["qkv"])
-        q, k, v = jnp.split(qkv, [nh * d, nh * d + nkv * d], axis=-1)
-        q = q.reshape(b, s, nh, d)
-        k = k.reshape(b, s, nkv, d)
-        v = v.reshape(b, s, nkv, d)
-        q, k = _rope_at(q, k, pos, cfg.rope_theta)
-        if quant:
-            from ..quantization import quantize_kv
-
-            k, k_s = quantize_kv(k)
-            v, v_s = quantize_kv(v)
-            ks = ks.at[li, blk, off].set(k_s)
-            vs = vs.at[li, blk, off].set(v_s)
-        kp = kp.at[li, blk, off].set(k)
-        vp = vp.at[li, blk, off].set(v)
-        if paged and s == 1:
-            # Pallas paged read: gather straight from the pool via the
-            # block table, touching only each lane's live prefix — the
-            # dense gather below reads every table slot.
-            # (kp[li] here still hands the kernel a copy of the layer's
-            # whole pool; no cell engages this branch — PERF.md 7)
-            interp = not on_tpu()
-            if quant:
-                from ..ops.pallas.paged_attention import \
-                    paged_attend_int8
-
-                out = paged_attend_int8(
-                    q.reshape(b, nh, d), kp[li], vp[li], ks[li],
-                    vs[li], tables, pos[:, 0],
-                    window=cfg.sliding_window, dead=paged_dead,
-                    interpret=interp)[:, None]
-            else:
-                from ..ops.pallas.paged_attention import paged_attend
-
-                out = paged_attend(
-                    q.reshape(b, nh, d), kp[li], vp[li], tables,
-                    pos[:, 0], window=cfg.sliding_window,
-                    dead=paged_dead, interpret=interp)[:, None]
-        else:
-            # ONE gather per pool on the stacked pool, by (layer, block):
-            # kp[li][tables] makes the TPU materialise kp[li], the
-            # layer's whole pool, before every gather. Which of the two
-            # forms without it follows the pool's dtype, as the chip
-            # ran them (PERF.md section 6, PR 25): bf16 pools flattened
-            # over (layer, block), int8 pools and their scales indexed
-            # by the pair
-            if quant:
-                from ..quantization import dequantize_kv
-
-                kc = dequantize_kv(
-                    kp[li, tables].reshape(b, M * B, nkv, d),
-                    ks[li, tables].reshape(b, M * B, nkv), dt)
-                vc = dequantize_kv(
-                    vp[li, tables].reshape(b, M * B, nkv, d),
-                    vs[li, tables].reshape(b, M * B, nkv), dt)
-            else:
-                rows = tables + li * kp.shape[1]
-                kc = kp.reshape(-1, B, nkv, d)[rows].reshape(
-                    b, M * B, nkv, d)
-                vc = vp.reshape(-1, B, nkv, d)[rows].reshape(
-                    b, M * B, nkv, d)
-            out = _attend_lanes(q, kc, vc, pos, nh, nkv,
-                                sliding_window=cfg.sliding_window)
-        x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])
-        h2 = _rms(x, layer_p["ln2"], cfg.rms_norm_eps)
-        gu = _mm(h2, layer_p["gate_up"])
-        gate, up = jnp.split(gu, 2, axis=-1)
-        x = x + _mm(jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
-                    * up, layer_p["down"])
-        if quant:
-            return (x, kp, vp, ks, vs), None
-        return (x, kp, vp), None
-
-    if quant:
-        (x, kpool, vpool, kscale, vscale), _ = jax.lax.scan(
-            body, (x, kpool, vpool, kscale, vscale),
-            jnp.arange(n_layers))
-    else:
-        (x, kpool, vpool), _ = jax.lax.scan(
-            body, (x, kpool, vpool), jnp.arange(n_layers))
-    return x, kpool, vpool, kscale, vscale
-
-
-def _prefill_chunk(params, kpool, vpool, kscale, vscale, table, ids,
-                   start, ctx_len, last_idx, *, cfg):
-    """One lane's prefill chunk: ``ids`` [1, C] at positions
-    [start, start+C); greedy-samples from position ``last_idx`` within
-    the chunk (the overall last real token on the final chunk; ignored
-    by the caller otherwise). Returns
-    (tok [1], kpool, vpool, kscale, vscale)."""
-    C = ids.shape[1]
-    pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
-    x, kpool, vpool, kscale, vscale = _pool_forward(
-        params, kpool, vpool, kscale, vscale, table, ids, pos,
-        jnp.reshape(ctx_len, (1,)), cfg)
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
-    logits = _mm(h, params["lm_head"]).astype(jnp.float32)
-    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
-            kscale, vscale)
-
-
-def _decode_step(params, kpool, vpool, kscale, vscale, tables, cur_len,
-                 last_tok, *, cfg, paged=False, paged_dead="clamp"):
-    """The shared decode step: every lane feeds its pending token at
-    position ``cur_len`` (write-then-attend, so the token sees itself
-    like ``generate()``'s step does) and greedy-samples the next. Idle
-    lanes (cur_len 0, table row 0) write to the null block and their
-    outputs are ignored host-side. ``paged`` (static) swaps the dense
-    gathered KV read for the Pallas paged-attention kernel. Returns
-    (tok [L], kpool, vpool, kscale, vscale)."""
-    pos = cur_len[:, None]
-    x, kpool, vpool, kscale, vscale = _pool_forward(
-        params, kpool, vpool, kscale, vscale, tables, last_tok[:, None],
-        pos, cur_len + 1, cfg, paged=paged, paged_dead=paged_dead)
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    logits = _mm(x[:, -1], params["lm_head"]).astype(jnp.float32)
-    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
-            kscale, vscale)
-
-
-def _verify_step(params, kpool, vpool, kscale, vscale, tables, cur_len,
-                 toks, wlimit, *, cfg):
-    """The speculative verify step: ``toks`` [L, k+1] holds each lane's
-    pending token (column 0) followed by its draft, at absolute
-    positions ``cur_len + j``. Writes at positions >= ``wlimit[b]`` (=
-    ``cur_len + 1 + draft_len``: the pad tail of a short/empty draft,
-    idle lanes) go to the null block, exactly like a prefill chunk's pad
-    tail — draft length is data, never shape. Write-then-attend per
-    layer means draft token ``j`` attends over slots ``<= cur_len + j``,
-    the same causal view plain decode would give it, so the returned
-    greedy argmaxes [L, k+1] are the tokens the decode step WOULD emit
-    after each draft prefix — the host's acceptance rule compares
-    drafts against them directly."""
-    S = toks.shape[1]
-    pos = cur_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    x, kpool, vpool, kscale, vscale = _pool_forward(
-        params, kpool, vpool, kscale, vscale, tables, toks, pos, wlimit,
-        cfg)
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    logits = _mm(x, params["lm_head"]).astype(jnp.float32)
-    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
-            kscale, vscale)
-
-
 # -- the engine ---------------------------------------------------------------
 
 class ServingEngine:
@@ -442,46 +218,25 @@ class ServingEngine:
 
     def __init__(self, model, config: ServingConfig | None = None,
                  drafter=None):
-        if getattr(model.config, "moe_num_experts", 0) > 1:
-            from ..framework.errors import UnimplementedError
-
-            raise UnimplementedError(
-                "ServingEngine does not decode MoE Llama configs yet "
-                "(same gap as models/generation.generate)")
         self.model = model
         self.config = config or ServingConfig()
         cfg = self.config
-        self._gcfg = _GenCfg(model.config)
-        self._params = _collect_params(model,
-                                       int8_weights=cfg.int8_weights)
+        # what differs between architectures — the cache a token takes,
+        # the collected parameters, the step programs — is the model's
+        # family's (serving/families); everything below is shared
+        fam = self._family = family_for(model, cfg)
+        self._gcfg = fam.gcfg
+        self._params = fam.params
         self.max_seq_len = int(cfg.max_seq_len
-                               or model.config.max_position_embeddings)
+                               or fam.max_position_embeddings)
         self.blocks_per_lane = blocks_needed(self.max_seq_len,
                                              cfg.block_size)
         num_blocks = int(cfg.num_blocks
                          or cfg.max_lanes * self.blocks_per_lane + 1)
-        nh = self._gcfg.num_attention_heads
-        nkv = self._gcfg.num_key_value_heads or nh
-        d = self._gcfg.hidden_size // nh
-        layers = self._params["ln1"].shape[0]
-        dt = jnp.int8 if cfg.kv_int8 else jnp.dtype(self._gcfg.dtype)
-        self._kpool = jnp.zeros(
-            (layers, num_blocks, cfg.block_size, nkv, d), dt)
-        self._vpool = jnp.zeros_like(self._kpool)
-        # int8 mode: paired per-position fp32 amax scales (null block
-        # included — masked writes land there like K/V pad writes do);
-        # None in bf16 mode so the compiled programs stay byte-identical
-        # to the pre-int8 engine (None is an empty pytree operand)
-        if cfg.kv_int8:
-            self._kscale = jnp.zeros(
-                (layers, num_blocks, cfg.block_size, nkv), jnp.float32)
-            self._vscale = jnp.zeros_like(self._kscale)
-        else:
-            self._kscale = self._vscale = None
-        self.kv_pool_bytes = int(
-            self._kpool.nbytes + self._vpool.nbytes
-            + (self._kscale.nbytes + self._vscale.nbytes
-               if cfg.kv_int8 else 0))
+        # the device state every step program threads through (the
+        # family's pools; an entry may be None), replaced after each call
+        self._pools = tuple(fam.make_pools(num_blocks, cfg.block_size))
+        self.kv_pool_bytes = fam.kv_pool_bytes(self._pools)
         self.scheduler = FCFSScheduler(
             BlockPool(num_blocks, cfg.block_size), cfg.max_lanes,
             self.blocks_per_lane, self.max_seq_len,
@@ -503,7 +258,9 @@ class ServingEngine:
         self.spec_active = bool(cfg.spec and cfg.spec_k > 0)
         self.drafter = drafter if drafter is not None \
             else (NgramDrafter() if self.spec_active else None)
-        self.paged_active = self._resolve_paged()
+        self.paged_active = fam.paged_active
+        self._paged_family = fam.paged_family
+        self._paged_dead = fam.paged_dead
         # always-on plain-int accounting (the serving bench's source of
         # truth; independent of the monitor like exec_cache._stats).
         # kv_read_tokens counts the LIVE prefix (what the paged kernel
@@ -533,6 +290,8 @@ class ServingEngine:
             "first_fetch_s": 0.0, "grow_s": 0.0, "draft_s": 0.0,
             "pack_s": 0.0, "dispatch_s": 0.0, "fetch_s": 0.0,
             "emit_s": 0.0,
+            # the family's own (fetched with the round's tokens)
+            **fam.counters,
         }
         # postmortem hook: on an engine raise (or an external crash
         # site) the blackbox dump snapshots scheduler + request state
@@ -541,39 +300,6 @@ class ServingEngine:
         # /statusz hook: same weak-provider pattern for the live
         # exporter's debug page (stats() is plain-int and read-only)
         _live_telemetry.register_status("serving_engine", self.stats)
-
-    def _resolve_paged(self) -> bool:
-        """Decode read-path selection (ServingConfig.paged): forced
-        on/off, or ``auto`` = engaged only on a measured-faster
-        tune-table row for this geometry on this device (the
-        measurement-first convention — no row, no flip). Which FAMILY
-        is consulted follows the pool dtype: ``paged_attention`` for
-        bf16 pools, ``paged_attention_int8`` (the quantized-gather
-        variant) when ``kv_int8`` — an int8 engine never engages on a
-        bf16 row or vice versa (``self._paged_family`` is what the
-        bench/guard surface reports). Also resolves
-        ``self._paged_dead``: the row's WINNING dead-iteration strategy
-        — engaging the measured configuration, not the default —
-        falling back to ``"clamp"`` when forced on with no row."""
-        from ..ops.pallas import paged_attention as _pa
-        from ..ops.pallas import search as _ksearch
-
-        nh = self._gcfg.num_attention_heads
-        nkv = self._gcfg.num_key_value_heads or nh
-        d = self._gcfg.hidden_size // nh
-        key = _pa.family_key(self.config.block_size, nkv, nh // nkv, d,
-                             window=self._gcfg.sliding_window)
-        self._paged_family = ("paged_attention_int8"
-                              if self.config.kv_int8
-                              else "paged_attention")
-        cfg_row = _ksearch.best_config(self._paged_family, key) or {}
-        self._paged_dead = cfg_row.get("dead", "clamp")
-        mode = self.config.paged
-        if mode == "on":
-            return True
-        if mode == "off":
-            return False
-        return _ksearch.decide(self._paged_family, key)
 
     # -- intake --------------------------------------------------------------
 
@@ -610,75 +336,65 @@ class ServingEngine:
             return
         from ..jit import exec_cache
 
-        cfgv = self.config
+        cfgv, fam = self.config, self._family
         L, M, C = cfgv.max_lanes, self.blocks_per_lane, cfgv.prefill_chunk
         i32 = jnp.int32
         # donation halves pool HBM traffic; XLA:CPU can't donate these
-        # and would warn per call. int8 mode donates the scale pools too
-        # — they churn write-for-write with the K/V pools.
+        # and would warn per call. Which operands churn write-for-write
+        # with the cache (int8 mode: the scale pools too) is the family's
         donate = on_tpu()
-        kw = {"static_argnames": ("cfg",)}
-        if donate:
-            kw["donate_argnums"] = (1, 2, 3, 4) if cfgv.kv_int8 \
-                else (1, 2)
-        pspec = jax.ShapeDtypeStruct(self._kpool.shape, self._kpool.dtype)
-        sspec = None if self._kscale is None else \
-            jax.ShapeDtypeStruct(self._kscale.shape, self._kscale.dtype)
+        pools = tuple(None if a is None
+                      else jax.ShapeDtypeStruct(a.shape, a.dtype)
+                      for a in self._pools)
+        fam_key = fam.exec_key(self._pools) if exec_cache.enabled() else None
 
         def key(kind, **extra):
-            if not exec_cache.enabled():
+            if fam_key is None:
                 return None
-            k = {"kind": kind, "gen_cfg": self._gcfg._key(),
-                 "params": [exec_cache.array_spec(a) for a in
-                            jax.tree_util.tree_leaves(self._params)],
-                 "pool": (tuple(int(x) for x in self._kpool.shape),
-                          str(self._kpool.dtype)),
-                 "donate": donate,
-                 "mesh": exec_cache.mesh_spec(), **extra}
-            if cfgv.kv_int8:
-                # the pool dtype above already splits int8 from bf16
-                # entries; the explicit marker + scale spec make the
-                # cache key self-describing (meta sidecar, audits)
-                k["kv_int8"] = True
-                k["scale"] = (tuple(int(x) for x in self._kscale.shape),
-                              str(self._kscale.dtype))
-            return k
+            return {"kind": kind, **fam_key, "donate": donate,
+                    "mesh": exec_cache.mesh_spec(), **extra}
 
-        dkw = dict(kw)
-        dkw["static_argnames"] = ("cfg", "paged", "paged_dead")
-        dec = jax.jit(_decode_step, **dkw)
+        def jitted(kind):
+            fn, static = fam.program(kind)
+            kw = {"static_argnames": tuple(static)}
+            if donate:
+                kw["donate_argnums"] = fam.donate_argnums
+            return jax.jit(fn, **kw), static
+
+        def extra(static):  # what of a program's statics its key names
+            return {k: v for k, v in static.items() if k != "cfg"}
+
+        dec, dstatic = jitted("decode")
         self._decode_exec = exec_cache.get_or_compile(
-            key("serving_decode", lanes=L, m=M,
-                paged=self.paged_active, paged_dead=self._paged_dead),
+            key("serving_decode", lanes=L, m=M, **extra(dstatic)),
             lambda: dec.lower(
-                self._params, pspec, pspec, sspec, sspec,
+                self._params, *pools,
                 jax.ShapeDtypeStruct((L, M), i32),
                 jax.ShapeDtypeStruct((L,), i32),
-                jax.ShapeDtypeStruct((L,), i32), cfg=self._gcfg,
-                paged=self.paged_active,
-                paged_dead=self._paged_dead),
+                jax.ShapeDtypeStruct((L,), i32), **dstatic),
             label="serving/decode")
-        pre = jax.jit(_prefill_chunk, **kw)
+        pre, pstatic = jitted("prefill")
         scal = jax.ShapeDtypeStruct((), i32)
         self._prefill_exec = exec_cache.get_or_compile(
-            key("serving_prefill", m=M, chunk=C),
+            key("serving_prefill", m=M, chunk=C, **extra(pstatic)),
             lambda: pre.lower(
-                self._params, pspec, pspec, sspec, sspec,
+                self._params, *pools,
                 jax.ShapeDtypeStruct((1, M), i32),
                 jax.ShapeDtypeStruct((1, C), i32),
-                scal, scal, scal, cfg=self._gcfg),
+                scal, scal, scal, **pstatic),
             label="serving/prefill")
         if self.spec_active:
             S = self.config.spec_k + 1
-            ver = jax.jit(_verify_step, **kw)
+            ver, vstatic = jitted("verify")
             self._verify_exec = exec_cache.get_or_compile(
-                key("serving_verify", lanes=L, m=M, k=self.config.spec_k),
+                key("serving_verify", lanes=L, m=M, k=self.config.spec_k,
+                    **extra(vstatic)),
                 lambda: ver.lower(
-                    self._params, pspec, pspec, sspec, sspec,
+                    self._params, *pools,
                     jax.ShapeDtypeStruct((L, M), i32),
                     jax.ShapeDtypeStruct((L,), i32),
                     jax.ShapeDtypeStruct((L, S), i32),
-                    jax.ShapeDtypeStruct((L,), i32), cfg=self._gcfg),
+                    jax.ShapeDtypeStruct((L,), i32), **vstatic),
                 label="serving/verify")
 
     # -- the step loop -------------------------------------------------------
@@ -803,10 +519,8 @@ class ServingEngine:
                 chunk = np.zeros((1, C), np.int32)
                 chunk[0, :piece.size] = piece
                 last_idx = ctx - 1 - start if start + C >= ctx else 0
-                (tok, self._kpool, self._vpool, self._kscale,
-                 self._vscale) = self._prefill_exec(
-                    self._params, self._kpool, self._vpool, self._kscale,
-                    self._vscale, table, jnp.asarray(chunk),
+                tok, *self._pools = self._prefill_exec(
+                    self._params, *self._pools, table, jnp.asarray(chunk),
                     jnp.int32(start), jnp.int32(ctx), jnp.int32(last_idx))
                 nchunks += 1
                 if sp is not None:
@@ -849,7 +563,9 @@ class ServingEngine:
                 end = time.perf_counter()
             else:
                 with self._phase("first_token_fetch", "first_fetch_s") as f:
-                    first_tok = int(np.asarray(tok)[0])  # the TTFT host sync
+                    # the TTFT host sync
+                    first_tok = int(self._family.absorb(
+                        np.asarray(tok), self.counters)[0])
                 end = f.t1
             if p_t0 is not None:
                 req.prefill_ms += (end - p_t0) * 1e3
@@ -910,13 +626,12 @@ class ServingEngine:
         returns them as numpy with the stamp of the fetch's end — the
         round's ONE host sync, and every lane's attribution mark."""
         with self._phase("dispatch", "dispatch_s", kind=kind, lanes=lanes):
-            (out, self._kpool, self._vpool, self._kscale,
-             self._vscale) = program(
-                self._params, self._kpool, self._vpool, self._kscale,
-                self._vscale, *operands)
+            out, *self._pools = program(self._params, *self._pools,
+                                        *operands)
         with self._phase("token_fetch", "fetch_s") as fetch:
             out = np.asarray(out)
-        return out, fetch.t1
+        # a family's own counters ride on the fetched array
+        return self._family.absorb(out, self.counters), fetch.t1
 
     def _verify_round(self, act, drafts) -> None:
         """One [L, k+1] verify step for every occupied lane: score the
@@ -945,6 +660,7 @@ class ServingEngine:
                         jnp.asarray(toks), jnp.asarray(wlim))
         preds, now = self._launch("verify", self._verify_exec, operands,
                                   len(act))
+        preds = preds.reshape(L, K + 1)
         with self._phase("emit", "emit_s") as ph:
             self._accept(act, drafts, preds, now, ph)
 
@@ -1194,6 +910,8 @@ class ServingEngine:
             waiting=len(self.scheduler.waiting),
             requests=len(self._requests),
             uncollected=len(self._finished),
+            family=self._family.name,
+            **self._family.stats(),
         )
         return out
 

@@ -1,0 +1,367 @@
+"""The dense grouped-query decoder family (``models/llama.py``) as the
+serving engine sees it: a K pool and a V pool ``[layers, blocks, block,
+kv_heads, head_dim]`` (int8 mode: paired float32 scale pools), the
+weights stacked per leaf so each program scans over layers, and the three
+step programs over block tables. The programs are the ones
+``serving/engine.py`` held until the engine stopped knowing one
+architecture; they were moved here, not rewritten
+(tests/test_chip_compile.py holds what they compile to).
+
+The attention/RoPE/MLP math reuses ``models/generation.py``'s helpers
+(``_rms``/``_mm``/``_rope_at``) and mirrors its ``_attend`` line for line
+— engine outputs are token-identical to per-request ``generate()`` calls
+(tests/test_serving.py proves it, padding included, because masked slots
+contribute exactly-zero softmax weight).
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ...framework.device import on_tpu
+from ...models.generation import (
+    _GenCfg, _collect_params, _mm, _rms, _rope_at,
+)
+
+__all__ = ["DenseGQAFamily"]
+
+
+# -- compiled phases ----------------------------------------------------------
+
+def _attend_lanes(q, kc, vc, pos, nh, nkv, sliding_window=0):
+    """``models/generation.py:_attend`` with PER-TOKEN positions: q
+    [b, s, nh, d] against the gathered block slots kc/vc [b, L, nkv, d].
+    Slot ``l`` is visible to the query at absolute position ``p =
+    pos[b, t]`` iff ``l <= p`` — block tables lay a lane's positions out
+    in order, so slot index == absolute position for every allocated
+    slot, and unallocated/pad slots sit above every real ``p``. The math
+    (fp32 einsum, 1/sqrt(d), -1e30 mask, fp32 softmax/AV) mirrors
+    ``_attend`` exactly so masked slots carry exactly-zero weight and
+    engine outputs stay token-identical to ``generate()``."""
+    b, s, _, d = q.shape
+    L = kc.shape[1]
+    g = nh // nkv
+    qg = q.reshape(b, s, nkv, g, d)
+    logits = jnp.einsum("bskgd,blkd->bskgl", qg.astype(jnp.float32),
+                        kc.astype(jnp.float32)) / np.sqrt(d)
+    vis = jnp.arange(L)[None, None, :] <= pos[:, :, None]  # [b, s, L]
+    if sliding_window > 0:
+        vis &= jnp.arange(L)[None, None, :] > pos[:, :, None] \
+            - sliding_window
+    logits = jnp.where(vis[:, :, None, None, :], logits, -1e30)
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bskgl,blkd->bskgd", p, vc.astype(jnp.float32))
+    return out.reshape(b, s, nh, d).astype(q.dtype)
+
+
+def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
+                  pos, wlimit, cfg, paged=False, paged_dead="clamp"):
+    """Forward ``ids`` [b, s] at absolute positions ``pos`` [b, s]
+    against the block pool: per layer, write each token's K/V into its
+    lane's block at ``pos`` (writes at positions >= ``wlimit[b]`` — pad
+    tail of a final prefill chunk, idle decode lanes — are redirected to
+    null block 0 so they can never clobber live KV), then attend over
+    the lane's whole gathered table: every table slot, live or not, read
+    in one gather from the stacked pool by (layer, block) — no value of
+    one layer's pool shape is produced (tests/test_chip_compile.py holds
+    the compiled programs to that). Layer math is
+    ``models/generation.py:_block`` on the pooled layout.
+
+    ``kscale``/``vscale`` are the int8 mode's paired fp32 scale pools
+    (``[layers, num_blocks, block_size, kv_heads]``; None in bf16 mode
+    — None is an empty pytree, so the bf16 jaxpr is byte-identical to
+    the pre-int8 program): writes quantize K/V per position through the
+    shared `quantization.quantize_kv` (scale writes ride the same
+    null-redirected ``blk``/``off``, null block included), reads
+    dequantize the gathered blocks before the same fp32 attention —
+    identical ops to ``generate(kv_int8=True)``'s round-trip, so the
+    two paths stay bit-equal. Returns
+    (x [b, s, hidden], kpool, vpool, kscale, vscale)."""
+    b, s = ids.shape
+    nh = cfg.num_attention_heads
+    nkv = cfg.num_key_value_heads or nh
+    d = cfg.hidden_size // nh
+    B = kpool.shape[2]
+    M = tables.shape[1]
+    dt = jnp.dtype(cfg.dtype)
+    quant = kscale is not None
+    x = params["embed"][ids].astype(dt)
+    idx = jnp.minimum(pos // B, M - 1)  # pad pos can run past the table
+    blk = jnp.take_along_axis(tables, idx, axis=1)
+    ok = pos < wlimit[:, None]
+    blk = jnp.where(ok, blk, 0)
+    off = jnp.where(ok, pos % B, 0)
+    n_layers = params["ln1"].shape[0]
+
+    def body(carry, li):
+        if quant:
+            x, kp, vp, ks, vs = carry
+        else:
+            x, kp, vp = carry
+            ks = vs = None
+        layer_p = {k: jax.tree_util.tree_map(lambda a: a[li], params[k])
+                   for k in
+                   ("ln1", "qkv", "o", "ln2", "gate_up", "down")}
+        h = _rms(x, layer_p["ln1"], cfg.rms_norm_eps)
+        qkv = _mm(h, layer_p["qkv"])
+        q, k, v = jnp.split(qkv, [nh * d, nh * d + nkv * d], axis=-1)
+        q = q.reshape(b, s, nh, d)
+        k = k.reshape(b, s, nkv, d)
+        v = v.reshape(b, s, nkv, d)
+        q, k = _rope_at(q, k, pos, cfg.rope_theta)
+        if quant:
+            from ...quantization import quantize_kv
+
+            k, k_s = quantize_kv(k)
+            v, v_s = quantize_kv(v)
+            ks = ks.at[li, blk, off].set(k_s)
+            vs = vs.at[li, blk, off].set(v_s)
+        kp = kp.at[li, blk, off].set(k)
+        vp = vp.at[li, blk, off].set(v)
+        if paged and s == 1:
+            # Pallas paged read: gather straight from the pool via the
+            # block table, touching only each lane's live prefix — the
+            # dense gather below reads every table slot.
+            # (kp[li] here still hands the kernel a copy of the layer's
+            # whole pool; no cell engages this branch — PERF.md 7)
+            interp = not on_tpu()
+            if quant:
+                from ...ops.pallas.paged_attention import \
+                    paged_attend_int8
+
+                out = paged_attend_int8(
+                    q.reshape(b, nh, d), kp[li], vp[li], ks[li],
+                    vs[li], tables, pos[:, 0],
+                    window=cfg.sliding_window, dead=paged_dead,
+                    interpret=interp)[:, None]
+            else:
+                from ...ops.pallas.paged_attention import paged_attend
+
+                out = paged_attend(
+                    q.reshape(b, nh, d), kp[li], vp[li], tables,
+                    pos[:, 0], window=cfg.sliding_window,
+                    dead=paged_dead, interpret=interp)[:, None]
+        else:
+            # ONE gather per pool on the stacked pool, by (layer, block):
+            # kp[li][tables] makes the TPU materialise kp[li], the
+            # layer's whole pool, before every gather. Which of the two
+            # forms without it follows the pool's dtype, as the chip
+            # ran them (PERF.md section 6, PR 25): bf16 pools flattened
+            # over (layer, block), int8 pools and their scales indexed
+            # by the pair
+            if quant:
+                from ...quantization import dequantize_kv
+
+                kc = dequantize_kv(
+                    kp[li, tables].reshape(b, M * B, nkv, d),
+                    ks[li, tables].reshape(b, M * B, nkv), dt)
+                vc = dequantize_kv(
+                    vp[li, tables].reshape(b, M * B, nkv, d),
+                    vs[li, tables].reshape(b, M * B, nkv), dt)
+            else:
+                rows = tables + li * kp.shape[1]
+                kc = kp.reshape(-1, B, nkv, d)[rows].reshape(
+                    b, M * B, nkv, d)
+                vc = vp.reshape(-1, B, nkv, d)[rows].reshape(
+                    b, M * B, nkv, d)
+            out = _attend_lanes(q, kc, vc, pos, nh, nkv,
+                                sliding_window=cfg.sliding_window)
+        x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])
+        h2 = _rms(x, layer_p["ln2"], cfg.rms_norm_eps)
+        gu = _mm(h2, layer_p["gate_up"])
+        gate, up = jnp.split(gu, 2, axis=-1)
+        x = x + _mm(jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
+                    * up, layer_p["down"])
+        if quant:
+            return (x, kp, vp, ks, vs), None
+        return (x, kp, vp), None
+
+    if quant:
+        (x, kpool, vpool, kscale, vscale), _ = jax.lax.scan(
+            body, (x, kpool, vpool, kscale, vscale),
+            jnp.arange(n_layers))
+    else:
+        (x, kpool, vpool), _ = jax.lax.scan(
+            body, (x, kpool, vpool), jnp.arange(n_layers))
+    return x, kpool, vpool, kscale, vscale
+
+
+def _prefill_chunk(params, kpool, vpool, kscale, vscale, table, ids,
+                   start, ctx_len, last_idx, *, cfg):
+    """One lane's prefill chunk: ``ids`` [1, C] at positions
+    [start, start+C); greedy-samples from position ``last_idx`` within
+    the chunk (the overall last real token on the final chunk; ignored
+    by the caller otherwise). Returns
+    (tok [1], kpool, vpool, kscale, vscale)."""
+    C = ids.shape[1]
+    pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
+    x, kpool, vpool, kscale, vscale = _pool_forward(
+        params, kpool, vpool, kscale, vscale, table, ids, pos,
+        jnp.reshape(ctx_len, (1,)), cfg)
+    x = _rms(x, params["norm"], cfg.rms_norm_eps)
+    h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
+    logits = _mm(h, params["lm_head"]).astype(jnp.float32)
+    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
+            kscale, vscale)
+
+
+def _decode_step(params, kpool, vpool, kscale, vscale, tables, cur_len,
+                 last_tok, *, cfg, paged=False, paged_dead="clamp"):
+    """The shared decode step: every lane feeds its pending token at
+    position ``cur_len`` (write-then-attend, so the token sees itself
+    like ``generate()``'s step does) and greedy-samples the next. Idle
+    lanes (cur_len 0, table row 0) write to the null block and their
+    outputs are ignored host-side. ``paged`` (static) swaps the dense
+    gathered KV read for the Pallas paged-attention kernel. Returns
+    (tok [L], kpool, vpool, kscale, vscale)."""
+    pos = cur_len[:, None]
+    x, kpool, vpool, kscale, vscale = _pool_forward(
+        params, kpool, vpool, kscale, vscale, tables, last_tok[:, None],
+        pos, cur_len + 1, cfg, paged=paged, paged_dead=paged_dead)
+    x = _rms(x, params["norm"], cfg.rms_norm_eps)
+    logits = _mm(x[:, -1], params["lm_head"]).astype(jnp.float32)
+    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
+            kscale, vscale)
+
+
+def _verify_step(params, kpool, vpool, kscale, vscale, tables, cur_len,
+                 toks, wlimit, *, cfg):
+    """The speculative verify step: ``toks`` [L, k+1] holds each lane's
+    pending token (column 0) followed by its draft, at absolute
+    positions ``cur_len + j``. Writes at positions >= ``wlimit[b]`` (=
+    ``cur_len + 1 + draft_len``: the pad tail of a short/empty draft,
+    idle lanes) go to the null block, exactly like a prefill chunk's pad
+    tail — draft length is data, never shape. Write-then-attend per
+    layer means draft token ``j`` attends over slots ``<= cur_len + j``,
+    the same causal view plain decode would give it, so the returned
+    greedy argmaxes [L, k+1] are the tokens the decode step WOULD emit
+    after each draft prefix — the host's acceptance rule compares
+    drafts against them directly."""
+    S = toks.shape[1]
+    pos = cur_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    x, kpool, vpool, kscale, vscale = _pool_forward(
+        params, kpool, vpool, kscale, vscale, tables, toks, pos, wlimit,
+        cfg)
+    x = _rms(x, params["norm"], cfg.rms_norm_eps)
+    logits = _mm(x, params["lm_head"]).astype(jnp.float32)
+    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
+            kscale, vscale)
+
+
+# -- the family ---------------------------------------------------------------
+
+class DenseGQAFamily:
+    """What :class:`~paddle_tpu.serving.ServingEngine` asks a model's
+    family for (see ``families/__init__.py``)."""
+
+    name = "dense_gqa"
+
+    def __init__(self, model, config):
+        if getattr(model.config, "moe_num_experts", 0) > 1:
+            from ...framework.errors import UnimplementedError
+
+            raise UnimplementedError(
+                "ServingEngine does not decode MoE Llama configs yet "
+                "(same gap as models/generation.generate)")
+        self.config = config
+        self.gcfg = _GenCfg(model.config)
+        self.params = _collect_params(model,
+                                      int8_weights=config.int8_weights)
+        self.layers = self.params["ln1"].shape[0]
+        self.max_position_embeddings = model.config.max_position_embeddings
+        self.paged_active = self._resolve_paged()
+        self.donate_argnums = (1, 2, 3, 4) if config.kv_int8 else (1, 2)
+
+    def _kv_geometry(self):
+        nh = self.gcfg.num_attention_heads
+        nkv = self.gcfg.num_key_value_heads or nh
+        return nh, nkv, self.gcfg.hidden_size // nh
+
+    def make_pools(self, num_blocks, block_size):
+        """(kpool, vpool, kscale, vscale). int8 mode: paired per-position
+        fp32 amax scales (null block included — masked writes land there
+        like K/V pad writes do); None in bf16 mode so the compiled
+        programs stay byte-identical to the pre-int8 engine (None is an
+        empty pytree operand)."""
+        _, nkv, d = self._kv_geometry()
+        int8 = self.config.kv_int8
+        dt = jnp.int8 if int8 else jnp.dtype(self.gcfg.dtype)
+        kpool = jnp.zeros((self.layers, num_blocks, block_size, nkv, d), dt)
+        vpool = jnp.zeros_like(kpool)
+        if not int8:
+            return kpool, vpool, None, None
+        kscale = jnp.zeros((self.layers, num_blocks, block_size, nkv),
+                           jnp.float32)
+        return kpool, vpool, kscale, jnp.zeros_like(kscale)
+
+    def kv_pool_bytes(self, pools):
+        return int(sum(a.nbytes for a in pools if a is not None))
+
+    def _resolve_paged(self) -> bool:
+        """Decode read-path selection (ServingConfig.paged): forced
+        on/off, or ``auto`` = engaged only on a measured-faster
+        tune-table row for this geometry on this device (the
+        measurement-first convention — no row, no flip). Which FAMILY
+        is consulted follows the pool dtype: ``paged_attention`` for
+        bf16 pools, ``paged_attention_int8`` (the quantized-gather
+        variant) when ``kv_int8`` — an int8 engine never engages on a
+        bf16 row or vice versa (``self.paged_family`` is what the
+        bench/guard surface reports). Also resolves
+        ``self.paged_dead``: the row's WINNING dead-iteration strategy
+        — engaging the measured configuration, not the default —
+        falling back to ``"clamp"`` when forced on with no row."""
+        from ...ops.pallas import paged_attention as _pa
+        from ...ops.pallas import search as _ksearch
+
+        nh, nkv, d = self._kv_geometry()
+        key = _pa.family_key(self.config.block_size, nkv, nh // nkv, d,
+                             window=self.gcfg.sliding_window)
+        self.paged_family = ("paged_attention_int8"
+                             if self.config.kv_int8
+                             else "paged_attention")
+        cfg_row = _ksearch.best_config(self.paged_family, key) or {}
+        self.paged_dead = cfg_row.get("dead", "clamp")
+        mode = self.config.paged
+        if mode == "on":
+            return True
+        if mode == "off":
+            return False
+        return _ksearch.decide(self.paged_family, key)
+
+    def program(self, kind):
+        """(function, static keyword arguments) of one step program."""
+        if kind == "decode":
+            return _decode_step, {"cfg": self.gcfg,
+                                  "paged": self.paged_active,
+                                  "paged_dead": self.paged_dead}
+        return {"prefill": _prefill_chunk, "verify": _verify_step}[kind], \
+            {"cfg": self.gcfg}
+
+    def exec_key(self, pools):
+        """The family's part of an exec-cache key."""
+        from ...jit import exec_cache
+
+        kpool, kscale = pools[0], pools[2]
+        k = {"gen_cfg": self.gcfg._key(),
+             "params": [exec_cache.array_spec(a) for a in
+                        jax.tree_util.tree_leaves(self.params)],
+             "pool": (tuple(int(x) for x in kpool.shape),
+                      str(kpool.dtype))}
+        if self.config.kv_int8:
+            # the pool dtype above already splits int8 from bf16
+            # entries; the explicit marker + scale spec make the
+            # cache key self-describing (meta sidecar, audits)
+            k["kv_int8"] = True
+            k["scale"] = (tuple(int(x) for x in kscale.shape),
+                          str(kscale.dtype))
+        return k
+
+    counters = {}  # nothing beyond the engine's own
+
+    def absorb(self, out, counters):
+        """The round's fetched output IS its tokens."""
+        return out
+
+    def stats(self):
+        return {}

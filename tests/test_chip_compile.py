@@ -176,10 +176,10 @@ def test_engine_program_never_holds_one_layers_pool(
     them (lowered as ``ServingEngine._ensure_compiled`` lowers them on
     the chip, pools donated), no instruction's result has one layer's
     pool shape (nor, in int8 mode, one layer's scale-pool shape)."""
-    import paddle_tpu.serving.engine as E
+    import paddle_tpu.serving.families.dense_gqa as E
 
     eng = engines[kv_int8]
-    assert eng._kpool.shape[1:] == _POOL
+    assert eng._pools[0].shape[1:] == _POOL
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def spec(a):
@@ -198,8 +198,7 @@ def test_engine_program_never_holds_one_layers_pool(
                     (i32(1, M), i32(1, cfg.prefill_chunk), i32(), i32(),
                      i32())),
     }[kind]
-    pools = jax.tree_util.tree_map(spec, (
-        eng._params, eng._kpool, eng._vpool, eng._kscale, eng._vscale))
+    pools = jax.tree_util.tree_map(spec, (eng._params, *eng._pools))
     text = jax.jit(
         fn, static_argnames=("cfg",),
         donate_argnums=(1, 2, 3, 4) if kv_int8 else (1, 2),
@@ -210,3 +209,139 @@ def test_engine_program_never_holds_one_layers_pool(
              if held.search(ln)]
     assert not lines, "\n".join(lines[:6])
     assert f"[3,{nb},{block},{nkv},{d}]" in text  # the stacked pool is
+
+
+# -- the latent-attention sparse-expert family's programs ----------------------
+
+# [layers, num_blocks, block, width] at the published 512 + 64 = 576 numbers
+# a token, which the family pads to whole 128-lane tiles: 640
+_LATENT_POOL = (3, 2049, 16, 640)
+
+
+@pytest.fixture(scope="module")
+def latent_engine():
+    """A 1-dense + 2-expert-layer bf16 engine, built on the CPU for its
+    shapes."""
+    from paddle_tpu.models import LatentMoEConfig, LatentMoEForCausalLM
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    layers, nb, block, _ = _LATENT_POOL
+    model = LatentMoEForCausalLM(LatentMoEConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=layers,
+        first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=128,
+        kv_lora_rank=512, qk_nope_head_dim=32, qk_rope_head_dim=64,
+        v_head_dim=32, n_routed_experts=4, router_experts=16,
+        num_experts_per_tok=4, initializer_range=0.0, dtype="bfloat16"))
+    model.eval()
+    return ServingEngine(model, ServingConfig(
+        max_lanes=4, block_size=block, num_blocks=nb, prefill_chunk=32,
+        max_seq_len=5 * block))
+
+
+def _latent_program_text(topo, eng, kind, monkeypatch):
+    """The family's program ``kind`` as the chip's compiler leaves it."""
+    import paddle_tpu.framework.device as device
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    fam = eng._family
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    cfg = eng.config
+    L, M = cfg.max_lanes, eng.blocks_per_lane
+    rest = {"decode": (i32(L, M), i32(L), i32(L)),
+            "verify": (i32(L, M), i32(L), i32(L, cfg.spec_k + 1), i32(L)),
+            "prefill": (i32(1, M), i32(1, cfg.prefill_chunk), i32(), i32(),
+                        i32())}[kind]
+    fn, static = fam.program(kind)
+    return jax.jit(
+        fn, static_argnames=tuple(static), donate_argnums=fam.donate_argnums,
+    ).lower(*jax.tree_util.tree_map(spec, (eng._params, *eng._pools)),
+            *rest, **static).compile().as_text()
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+def test_latent_program_never_copies_its_pool(topo, latent_engine, kind,
+                                              monkeypatch):
+    """A ``[layers, blocks, block, 576]`` pool the TPU lays out
+    blocks-minor (576 is 4.5 lane tiles: row-major pads 11%, blocks-minor
+    2049 -> 2176 only 6%), and each program then copied the whole pool
+    into row-major and back, every call (2 x 1.95 ms at the benchmark's
+    0.57 GB: PERF.md section 6, PR 27; with width 576 here this test
+    finds both copies). The family pads the entry to 640, whose own
+    layout is row-major: no instruction is a copy of the pool, the
+    stacked pool is what the gather reads (no one layer's pool is
+    produced either), and the expert products are the grouped-matmul
+    kernel, not its interpreter."""
+    assert latent_engine._pools[0].shape == _LATENT_POOL
+    text = _latent_program_text(topo, latent_engine, kind, monkeypatch)
+    layers, nb, block, width = _LATENT_POOL
+    whole = rf"\w+\[{layers},{nb},{block},{width}\]"
+    copies = [ln.strip()[:200] for ln in text.splitlines()
+              if re.search(rf"= {whole}\S* copy\(", ln)]
+    assert not copies, "\n".join(copies[:4])
+    one_layer = [ln.strip()[:200] for ln in text.splitlines()
+                 if re.search(rf"= \w+\[{nb},{block},{width}\]", ln)]
+    assert not one_layer, "\n".join(one_layer[:4])
+    assert re.search(whole, text)
+    # two grouped products an expert layer, as Mosaic kernels
+    assert len(re.findall(r"%gmm[\.\d]* = [^\n]*custom_call_target="
+                          r"\"tpu_custom_call\"", text)) == 4
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_latent_attention_reader_picks_the_cache_not_the_new_entries(
+        topo, latent_engine, kind, monkeypatch):
+    """The benchmark's ``mla_attend_roofline`` picks operations by the
+    shapes in their instruction text (the device trace's events are named
+    by it). It once took the gathered cache's slot count from the first
+    ``[lanes, N, 576]`` it met: with the pool stored 640 wide that is the
+    round's k + 1 NEW entries, so it timed the write path (review of PR
+    27). Held here to the compiled programs' own instructions: the slots
+    are the table's (5 blocks of 16), and what is picked is each layer's
+    gather, score product and weighted sum and nothing of the expert
+    layer, the query path or the cache write."""
+    import importlib.util
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmarks", "chip"))
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "mla_attend_roofline_reader", os.path.join(
+                root, "benchmarks/chip/metrics/mla_attend_roofline.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.pop(0)
+    text = _latent_program_text(topo, latent_engine, kind, monkeypatch)
+    names = [ln.strip() for ln in text[text.index("\nENTRY"):].splitlines()
+             if " = " in ln]
+    cfg = latent_engine.config
+    lanes, slots = cfg.max_lanes, latent_engine.blocks_per_lane * 16
+    picked = reader.pattern(names, lanes, {
+        "kv_lora_rank": 512, "qk_rope_head_dim": 64,
+        "num_attention_heads": 4})
+    assert rf"\[{lanes},{slots},640" in picked, picked
+    hit = [n for n in names if re.search(picked, n)]
+    layers = _LATENT_POOL[0]
+    for what in ("/gather", "bshl,blc->bshc"):
+        assert sum(what in n for n in hit) == layers, (what, hit)
+    # the float32 scores over every slot, one product a layer at least
+    scores = (rf"= \(?(f32\[[\d,]*\]\S* )?f32\[{lanes},(\d+,)*{slots}\]"
+              r"\S* fusion")
+    assert sum(bool(re.search(scores, n)) for n in hit) >= layers, hit
+    stray = [n[:160] for n in hit
+             if re.search(r"moe/|mla/q/|mla/kv_write", n)]
+    assert not stray, stray
+    # no cache among the names (the parent's programs): nothing to read
+    assert reader.pattern([n for n in names if "640" not in n
+                           and "576" not in n], lanes, {
+        "kv_lora_rank": 512, "qk_rope_head_dim": 64,
+        "num_attention_heads": 4}) is None

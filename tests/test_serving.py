@@ -937,7 +937,7 @@ def _parent_read_logits(params, kpool, vpool, tables, ids, pos, wlimit,
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.serving import engine as E
+    from paddle_tpu.serving.families import dense_gqa as E
 
     nh, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
     d = cfg.hidden_size // nh
@@ -979,7 +979,7 @@ def test_engine_each_layer_reads_its_own_pool():
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.serving import engine as E
+    from paddle_tpu.serving.families import dense_gqa as E
 
     pt.seed(3)
     m = LlamaForCausalLM(LlamaConfig.tiny(num_hidden_layers=3))
@@ -990,7 +990,7 @@ def test_engine_each_layer_reads_its_own_pool():
     eng = ServingEngine(m, ServingConfig(
         max_lanes=3, block_size=4, num_blocks=23, prefill_chunk=8,
         max_seq_len=28))
-    assert eng._kpool.shape[:3] == (3, 23, 4) and eng.blocks_per_lane == 7
+    assert eng._pools[0].shape[:3] == (3, 23, 4) and eng.blocks_per_lane == 7
     rng = np.random.RandomState(25)
     reqs = []
     for _ in range(7):
@@ -1005,7 +1005,7 @@ def test_engine_each_layer_reads_its_own_pool():
             err_msg=f"request {r.request_id} diverged from generate()")
 
     # the same programs' forward on a pool no layer shares with another
-    shape = eng._kpool.shape
+    shape = eng._pools[0].shape
     layer_of = np.arange(3, dtype=np.float32).reshape(3, 1, 1, 1, 1)
     kpool = jnp.asarray(rng.randn(*shape).astype(np.float32)
                         * (1 + 2 * layer_of) + layer_of)
@@ -1045,7 +1045,7 @@ def test_paged_kernel_parity_vs_attend_lanes():
     full."""
     import jax.numpy as jnp
 
-    from paddle_tpu.serving.engine import _attend_lanes
+    from paddle_tpu.serving.families.dense_gqa import _attend_lanes
     from paddle_tpu.ops.pallas.paged_attention import paged_attend
 
     L, M, B, nkv, g, d = 4, 4, 8, 2, 2, 16

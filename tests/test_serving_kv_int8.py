@@ -143,16 +143,16 @@ class TestInt8PoolInvariants:
         eng = ServingEngine(model, ServingConfig(kv_int8=True, **GEOM))
         import jax.numpy as jnp
 
-        assert eng._kpool.dtype == jnp.int8
-        assert eng._vpool.dtype == jnp.int8
+        kpool, vpool, kscale, vscale = eng._pools
+        assert kpool.dtype == jnp.int8
+        assert vpool.dtype == jnp.int8
         # paired fp32 amax scales, one per (position, kv_head), the
         # null block included (its zero scale dequantizes to zero)
-        assert eng._kscale.dtype == jnp.float32
-        assert eng._kscale.shape == eng._kpool.shape[:-1]
-        assert eng._vscale.shape == eng._vpool.shape[:-1]
-        assert eng.kv_pool_bytes == (eng._kpool.nbytes + eng._vpool.nbytes
-                                     + eng._kscale.nbytes
-                                     + eng._vscale.nbytes)
+        assert kscale.dtype == jnp.float32
+        assert kscale.shape == kpool.shape[:-1]
+        assert vscale.shape == vpool.shape[:-1]
+        assert eng.kv_pool_bytes == (kpool.nbytes + vpool.nbytes
+                                     + kscale.nbytes + vscale.nbytes)
         assert eng.stats()["kv_int8"] is True
         assert eng.stats()["kv_pool_bytes"] == eng.kv_pool_bytes
 
@@ -234,8 +234,8 @@ def test_int8_off_restores_baseline_engine(model):
     model dtype, quant counters parked at zero, tokens identical to
     plain generate()."""
     eng = ServingEngine(model, ServingConfig(**GEOM))
-    assert eng._kscale is None and eng._vscale is None
-    assert eng._kpool.dtype == np.dtype(model.config.dtype)
+    assert eng._pools[2] is None and eng._pools[3] is None
+    assert eng._pools[0].dtype == np.dtype(model.config.dtype)
     assert eng.stats()["kv_int8"] is False
     work = _workload(model, seed=2, n=4)
     handles = [eng.submit(p, max_new_tokens=n) for p, n in work]
