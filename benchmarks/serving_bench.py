@@ -366,9 +366,11 @@ def main():
     # HBM roofline (decode_bench's byte model on the decode phase): per
     # step the chip reads every matmul weight once (lanes share the
     # read) + each live lane's KV prefix, writes one KV token per
-    # layer/lane. kv_read_tokens is the engine's live-prefix count — the
-    # bytes a paged-attention kernel would move; the XLA gathered step
-    # reads whole tables, so measured-vs-model gap = paging overhead.
+    # layer/lane. kv_read_tokens is the engine's live-token count — the
+    # bytes a perfectly ragged read would move (since PR 28 the counters
+    # cover prefill chunks' reads too, a few percent of a decode-heavy
+    # run); the engine gathers kv_gathered_tokens (rows and tiles pad),
+    # and kv_dense_read_tokens is every lane's whole table.
     db = _load_decode_bench()
     # byte-size facts from the engine's OWN param arrays — re-running
     # _collect_params would materialize a duplicate full weight copy

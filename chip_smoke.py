@@ -361,7 +361,7 @@ def run_engine(model, requests, events, **cfg_kw):
     c.need(st["spec_accepted_tokens"] > 0, "zero accepted draft tokens")
     facts = {
         "read_path": ("pallas " + st["paged_family"]
-                      if st["paged_attention"] else "dense gather"),
+                      if st["paged_attention"] else "row gather"),
         "kv_int8": st["kv_int8"], "programs_compiled": n_programs,
         "compiles_while_serving": events["backend_compiles"] - b0,
         "warmup_s": round(warm_s, 1), "serve_wall_s": round(wall_s, 2),
@@ -413,7 +413,14 @@ class LogitRows:
         dt = jnp.dtype(cfg.dtype)
         block = 16
         nblk = bucket // block
-        self.table = jnp.arange(1, nblk + 1, dtype=jnp.int32)[None, :]
+        # the bucket's blocks 1..nblk as the engine's read operand: live
+        # rows of the family's width, and each position's write block
+        from paddle_tpu.serving.engine import fit_rows, pack_rows
+
+        w, tile, cap = fit_rows((E.ROW_BLOCKS, E.PREFILL_TILE), 1, nblk)
+        self.read = tuple(jnp.asarray(a) for a in pack_rows(
+            [(0, list(range(1, nblk + 1)), 0, bucket)], 1, bucket, block,
+            w, cap)[:2])
 
         @jax.jit
         def ref_row(params, ids, n):
@@ -431,8 +438,8 @@ class LogitRows:
                                jnp.float32) if kv_int8 else None)
             pos = jnp.arange(bucket, dtype=jnp.int32)[None, :]
             x, *_ = E._pool_forward(params, pool, pool, scale, scale,
-                                    self.table, ids, pos,
-                                    jnp.reshape(n, (1,)), cfg)
+                                    self.read, ids, pos,
+                                    jnp.reshape(n, (1,)), cfg, tile=tile)
             x = G._rms(x, params["norm"], cfg.rms_norm_eps)
             h = jax.lax.dynamic_index_in_dim(x, n - 1, axis=1,
                                              keepdims=False)

@@ -1,20 +1,21 @@
 """Paged-attention decode kernel: gather KV straight from the block pool.
 
-The serving engine's decode step (`serving/engine.py`) currently
-materializes each lane's KV with a dense gather —
-``kpool[tables].reshape(L, M*B, ...)`` — and attends over ALL ``M*B``
-slots with a mask. Every decode round therefore reads each lane's WHOLE
-table worth of KV from HBM, live or not; the serving bench's
-``hbm_util`` gap quantifies the waste (decode is bandwidth-bound —
-PERF.md). This kernel is the PagedAttention read path done TPU-style:
-one grid row per (lane, table-slot), the K/V BlockSpec index maps
-resolve through the lane's block table (scalar-prefetch — the table and
-the per-lane lengths arrive before the body runs), and iterations past
-the lane's live prefix REPEAT the previous block index, which the
-Pallas pipeline recognizes as "block unchanged" and elides the DMA — so
-HBM traffic is ``pool_len`` live tokens per lane, not ``M·B``.
+A Pallas read of each lane's live prefix for the serving engine's decode
+step, walking a ``[lanes, M]`` block table: one grid row per (lane,
+table-slot), the K/V BlockSpec index maps resolve through the lane's
+block table (scalar-prefetch — the table and the per-lane lengths arrive
+before the body runs), and iterations past the lane's live prefix REPEAT
+the previous block index, which the Pallas pipeline recognizes as "block
+unchanged" and elides the DMA — so HBM traffic is ``pool_len`` live
+tokens per lane, not ``M·B``. The engine's own read
+(``serving/families/dense_gqa.py:_attend_rows``) no longer gathers every
+table slot either: it gathers the rows of blocks the lanes hold, in
+plain XLA, in all three programs (PERF.md section 6, PR 28) — what this
+kernel has to beat, at one grid step a 16-token block, dead steps
+included, and for one position a lane only. Whether it is still worth
+its code is ROADMAP C's question.
 
-The math mirrors ``serving/engine.py:_attend_lanes`` (fp32 grouped-GQA
+The math mirrors ``serving/families/dense_gqa.py:_attend_lanes`` (fp32 grouped-GQA
 dots, 1/sqrt(d), -1e30 masking) as a streaming softmax over table
 slots; masked slots carry exactly-zero weight, so engine outputs stay
 token-identical to ``generate()`` (tests/test_serving.py extends the
@@ -22,8 +23,8 @@ token-identity proof to this path).
 
 Ships **disengaged by default**: the engine's auto mode consults the
 search harness's ``paged_attention`` tune-table row for this geometry
-(``ops/pallas/search.py``; engagement = measured-faster-than-the-dense-
-gather only) and no hardware row exists yet (``kernel_tune.json`` is
+(``ops/pallas/search.py``; engagement = measured-faster-than-the-
+gathered read only) and no hardware row exists yet (``kernel_tune.json`` is
 tracked, and empty). ``PT_SERVE_PAGED=1/0`` forces it on/off
 (docs/SERVING.md); forced on, it has run on the v5e inside the engine's
 decode program at Llama-2-7B widths (chip_smoke.py).
@@ -364,9 +365,11 @@ class PagedAttentionFamily(search.KernelFamily):
         return run
 
     def build_composite(self, shape):
-        """The dense gathered read this kernel replaces — the engine's
-        real `_attend_lanes` on `kpool[tables]` (serving/engine.py), so
-        the composite cannot drift from production."""
+        """The full-table gathered read: `_attend_lanes` on
+        `kpool[tables]`, the definition this kernel and the engine's row
+        read (`dense_gqa._attend_rows`) are both held to. It is what the
+        engine ran until PR 28, not what it runs: a tune row measured
+        against it over-states the kernel (PERF.md section 7)."""
         L, M, B, nkv, g, d = shape
         nh = nkv * g
 
@@ -416,10 +419,9 @@ class PagedAttentionInt8Family(PagedAttentionFamily):
         return run
 
     def build_composite(self, shape):
-        """The engine's int8 dense read (`serving/engine.py:
-        _pool_forward` with ``kv_int8``): gather int8 blocks + scales,
-        `quantization.dequantize_kv`, then `_attend_lanes` — the
-        production fallback this kernel replaces."""
+        """The int8 full-table read: gather int8 blocks + scales,
+        `quantization.dequantize_kv`, then `_attend_lanes` (the bf16
+        composite's caveat holds: the engine reads rows now)."""
         L, M, B, nkv, g, d = shape
         nh = nkv * g
 

@@ -1,9 +1,14 @@
 """Continuous-batching decode engine over the block KV pool.
 
 The millions-of-users path (ROADMAP item 1): requests of unequal prompt
-and output lengths share ONE compiled decode step — per-lane block
-tables and valid lengths are runtime *data*, so admission, eviction, and
-growth never retrace. Three compiled programs serve the whole lifetime:
+and output lengths share ONE compiled decode step — which blocks a lane
+holds and its valid length are runtime *data*, so admission, eviction,
+and growth never retrace. Every program call is told where its lanes'
+K/V lies in the form its family takes (``read_form``): the lanes' LIVE
+ROWS — each lane's block list cut into rows of a few blocks, all lanes'
+rows end to end (:func:`pack_rows`), so a call gathers what the lanes
+hold and not every slot of every lane's table — or a ``[lanes, M]``
+block table. Three compiled programs serve the whole lifetime:
 
 - **prefill chunk** ``[1, C]``: one lane's context enters the pool C
   tokens at a time (padded tail chunks write only below the context
@@ -14,8 +19,8 @@ growth never retrace. Three compiled programs serve the whole lifetime:
   a fully cached system prompt costs zero prefill chunks beyond its
   private tail.
 - **decode step** ``[L, 1]``: every occupied lane advances one token —
-  write the pending token's K/V at ``pool_len``, attend over the lane's
-  gathered blocks masked to ``slot <= pos``, greedy-sample the next.
+  write the pending token's K/V at ``pool_len``, attend over the blocks
+  the lane holds masked to ``slot <= pos``, greedy-sample the next.
 - **verify step** ``[L, k+1]`` (speculative decoding, ``PT_SERVE_SPEC``
   — docs/SERVING.md): when the host-side drafter
   (:mod:`.speculative`) proposed tokens for any lane, every lane's
@@ -144,7 +149,7 @@ class ServingConfig:
       ``"auto"`` (default) engages the Pallas paged-attention kernel
       (``ops/pallas/paged_attention.py``) only on a measured-faster
       tune-table row for this geometry (measurement-first; no row =
-      the dense gathered read), ``"1"``/True forces it on,
+      the gathered row read), ``"1"``/True forces it on,
       ``"0"``/False off.
     - ``prefix_cache`` (``PT_SERVE_PREFIX_CACHE``, on): ref-counted
       prefix sharing in the block pool — requests whose context starts
@@ -208,6 +213,57 @@ class ServingConfig:
                                  f"got {getattr(self, name)}")
 
 
+def fit_rows(form, lanes, blocks_per_lane):
+    """A family's ``read_form`` ``(W, tile)`` fitted to an engine's
+    geometry, as ``(W, tile, R)``: a row no wider than a lane's table, a
+    tile no larger than every lane's whole table cut into rows, and
+    ``R`` those rows in whole tiles (the ``rows`` operand's length)."""
+    w = min(form[0], blocks_per_lane)
+    rows = lanes * -(-blocks_per_lane // w)
+    tile = min(form[1], rows)
+    return w, tile, -(-rows // tile) * tile
+
+
+def pack_rows(items, lanes, width, block, w, cap):
+    """The live-rows read operand of one program call (the dense
+    family's ``_attend_rows`` reads it), as numpy. ``items``: per
+    occupied lane ``(lane, blocks, first, upto)`` — its block list, the
+    position of its first token this call, and the slots ``[0, upto)``
+    its queries may see. Returns ``(rows [cap, 2 + w], wblk [lanes,
+    width], live rows, live blocks)``: each lane's blocks below ``upto``
+    cut into rows of ``w`` (lane, first slot's position, ``w`` block
+    ids; the last row null-padded), all lanes' rows end to end, then pad
+    rows (lane -1); and the block each of the call's ``width`` positions
+    from ``first`` falls in (0 past the lane's list: such writes are
+    redirected anyway)."""
+    ids = np.zeros((cap * w,), np.int32)
+    wblk = np.zeros((lanes, width), np.int32)
+    owners, counts = [], []
+    n = live = 0
+    for lane, blocks, first, upto in items:
+        nb = -(-upto // block)
+        ids[n * w:n * w + nb] = blocks[:nb]
+        owners.append(lane)
+        counts.append(-(-nb // w))
+        n += counts[-1]
+        live += nb
+        lo = first // block
+        seg = blocks[lo:(first + width - 1) // block + 1]
+        for j in range(width):
+            k = (first + j) // block - lo
+            if k < len(seg):
+                wblk[lane, j] = seg[k]
+    rows = np.empty((cap, 2 + w), np.int32)
+    rows[:n, 0] = np.repeat(owners, counts)
+    rows[n:, 0] = -1
+    # a row's place within its lane, times the slots a row spans
+    starts = np.cumsum(counts) - counts
+    rows[:n, 1] = (np.arange(n) - np.repeat(starts, counts)) * (w * block)
+    rows[n:, 1] = 0
+    rows[:, 2:] = ids.reshape(cap, w)
+    return rows, wblk, n, live
+
+
 # -- the engine ---------------------------------------------------------------
 
 class ServingEngine:
@@ -263,9 +319,14 @@ class ServingEngine:
         self._paged_dead = fam.paged_dead
         # always-on plain-int accounting (the serving bench's source of
         # truth; independent of the monitor like exec_cache._stats).
-        # kv_read_tokens counts the LIVE prefix (what the paged kernel
-        # reads); kv_dense_read_tokens the full-table slots the dense
-        # gather reads — the pair is the bench's hbm_util delta.
+        # Per program call (rounds and prefill chunks): kv_read_tokens
+        # the LIVE tokens its lanes hold, kv_gathered_tokens the slots
+        # it gathers (rows run x row width; a table-form program: its
+        # whole tables), kv_dense_read_tokens what a gather of every
+        # lane's whole table reads (idle lanes' too: the full-table
+        # read this engine had gathered those). gathered / read is the
+        # read's amplification, gathered / dense the share of the table
+        # still read (_pack_read bills all three).
         # prefix_{hit,miss}_tokens split every (re-)prefilled context:
         # hit = tokens served by acquired shared blocks (no compute),
         # miss = tokens actually pushed through the prefill program —
@@ -281,7 +342,8 @@ class ServingEngine:
             "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
             "spec_bonus_tokens": 0,
             "prefix_hit_tokens": 0, "prefix_miss_tokens": 0,
-            "kv_read_tokens": 0, "kv_dense_read_tokens": 0,
+            "kv_read_tokens": 0, "kv_gathered_tokens": 0,
+            "kv_dense_read_tokens": 0,
             "kv_quant_writes": 0, "kv_quant_tokens": 0,
             # wall seconds per phase of step() (monitor/spans.Phase):
             # they telescope to step_s up to the statements between
@@ -368,8 +430,7 @@ class ServingEngine:
         self._decode_exec = exec_cache.get_or_compile(
             key("serving_decode", lanes=L, m=M, **extra(dstatic)),
             lambda: dec.lower(
-                self._params, *pools,
-                jax.ShapeDtypeStruct((L, M), i32),
+                self._params, *pools, self._read_spec("decode", L, 1),
                 jax.ShapeDtypeStruct((L,), i32),
                 jax.ShapeDtypeStruct((L,), i32), **dstatic),
             label="serving/decode")
@@ -378,8 +439,7 @@ class ServingEngine:
         self._prefill_exec = exec_cache.get_or_compile(
             key("serving_prefill", m=M, chunk=C, **extra(pstatic)),
             lambda: pre.lower(
-                self._params, *pools,
-                jax.ShapeDtypeStruct((1, M), i32),
+                self._params, *pools, self._read_spec("prefill", 1, C),
                 jax.ShapeDtypeStruct((1, C), i32),
                 scal, scal, scal, **pstatic),
             label="serving/prefill")
@@ -390,8 +450,7 @@ class ServingEngine:
                 key("serving_verify", lanes=L, m=M, k=self.config.spec_k,
                     **extra(vstatic)),
                 lambda: ver.lower(
-                    self._params, *pools,
-                    jax.ShapeDtypeStruct((L, M), i32),
+                    self._params, *pools, self._read_spec("verify", L, S),
                     jax.ShapeDtypeStruct((L,), i32),
                     jax.ShapeDtypeStruct((L, S), i32),
                     jax.ShapeDtypeStruct((L,), i32), **vstatic),
@@ -481,10 +540,45 @@ class ServingEngine:
 
     # -- phases --------------------------------------------------------------
 
-    def _table_row(self, req) -> np.ndarray:
-        row = np.zeros((1, self.blocks_per_lane), np.int32)
-        row[0, :len(req.blocks)] = req.blocks
-        return row
+    def _rows_form(self, kind, lanes):
+        """:func:`fit_rows` of program ``kind``'s live-rows operand at
+        ``lanes`` lanes, or ``None``: the program takes a block table."""
+        form = self._family.read_form(kind)
+        return form and fit_rows(form, lanes, self.blocks_per_lane)
+
+    def _read_spec(self, kind, lanes, width):
+        """Shapes of program ``kind``'s read operand (:meth:`_pack_read`)
+        at ``lanes`` lanes of ``width`` positions."""
+        i32 = jnp.int32
+        form = self._rows_form(kind, lanes)
+        if form is None:
+            return jax.ShapeDtypeStruct((lanes, self.blocks_per_lane), i32)
+        return (jax.ShapeDtypeStruct((form[2], 2 + form[0]), i32),
+                jax.ShapeDtypeStruct((lanes, width), i32))
+
+    def _pack_read(self, kind, lanes, width, items, ph=None):
+        """Program ``kind``'s read operand for one call, as numpy, in
+        the form its family takes: a block TABLE ``[lanes, M]`` (every
+        lane's whole list, null-padded), or LIVE ROWS ``(rows, wblk)``
+        (:func:`pack_rows`; ``items`` as there). Bills the call to the
+        three ``kv_*`` read counters."""
+        B, M = self.config.block_size, self.blocks_per_lane
+        c = self.counters
+        c["kv_read_tokens"] += sum(it[3] for it in items)
+        c["kv_dense_read_tokens"] += lanes * M * B
+        form = self._rows_form(kind, lanes)
+        if form is None:
+            tables = np.zeros((lanes, M), np.int32)
+            for lane, blocks, _, _ in items:
+                tables[lane, :len(blocks)] = blocks
+            c["kv_gathered_tokens"] += lanes * M * B
+            return tables
+        w, tile, cap = form
+        rows, wblk, n, live = pack_rows(items, lanes, width, B, w, cap)
+        c["kv_gathered_tokens"] += -(-n // tile) * tile * w * B
+        if ph is not None and _spans is not None:
+            ph.args.update(rows=n, live_blocks=live)
+        return rows, wblk
 
     def _prefill(self, req) -> None:
         """Fill the lane's blocks chunk by chunk — starting at
@@ -508,7 +602,6 @@ class ServingEngine:
                          f"req/{req.trace_id}", request=req.trace_id,
                          hit_tokens=cached, miss_tokens=ctx - cached) as ph:
             C = self.config.prefill_chunk
-            table = jnp.asarray(self._table_row(req))
             sp = _spans
             p_t0 = req._t_mark  # admission stamped it just before this call
             nchunks = 0
@@ -519,9 +612,14 @@ class ServingEngine:
                 chunk = np.zeros((1, C), np.int32)
                 chunk[0, :piece.size] = piece
                 last_idx = ctx - 1 - start if start + C >= ctx else 0
+                # the chunk sees its lane's slots below its own end
+                read = self._pack_read(
+                    "prefill", 1, C,
+                    [(0, req.blocks, start, min(start + C, ctx))])
                 tok, *self._pools = self._prefill_exec(
-                    self._params, *self._pools, table, jnp.asarray(chunk),
-                    jnp.int32(start), jnp.int32(ctx), jnp.int32(last_idx))
+                    self._params, *self._pools, *jax.device_put(
+                        (read, chunk, np.int32(start), np.int32(ctx),
+                         np.int32(last_idx))))
                 nchunks += 1
                 if sp is not None:
                     # enqueue wall only (no per-chunk host sync — the one
@@ -641,23 +739,26 @@ class ServingEngine:
         their K/V sits above the lane's valid length in lane-private
         blocks (masked out of every later attend) until the next
         accepted write overwrites it."""
-        L, M = self.config.max_lanes, self.blocks_per_lane
-        K = self.config.spec_k
-        with self._phase("pack", "pack_s"):
-            tables = np.zeros((L, M), np.int32)
+        L, K = self.config.max_lanes, self.config.spec_k
+        with self._phase("pack", "pack_s") as ph:
             cur = np.zeros((L,), np.int32)
             toks = np.zeros((L, K + 1), np.int32)
             wlim = np.zeros((L,), np.int32)
+            items = []
             for req in act:
                 d = drafts.get(id(req), _EMPTY_DRAFT)
-                tables[req.lane, :len(req.blocks)] = req.blocks
                 cur[req.lane] = req.pool_len
                 toks[req.lane, 0] = req.output[-1]
                 if d.size:
                     toks[req.lane, 1:1 + d.size] = d
                 wlim[req.lane] = req.pool_len + 1 + d.size
-            operands = (jnp.asarray(tables), jnp.asarray(cur),
-                        jnp.asarray(toks), jnp.asarray(wlim))
+                # rejected positions sit above pool_len in lane-private
+                # blocks: the read covers the pending token and the draft
+                items.append((req.lane, req.blocks, req.pool_len,
+                              req.pool_len + 1 + int(d.size)))
+            operands = jax.device_put(
+                (self._pack_read("verify", L, K + 1, items, ph), cur, toks,
+                 wlim))
         preds, now = self._launch("verify", self._verify_exec, operands,
                                   len(act))
         preds = preds.reshape(L, K + 1)
@@ -665,7 +766,6 @@ class ServingEngine:
             self._accept(act, drafts, preds, now, ph)
 
     def _accept(self, act, drafts, preds, now, ph) -> None:
-        M = self.blocks_per_lane
         c = self.counters
         c["verify_steps"] += 1
         proposed = accepted = bonus = emitted = 0
@@ -714,16 +814,6 @@ class ServingEngine:
         c["spec_proposed_tokens"] += proposed
         c["spec_accepted_tokens"] += accepted
         c["spec_bonus_tokens"] += bonus
-        # byte-model inputs (see _plain_decode_round): a verify round
-        # performs the DENSE gather regardless of the paged engagement
-        # (s > 1 — no paged verify kernel exists), so both byte models
-        # bill the full table here; the paged-vs-dense delta the bench
-        # reports comes from plain decode rounds alone, which keeps the
-        # "what the chip actually moves" readout honest for spec-on
-        # paged engines
-        dense_slots = len(act) * M * self.config.block_size
-        c["kv_read_tokens"] += dense_slots
-        c["kv_dense_read_tokens"] += dense_slots
         if self.config.kv_int8:
             # every non-pad write this round quantized: each lane's
             # pending token + its (possibly rejected) draft — rejected
@@ -746,17 +836,18 @@ class ServingEngine:
                            accepted=accepted, bonus=bonus, emitted=emitted)
 
     def _plain_decode_round(self, act) -> None:
-        L, M = self.config.max_lanes, self.blocks_per_lane
-        with self._phase("pack", "pack_s"):
-            tables = np.zeros((L, M), np.int32)
+        L = self.config.max_lanes
+        with self._phase("pack", "pack_s") as ph:
             cur = np.zeros((L,), np.int32)
             last = np.zeros((L,), np.int32)
             for req in act:
-                tables[req.lane, :len(req.blocks)] = req.blocks
                 cur[req.lane] = req.pool_len
                 last[req.lane] = req.output[-1]
-            operands = (jnp.asarray(tables), jnp.asarray(cur),
-                        jnp.asarray(last))
+            read = self._pack_read(
+                "decode", L, 1,
+                [(r.lane, r.blocks, r.pool_len, r.pool_len + 1)
+                 for r in act], ph)
+            operands = jax.device_put((read, cur, last))
         toks, now = self._launch("decode", self._decode_exec, operands,
                                  len(act))
         with self._phase("emit", "emit_s") as ph:
@@ -765,13 +856,6 @@ class ServingEngine:
             c = self.counters
             c["decode_steps"] += 1
             c["decoded_tokens"] += len(act)
-            # live-prefix KV slots the paged kernel reads this round vs
-            # the full-table slots the dense gather reads — the roofline
-            # byte model's inputs (benchmarks/serving_bench.py hbm_util
-            # delta)
-            c["kv_read_tokens"] += sum(r.pool_len + 1 for r in act)
-            c["kv_dense_read_tokens"] += \
-                len(act) * M * self.config.block_size
             if self.config.kv_int8:
                 c["kv_quant_writes"] += 1
                 c["kv_quant_tokens"] += len(act)

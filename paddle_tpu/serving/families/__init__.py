@@ -12,12 +12,17 @@ and asks the model's *family* for the three things that differ:
 (b) the collected parameters: ``params`` (a pytree of device arrays; how
     it is laid out, and whether it copies the model's arrays, is the
     family's), ``gcfg`` the hashable static view the programs are keyed on;
-(c) the step programs over block tables: ``program(kind)`` for ``kind`` in
-    ``prefill`` / ``decode`` / ``verify`` gives ``(fn, static_kwargs)``
-    with ``fn(params, *pools, *operands, **static) -> (out, *pools)``;
-    operands are the engine's (``[1, M]`` table, ``[1, C]`` chunk, start,
-    context length, last index | ``[L, M]`` tables, ``[L]`` lengths, ``[L]``
-    tokens | tables, lengths, ``[L, k+1]`` tokens, ``[L]`` write limits).
+(c) the step programs: ``program(kind)`` for ``kind`` in ``prefill`` /
+    ``decode`` / ``verify`` gives ``(fn, static_kwargs)`` with
+    ``fn(params, *pools, read, *operands, **static) -> (out, *pools)``;
+    operands are the engine's (``[1, C]`` chunk, start, context length,
+    last index | ``[L]`` lengths, ``[L]`` tokens | lengths, ``[L, k+1]``
+    tokens, ``[L]`` write limits), and ``read`` says where the lanes' K/V
+    lies, in the form ``read_form(kind)`` names: ``None`` — a ``[lanes,
+    M]`` block table; ``(W, tile)`` — the lanes' live rows of ``W`` blocks,
+    which the program runs ``tile`` at a time, and each fed token's write
+    block: ``(rows [R, 2 + W], wblk [lanes, width])``
+    (``engine.pack_rows``).
 
 Plus ``absorb(out, counters)``: the round's ONE fetched array goes
 through it — a family that rides its own counters on that array strips

@@ -2,16 +2,24 @@
 serving engine sees it: a K pool and a V pool ``[layers, blocks, block,
 kv_heads, head_dim]`` (int8 mode: paired float32 scale pools), the
 weights stacked per leaf so each program scans over layers, and the three
-step programs over block tables. The programs are the ones
-``serving/engine.py`` held until the engine stopped knowing one
-architecture; they were moved here, not rewritten
-(tests/test_chip_compile.py holds what they compile to).
+step programs (``tests/test_chip_compile.py`` holds what they compile to).
+
+**The K/V read follows what the lanes hold** (``_attend_rows``): the
+engine's ``pack`` phase cuts each running lane's block list into rows of
+``ROW_BLOCKS`` blocks and lays all lanes' rows end to end; a program
+gathers the live rows a tile at a time from the stacked pool, attends row
+by row and recombines per lane as one softmax. No program gathers a
+table slot that holds nothing, so a call's cost follows the live blocks,
+not ``max_seq_len`` (PERF.md section 6, PR 28). ``_attend_lanes``, the
+read over a lane's whole gathered table, stays as the definition the row
+read (and the Pallas paged kernel) is held to.
 
 The attention/RoPE/MLP math reuses ``models/generation.py``'s helpers
-(``_rms``/``_mm``/``_rope_at``) and mirrors its ``_attend`` line for line
-— engine outputs are token-identical to per-request ``generate()`` calls
-(tests/test_serving.py proves it, padding included, because masked slots
-contribute exactly-zero softmax weight).
+(``_rms``/``_mm``/``_rope_at``) and mirrors its ``_attend`` — engine
+outputs are token-identical to per-request ``generate()`` calls
+(tests/test_serving.py and tests/test_serving_rows.py prove it, padding
+included, because masked slots and padded rows contribute exactly-zero
+softmax weight).
 """
 from __future__ import annotations
 
@@ -26,6 +34,16 @@ from ...models.generation import (
 
 __all__ = ["DenseGQAFamily"]
 
+# The K/V read's constants, chosen on the chip (PERF.md section 6, PR 28):
+# a row is ROW_BLOCKS blocks of one lane (wider rows make a 5-position
+# verify call cheaper, narrower ones pad a lane's last row less), and a
+# program runs its live rows ROW_TILE at a time (a tile costs ~9 us a
+# layer to start and pads a call by half of itself on average); the
+# prefill chunk, all rows one lane's: PREFILL_TILE.
+ROW_BLOCKS = 16
+ROW_TILE = 16
+PREFILL_TILE = 4
+
 
 # -- compiled phases ----------------------------------------------------------
 
@@ -38,7 +56,9 @@ def _attend_lanes(q, kc, vc, pos, nh, nkv, sliding_window=0):
     slot, and unallocated/pad slots sit above every real ``p``. The math
     (fp32 einsum, 1/sqrt(d), -1e30 mask, fp32 softmax/AV) mirrors
     ``_attend`` exactly so masked slots carry exactly-zero weight and
-    engine outputs stay token-identical to ``generate()``."""
+    engine outputs stay token-identical to ``generate()``. The programs
+    read by rows (``_attend_rows``); this is what a row read must equal,
+    and the Pallas paged kernel's composite."""
     b, s, _, d = q.shape
     L = kc.shape[1]
     g = nh // nkv
@@ -55,40 +75,125 @@ def _attend_lanes(q, kc, vc, pos, nh, nkv, sliding_window=0):
     return out.reshape(b, s, nh, d).astype(q.dtype)
 
 
-def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
-                  pos, wlimit, cfg, paged=False, paged_dead="clamp"):
+def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0):
+    """``_attend_lanes`` over LIVE ROWS: what each lane holds, cut into
+    rows of ``W`` blocks, and nothing else of its table. ``rows``
+    [R, 2 + W] int32 is one row a line, live rows first: the lane whose
+    query the row answers to (-1: a pad row, run only to fill the last
+    tile, answering to nobody), the absolute position of its first slot,
+    its ``W`` block ids (a lane's last row padded with null block 0).
+    ``gather(blocks [T, W])`` returns those blocks' K and V as
+    ``[T, W * B, nkv, d]``. q [b, s, nh, d], pos [b, s].
+
+    Rows run ``tile`` at a time under a device-side loop whose trip count
+    is data (the live rows, counted here): per row the fp32 scores of its
+    lane's query against its slots, the mask ``first + slot <= pos`` (and
+    the sliding window's lower edge), the row's max, its sum of
+    exponentials and its weighted sum of V; per tile those are folded into
+    each lane's running max / sum / weighted sum as a softmax over the
+    union of the lane's slots (rescaled by ``exp(row_max - lane_max)``;
+    added up by lane with a [lanes, rows] one-hot product). A masked slot
+    weighs exp(-1e30 - max) = 0 exactly, and so does every slot of a
+    wholly masked row once its lane has met a visible slot, before or
+    after it. A lane with no row (idle) reads 0."""
+    b, s, nh, d = q.shape
+    g = nh // nkv
+    f32 = jnp.float32
+    lane, first, blocks = rows[:, 0], rows[:, 1], rows[:, 2:]
+    tile = min(tile, rows.shape[0])  # engine.fit_rows: fewer rows, one tile
+    assert rows.shape[0] % tile == 0, (rows.shape, tile)
+    qg = q.reshape(b, s, nkv, g, d).astype(f32)
+
+    def one_tile(t, carry):
+        m, l, o = carry  # [b, s, nkv, g] twice, [b, s, nkv, g, d]
+        r0 = t * tile
+        ln = jax.lax.dynamic_slice_in_dim(lane, r0, tile)
+        own = jnp.maximum(ln, 0)
+        kc, vc = gather(jax.lax.dynamic_slice_in_dim(blocks, r0, tile))
+        S = kc.shape[1]
+        at = jax.lax.dynamic_slice_in_dim(first, r0, tile)[:, None, None] \
+            + jnp.arange(S)[None, None, :]                 # [T, 1, S]
+        p_own = pos[own][:, :, None]                       # [T, s, 1]
+        vis = at <= p_own
+        if sliding_window > 0:
+            vis &= at > p_own - sliding_window
+        logits = jnp.einsum("tskgd,tlkd->tskgl", qg[own],
+                            kc.astype(f32)) / np.sqrt(d)
+        logits = jnp.where(vis[:, :, None, None, :], logits, -1e30)
+        rm = jnp.max(logits, axis=-1)                      # [T, s, nkv, g]
+        p = jnp.exp(logits - rm[..., None])
+        rl = jnp.sum(p, axis=-1)
+        ro = jnp.einsum("tskgl,tlkd->tskgd", p, vc.astype(f32))
+        mine = ln[None, :] == jnp.arange(b)[:, None]       # [b, T]
+        m_new = jnp.maximum(m, jnp.max(
+            jnp.where(mine[:, :, None, None, None], rm[None], -1e30),
+            axis=1))
+        # (a pad row may outscore lane 0's max: its weight is 0, not inf)
+        w = jnp.exp(jnp.where((ln >= 0)[:, None, None, None],
+                              rm - m_new[own], -1e30))
+        keep = jnp.exp(m - m_new)
+        hot = mine.astype(f32)
+        exact = jax.lax.Precision.HIGHEST  # the one-hot sum is a sum
+        l = l * keep + jnp.einsum("bt,tskg->bskg", hot, rl * w,
+                                  precision=exact)
+        o = o * keep[..., None] + jnp.einsum(
+            "bt,tskgd->bskgd", hot, ro * w[..., None], precision=exact)
+        return m_new, l, o
+
+    n_tiles = (jnp.sum(lane >= 0, dtype=jnp.int32) + tile - 1) // tile
+    _, l, o = jax.lax.fori_loop(
+        0, n_tiles, one_tile,
+        (jnp.full((b, s, nkv, g), -1e30, f32),
+         jnp.zeros((b, s, nkv, g), f32),
+         jnp.zeros((b, s, nkv, g, d), f32)))
+    out = o / jnp.where(l > 0, l, 1.0)[..., None]
+    return out.reshape(b, s, nh, d).astype(q.dtype)
+
+
+def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
+                  pos, wlimit, cfg, tile, paged=False,
+                  paged_dead="clamp"):
     """Forward ``ids`` [b, s] at absolute positions ``pos`` [b, s]
     against the block pool: per layer, write each token's K/V into its
     lane's block at ``pos`` (writes at positions >= ``wlimit[b]`` — pad
     tail of a final prefill chunk, idle decode lanes — are redirected to
     null block 0 so they can never clobber live KV), then attend over
-    the lane's whole gathered table: every table slot, live or not, read
-    in one gather from the stacked pool by (layer, block) — no value of
-    one layer's pool shape is produced (tests/test_chip_compile.py holds
-    the compiled programs to that). Layer math is
+    the blocks the lanes HOLD: ``read`` is ``(rows, wblk)`` — the live
+    rows of ``_attend_rows`` and, per token, the block its position falls
+    in (``wblk`` [b, s], the host's lookup in the lane's block list) —
+    and each tile of rows is gathered from the stacked pool by
+    (layer, block): no value of one layer's pool shape is produced, nor
+    one of every lane's whole table (tests/test_chip_compile.py holds the
+    compiled programs to both). What a call reads follows the live
+    blocks, not ``max_seq_len``. Layer math is
     ``models/generation.py:_block`` on the pooled layout.
+
+    ``paged`` (static; one position a lane): ``read`` is the ``[b, M]``
+    block table and the Pallas paged-attention kernel walks it.
 
     ``kscale``/``vscale`` are the int8 mode's paired fp32 scale pools
     (``[layers, num_blocks, block_size, kv_heads]``; None in bf16 mode
-    — None is an empty pytree, so the bf16 jaxpr is byte-identical to
-    the pre-int8 program): writes quantize K/V per position through the
-    shared `quantization.quantize_kv` (scale writes ride the same
+    — None is an empty pytree): writes quantize K/V per position through
+    the shared `quantization.quantize_kv` (scale writes ride the same
     null-redirected ``blk``/``off``, null block included), reads
-    dequantize the gathered blocks before the same fp32 attention —
-    identical ops to ``generate(kv_int8=True)``'s round-trip, so the
-    two paths stay bit-equal. Returns
+    dequantize the gathered rows before the same fp32 attention — the
+    ops of ``generate(kv_int8=True)``'s round-trip. Returns
     (x [b, s, hidden], kpool, vpool, kscale, vscale)."""
     b, s = ids.shape
     nh = cfg.num_attention_heads
     nkv = cfg.num_key_value_heads or nh
     d = cfg.hidden_size // nh
     B = kpool.shape[2]
-    M = tables.shape[1]
     dt = jnp.dtype(cfg.dtype)
     quant = kscale is not None
     x = params["embed"][ids].astype(dt)
-    idx = jnp.minimum(pos // B, M - 1)  # pad pos can run past the table
-    blk = jnp.take_along_axis(tables, idx, axis=1)
+    if paged:
+        tables = read
+        # pad pos can run past the table
+        blk = jnp.take_along_axis(
+            tables, jnp.minimum(pos // B, tables.shape[1] - 1), axis=1)
+    else:
+        rows, blk = read
     ok = pos < wlimit[:, None]
     blk = jnp.where(ok, blk, 0)
     off = jnp.where(ok, pos % B, 0)
@@ -119,10 +224,7 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
             vs = vs.at[li, blk, off].set(v_s)
         kp = kp.at[li, blk, off].set(k)
         vp = vp.at[li, blk, off].set(v)
-        if paged and s == 1:
-            # Pallas paged read: gather straight from the pool via the
-            # block table, touching only each lane's live prefix — the
-            # dense gather below reads every table slot.
+        if paged:
             # (kp[li] here still hands the kernel a copy of the layer's
             # whole pool; no cell engages this branch — PERF.md 7)
             interp = not on_tpu()
@@ -143,8 +245,8 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
                     pos[:, 0], window=cfg.sliding_window,
                     dead=paged_dead, interpret=interp)[:, None]
         else:
-            # ONE gather per pool on the stacked pool, by (layer, block):
-            # kp[li][tables] makes the TPU materialise kp[li], the
+            # a tile's blocks come from the STACKED pool by (layer,
+            # block): kp[li][...] makes the TPU materialise kp[li], the
             # layer's whole pool, before every gather. Which of the two
             # forms without it follows the pool's dtype, as the chip
             # ran them (PERF.md section 6, PR 25): bf16 pools flattened
@@ -153,20 +255,21 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
             if quant:
                 from ...quantization import dequantize_kv
 
-                kc = dequantize_kv(
-                    kp[li, tables].reshape(b, M * B, nkv, d),
-                    ks[li, tables].reshape(b, M * B, nkv), dt)
-                vc = dequantize_kv(
-                    vp[li, tables].reshape(b, M * B, nkv, d),
-                    vs[li, tables].reshape(b, M * B, nkv), dt)
+                def gather(blocks):
+                    T, W = blocks.shape
+                    return tuple(dequantize_kv(
+                        c[li, blocks].reshape(T, W * B, nkv, d),
+                        sc[li, blocks].reshape(T, W * B, nkv), dt)
+                        for c, sc in ((kp, ks), (vp, vs)))
             else:
-                rows = tables + li * kp.shape[1]
-                kc = kp.reshape(-1, B, nkv, d)[rows].reshape(
-                    b, M * B, nkv, d)
-                vc = vp.reshape(-1, B, nkv, d)[rows].reshape(
-                    b, M * B, nkv, d)
-            out = _attend_lanes(q, kc, vc, pos, nh, nkv,
-                                sliding_window=cfg.sliding_window)
+                def gather(blocks):
+                    T, W = blocks.shape
+                    at = blocks + li * kp.shape[1]
+                    return tuple(
+                        c.reshape(-1, B, nkv, d)[at].reshape(
+                            T, W * B, nkv, d) for c in (kp, vp))
+            out = _attend_rows(q, pos, rows, gather, tile, nkv,
+                               sliding_window=cfg.sliding_window)
         x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])
         h2 = _rms(x, layer_p["ln2"], cfg.rms_norm_eps)
         gu = _mm(h2, layer_p["gate_up"])
@@ -187,18 +290,19 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, tables, ids,
     return x, kpool, vpool, kscale, vscale
 
 
-def _prefill_chunk(params, kpool, vpool, kscale, vscale, table, ids,
-                   start, ctx_len, last_idx, *, cfg):
+def _prefill_chunk(params, kpool, vpool, kscale, vscale, read, ids,
+                   start, ctx_len, last_idx, *, cfg, tile):
     """One lane's prefill chunk: ``ids`` [1, C] at positions
-    [start, start+C); greedy-samples from position ``last_idx`` within
+    [start, start+C), ``read`` its lane's rows live up to the chunk's
+    end; greedy-samples from position ``last_idx`` within
     the chunk (the overall last real token on the final chunk; ignored
     by the caller otherwise). Returns
     (tok [1], kpool, vpool, kscale, vscale)."""
     C = ids.shape[1]
     pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
     x, kpool, vpool, kscale, vscale = _pool_forward(
-        params, kpool, vpool, kscale, vscale, table, ids, pos,
-        jnp.reshape(ctx_len, (1,)), cfg)
+        params, kpool, vpool, kscale, vscale, read, ids, pos,
+        jnp.reshape(ctx_len, (1,)), cfg, tile=tile)
     x = _rms(x, params["norm"], cfg.rms_norm_eps)
     h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
     logits = _mm(h, params["lm_head"]).astype(jnp.float32)
@@ -206,27 +310,29 @@ def _prefill_chunk(params, kpool, vpool, kscale, vscale, table, ids,
             kscale, vscale)
 
 
-def _decode_step(params, kpool, vpool, kscale, vscale, tables, cur_len,
-                 last_tok, *, cfg, paged=False, paged_dead="clamp"):
+def _decode_step(params, kpool, vpool, kscale, vscale, read, cur_len,
+                 last_tok, *, cfg, tile, paged=False, paged_dead="clamp"):
     """The shared decode step: every lane feeds its pending token at
     position ``cur_len`` (write-then-attend, so the token sees itself
     like ``generate()``'s step does) and greedy-samples the next. Idle
-    lanes (cur_len 0, table row 0) write to the null block and their
-    outputs are ignored host-side. ``paged`` (static) swaps the dense
-    gathered KV read for the Pallas paged-attention kernel. Returns
+    lanes (cur_len 0, no row, write block 0) write to the null block and
+    their outputs are ignored host-side. ``paged`` (static) swaps the
+    row read for the Pallas paged-attention kernel over ``read`` = the
+    ``[L, M]`` block tables. Returns
     (tok [L], kpool, vpool, kscale, vscale)."""
     pos = cur_len[:, None]
     x, kpool, vpool, kscale, vscale = _pool_forward(
-        params, kpool, vpool, kscale, vscale, tables, last_tok[:, None],
-        pos, cur_len + 1, cfg, paged=paged, paged_dead=paged_dead)
+        params, kpool, vpool, kscale, vscale, read, last_tok[:, None],
+        pos, cur_len + 1, cfg, tile=tile, paged=paged,
+        paged_dead=paged_dead)
     x = _rms(x, params["norm"], cfg.rms_norm_eps)
     logits = _mm(x[:, -1], params["lm_head"]).astype(jnp.float32)
     return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
             kscale, vscale)
 
 
-def _verify_step(params, kpool, vpool, kscale, vscale, tables, cur_len,
-                 toks, wlimit, *, cfg):
+def _verify_step(params, kpool, vpool, kscale, vscale, read, cur_len,
+                 toks, wlimit, *, cfg, tile):
     """The speculative verify step: ``toks`` [L, k+1] holds each lane's
     pending token (column 0) followed by its draft, at absolute
     positions ``cur_len + j``. Writes at positions >= ``wlimit[b]`` (=
@@ -241,8 +347,8 @@ def _verify_step(params, kpool, vpool, kscale, vscale, tables, cur_len,
     S = toks.shape[1]
     pos = cur_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     x, kpool, vpool, kscale, vscale = _pool_forward(
-        params, kpool, vpool, kscale, vscale, tables, toks, pos, wlimit,
-        cfg)
+        params, kpool, vpool, kscale, vscale, read, toks, pos, wlimit,
+        cfg, tile=tile)
     x = _rms(x, params["norm"], cfg.rms_norm_eps)
     logits = _mm(x, params["lm_head"]).astype(jnp.float32)
     return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
@@ -329,14 +435,24 @@ class DenseGQAFamily:
             return False
         return _ksearch.decide(self.paged_family, key)
 
+    def read_form(self, kind):
+        """How program ``kind`` is told where its lanes' K/V lies
+        (``ServingEngine._pack_read`` builds it): ``(W, tile)`` — live
+        rows of ``W`` blocks, run ``tile`` at a time; ``None`` — a
+        ``[lanes, M]`` block table, which the Pallas paged kernel walks."""
+        if kind == "decode" and self.paged_active:
+            return None
+        return ROW_BLOCKS, PREFILL_TILE if kind == "prefill" else ROW_TILE
+
     def program(self, kind):
         """(function, static keyword arguments) of one step program."""
+        form = self.read_form(kind)
+        static = {"cfg": self.gcfg, "tile": form[1] if form else 0}
         if kind == "decode":
-            return _decode_step, {"cfg": self.gcfg,
-                                  "paged": self.paged_active,
+            return _decode_step, {**static, "paged": self.paged_active,
                                   "paged_dead": self.paged_dead}
-        return {"prefill": _prefill_chunk, "verify": _verify_step}[kind], \
-            {"cfg": self.gcfg}
+        return {"prefill": _prefill_chunk,
+                "verify": _verify_step}[kind], static
 
     def exec_key(self, pools):
         """The family's part of an exec-cache key."""

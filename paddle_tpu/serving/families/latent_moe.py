@@ -182,6 +182,11 @@ class LatentMoEFamily:
     def kv_pool_bytes(self, pools):
         return int(pools[0].nbytes)
 
+    def read_form(self, kind):
+        """Every program takes a ``[lanes, M]`` block table and gathers
+        all of it (what waits on the dense family's row read: PERF.md 7)."""
+        return None
+
     def program(self, kind):
         return {"prefill": _prefill_chunk, "decode": _decode_step,
                 "verify": _verify_step}[kind], {"cfg": self.gcfg}

@@ -143,6 +143,11 @@ _POOL = (37, 16, 2, 64)  # [num_blocks, block, kv_heads, head_dim]: no
 #                          other value of the programs has this shape
 
 
+_LANES, _TABLE = 8, 72  # lanes, blocks a lane: 5 rows of 16 blocks, so
+#                         rounds run 40 rows in tiles of 16 and a chunk 5
+#                         rows in tiles of 4: no tile is a whole table
+
+
 @pytest.fixture(scope="module")
 def engines():
     """A 3-layer bf16 engine per pool dtype whose layer pool is ``_POOL``
@@ -159,9 +164,44 @@ def engines():
         p._data = p._data.astype("bfloat16")
     model.eval()
     return {kv_int8: ServingEngine(model, ServingConfig(
-        max_lanes=4, block_size=block, num_blocks=nb, prefill_chunk=32,
-        max_seq_len=5 * block, kv_int8=kv_int8))
+        max_lanes=_LANES, block_size=block, num_blocks=nb,
+        prefill_chunk=32, max_seq_len=_TABLE * block, kv_int8=kv_int8))
         for kv_int8 in (False, True)}
+
+
+def _dense_program_text(topo, eng, kind):
+    """The dense family's program ``kind`` as the chip's compiler leaves
+    it, lowered as ``ServingEngine._ensure_compiled`` lowers it on the
+    chip (pools donated)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    cfg = eng.config
+    L, S, C = cfg.max_lanes, cfg.spec_k + 1, cfg.prefill_chunk
+    lanes, width, rest = {
+        "decode": (L, 1, (i32(L), i32(L))),
+        "verify": (L, S, (i32(L), i32(L, S), i32(L))),
+        "prefill": (1, C, (i32(1, C), i32(), i32(), i32())),
+    }[kind]
+    read = jax.tree_util.tree_map(spec, eng._read_spec(kind, lanes, width))
+    fn, static = eng._family.program(kind)
+    return jax.jit(
+        fn, static_argnames=tuple(static),
+        donate_argnums=eng._family.donate_argnums,
+    ).lower(*jax.tree_util.tree_map(spec, (eng._params, *eng._pools)),
+            read, *rest, **static).compile().as_text()
+
+
+def _results_shaped(text, dims):
+    """The instructions of ``text`` whose result has the shape ``dims``
+    (a regex over the comma-separated dims)."""
+    held = re.compile(rf"= \w+\[{dims}\]")
+    return [ln.strip()[:200] for ln in text.splitlines() if held.search(ln)]
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
@@ -173,42 +213,40 @@ def test_engine_program_never_holds_one_layers_pool(
     chip, to a fusion that writes out that layer's whole pool before the
     gather reads it — in every layer of every call (PERF.md section 6,
     PR 25). So: in the engine's programs as the chip's compiler leaves
-    them (lowered as ``ServingEngine._ensure_compiled`` lowers them on
-    the chip, pools donated), no instruction's result has one layer's
-    pool shape (nor, in int8 mode, one layer's scale-pool shape)."""
-    import paddle_tpu.serving.families.dense_gqa as E
-
+    them, no instruction's result has one layer's pool shape (nor, in
+    int8 mode, one layer's scale-pool shape)."""
     eng = engines[kv_int8]
     assert eng._pools[0].shape[1:] == _POOL
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def spec(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    cfg = eng.config
-    L, M = cfg.max_lanes, eng.blocks_per_lane
-    fn, rest = {
-        "decode": (E._decode_step, (i32(L, M), i32(L), i32(L))),
-        "verify": (E._verify_step,
-                   (i32(L, M), i32(L), i32(L, cfg.spec_k + 1), i32(L))),
-        "prefill": (E._prefill_chunk,
-                    (i32(1, M), i32(1, cfg.prefill_chunk), i32(), i32(),
-                     i32())),
-    }[kind]
-    pools = jax.tree_util.tree_map(spec, (eng._params, *eng._pools))
-    text = jax.jit(
-        fn, static_argnames=("cfg",),
-        donate_argnums=(1, 2, 3, 4) if kv_int8 else (1, 2),
-    ).lower(*pools, *rest, cfg=eng._gcfg).compile().as_text()
+    text = _dense_program_text(topo, eng, kind)
     nb, block, nkv, d = _POOL
-    held = re.compile(rf"= \w+\[{nb},{block},{nkv}(,{d})?\]")
-    lines = [ln.strip()[:200] for ln in text.splitlines()
-             if held.search(ln)]
+    lines = _results_shaped(text, rf"{nb},{block},{nkv}(,{d})?")
     assert not lines, "\n".join(lines[:6])
     assert f"[3,{nb},{block},{nkv},{d}]" in text  # the stacked pool is
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+def test_engine_program_never_holds_every_lanes_table(
+        topo, engines, kind, kv_int8):
+    """The K/V read gathers the rows the lanes hold, a tile at a time
+    (PERF.md section 6, PR 28): no instruction's result is shaped like
+    every lane's whole table — ``[L * M, block, kv_heads, head_dim]`` as
+    the full-table gather wrote it, or ``[L, M * block, ...]`` as the
+    attention then read it (int8: the scales' shapes, without the head
+    dim) — while a tile of rows is."""
+    eng = engines[kv_int8]
+    lanes = 1 if kind == "prefill" else _LANES
+    assert eng.blocks_per_lane == _TABLE
+    w, tile, cap = eng._rows_form(kind, lanes)
+    assert cap > tile  # several tiles: a tile is not the table
+    text = _dense_program_text(topo, eng, kind)
+    _, block, nkv, d = _POOL
+    for dims in (rf"{lanes * _TABLE},{block},{nkv}(,{d})?",
+                 rf"{lanes},{_TABLE * block},{nkv}(,{d})?"):
+        lines = _results_shaped(text, dims)
+        assert not lines, "\n".join(lines[:6])
+    assert _results_shaped(
+        text, rf"({tile * w},{block}|{tile},{w * block}),{nkv},{d}")
 
 
 # -- the latent-attention sparse-expert family's programs ----------------------

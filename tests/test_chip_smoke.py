@@ -66,7 +66,7 @@ def test_rehearsal_train_line(rehearsal):
 
 
 @pytest.mark.parametrize("engine,read_path,held_to", [
-    ("default", "dense gather", "generate()"),
+    ("default", "row gather", "generate()"),
     ("paged_on", "pallas paged_attention", "default engine"),
     ("kv_int8_paged_on", "pallas paged_attention_int8",
      "generate(kv_int8=True)"),

@@ -453,23 +453,22 @@ def test_a_router_moved_inside_the_margin_reads_no_gap(ref, walked,
     assert (walk <= plain + 1e-6).all()  # never reads higher than plainly
 
 
-# -- the dense family's programs did not change --------------------------------
+# -- the dense family's programs change only on purpose -------------------------
 
 # sha256 (first 16 hex) of the lowered text of the three dense serving
-# programs at the geometry below, read on the parent of the PR that moved
-# them from serving/engine.py to serving/families/dense_gqa.py (PR 27;
-# JAX 0.9.0, matmul precision "highest" as tests/conftest.py sets it; at
-# the default precision parent and change agreed too: 14741224...,
-# 6c3d6418..., 61473485..., f40557df..., fe918b41..., 2693dc13...). A
-# change to the moved functions changes a hash; so does another JAX: then
-# read them again from a checkout of the parent.
+# programs at the geometry below (JAX 0.9.0, matmul precision "highest" as
+# tests/conftest.py sets it). PR 27 read them on its parent to prove that
+# moving the programs to serving/families/dense_gqa.py changed nothing;
+# PR 28 changed the programs on purpose (the K/V read goes by live rows)
+# and read them again. A change to the programs' functions changes a
+# hash: read them again when that is meant. So does another JAX.
 _DENSE_PROGRAMS = {
-    (False, "decode"): "8fcfc52440a51da9",
-    (False, "verify"): "f4d751dd0d16c668",
-    (False, "prefill"): "6c4d9e18e0f08fb6",
-    (True, "decode"): "87efdeb5005e3140",
-    (True, "verify"): "041c860e30b5ba62",
-    (True, "prefill"): "fc4c303ffcf3dbc5",
+    (False, "decode"): "d2b0e0cbb344f730",
+    (False, "verify"): "7a3e9826422a3c0e",
+    (False, "prefill"): "0507c2621efdc46b",
+    (True, "decode"): "8e811a28ea173db7",
+    (True, "verify"): "a35ff204101ef2f3",
+    (True, "prefill"): "e80960dc3740385f",
 }
 
 
@@ -505,14 +504,16 @@ def test_dense_serving_programs_are_unchanged(dense_engines, kv_int8, kind):
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
-    L, M = 4, eng.blocks_per_lane
-    rest = {"decode": (i32(L, M), i32(L), i32(L)),
-            "verify": (i32(L, M), i32(L), i32(L, 5), i32(L)),
-            "prefill": (i32(1, M), i32(1, 32), i32(), i32(), i32())}[kind]
-    fn, _ = fam.program(kind)
+    L = 4
+    lanes, width, rest = {
+        "decode": (L, 1, (i32(L), i32(L))),
+        "verify": (L, 5, (i32(L), i32(L, 5), i32(L))),
+        "prefill": (1, 32, (i32(1, 32), i32(), i32(), i32()))}[kind]
+    fn, static = fam.program(kind)
     pools = jax.tree_util.tree_map(spec, (eng._params, *eng._pools))
     with jax.default_matmul_precision("highest"):
-        text = jax.jit(fn, static_argnames=("cfg",)).lower(
-            *pools, *rest, cfg=eng._gcfg).as_text()
+        text = jax.jit(fn, static_argnames=tuple(static)).lower(
+            *pools, eng._read_spec(kind, lanes, width), *rest,
+            **static).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == _DENSE_PROGRAMS[kv_int8, kind]

@@ -1,0 +1,292 @@
+"""The dense family's K/V read over LIVE ROWS (ISSUE 28): each lane's
+blocks cut into rows of ``W``, all lanes' rows end to end, run a tile at
+a time and recombined per lane as one softmax.
+
+(a) ``_attend_rows`` against ``_attend_lanes`` over every lane's whole
+    table, at fp32 tolerance, over the ragged shapes that decide it;
+(b) engine outputs token-identical to per-request ``generate()`` with the
+    read's constants steered small, so that tiny engines cut lanes into
+    several rows over several tiles: decode, verify with accepted and
+    rejected drafts, chunked prefill over a prefix-cache hit,
+    preempt-and-recompute, the int8 pool;
+(c) the three read counters on a ragged run.
+"""
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, generate
+from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.serving.engine import fit_rows, pack_rows
+from paddle_tpu.serving.families import dense_gqa as E
+
+B, NKV, G, D = 4, 2, 2, 8  # block, kv heads, group, head dim
+M = 12                     # blocks a lane: 48 slots
+
+# lens: tokens each lane already holds (0: an idle lane); the call feeds
+# `s` more a lane. W, tile: the read's constants. window: sliding window.
+_CASES = {
+    "ragged": dict(lens=[5, 17, 30, 2], W=2, tile=4),
+    "exactly_k_rows": dict(lens=[15, 23, 7], W=2, tile=3),  # +1: 16, 24, 8
+    "one_slot_into_a_new_row": dict(lens=[16, 24, 8], W=2, tile=3),
+    "idle_lanes": dict(lens=[0, 9, 0, 21], W=2, tile=2),
+    "all_pad_tile": dict(lens=[3, 0, 0, 0], W=4, tile=8),
+    "one_lane_over_several_tiles": dict(lens=[43, 1], W=2, tile=2),
+    "one_row_a_lane": dict(lens=[20, 9, 33], W=12, tile=3),
+    "one_block_rows": dict(lens=[20, 9, 33], W=1, tile=5),
+    "one_tile": dict(lens=[20, 9, 33], W=3, tile=12),
+    "window": dict(lens=[40, 13, 26], W=2, tile=4, window=6),
+    "window_wider_than_a_row": dict(lens=[40, 13, 26], W=2, tile=3,
+                                    window=19),
+}
+
+
+def _operands(lens, s, seed):
+    rng = np.random.RandomState(seed)
+    L = len(lens)
+    nb = 1 + L * M
+    pools = [jnp.asarray(rng.randn(nb, B, NKV, D).astype(np.float32))
+             for _ in range(2)]
+    tables = rng.permutation(np.arange(1, nb)).reshape(L, M).astype(np.int32)
+    q = jnp.asarray(rng.randn(L, s, NKV * G, D).astype(np.float32))
+    pos = jnp.asarray(np.asarray(lens)[:, None] + np.arange(s)[None, :],
+                      jnp.int32)
+    return pools, tables, q, pos
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_rows_read_is_the_full_table_read(case, s):
+    c = _CASES[case]
+    lens, W, window = c["lens"], c["W"], c.get("window", 0)
+    (kp, vp), tables, q, pos = _operands(lens, s, seed=len(case))
+    L = len(lens)
+    want = E._attend_lanes(
+        q, kp[tables].reshape(L, M * B, NKV, D),
+        vp[tables].reshape(L, M * B, NKV, D), pos, NKV * G, NKV,
+        sliding_window=window)
+
+    w, tile, cap = fit_rows((W, c["tile"]), L, M)
+    rows, _, n, live = pack_rows(
+        [(i, list(tables[i]), lens[i], lens[i] + s)
+         for i in range(L) if lens[i]], L, s, B, w, cap)
+    assert live == sum(-(-(n_ + s) // B) for n_ in lens if n_)
+    assert (rows[:n, 0] >= 0).all() and (rows[n:, 0] == -1).all()
+
+    def gather(blocks):
+        T = blocks.shape[0]
+        return tuple(p[blocks].reshape(T, w * B, NKV, D) for p in (kp, vp))
+
+    got = E._attend_rows(q, pos, jnp.asarray(rows), gather, tile, NKV,
+                         sliding_window=window)
+    held = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(got)[held],
+                               np.asarray(want)[held], rtol=2e-5, atol=2e-6)
+    assert np.isfinite(np.asarray(got)).all()  # idle lanes read 0, not NaN
+    assert (np.asarray(got)[~held] == 0).all()
+
+
+def test_a_masked_row_weighs_nothing_whichever_side_it_lies():
+    """A wholly masked row (under the window, or above the position)
+    contributes exactly zero: poisoning its blocks with huge values moves
+    nothing, before the lane's visible rows or after them."""
+    lens, s, W, tile, window = [37], 2, 2, 2, 9
+    (kp, vp), tables, q, pos = _operands(lens, s, seed=3)
+    w, tile, cap = fit_rows((W, tile), 1, M)
+    # rows over the lane's WHOLE table: the rows above its positions too
+    rows, *_ = pack_rows([(0, list(tables[0]), lens[0], M * B)], 1, s, B,
+                         w, cap)
+
+    def read(k, v):
+        def gather(blocks):
+            T = blocks.shape[0]
+            return tuple(p[blocks].reshape(T, w * B, NKV, D)
+                         for p in (k, v))
+        return np.asarray(E._attend_rows(
+            q, pos, jnp.asarray(rows), gather, tile, NKV,
+            sliding_window=window))
+
+    # rows 0-2 (slots 0..23) lie under the window of positions 37-38
+    # (slots > 28), rows 5 (slots 40..47) above them
+    dead = np.concatenate([tables[0, :6], tables[0, 10:]])
+    got = read(kp.at[dead].set(3e4), vp.at[dead].set(-7e4))
+    np.testing.assert_array_equal(got, read(kp, vp))
+
+
+# -- (b) the engine, with the constants steered small --------------------------
+
+@pytest.fixture(scope="module")
+def model():
+    pt.seed(0)
+    m = LlamaForCausalLM(LlamaConfig.tiny())
+    m.eval()
+    return m
+
+
+def _reference(model, prompt, new, **kw):
+    return generate(model, pt.to_tensor(np.asarray(prompt)[None, :]),
+                    max_new_tokens=new, **kw).numpy()[0]
+
+
+@pytest.fixture(params=[(2, 2, 1), (3, 5, 2), (1, 3, 3)],
+                ids=["W2_tile2", "W3_tile5", "W1_tile3"])
+def small_rows(request, monkeypatch):
+    """Rows of W blocks, tiles of a few rows: an engine of 3 lanes x 16
+    blocks then runs 2-6 rows a lane over several tiles."""
+    w, tile, ptile = request.param
+    monkeypatch.setattr(E, "ROW_BLOCKS", w)
+    monkeypatch.setattr(E, "ROW_TILE", tile)
+    monkeypatch.setattr(E, "PREFILL_TILE", ptile)
+    return request.param
+
+
+class _OracleDrafter:
+    """Proposes each request's true continuation, every second proposal
+    with its second token wrong: accepted prefixes AND rejections."""
+
+    def __init__(self, refs, vocab):
+        self.refs, self.vocab, self.calls = refs, vocab, 0
+
+    def propose(self, ctx, cap):
+        ctx = np.asarray(ctx)
+        for prompt, full in self.refs:
+            if ctx.size >= prompt.size \
+                    and (ctx[:prompt.size] == prompt).all():
+                d = np.array(full[ctx.size:ctx.size + cap], np.int32)
+                self.calls += 1
+                if d.size > 1 and self.calls % 2:
+                    d[1] = (d[1] + 1) % self.vocab
+                return d
+        return np.zeros((0,), np.int32)
+
+
+def _requests(model, n, seed, lo=3, hi=14, new=(5, 13)):
+    rng = np.random.RandomState(seed)
+    out = []
+    for _ in range(n):
+        plen, k = int(rng.randint(lo, hi)), int(rng.randint(*new))
+        out.append((rng.randint(0, model.config.vocab_size,
+                                (plen,)).astype(np.int32), k))
+    return out
+
+
+def _serve(eng, reqs):
+    handles = [eng.submit(p, max_new_tokens=k) for p, k in reqs]
+    outs = eng.run()
+    return [outs[h.request_id] for h in handles]
+
+
+def _hold(model, got, reqs, **kw):
+    for i, ((p, k), out) in enumerate(zip(reqs, got)):
+        np.testing.assert_array_equal(
+            out, _reference(model, p, k, **kw),
+            err_msg=f"request {i} diverged from generate()")
+
+
+def test_engine_decode_is_generate(model, small_rows):
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32,
+        spec=False))
+    reqs = _requests(model, 7, seed=1)
+    _hold(model, _serve(eng, reqs), reqs)
+    assert eng.counters["decode_steps"] > 0 == eng.counters["verify_steps"]
+    w, tile, cap = eng._rows_form("decode", 3)
+    assert (w, tile) == small_rows[:2] and cap > tile  # several tiles
+
+
+def test_engine_verify_accepts_rejects_and_overwrites(model, small_rows):
+    reqs = _requests(model, 6, seed=2, new=(8, 14))
+    refs = [(p, np.concatenate([p, _reference(model, p, k)]))
+            for p, k in reqs]
+    drafter = _OracleDrafter(refs, model.config.vocab_size)
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32,
+        spec_k=3), drafter=drafter)
+    _hold(model, _serve(eng, reqs), reqs)
+    c = eng.counters
+    assert c["verify_steps"] > 0
+    # accepted prefixes, and rejected tails whose K/V a later round
+    # overwrote (rollback is a rewind of pool_len alone)
+    assert 0 < c["spec_accepted_tokens"] < c["spec_proposed_tokens"]
+
+
+def test_engine_prefill_over_a_prefix_hit(model, small_rows):
+    rng = np.random.RandomState(4)
+    system = rng.randint(0, model.config.vocab_size, (11,)).astype(np.int32)
+    reqs = [(np.concatenate([system, rng.randint(
+        0, model.config.vocab_size, (int(n),)).astype(np.int32)]), 6)
+        for n in (3, 9, 1, 6)]
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=2, block_size=2, prefill_chunk=4, max_seq_len=32))
+    _hold(model, _serve(eng, reqs), reqs)
+    # later requests started their chunks above the shared blocks
+    assert eng.counters["prefix_hit_tokens"] >= 10
+
+
+def test_engine_preempt_and_recompute(model, small_rows):
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=3, block_size=2, num_blocks=14, prefill_chunk=4,
+        max_seq_len=24))
+    reqs = _requests(model, 6, seed=5, lo=2, hi=9, new=(6, 12))
+    _hold(model, _serve(eng, reqs), reqs)
+    assert eng.counters["preemptions"] > 0, "never preempted: vacuous"
+
+
+def test_engine_int8_pool_is_generate_kv_int8(model, small_rows):
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32,
+        kv_int8=True))
+    reqs = _requests(model, 5, seed=6)
+    _hold(model, _serve(eng, reqs), reqs, kv_int8=True)
+    assert eng.counters["kv_quant_tokens"] > 0
+
+
+def test_engine_sliding_window_reads_rows(small_rows):
+    pt.seed(7)
+    m = LlamaForCausalLM(LlamaConfig.tiny(sliding_window=5))
+    m.eval()
+    eng = ServingEngine(m, ServingConfig(
+        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32))
+    reqs = _requests(m, 5, seed=8, lo=6, hi=15)
+    _hold(m, _serve(eng, reqs), reqs)
+
+
+# -- (c) the counters -----------------------------------------------------------
+
+@pytest.mark.parametrize("spec", [False, True], ids=["decode", "verify"])
+def test_read_counters_on_a_ragged_run(model, small_rows, spec):
+    """Per program call: live tokens <= slots gathered < live tokens + a
+    row's slack a lane + a tile's slack; and never more than a gather of
+    every lane's whole table."""
+    lanes, block = 3, 2
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=lanes, block_size=block, prefill_chunk=4, max_seq_len=32,
+        spec=spec))
+    reqs = [(p, k) for (p, k), n in zip(
+        _requests(model, 6, seed=9), (3, 14, 5, 9, 2, 12)) for p in [p[:n]]]
+    _serve(eng, reqs)
+    c = eng.counters
+    calls = c["decode_steps"] + c["verify_steps"] + c["prefill_chunks"]
+    w, tile, _ = eng._rows_form("decode", lanes)
+    slack = lanes * w * block + tile * w * block
+    assert 0 < c["kv_read_tokens"] <= c["kv_gathered_tokens"] \
+        < c["kv_read_tokens"] + calls * slack
+    assert c["kv_gathered_tokens"] <= c["kv_dense_read_tokens"]
+    assert c["kv_dense_read_tokens"] == eng.blocks_per_lane * block * (
+        lanes * (c["decode_steps"] + c["verify_steps"])
+        + c["prefill_chunks"])
+
+
+def test_a_table_form_program_bills_its_whole_table(model):
+    """The Pallas paged decode walks a [lanes, M] table: gathered == the
+    table there; verify rounds and chunks of the same engine read rows."""
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=2, block_size=2, prefill_chunk=4, max_seq_len=16,
+        paged=True, spec=False))
+    assert eng._rows_form("decode", 2) is None
+    assert eng._rows_form("prefill", 1) is not None
+    _serve(eng, _requests(model, 3, seed=10, hi=8, new=(3, 6)))
+    c = eng.counters
+    assert c["kv_read_tokens"] < c["kv_gathered_tokens"] \
+        <= c["kv_dense_read_tokens"]
