@@ -403,16 +403,8 @@ def main():
     decode_bytes = (rounds * param_bytes
                     + stats["kv_read_tokens"] * tok_kv_bytes
                     + stats["decoded_tokens"] * tok_kv_bytes)
-    # the dense gathered read's byte model (every table slot, live or
-    # not): with the paged kernel active the live-prefix model above is
-    # what the chip actually moves, and util_dense - util is the
-    # fraction of the pipe the paged read freed
-    dense_bytes = (rounds * param_bytes
-                   + stats["kv_dense_read_tokens"] * tok_kv_bytes
-                   + stats["decoded_tokens"] * tok_kv_bytes)
     decode_wall = stats["dispatch_s"] + stats["fetch_s"] or 1e-9
     achieved_gbps = decode_bytes / decode_wall / 1e9
-    dense_gbps = dense_bytes / decode_wall / 1e9
     peak = db._peak_hbm_gbps(jax.devices()[0])
 
     rec = {"metric": "serving_tokens_per_sec",
@@ -472,7 +464,6 @@ def main():
            "kv_bytes_per_token": int(tok_kv_bytes),
            "allocatable_tokens": int(allocatable),
            "kv_pool_bytes": stats.get("kv_pool_bytes"),
-           "paged_attention": bool(stats["paged_attention"]),
            "replicas": replicas if replicas > 1 else 1}
     if replicas > 1:
         # router readout: affinity hit rate is the --affinity-drop
@@ -583,24 +574,11 @@ def main():
                 rec["kv_bf16"]["peak_hbm_gib"] = pk
         except Exception:  # noqa: BLE001 — a readout must not break the line
             pass
-    if stats["paged_attention"] and peak:
-        # the dense read this engine no longer performs, as utilization
-        # (docs/KERNELS.md: the paged kernel's measured-win readout)
-        rec["hbm_util_dense"] = round(dense_gbps / peak, 4)
-        rec["hbm_util_delta"] = round((dense_gbps - achieved_gbps)
-                                      / peak, 4)
     try:
         from paddle_tpu.ops.pallas import search as _ksearch
 
-        # {family: engaged} for the guard's engagement-regression gate;
-        # the serving engine's ACTUAL read path overrides the
-        # table-derived view (forced modes included)
-        kernels = _ksearch.engagement_report()
-        # the engine reads through paged_attention_int8 when kv_int8 —
-        # override the family it ACTUALLY routed, not the bf16 one
-        kernels[stats.get("paged_family", "paged_attention")] = bool(
-            stats["paged_attention"])
-        rec["kernels"] = kernels
+        # {family: engaged} for the guard's engagement-regression gate
+        rec["kernels"] = _ksearch.engagement_report()
     except Exception:  # noqa: BLE001 — a readout must not break the line
         pass
     # runtime telemetry rides along like bench.py's line: compile cost

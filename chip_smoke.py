@@ -360,8 +360,7 @@ def run_engine(model, requests, events, **cfg_kw):
     c.need(st["prefix_hit_tokens"] > 0, "zero prefix-hit tokens")
     c.need(st["spec_accepted_tokens"] > 0, "zero accepted draft tokens")
     facts = {
-        "read_path": ("pallas " + st["paged_family"]
-                      if st["paged_attention"] else "row gather"),
+        "read_path": "row gather",
         "kv_int8": st["kv_int8"], "programs_compiled": n_programs,
         "compiles_while_serving": events["backend_compiles"] - b0,
         "warmup_s": round(warm_s, 1), "serve_wall_s": round(wall_s, 2),
@@ -516,24 +515,13 @@ def phase_serve(args, size, events):
     line["default"] = {**facts, "held_to": "generate()", **cmp_}
     c.failed += failed + failed2
 
-    paged, facts, failed = run_engine(model, requests, events, paged="on")
-    cmp_, failed2 = hold_to("paged=on vs default engine", paged, default,
-                            requests, rows[False])
-    c.need(facts["read_path"].startswith("pallas"),
-           "paged='on' did not resolve to the Pallas read path")
-    line["paged_on"] = {**facts, "held_to": "default engine", **cmp_}
-    c.failed += failed + failed2
-
     ref8 = reference(model, requests, kv_int8=True)
     int8, facts, failed = run_engine(model, requests, events,
-                                     kv_int8=True, paged="on")
+                                     kv_int8=True)
     cmp_, failed2 = hold_to("kv_int8 vs generate(kv_int8=True)", int8,
                             ref8, requests, rows[True])
-    c.need(facts["read_path"] == "pallas paged_attention_int8",
-           "kv_int8 + paged='on' did not resolve to the int8 kernel")
-    line["kv_int8_paged_on"] = {**facts,
-                                "held_to": "generate(kv_int8=True)",
-                                **cmp_}
+    line["kv_int8"] = {**facts, "held_to": "generate(kv_int8=True)",
+                       **cmp_}
     c.failed += failed + failed2
 
     line.update(ok=not c.failed, failed=c.failed,

@@ -5,18 +5,14 @@ Mosaic kernels). Importing this package registers every kernel for platform
 from . import autotune as _autotune  # noqa: F401 — registers the flash family
 from . import flash_attention as _fa
 from . import head_flash as _hf
-from . import paged_attention as _pa
 from . import search  # noqa: F401 — the kernel search harness
 
 _fa.register(platform="tpu")
 _hf.register(platform="tpu")
-_pa.register(platform="tpu")
 
 flash_attention_kernel = _fa.flash_attention_kernel
 register_flash_attention = _fa.register
 hb_flash = _hf.hb_flash
-paged_attend = _pa.paged_attend
-paged_attend_int8 = _pa.paged_attend_int8
 
 
 def check_tpu_lowering():

@@ -241,10 +241,10 @@ def quantize_weight_int8(w):
 def quantize_kv(x):
     """Per-position symmetric int8 KV quantization — THE shared helper
     for the int8 KV-cache path (`PT_SERVE_KV_INT8`): the serving
-    engine's quantize-on-write (`serving/engine.py:_pool_forward`), the
-    reference round-trip (`models/generation.py` ``kv_int8=True``), and
-    the `paged_attention_int8` kernel family's input builder all route
-    through it, so the three paths cannot diverge on scale/clip
+    engine's quantize-on-write
+    (`serving/families/dense_gqa.py:_pool_forward`) and the reference
+    round-trip (`models/generation.py` ``kv_int8=True``) both route
+    through it, so the two paths cannot diverge on scale/clip
     semantics. Amax is over the trailing head_dim axis: x [..., d] ->
     (q int8 [..., d], s fp32 [...]) — one scale per (position, kv_head),
     which is exactly per (layer, block, slot, kv_head) once written into
@@ -260,9 +260,8 @@ def quantize_kv(x):
 def dequantize_kv(q, s, dtype):
     """Inverse of :func:`quantize_kv`: q int8 [..., d] and s fp32 [...]
     back to ``dtype``. fp32 multiply then one cast — bit-identical
-    whether it runs in the engine's dense read, the reference
-    round-trip, or the paged kernel's in-tile dequant (which keeps the
-    fp32 product and lets the attention math consume it)."""
+    whether it runs in the engine's row read or the reference
+    round-trip."""
     return (q.astype(jnp.float32) * s[..., None]).astype(dtype)
 
 

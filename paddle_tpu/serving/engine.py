@@ -7,8 +7,9 @@ and growth never retrace. Every program call is told where its lanes'
 K/V lies in the form its family takes (``read_form``): the lanes' LIVE
 ROWS — each lane's block list cut into rows of a few blocks, all lanes'
 rows end to end (:func:`pack_rows`), so a call gathers what the lanes
-hold and not every slot of every lane's table — or a ``[lanes, M]``
-block table. Three compiled programs serve the whole lifetime:
+hold and not every slot of every lane's table (the dense family) — or
+a ``[lanes, M]`` block table (the latent family, its one user). Three
+compiled programs serve the whole lifetime:
 
 - **prefill chunk** ``[1, C]``: one lane's context enters the pool C
   tokens at a time (padded tail chunks write only below the context
@@ -145,12 +146,6 @@ class ServingConfig:
       static exec-cache key, so churn still never retraces and a fleet
       still pays exactly 3 fresh compiles. Off = today's engine, byte
       for byte. docs/SERVING.md "int8 KV".
-    - ``paged`` (``PT_SERVE_PAGED``): decode-attention read path —
-      ``"auto"`` (default) engages the Pallas paged-attention kernel
-      (``ops/pallas/paged_attention.py``) only on a measured-faster
-      tune-table row for this geometry (measurement-first; no row =
-      the gathered row read), ``"1"``/True forces it on,
-      ``"0"``/False off.
     - ``prefix_cache`` (``PT_SERVE_PREFIX_CACHE``, on): ref-counted
       prefix sharing in the block pool — requests whose context starts
       with already-cached full blocks (shared system prompts, few-shot
@@ -166,8 +161,7 @@ class ServingConfig:
 
     def __init__(self, max_lanes=None, block_size=None, num_blocks=None,
                  prefill_chunk=None, max_seq_len=None, int8_weights=None,
-                 paged=None, prefix_cache=None, spec=None, spec_k=None,
-                 kv_int8=None):
+                 prefix_cache=None, spec=None, spec_k=None, kv_int8=None):
         self.max_lanes = max_lanes if max_lanes is not None \
             else _env_int("PT_SERVE_LANES", 8)
         self.block_size = block_size if block_size is not None \
@@ -184,14 +178,6 @@ class ServingConfig:
         if kv_int8 is None:
             kv_int8 = os.environ.get("PT_SERVE_KV_INT8") == "1"
         self.kv_int8 = bool(kv_int8)
-        if paged is None:
-            paged = os.environ.get("PT_SERVE_PAGED", "auto")
-        if paged in (True, 1, "1", "on"):
-            self.paged = "on"
-        elif paged in (False, 0, "0", "off"):
-            self.paged = "off"
-        else:
-            self.paged = "auto"
         if prefix_cache is None:
             prefix_cache = os.environ.get(
                 "PT_SERVE_PREFIX_CACHE", "1") not in ("0", "off")
@@ -314,9 +300,6 @@ class ServingEngine:
         self.spec_active = bool(cfg.spec and cfg.spec_k > 0)
         self.drafter = drafter if drafter is not None \
             else (NgramDrafter() if self.spec_active else None)
-        self.paged_active = fam.paged_active
-        self._paged_family = fam.paged_family
-        self._paged_dead = fam.paged_dead
         # always-on plain-int accounting (the serving bench's source of
         # truth; independent of the monitor like exec_cache._stats).
         # Per program call (rounds and prefill chunks): kv_read_tokens
@@ -542,7 +525,8 @@ class ServingEngine:
 
     def _rows_form(self, kind, lanes):
         """:func:`fit_rows` of program ``kind``'s live-rows operand at
-        ``lanes`` lanes, or ``None``: the program takes a block table."""
+        ``lanes`` lanes, or ``None``: the program takes a block table
+        (the latent family's three do)."""
         form = self._family.read_form(kind)
         return form and fit_rows(form, lanes, self.blocks_per_lane)
 
@@ -558,10 +542,11 @@ class ServingEngine:
 
     def _pack_read(self, kind, lanes, width, items, ph=None):
         """Program ``kind``'s read operand for one call, as numpy, in
-        the form its family takes: a block TABLE ``[lanes, M]`` (every
-        lane's whole list, null-padded), or LIVE ROWS ``(rows, wblk)``
-        (:func:`pack_rows`; ``items`` as there). Bills the call to the
-        three ``kv_*`` read counters."""
+        the form its family takes: LIVE ROWS ``(rows, wblk)``
+        (:func:`pack_rows`; ``items`` as there), or — the latent family
+        only — a block TABLE ``[lanes, M]`` (every lane's whole list,
+        null-padded). Bills the call to the three ``kv_*`` read
+        counters."""
         B, M = self.config.block_size, self.blocks_per_lane
         c = self.counters
         c["kv_read_tokens"] += sum(it[3] for it in items)
@@ -956,7 +941,6 @@ class ServingEngine:
                 "spec": self.spec_active,
                 "spec_k": self.config.spec_k,
                 "prefix_cache": self.config.prefix_cache,
-                "paged": self.paged_active,
                 "kv_int8": self.config.kv_int8,
             },
             "counters": dict(self.counters),
@@ -983,9 +967,8 @@ class ServingEngine:
             int8_weights=self.config.int8_weights,
             kv_int8=self.config.kv_int8,
             kv_pool_bytes=self.kv_pool_bytes,
-            paged_attention=self.paged_active,
-            paged_family=self._paged_family,
-            paged_dead=self._paged_dead,
+            # a constant: benchmarks/chip/chiplib/serve.py reads the key
+            paged_attention=False,
             prefix_cache=self.config.prefix_cache,
             shared_blocks=self.scheduler.pool.shared_count,
             cold_blocks=self.scheduler.pool.cold_count,

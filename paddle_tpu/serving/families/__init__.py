@@ -18,17 +18,16 @@ and asks the model's *family* for the three things that differ:
     operands are the engine's (``[1, C]`` chunk, start, context length,
     last index | ``[L]`` lengths, ``[L]`` tokens | lengths, ``[L, k+1]``
     tokens, ``[L]`` write limits), and ``read`` says where the lanes' K/V
-    lies, in the form ``read_form(kind)`` names: ``None`` — a ``[lanes,
-    M]`` block table; ``(W, tile)`` — the lanes' live rows of ``W`` blocks,
-    which the program runs ``tile`` at a time, and each fed token's write
-    block: ``(rows [R, 2 + W], wblk [lanes, width])``
-    (``engine.pack_rows``).
+    lies, in the form ``read_form(kind)`` names: ``(W, tile)`` — the
+    lanes' live rows of ``W`` blocks, which the program runs ``tile`` at a
+    time, and each fed token's write block: ``(rows [R, 2 + W], wblk
+    [lanes, width])`` (``engine.pack_rows``), the dense family's read;
+    ``None`` — a ``[lanes, M]`` block table, the latent family's.
 
 Plus ``absorb(out, counters)``: the round's ONE fetched array goes
 through it — a family that rides its own counters on that array strips
 them into ``counters`` (initial values: ``counters``) and returns the
-tokens; ``exec_key(pools)``, ``stats()``, and the paged-read triple
-``paged_active`` / ``paged_family`` / ``paged_dead``.
+tokens; ``exec_key(pools)`` and ``stats()``.
 
 A model names its family by a ``serving_family(serving_config)`` method;
 one without it is the dense grouped-query decoder the engine began with.

@@ -12,7 +12,7 @@ by row and recombines per lane as one softmax. No program gathers a
 table slot that holds nothing, so a call's cost follows the live blocks,
 not ``max_seq_len`` (PERF.md section 6, PR 28). ``_attend_lanes``, the
 read over a lane's whole gathered table, stays as the definition the row
-read (and the Pallas paged kernel) is held to.
+read is held to (``tests/test_serving_rows.py``).
 
 The attention/RoPE/MLP math reuses ``models/generation.py``'s helpers
 (``_rms``/``_mm``/``_rope_at``) and mirrors its ``_attend`` — engine
@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...framework.device import on_tpu
 from ...models.generation import (
     _GenCfg, _collect_params, _mm, _rms, _rope_at,
 )
@@ -57,8 +56,7 @@ def _attend_lanes(q, kc, vc, pos, nh, nkv, sliding_window=0):
     (fp32 einsum, 1/sqrt(d), -1e30 mask, fp32 softmax/AV) mirrors
     ``_attend`` exactly so masked slots carry exactly-zero weight and
     engine outputs stay token-identical to ``generate()``. The programs
-    read by rows (``_attend_rows``); this is what a row read must equal,
-    and the Pallas paged kernel's composite."""
+    read by rows (``_attend_rows``); this is what a row read must equal."""
     b, s, _, d = q.shape
     L = kc.shape[1]
     g = nh // nkv
@@ -151,8 +149,7 @@ def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0):
 
 
 def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
-                  pos, wlimit, cfg, tile, paged=False,
-                  paged_dead="clamp"):
+                  pos, wlimit, cfg, tile):
     """Forward ``ids`` [b, s] at absolute positions ``pos`` [b, s]
     against the block pool: per layer, write each token's K/V into its
     lane's block at ``pos`` (writes at positions >= ``wlimit[b]`` — pad
@@ -167,9 +164,6 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
     compiled programs to both). What a call reads follows the live
     blocks, not ``max_seq_len``. Layer math is
     ``models/generation.py:_block`` on the pooled layout.
-
-    ``paged`` (static; one position a lane): ``read`` is the ``[b, M]``
-    block table and the Pallas paged-attention kernel walks it.
 
     ``kscale``/``vscale`` are the int8 mode's paired fp32 scale pools
     (``[layers, num_blocks, block_size, kv_heads]``; None in bf16 mode
@@ -187,13 +181,7 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
     dt = jnp.dtype(cfg.dtype)
     quant = kscale is not None
     x = params["embed"][ids].astype(dt)
-    if paged:
-        tables = read
-        # pad pos can run past the table
-        blk = jnp.take_along_axis(
-            tables, jnp.minimum(pos // B, tables.shape[1] - 1), axis=1)
-    else:
-        rows, blk = read
+    rows, blk = read
     ok = pos < wlimit[:, None]
     blk = jnp.where(ok, blk, 0)
     off = jnp.where(ok, pos % B, 0)
@@ -224,52 +212,31 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
             vs = vs.at[li, blk, off].set(v_s)
         kp = kp.at[li, blk, off].set(k)
         vp = vp.at[li, blk, off].set(v)
-        if paged:
-            # (kp[li] here still hands the kernel a copy of the layer's
-            # whole pool; no cell engages this branch — PERF.md 7)
-            interp = not on_tpu()
-            if quant:
-                from ...ops.pallas.paged_attention import \
-                    paged_attend_int8
+        # a tile's blocks come from the STACKED pool by (layer,
+        # block): kp[li][...] makes the TPU materialise kp[li], the
+        # layer's whole pool, before every gather. Which of the two
+        # forms without it follows the pool's dtype, as the chip
+        # ran them (PERF.md section 6, PR 25): bf16 pools flattened
+        # over (layer, block), int8 pools and their scales indexed
+        # by the pair
+        if quant:
+            from ...quantization import dequantize_kv
 
-                out = paged_attend_int8(
-                    q.reshape(b, nh, d), kp[li], vp[li], ks[li],
-                    vs[li], tables, pos[:, 0],
-                    window=cfg.sliding_window, dead=paged_dead,
-                    interpret=interp)[:, None]
-            else:
-                from ...ops.pallas.paged_attention import paged_attend
-
-                out = paged_attend(
-                    q.reshape(b, nh, d), kp[li], vp[li], tables,
-                    pos[:, 0], window=cfg.sliding_window,
-                    dead=paged_dead, interpret=interp)[:, None]
+            def gather(blocks):
+                T, W = blocks.shape
+                return tuple(dequantize_kv(
+                    c[li, blocks].reshape(T, W * B, nkv, d),
+                    sc[li, blocks].reshape(T, W * B, nkv), dt)
+                    for c, sc in ((kp, ks), (vp, vs)))
         else:
-            # a tile's blocks come from the STACKED pool by (layer,
-            # block): kp[li][...] makes the TPU materialise kp[li], the
-            # layer's whole pool, before every gather. Which of the two
-            # forms without it follows the pool's dtype, as the chip
-            # ran them (PERF.md section 6, PR 25): bf16 pools flattened
-            # over (layer, block), int8 pools and their scales indexed
-            # by the pair
-            if quant:
-                from ...quantization import dequantize_kv
-
-                def gather(blocks):
-                    T, W = blocks.shape
-                    return tuple(dequantize_kv(
-                        c[li, blocks].reshape(T, W * B, nkv, d),
-                        sc[li, blocks].reshape(T, W * B, nkv), dt)
-                        for c, sc in ((kp, ks), (vp, vs)))
-            else:
-                def gather(blocks):
-                    T, W = blocks.shape
-                    at = blocks + li * kp.shape[1]
-                    return tuple(
-                        c.reshape(-1, B, nkv, d)[at].reshape(
-                            T, W * B, nkv, d) for c in (kp, vp))
-            out = _attend_rows(q, pos, rows, gather, tile, nkv,
-                               sliding_window=cfg.sliding_window)
+            def gather(blocks):
+                T, W = blocks.shape
+                at = blocks + li * kp.shape[1]
+                return tuple(
+                    c.reshape(-1, B, nkv, d)[at].reshape(
+                        T, W * B, nkv, d) for c in (kp, vp))
+        out = _attend_rows(q, pos, rows, gather, tile, nkv,
+                           sliding_window=cfg.sliding_window)
         x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])
         h2 = _rms(x, layer_p["ln2"], cfg.rms_norm_eps)
         gu = _mm(h2, layer_p["gate_up"])
@@ -311,20 +278,17 @@ def _prefill_chunk(params, kpool, vpool, kscale, vscale, read, ids,
 
 
 def _decode_step(params, kpool, vpool, kscale, vscale, read, cur_len,
-                 last_tok, *, cfg, tile, paged=False, paged_dead="clamp"):
+                 last_tok, *, cfg, tile):
     """The shared decode step: every lane feeds its pending token at
     position ``cur_len`` (write-then-attend, so the token sees itself
     like ``generate()``'s step does) and greedy-samples the next. Idle
     lanes (cur_len 0, no row, write block 0) write to the null block and
-    their outputs are ignored host-side. ``paged`` (static) swaps the
-    row read for the Pallas paged-attention kernel over ``read`` = the
-    ``[L, M]`` block tables. Returns
+    their outputs are ignored host-side. Returns
     (tok [L], kpool, vpool, kscale, vscale)."""
     pos = cur_len[:, None]
     x, kpool, vpool, kscale, vscale = _pool_forward(
         params, kpool, vpool, kscale, vscale, read, last_tok[:, None],
-        pos, cur_len + 1, cfg, tile=tile, paged=paged,
-        paged_dead=paged_dead)
+        pos, cur_len + 1, cfg, tile=tile)
     x = _rms(x, params["norm"], cfg.rms_norm_eps)
     logits = _mm(x[:, -1], params["lm_head"]).astype(jnp.float32)
     return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
@@ -376,13 +340,7 @@ class DenseGQAFamily:
                                       int8_weights=config.int8_weights)
         self.layers = self.params["ln1"].shape[0]
         self.max_position_embeddings = model.config.max_position_embeddings
-        self.paged_active = self._resolve_paged()
         self.donate_argnums = (1, 2, 3, 4) if config.kv_int8 else (1, 2)
-
-    def _kv_geometry(self):
-        nh = self.gcfg.num_attention_heads
-        nkv = self.gcfg.num_key_value_heads or nh
-        return nh, nkv, self.gcfg.hidden_size // nh
 
     def make_pools(self, num_blocks, block_size):
         """(kpool, vpool, kscale, vscale). int8 mode: paired per-position
@@ -390,7 +348,9 @@ class DenseGQAFamily:
         like K/V pad writes do); None in bf16 mode so the compiled
         programs stay byte-identical to the pre-int8 engine (None is an
         empty pytree operand)."""
-        _, nkv, d = self._kv_geometry()
+        nh = self.gcfg.num_attention_heads
+        nkv = self.gcfg.num_key_value_heads or nh
+        d = self.gcfg.hidden_size // nh
         int8 = self.config.kv_int8
         dt = jnp.int8 if int8 else jnp.dtype(self.gcfg.dtype)
         kpool = jnp.zeros((self.layers, num_blocks, block_size, nkv, d), dt)
@@ -404,55 +364,17 @@ class DenseGQAFamily:
     def kv_pool_bytes(self, pools):
         return int(sum(a.nbytes for a in pools if a is not None))
 
-    def _resolve_paged(self) -> bool:
-        """Decode read-path selection (ServingConfig.paged): forced
-        on/off, or ``auto`` = engaged only on a measured-faster
-        tune-table row for this geometry on this device (the
-        measurement-first convention — no row, no flip). Which FAMILY
-        is consulted follows the pool dtype: ``paged_attention`` for
-        bf16 pools, ``paged_attention_int8`` (the quantized-gather
-        variant) when ``kv_int8`` — an int8 engine never engages on a
-        bf16 row or vice versa (``self.paged_family`` is what the
-        bench/guard surface reports). Also resolves
-        ``self.paged_dead``: the row's WINNING dead-iteration strategy
-        — engaging the measured configuration, not the default —
-        falling back to ``"clamp"`` when forced on with no row."""
-        from ...ops.pallas import paged_attention as _pa
-        from ...ops.pallas import search as _ksearch
-
-        nh, nkv, d = self._kv_geometry()
-        key = _pa.family_key(self.config.block_size, nkv, nh // nkv, d,
-                             window=self.gcfg.sliding_window)
-        self.paged_family = ("paged_attention_int8"
-                             if self.config.kv_int8
-                             else "paged_attention")
-        cfg_row = _ksearch.best_config(self.paged_family, key) or {}
-        self.paged_dead = cfg_row.get("dead", "clamp")
-        mode = self.config.paged
-        if mode == "on":
-            return True
-        if mode == "off":
-            return False
-        return _ksearch.decide(self.paged_family, key)
-
     def read_form(self, kind):
         """How program ``kind`` is told where its lanes' K/V lies
         (``ServingEngine._pack_read`` builds it): ``(W, tile)`` — live
-        rows of ``W`` blocks, run ``tile`` at a time; ``None`` — a
-        ``[lanes, M]`` block table, which the Pallas paged kernel walks."""
-        if kind == "decode" and self.paged_active:
-            return None
+        rows of ``W`` blocks, run ``tile`` at a time."""
         return ROW_BLOCKS, PREFILL_TILE if kind == "prefill" else ROW_TILE
 
     def program(self, kind):
         """(function, static keyword arguments) of one step program."""
-        form = self.read_form(kind)
-        static = {"cfg": self.gcfg, "tile": form[1] if form else 0}
-        if kind == "decode":
-            return _decode_step, {**static, "paged": self.paged_active,
-                                  "paged_dead": self.paged_dead}
-        return {"prefill": _prefill_chunk,
-                "verify": _verify_step}[kind], static
+        fn = {"prefill": _prefill_chunk, "decode": _decode_step,
+              "verify": _verify_step}[kind]
+        return fn, {"cfg": self.gcfg, "tile": self.read_form(kind)[1]}
 
     def exec_key(self, pools):
         """The family's part of an exec-cache key."""

@@ -22,9 +22,9 @@ as the serving engine sees it.
   the final prefill chunk) return ``[tokens..., accumulator]`` as one
   int32 vector — the engine's one fetch a round brings them along.
 
-``kv_int8``, ``paged="on"`` and ``int8_weights`` raise
-``UnimplementedError``: the scale pools and both paged kernels assume
-``kv_heads x head_dim``, and an int8 weight pack would be a second copy.
+``kv_int8`` and ``int8_weights`` raise ``UnimplementedError``: the scale
+pools assume ``kv_heads x head_dim``, and an int8 weight pack would be a
+second copy.
 """
 from __future__ import annotations
 
@@ -130,9 +130,6 @@ class LatentMoEFamily:
     """See ``families/__init__.py`` for what the engine asks of it."""
 
     name = "latent_moe"
-    paged_active = False
-    paged_family = None
-    paged_dead = None
     donate_argnums = (1, 2)
 
     def __init__(self, model, config):
@@ -141,8 +138,6 @@ class LatentMoEFamily:
         for flag, why in (
                 (config.kv_int8, "kv_int8: the int8 scale pools are "
                  "[.., kv_heads] beside [.., kv_heads, head_dim] pools"),
-                (config.paged == "on", "paged='on': both paged-attention "
-                 "kernels read kv_heads x head_dim blocks"),
                 (config.int8_weights, "int8_weights: the pack would be a "
                  "second copy of the weights")):
             if flag:

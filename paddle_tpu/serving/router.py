@@ -371,7 +371,7 @@ class RouterEngine:
             return dict(config)
         fields = ("max_lanes", "block_size", "num_blocks",
                   "prefill_chunk", "max_seq_len", "int8_weights",
-                  "paged", "prefix_cache", "spec", "spec_k")
+                  "prefix_cache", "spec", "spec_k")
         return {f: getattr(config, f) for f in fields
                 if getattr(config, f, None) is not None}
 

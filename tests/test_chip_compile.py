@@ -7,9 +7,10 @@ pre-flight"). The TPU compiler is installed here and compiles for a chip
 that is described and not attached: every ``lowering_cases()`` entry of
 every registered kernel — the shapes each ``check_lowering`` lists,
 which include the main path's widths (Llama-2-7B training attention,
-the 7B engine's paged read, BERT-base) — is compiled for one chip of a
-``v5e:2x2`` topology, one parametrised case each. So are the serving
-engine's three programs, held to what their K/V read may compile to.
+BERT-base) — is compiled for one chip of a ``v5e:2x2`` topology, one
+parametrised case each. So are the serving engine's three programs,
+held to what their K/V read may compile to, at a small geometry and at
+the attention geometries engines are served at.
 Nothing runs, so this says nothing about results or speed, and it is
 never reported as a chip run.
 
@@ -79,8 +80,7 @@ def test_every_registered_kernel_lists_its_cases():
     compiles below (same contract as ``check_lowering``)."""
     kernels = registry.platform_kernels("tpu")
     assert {n for n, _ in kernels} >= {
-        "flash_attention", "flash_attention_headbatch",
-        "paged_attention", "paged_attention_int8"}
+        "flash_attention", "flash_attention_headbatch"}
     for name, fn in kernels:
         assert fn.lowering_cases(), name
 
@@ -148,25 +148,29 @@ _LANES, _TABLE = 8, 72  # lanes, blocks a lane: 5 rows of 16 blocks, so
 #                         rows in tiles of 4: no tile is a whole table
 
 
-@pytest.fixture(scope="module")
-def engines():
-    """A 3-layer bf16 engine per pool dtype whose layer pool is ``_POOL``
-    (scales: its first three dims), built once — on the CPU, for its
-    shapes."""
+def _engines(pool, heads, lanes, table):
+    """A 3-layer bf16 engine per pool dtype whose layer pool is ``pool``
+    (scales: its first three dims) — built on the CPU, for its shapes."""
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
-    nb, block, nkv, d = _POOL
+    nb, block, nkv, d = pool
     model = LlamaForCausalLM(LlamaConfig.tiny(
-        num_hidden_layers=3, hidden_size=4 * d, num_attention_heads=4,
-        num_key_value_heads=nkv, intermediate_size=512, dtype="bfloat16"))
+        num_hidden_layers=3, hidden_size=heads * d,
+        num_attention_heads=heads, num_key_value_heads=nkv,
+        intermediate_size=512, dtype="bfloat16"))
     for p in model.parameters():  # fp32 init, served in bf16
         p._data = p._data.astype("bfloat16")
     model.eval()
     return {kv_int8: ServingEngine(model, ServingConfig(
-        max_lanes=_LANES, block_size=block, num_blocks=nb,
-        prefill_chunk=32, max_seq_len=_TABLE * block, kv_int8=kv_int8))
+        max_lanes=lanes, block_size=block, num_blocks=nb,
+        prefill_chunk=32, max_seq_len=table * block, kv_int8=kv_int8))
         for kv_int8 in (False, True)}
+
+
+@pytest.fixture(scope="module")
+def engines():
+    return _engines(_POOL, 4, _LANES, _TABLE)
 
 
 def _dense_program_text(topo, eng, kind):
@@ -204,6 +208,15 @@ def _results_shaped(text, dims):
     return [ln.strip()[:200] for ln in text.splitlines() if held.search(ln)]
 
 
+def _holds_no_layers_pool(text, pool):
+    """No instruction's result has one layer's pool shape (nor, in int8
+    mode, one layer's scale-pool shape); the stacked pool is there."""
+    nb, block, nkv, d = pool
+    lines = _results_shaped(text, rf"{nb},{block},{nkv}(,{d})?")
+    assert not lines, "\n".join(lines[:6])
+    assert f"[3,{nb},{block},{nkv},{d}]" in text
+
+
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
 def test_engine_program_never_holds_one_layers_pool(
@@ -217,11 +230,40 @@ def test_engine_program_never_holds_one_layers_pool(
     int8 mode, one layer's scale-pool shape)."""
     eng = engines[kv_int8]
     assert eng._pools[0].shape[1:] == _POOL
-    text = _dense_program_text(topo, eng, kind)
-    nb, block, nkv, d = _POOL
-    lines = _results_shaped(text, rf"{nb},{block},{nkv}(,{d})?")
-    assert not lines, "\n".join(lines[:6])
-    assert f"[3,{nb},{block},{nkv},{d}]" in text  # the stacked pool is
+    _holds_no_layers_pool(_dense_program_text(topo, eng, kind), _POOL)
+
+
+# (``_engines``' arguments: pool [num_blocks, block, kv_heads,
+# head_dim], heads, lanes, blocks a lane), the programs compiled: the
+# benchmark's dense cells' attention (group 4 at head_dim 128, block 16:
+# Mistral-7B's, two kv heads of its eight so that hidden stays 1024),
+# all three programs; a 128-slot block with group 2, and every head its
+# own K/V (the 7B engine chip_smoke.py serves), the decode program
+_SERVED_AT = {
+    "g4_d128_b16": (((37, 16, 2, 128), 8, 8, 72),
+                    ("decode", "verify", "prefill")),
+    "g2_d128_b128": (((37, 128, 2, 128), 4, 4, 8), ("decode",)),
+    "mha_d128_b16": (((37, 16, 4, 128), 4, 8, 32), ("decode",)),
+}
+
+
+@pytest.fixture(scope="module")
+def served_engines():
+    return {g: _engines(*args) for g, (args, _) in _SERVED_AT.items()}
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("geometry,kind", [
+    (g, k) for g, (_, kinds) in _SERVED_AT.items() for k in kinds])
+def test_row_read_compiles_at_served_geometries(
+        topo, served_engines, geometry, kind, kv_int8):
+    """The engines above run head_dim 64 and group 2. The row read at
+    the geometries engines are served at compiles for the chip, and
+    there too no program holds one layer's pool."""
+    pool = _SERVED_AT[geometry][0][0]
+    eng = served_engines[geometry][kv_int8]
+    assert eng._pools[0].shape[1:] == pool
+    _holds_no_layers_pool(_dense_program_text(topo, eng, kind), pool)
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])

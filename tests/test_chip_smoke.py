@@ -67,9 +67,7 @@ def test_rehearsal_train_line(rehearsal):
 
 @pytest.mark.parametrize("engine,read_path,held_to", [
     ("default", "row gather", "generate()"),
-    ("paged_on", "pallas paged_attention", "default engine"),
-    ("kv_int8_paged_on", "pallas paged_attention_int8",
-     "generate(kv_int8=True)"),
+    ("kv_int8", "row gather", "generate(kv_int8=True)"),
 ])
 def test_rehearsal_serve_engine(rehearsal, engine, read_path, held_to):
     serve = next(ln for ln in rehearsal[1] if ln.get("phase") == "serve")

@@ -25,7 +25,6 @@ import pytest
 
 from paddle_tpu import monitor
 from paddle_tpu.ops.pallas import autotune, head_flash, search
-from paddle_tpu.ops.pallas import paged_attention as pa
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -236,14 +235,8 @@ class TestCandidates:
         assert fam.candidates((64, 512, 512, 12, 12, 64, False)) == []
         assert fam.candidates((8, 1024, 1024, 12, 12, 128, True))
 
-    def test_paged_candidates_are_dead_strategies(self):
-        fam = search.FAMILIES["paged_attention"]
-        cands = fam.candidates((8, 128, 16, 12, 1, 128))
-        assert {c["dead"] for c in cands} == {"clamp", "null"}
-
     def test_registered_families(self):
-        assert {"flash", "flash_headbatch", "paged_attention"} \
-            <= set(search.FAMILIES)
+        assert set(search.FAMILIES) == {"flash", "flash_headbatch"}
 
     def test_family_keys_encode_variants(self):
         base = head_flash.shape_key(8, 1024, 1024, 12, 12, 128, True)
@@ -253,7 +246,6 @@ class TestCandidates:
             8, 1024, 1024, 12, 12, 128, True, kmask=True) != base
         assert "kv4" in head_flash.shape_key(8, 1024, 1024, 12, 4, 128,
                                              True)
-        assert pa.family_key(16, 12, 1, 128) == "B16_kv12_g1_d128"
 
 
 # -- the search pipeline ------------------------------------------------------
@@ -377,16 +369,15 @@ def test_kernel_search_cli_smoke_runs_full_pipeline(tmp_path):
                 if ln.startswith("{"))
     rec = json.loads(line)
     assert rec["metric"] == "kernel_search_shapes"
-    assert rec["value"] >= 3  # flash + headbatch + paged at least
+    assert rec["value"] >= 2  # flash + headbatch at least
     assert rec["failures"] == {}
     assert rec["note"] == "cpu smoke mode; not a TPU number"
     with open(table) as f:
         data = json.load(f)
     fams = data["families"]
-    assert {"flash", "flash_headbatch", "paged_attention"} <= set(fams)
-    for fam in ("flash_headbatch", "paged_attention"):
-        for row in fams[fam]["entries"].values():
-            assert row["backend"] == "cpu" and row["interpret"]
+    assert {"flash", "flash_headbatch"} <= set(fams)
+    for row in fams["flash_headbatch"]["entries"].values():
+        assert row["backend"] == "cpu" and row["interpret"]
 
 
 def test_monitor_audit_membership():
@@ -422,10 +413,10 @@ def test_monitor_report_renders_kernel_section(tmp_path):
     bench.write_text(json.dumps({
         "metric": "serving_tokens_per_sec", "value": 10.0,
         "unit": "tokens/s",
-        "kernels": {"paged_attention": True, "flash": False}}) + "\n")
+        "kernels": {"flash_headbatch": True, "flash": False}}) + "\n")
     text = mr.render(str(jsonl), bench_path=str(bench))
     assert "pallas kernels (engagement + search)" in text
     assert "engaged 3   composite fallbacks 1" in text
     assert "candidates timed 7" in text
     assert "best ratio flash: 3.4" in text
-    assert "paged_attention=engaged" in text
+    assert "flash_headbatch=engaged" in text

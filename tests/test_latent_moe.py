@@ -337,7 +337,6 @@ def test_a_lower_precision_fails_the_tolerance(ref):
 def test_what_the_family_does_not_serve_raises(ref):
     model = seeded(LatentMoEForCausalLM(tiny_config()))
     for kw, word in (({"kv_int8": True}, "kv_int8"),
-                     ({"paged": "on"}, "paged"),
                      ({"int8_weights": True}, "int8_weights")):
         with pytest.raises(UnimplementedError, match=word):
             ServingEngine(model, ServingConfig(max_lanes=2, **kw))

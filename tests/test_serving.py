@@ -1048,129 +1048,23 @@ def test_engine_each_layer_reads_its_own_pool():
         assert np.abs(np.asarray(wrong) - want).max() > 0.1
 
 
-def test_paged_kernel_parity_vs_attend_lanes():
-    """The Pallas paged-attention read (interpret mode) reproduces the
-    dense `_attend_lanes` gather over a ragged block pool — both dead-
-    iteration strategies, GQA, live lengths from 0 (idle lane) to
-    full."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.serving.families.dense_gqa import _attend_lanes
-    from paddle_tpu.ops.pallas.paged_attention import paged_attend
-
-    L, M, B, nkv, g, d = 4, 4, 8, 2, 2, 16
-    nh = nkv * g
-    rng = np.random.RandomState(0)
-    q = rng.randn(L, nh, d).astype(np.float32)
-    kpool = rng.randn(L * M + 1, B, nkv, d).astype(np.float32)
-    vpool = rng.randn(L * M + 1, B, nkv, d).astype(np.float32)
-    tables = (np.arange(L * M, dtype=np.int32).reshape(L, M) + 1)
-    pos = np.array([0, 5, B + 3, M * B - 1], np.int32)
-
-    kc = kpool[tables].reshape(L, M * B, nkv, d)
-    vc = vpool[tables].reshape(L, M * B, nkv, d)
-    ref = np.asarray(_attend_lanes(
-        jnp.asarray(q)[:, None], jnp.asarray(kc), jnp.asarray(vc),
-        jnp.asarray(pos)[:, None], nh, nkv))[:, 0]
-    for dead in ("clamp", "null"):
-        out = paged_attend(jnp.asarray(q), jnp.asarray(kpool),
-                           jnp.asarray(vpool), jnp.asarray(tables),
-                           jnp.asarray(pos), dead=dead, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5,
-                                   err_msg=f"dead={dead}")
-    # sliding window masks the low slots too
-    ref_w = np.asarray(_attend_lanes(
-        jnp.asarray(q)[:, None], jnp.asarray(kc), jnp.asarray(vc),
-        jnp.asarray(pos)[:, None], nh, nkv, sliding_window=6))[:, 0]
-    out_w = paged_attend(jnp.asarray(q), jnp.asarray(kpool),
-                         jnp.asarray(vpool), jnp.asarray(tables),
-                         jnp.asarray(pos), window=6, interpret=True)
-    np.testing.assert_allclose(np.asarray(out_w), ref_w, atol=2e-5)
-
-
-def test_engine_paged_token_identical(model):
-    """The serving token-identity proof, extended to the paged read
-    path (ISSUE 9): the engine with the Pallas paged-attention kernel
-    forced on reproduces per-request generate() bit for bit — through
-    unequal lengths, growth, and preemption-recompute churn."""
-    eng = ServingEngine(model, ServingConfig(
-        max_lanes=3, block_size=2, num_blocks=12, prefill_chunk=4,
-        max_seq_len=20, paged=True))
-    assert eng.paged_active
-    rng = np.random.RandomState(5)
-    reqs = []
-    for _ in range(6):
-        plen, new = int(rng.randint(2, 9)), int(rng.randint(6, 12))
-        prompt = rng.randint(0, model.config.vocab_size,
-                             (plen,)).astype(np.int32)
-        reqs.append((eng.submit(prompt, max_new_tokens=new), prompt,
-                     new))
-    outs = eng.run()
-    assert eng.counters["preemptions"] > 0, \
-        "pressure config never preempted — test is vacuous"
-    for r, prompt, new in reqs:
-        np.testing.assert_array_equal(
-            outs[r.request_id], _reference(model, prompt, new),
-            err_msg=f"request {r.request_id} diverged on the paged path")
-    # the bench's hbm_util delta inputs: dense reads full tables, the
-    # paged path only live prefixes
-    assert 0 < eng.counters["kv_read_tokens"] \
-        < eng.counters["kv_dense_read_tokens"]
-    assert eng.stats()["paged_attention"] is True
-
-
-def test_paged_knob_and_measured_engagement(model, tmp_path,
-                                            monkeypatch):
-    """PT_SERVE_PAGED=0/1 forces; auto engages ONLY on a measured-
-    faster hardware tune-table row for this exact geometry
-    (measurement-first — a CPU box with no row stays dense)."""
-    from paddle_tpu.ops.pallas import paged_attention as pa
-    from paddle_tpu.ops.pallas import search
-
-    monkeypatch.setenv("PT_SERVE_PAGED", "1")
-    assert ServingConfig().paged == "on"
-    monkeypatch.setenv("PT_SERVE_PAGED", "0")
-    assert ServingConfig().paged == "off"
-    monkeypatch.delenv("PT_SERVE_PAGED")
-    assert ServingConfig().paged == "auto"
-    assert ServingConfig(paged=True).paged == "on"
-
-    # auto on CPU with an empty table: dense
-    monkeypatch.setenv("PT_KERNEL_TUNE_PATH",
-                       str(tmp_path / "t.json"))
-    monkeypatch.setattr(search, "_table_cache", None)
+def test_stats_keys_the_benchmark_reads(model):
+    """What ``benchmarks/chip`` reads off an engine, by name: of
+    ``stats()`` the read-path key (chiplib/serve.py:423; a constant now
+    that each family has one read) and the pool's bytes (serve.py:473);
+    of ``counters`` the per-round differences (serve.py:102), and what
+    chiplib/optext.py and metrics/*.py divide."""
     eng = ServingEngine(model, ServingConfig(
         max_lanes=2, block_size=4, prefill_chunk=8, max_seq_len=32))
-    assert eng.paged_active is False
-    # a measured-faster row for the exact geometry flips auto on
-    cfg = model.config
-    nh = cfg.num_attention_heads
-    nkv = cfg.num_key_value_heads or nh
-    key = pa.family_key(4, nkv, nh // nkv, cfg.hidden_size // nh)
-    search.update_table(
-        lambda d: d.setdefault("families", {}).setdefault(
-            "paged_attention", {"entries": {}})["entries"].update(
-            {key: {"ratio": 1.4, "backend": "tpu",
-                   "device": search._device_kind(),
-                   "config": {"dead": "null"}}}))
-    eng2 = ServingEngine(model, ServingConfig(
-        max_lanes=2, block_size=4, prefill_chunk=8, max_seq_len=32))
-    assert eng2.paged_active is True
-    # the row's WINNING dead-iteration strategy is what actually runs
-    # (and what the bench's stats line reports)
-    assert eng2._paged_dead == "null"
-    assert eng2.stats()["paged_dead"] == "null"
-    # a sliding-window model carries a different key (the window is an
-    # engagement-relevant variant) — the window=0 row must not engage it
-    assert pa.family_key(4, nkv, nh // nkv,
-                         cfg.hidden_size // nh, window=8) != key
-    # a measured LOSS stays dense
-    search.update_table(
-        lambda d: d["families"]["paged_attention"]["entries"][key]
-        .update({"ratio": 0.8}))
-    eng3 = ServingEngine(model, ServingConfig(
-        max_lanes=2, block_size=4, prefill_chunk=8, max_seq_len=32))
-    assert eng3.paged_active is False
+    stats = eng.stats()
+    assert stats["paged_attention"] is False
+    assert "paged_family" not in stats and "paged_dead" not in stats
+    assert stats["kv_pool_bytes"] == eng.kv_pool_bytes > 0
+    for key in ("prefill_chunks", "decode_steps", "verify_steps",
+                "decoded_tokens", "spec_proposed_tokens",
+                "spec_accepted_tokens", "prefix_hit_tokens",
+                "prefix_miss_tokens", "preemptions"):
+        assert eng.counters[key] == 0, key
 
 
 def test_monitor_report_renders_bench_serving_section(tmp_path):

@@ -21,10 +21,6 @@ Five layers:
   reports >= 1.9x `allocatable_tokens` at head_dim=128 (2d/(d+4), the
   bench line's arithmetic) and the engine's resident pool bytes drop
   accordingly.
-- **Kernel family** — `paged_attention_int8` passes interpret-parity
-  against its dense dequant-then-attend composite, lowers for TPU, and
-  ships disengaged until a hardware tune row exists (engagement flips
-  on a measured-faster row, per convention).
 """
 import importlib.util
 import json
@@ -386,91 +382,6 @@ def test_monitor_report_renders_kv_pool_line(tmp_path):
     assert "87 token(s) quantized" in text
 
 
-# -- kernel family ------------------------------------------------------------
-
-class TestPagedAttentionInt8Family:
-    def test_interpret_parity_and_ships_disengaged(self, tmp_path,
-                                                   monkeypatch):
-        from paddle_tpu.ops import pallas  # noqa: F401 — registers
-        from paddle_tpu.ops.pallas import search
-
-        monkeypatch.setenv("PT_KERNEL_TUNE_PATH",
-                           str(tmp_path / "t.json"))
-        monkeypatch.setattr(search, "_table_cache", None)
-        fam = search.FAMILIES["paged_attention_int8"]
-        shape = fam.smoke_shapes()[0]
-        inp = fam.make_parity_inputs(shape)
-        want = np.asarray(fam.build_composite(shape)(*inp),
-                          dtype=np.float32)
-        for cand in fam.candidates(shape):
-            got = np.asarray(fam.build(shape, cand, interpret=True)(*inp),
-                             dtype=np.float32)
-            np.testing.assert_allclose(
-                got, want, atol=2e-5, rtol=2e-5,
-                err_msg=f"interpret parity failed for {cand}")
-        # empty table: disengaged by convention (measurement-first)
-        assert search.decide("paged_attention_int8",
-                             fam.key(shape)) is False
-        assert search.engagement_report()["paged_attention_int8"] is False
-
-    def test_lowering_self_check_registered(self):
-        from paddle_tpu.ops import pallas, registry
-
-        names = [n for n, _ in registry.platform_kernels("tpu")]
-        assert "paged_attention_int8" in names
-        # the registry-driven audit covers it (a kernel without a
-        # check_lowering attribute is a hard error in check_tpu_lowering)
-        pallas.check_tpu_lowering()
-
-    def test_engine_engages_only_on_int8_family_row(self, model,
-                                                    tmp_path,
-                                                    monkeypatch):
-        """An int8 engine keys engagement on paged_attention_int8 — a
-        measured bf16 paged_attention row must NOT flip it (different
-        read path, different bytes), and vice versa a measured int8 row
-        does."""
-        from paddle_tpu.ops.pallas import paged_attention as pa
-        from paddle_tpu.ops.pallas import search
-
-        monkeypatch.delenv("PT_SERVE_PAGED", raising=False)
-        monkeypatch.setenv("PT_KERNEL_TUNE_PATH",
-                           str(tmp_path / "t.json"))
-        monkeypatch.setattr(search, "_table_cache", None)
-        cfg = model.config
-        nh = cfg.num_attention_heads
-        nkv = cfg.num_key_value_heads or nh
-        key = pa.family_key(4, nkv, nh // nkv, cfg.hidden_size // nh)
-        geom = dict(kv_int8=True, **GEOM)
-        geom["block_size"] = 4
-        eng = ServingEngine(model, ServingConfig(**geom))
-        assert eng._paged_family == "paged_attention_int8"
-        assert eng.paged_active is False
-        # a bf16-family row alone: int8 engine stays dense
-        search.update_table(
-            lambda d: d.setdefault("families", {}).setdefault(
-                "paged_attention", {"entries": {}})["entries"].update(
-                {key: {"ratio": 1.4, "backend": "tpu",
-                       "device": search._device_kind(),
-                       "config": {"dead": "null"}}}))
-        eng2 = ServingEngine(model, ServingConfig(**geom))
-        assert eng2.paged_active is False
-        # the int8 family's own measured-faster row flips it
-        search.update_table(
-            lambda d: d.setdefault("families", {}).setdefault(
-                "paged_attention_int8", {"entries": {}})[
-                "entries"].update(
-                {key: {"ratio": 1.3, "backend": "tpu",
-                       "device": search._device_kind(),
-                       "config": {"dead": "null"}}}))
-        eng3 = ServingEngine(model, ServingConfig(**geom))
-        assert eng3.paged_active is True
-        assert eng3.stats()["paged_family"] == "paged_attention_int8"
-        # and the bf16 engine keys on its own family, not the int8 row
-        eng4 = ServingEngine(model, ServingConfig(**GEOM))
-        assert eng4._paged_family == "paged_attention"
-        assert eng4.paged_active is True  # bf16 row from above
-
-
 # -- bench contract -----------------------------------------------------------
 
 def test_serving_bench_int8_contract_line():
@@ -500,4 +411,5 @@ def test_serving_bench_int8_contract_line():
     assert ab["tokens_per_sec"] > 0 and ab["ttft_ms_p50"] is not None
     tel = rec["telemetry"]["serving"]
     assert tel["kv_quant_writes"] > 0 and tel["kv_quant_tokens"] > 0
-    assert "paged_attention_int8" in rec["kernels"]
+    assert rec["kv_pool_bytes"] > 0
+    assert set(rec["kernels"]) == {"flash", "flash_headbatch"}

@@ -278,15 +278,25 @@ def test_read_counters_on_a_ragged_run(model, small_rows, spec):
         + c["prefill_chunks"])
 
 
-def test_a_table_form_program_bills_its_whole_table(model):
-    """The Pallas paged decode walks a [lanes, M] table: gathered == the
-    table there; verify rounds and chunks of the same engine read rows."""
+def test_a_table_form_program_bills_its_whole_table():
+    """The latent family's programs (the table form's one user) gather a
+    [lanes, M] table whole: gathered == the table there, in every kind."""
+    from paddle_tpu.models import LatentMoEConfig, LatentMoEForCausalLM
+
+    pt.seed(3)
+    model = LatentMoEForCausalLM(LatentMoEConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64,
+        moe_intermediate_size=16, num_hidden_layers=2,
+        first_k_dense_replace=1, num_attention_heads=2, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=12, n_routed_experts=4, num_experts_per_tok=2))
+    model.eval()
     eng = ServingEngine(model, ServingConfig(
         max_lanes=2, block_size=2, prefill_chunk=4, max_seq_len=16,
-        paged=True, spec=False))
+        spec=False))
     assert eng._rows_form("decode", 2) is None
-    assert eng._rows_form("prefill", 1) is not None
+    assert eng._rows_form("prefill", 1) is None
     _serve(eng, _requests(model, 3, seed=10, hi=8, new=(3, 6)))
     c = eng.counters
     assert c["kv_read_tokens"] < c["kv_gathered_tokens"] \
-        <= c["kv_dense_read_tokens"]
+        == c["kv_dense_read_tokens"]

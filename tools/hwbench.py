@@ -102,11 +102,10 @@ BENCHES = [
                        "benchmarks/host_overhead_bench.py"], 1200, None),
     ("flashtune", [sys.executable, "tools/flash_autotune.py"], 2400, None),
     # kernel search harness (docs/KERNELS.md): enumerate + parity-filter
-    # + time the candidate spaces for every registered family (head-
-    # batched flash, paged attention, paged_attention_int8, flash
-    # blocks) and persist the engagement rows the runtime flips on —
-    # the timeboxed stage that settles the disengaged-by-default
-    # kernels (now incl. the quantized-gather int8 family) next chip-up
+    # + time the candidate spaces for every registered family (flash
+    # blocks, head-batched flash) and persist the engagement rows the
+    # runtime flips on — the timeboxed stage that settles the
+    # disengaged-by-default kernel next chip-up
     ("kernel_search", [sys.executable, "tools/kernel_search.py"], 2400,
      None),
     # automatic sharding planner (docs/AUTOSHARD.md): timeboxed candidate

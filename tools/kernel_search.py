@@ -5,7 +5,7 @@
     python tools/kernel_search.py --smoke          # CPU pipeline proof
 
 Hardware run: for every registered family (flash blocks, head-batched
-flash, paged attention — default: all), enumerate the candidate space,
+flash — default: all), enumerate the candidate space,
 interpret-parity-filter every candidate, time the survivors with the
 two-fori-loop discipline, and persist the best row (device + commit
 provenance) to ``paddle_tpu/ops/pallas/kernel_tune.json``. Engagement
