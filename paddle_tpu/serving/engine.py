@@ -81,6 +81,7 @@ traced lane vectors (same no-retrace discipline); left for a later PR.
 from __future__ import annotations
 
 import collections
+import functools
 import os
 import sys
 import time
@@ -98,7 +99,7 @@ from ..monitor.spans import Phase
 from .families import family_for
 from .kv_cache import BlockPool, blocks_needed
 from .scheduler import RUNNING, FCFSScheduler, Request
-from .speculative import NgramDrafter
+from .speculative import LaneContext, NgramDrafter
 
 _EMPTY_DRAFT = np.zeros((0,), np.int32)
 
@@ -300,6 +301,16 @@ class ServingEngine:
         self.spec_active = bool(cfg.spec and cfg.spec_k > 0)
         self.drafter = drafter if drafter is not None \
             else (NgramDrafter() if self.spec_active else None)
+        # a drafter's optional hooks, looked up once. begin() opens the
+        # state a request keeps from its first draft to its finish
+        # (NgramDrafter: an index that grows with the context); without
+        # it the drafter's propose() gets the lane's whole context a
+        # round, from a buffer that grows in place
+        self._begin_draft = getattr(self.drafter, "begin", None)
+        if self._begin_draft is None and self.drafter is not None:
+            self._begin_draft = functools.partial(
+                LaneContext, self.drafter.propose)
+        self._observe_draft = getattr(self.drafter, "observe", None)
         # always-on plain-int accounting (the serving bench's source of
         # truth; independent of the monitor like exec_cache._stats).
         # Per program call (rounds and prefill chunks): kv_read_tokens
@@ -317,13 +328,19 @@ class ServingEngine:
         # spec_{proposed,accepted}_tokens are post-trim (what the verify
         # step actually speculated) so accepted/proposed IS the accept
         # rate; bonus counts the +1 token a drafted lane's verification
-        # emitted on top of its accepted prefix.
+        # emitted on top of its accepted prefix. draft_* are the n-gram
+        # index's own (speculative.NgramIndex, handed this dict): every
+        # context token indexed once, and each lookup (one per lane per
+        # round with room to draft) by the length that matched, or missed.
         self.counters = {
             "admits": 0, "finished": 0, "preemptions": 0,
             "prefill_chunks": 0, "decode_steps": 0, "verify_steps": 0,
             "decoded_tokens": 0,
             "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
             "spec_bonus_tokens": 0,
+            "draft_indexed_tokens": 0, "draft_hits_ngram3": 0,
+            "draft_hits_ngram2": 0, "draft_hits_ngram1": 0,
+            "draft_misses": 0,
             "prefix_hit_tokens": 0, "prefix_miss_tokens": 0,
             "kv_read_tokens": 0, "kv_gathered_tokens": 0,
             "kv_dense_read_tokens": 0,
@@ -687,18 +704,23 @@ class ServingEngine:
         final token is pointless: its verification could emit past
         ``max_new_tokens``) and to the blocks the pool can back WITHOUT
         preempting anyone (`scheduler.grow_for_draft`): speculation is
-        opportunistic, it never evicts a runner."""
+        opportunistic, it never evicts a runner. A request's draft state
+        (a drafter's `begin` hook) opens here at its first draft, stays with it
+        through preemption — its context comes back as it left — and is
+        told only the tokens emitted since its last call."""
         k = self.config.spec_k
         drafts = {}
         for req in act:
             cap = min(k, req.max_new_tokens - len(req.output) - 1)
             d = _EMPTY_DRAFT
             if cap > 0:
-                ctx = np.concatenate(
-                    [req.prompt, np.asarray(req.output, np.int32)])
-                d = np.asarray(self.drafter.propose(ctx, cap),
-                               np.int32).reshape(-1)[:cap]
-                if d.size:
+                st = req._draft
+                if st is None:
+                    st = req._draft = self._begin_draft(
+                        req.prompt, req.max_new_tokens, self.counters)
+                got = st.propose(req.output[st.n - req.prompt.size:], cap)
+                if len(got):
+                    d = np.asarray(got, np.int32).reshape(-1)[:cap]
                     d = d[:self.scheduler.grow_for_draft(
                         req, int(d.size))]
             drafts[id(req)] = d
@@ -771,10 +793,8 @@ class ServingEngine:
             if n:
                 req.spec_rounds += 1
                 req.accepted_tokens += a
-            if n:  # optional feedback hook (Drafter.observe)
-                observe = getattr(self.drafter, "observe", None)
-                if observe is not None:
-                    observe(d, a)
+            if n and self._observe_draft is not None:
+                self._observe_draft(d, a)  # optional feedback hook
             # emit the a accepted drafts (== row[:a]) + the bonus token
             # row[a]; stop early when max_new_tokens/eos finishes the
             # request mid-prefix (the cap in _draft makes overshoot
@@ -868,6 +888,7 @@ class ServingEngine:
                 or (req.eos_token_id is not None
                     and tok == req.eos_token_id)):
             req.t_done = now
+            req._draft = None  # the draft state dies with the request
             self.scheduler.finish(req)
             self._finished[req.request_id] = \
                 self._requests.pop(req.request_id, req)

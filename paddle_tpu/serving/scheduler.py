@@ -106,7 +106,7 @@ class Request:
                  "_admit_seq", "trace_id", "_t_mark",
                  "queue_ms", "prefill_ms", "decode_ms", "preempted_ms",
                  "prefill_refunded_tokens", "spec_rounds",
-                 "accepted_tokens")
+                 "accepted_tokens", "_draft")
 
     def __init__(self, prompt_ids, max_new_tokens=32, eos_token_id=None,
                  request_id=None):
@@ -163,6 +163,11 @@ class Request:
         self.prefill_refunded_tokens = 0
         self.spec_rounds = 0
         self.accepted_tokens = 0
+        # the drafter's state for this request (speculative.LaneContext:
+        # its context in one growing buffer, the n-gram index over it):
+        # opened by the engine at the first draft, kept across
+        # preemption, dropped at finish
+        self._draft = None
 
     def attribution(self) -> dict:
         """The finished request's latency breakdown — the serving

@@ -18,6 +18,7 @@ Four layers:
   contract, monitor_report rendering, the serving_bench spec contract
   line (accept_rate > 0, tokens_per_decode_step > 1, spec-off A/B).
 """
+import functools
 import importlib.util
 import json
 import os
@@ -34,6 +35,7 @@ from paddle_tpu.serving import (
     BlockPool, Drafter, FCFSScheduler, NgramDrafter, Request,
     ServingConfig, ServingEngine, blocks_needed, prefix_keys,
 )
+from paddle_tpu.serving.speculative import LaneContext, NgramIndex
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -96,6 +98,108 @@ class TestNgramDrafter:
         # parametrizes over this list — membership is the contract
         assert "paddle_tpu.serving.speculative" \
             in monitor.INSTRUMENTED_MODULES
+
+
+# -- the per-request index against the stateless scan (pure host) -------------
+
+_HITS = ("draft_hits_ngram3", "draft_hits_ngram2", "draft_hits_ngram1")
+
+
+class TestNgramIndex:
+    @pytest.mark.parametrize("ngrams", [(3, 1), (2, 2), (1, 1)],
+                             ids=lambda ng: "ngram%d-%d" % ng)
+    @pytest.mark.parametrize("alphabet", [2, 4, 50, "wide"])
+    def test_proposes_what_the_scan_proposes_on_every_prefix(
+            self, alphabet, ngrams):
+        """The index is held to `NgramDrafter.propose`, the definition:
+        same draft at every step of contexts that grow by 1-5 tokens a
+        step (as accepted drafts make them), from prompts of 1-40
+        tokens, for k 1-4. The alphabets make 3-, 2- and 1-gram hits
+        and misses all occur (asserted through the tallies); "wide" has
+        rare ids past the keys' 21-bit fields, in the prompt or after
+        it, so the index is built wide or rebuilt so mid-way."""
+        d = NgramDrafter(*ngrams)
+        rng = np.random.RandomState(
+            1000 * (7 if alphabet == "wide" else alphabet)
+            + 10 * ngrams[0] + ngrams[1])
+        tally, lookups, tokens, widths = {}, 0, 0, set()
+        for _ in range(40):
+            size = int(rng.randint(41, 140))
+            if alphabet == "wide":
+                seq = rng.choice([3, 7, 2 ** 21 + 5, 2 ** 31 - 1], size,
+                                 p=[.48, .48, .02, .02])
+            else:
+                seq = rng.randint(0, alphabet, (size,))
+            n = int(rng.randint(1, 41))
+            ix = d.begin(seq[:n], seq.size - n, tally)
+            assert isinstance(ix, NgramIndex)
+            born = ix._shift
+            while n < seq.size:
+                step = int(rng.randint(1, 6))
+                k = int(rng.randint(1, 5))
+                new, n = seq[n:n + step].tolist(), min(n + step, seq.size)
+                got = ix.propose(new, k)
+                np.testing.assert_array_equal(got, d.propose(seq[:n], k))
+                assert got.dtype == np.int32 and got.size <= k
+                lookups += 1
+            assert ix.n == seq.size
+            np.testing.assert_array_equal(ix.buf[:ix.n], seq)
+            widths.add((born, ix._shift))
+            assert ix._shift == (32 if seq.max() >= 2 ** 21 else 21)
+            # and the maps are what the docstring says, no entry more:
+            # every n-gram that ended before the last token, with the
+            # position after its most recent occurrence
+            toks = seq.tolist()
+            for ng, _, after in ix._levels:
+                assert after == {
+                    functools.reduce(
+                        lambda key, t: (key << ix._shift) | t,
+                        toks[s:s + ng]): s + ng
+                    for s in range(seq.size - ng)}
+            assert [ng for ng, _, _ in ix._levels] == \
+                list(range(ngrams[0], ngrams[1] - 1, -1))
+            tokens += seq.size
+        assert widths == ({(21, 21), (21, 32), (32, 32)}
+                          if alphabet == "wide" else {(21, 21)})
+        assert tally["draft_indexed_tokens"] == tokens
+        hits = {ng: tally.get(f"draft_hits_ngram{ng}", 0)
+                for ng in (1, 2, 3)}
+        assert sum(hits.values()) + tally.get("draft_misses", 0) == lookups
+        lo, hi = ngrams[1], ngrams[0]
+        assert not any(hits[ng] for ng in hits if not lo <= ng <= hi), hits
+        if alphabet == 50:  # long matches are rare, misses common
+            assert hits[lo] > 0 and tally["draft_misses"] > 0
+        else:
+            assert all(hits[ng] > 0 for ng in range(lo, hi + 1)), hits
+
+    def test_tail_is_not_its_own_match_and_draft_stops_at_the_context(self):
+        ix = NgramDrafter().begin([5, 6, 7], 8)
+        assert ix.propose([], 4).size == 0  # nothing repeats yet
+        # [.., 5, 6, 7] again: the trigram's earlier occurrence ends at 3
+        np.testing.assert_array_equal(ix.propose([9, 5, 6, 7], 4),
+                                      [9, 5, 6, 7])
+        # the match sits one before the end: one token to propose, not
+        # whatever the buffer holds past the context
+        np.testing.assert_array_equal(ix.propose([7], 4), [7])
+        assert ix.propose([], 0).size == 0  # k == 0
+
+    def test_stateless_drafters_get_the_growing_context(self):
+        """What the engine builds for a drafter with `propose` alone:
+        it sees prompt + every token told so far, as one int32 view."""
+        seen = []
+
+        def echo(tokens, k):
+            seen.append(np.array(tokens))
+            return tokens[-k:]
+
+        ctx = LaneContext(echo, np.array([3, 4], np.int64), 5, {})
+        np.testing.assert_array_equal(ctx.propose([9], 2), [4, 9])
+        np.testing.assert_array_equal(ctx.propose([8, 7], 1), [7])
+        assert [a.tolist() for a in seen] == [[3, 4, 9], [3, 4, 9, 8, 7]]
+        assert all(a.dtype == np.int32 for a in seen)
+        assert not hasattr(Drafter, "begin")  # optional: the engine asks
+        with pytest.raises(ValueError):
+            ctx.propose([1, 1, 1], 1)  # more than `room` allowed
 
 
 # -- scheduler draft growth (pure host) ---------------------------------------
@@ -356,6 +460,118 @@ def test_spec_prefix_cache_preemption_churn_identity_and_replay(model):
     assert list(eng1.scheduler.events) == list(eng2.scheduler.events)
     for a, b in zip(out1, out2):
         np.testing.assert_array_equal(a, b)
+
+
+class _ScanDrafter:
+    """The stateless scan called per lane per round — what the engine
+    did before the index: `propose` alone, no `begin`."""
+
+    def __init__(self):
+        self._scan = NgramDrafter().propose
+        self.calls = 0
+
+    def propose(self, tokens, k):
+        self.calls += 1
+        return self._scan(tokens, k)
+
+
+def _churn_engine(model, drafter=None):
+    """Repeating prompts on a pool too small for the load: drafts hit,
+    some of each are rejected, lanes are preempted and re-admitted."""
+    rng = np.random.RandomState(21)
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=3, block_size=2, num_blocks=16, prefill_chunk=4,
+        max_seq_len=32, prefix_cache=False), drafter=drafter)
+    handles = []
+    for i in range(8):
+        motif = rng.randint(0, model.config.vocab_size, (3,))
+        plen = int(rng.randint(5, 11))
+        handles.append(eng.submit(
+            np.tile(motif, 4)[:plen].astype(np.int32),
+            max_new_tokens=int(rng.randint(10, 19)), request_id=i))
+    res = eng.run()
+    return eng, handles, [res[h.request_id] for h in handles]
+
+
+def test_index_drafts_what_the_scan_drafts_through_preemption(model):
+    """Token for token and draft for draft: the engine with the
+    per-request index against the same engine handed the stateless scan
+    every round, through partly rejected drafts and preempted,
+    re-admitted lanes — and a re-admitted request's tokens are indexed
+    once."""
+    scan = _ScanDrafter()
+    e_ix, handles, out_ix = _churn_engine(model)
+    e_sc, _, out_sc = _churn_engine(model, drafter=scan)
+    c = e_ix.counters
+    assert c["preemptions"] > 0, "never preempted: vacuous"
+    assert 0 < c["spec_accepted_tokens"] < c["spec_proposed_tokens"], \
+        "drafts all accepted or all rejected: vacuous"
+    for a, b in zip(out_ix, out_sc):
+        np.testing.assert_array_equal(a, b)
+    for key in ("spec_proposed_tokens", "spec_accepted_tokens",
+                "spec_bonus_tokens", "verify_steps", "decode_steps",
+                "decoded_tokens", "preemptions"):
+        assert c[key] == e_sc.counters[key], key
+    assert list(e_ix.scheduler.events) == list(e_sc.scheduler.events)
+    # one lookup per call of the scan; the scan's engine indexes nothing
+    assert sum(c[k] for k in _HITS) + c["draft_misses"] == scan.calls
+    assert all(e_sc.counters[k] == 0 for k in
+               _HITS + ("draft_misses", "draft_indexed_tokens"))
+    # every request here drafts from its first round to its last, so all
+    # of its context but the final round's tokens is indexed, once
+    # (preempted or not): prompt + output, less what the last verify
+    # round emitted (1 + accepted, never told to the index)
+    total = sum(h.prompt.size + len(h.output) for h in handles)
+    assert total - 5 * len(handles) <= c["draft_indexed_tokens"] < total
+    assert all(h._draft is None for h in handles)  # dies with the request
+
+
+@pytest.mark.parametrize("monitored", [False, True],
+                         ids=["monitor-off", "monitor-on"])
+def test_draft_lookup_counters(model, monkeypatch, monitored):
+    """Hits by matched length + misses == the lane-rounds in which the
+    lane had room to draft (every call of the index), with monitoring
+    on or off; indexed tokens == the context the drafting requests
+    showed it."""
+    calls, told = [], []
+    real_init, real = NgramIndex.__init__, NgramIndex.propose
+
+    def init(self, max_ngram, min_ngram, prompt, room, tally=None):
+        told.append(len(prompt))
+        real_init(self, max_ngram, min_ngram, prompt, room, tally)
+
+    def propose(self, new_tokens, k):
+        calls.append(k)
+        told.append(len(new_tokens))
+        return real(self, new_tokens, k)
+
+    monkeypatch.setattr(NgramIndex, "__init__", init)
+    monkeypatch.setattr(NgramIndex, "propose", propose)
+    was = monitor.enabled()
+    (monitor.enable if monitored else monitor.disable)()
+    try:
+        base = monitor.snapshot()["counters"] \
+            .get("serving/spec_draft_calls", 0)
+        eng, handles, _ = _churn_engine(model)
+        ticks = monitor.snapshot()["counters"] \
+            .get("serving/spec_draft_calls", 0) - base
+    finally:
+        (monitor.enable if was else monitor.disable)()
+    c = eng.stats()
+    assert c["preemptions"] > 0
+    assert len(calls) > 0 and min(calls) > 0  # cap > 0, or no lookup
+    assert sum(c[k] for k in _HITS) + c["draft_misses"] == len(calls)
+    assert ticks == (len(calls) if monitored else 0)
+    assert c["draft_hits_ngram3"] > 0 and c["draft_misses"] > 0
+    assert c["draft_indexed_tokens"] == sum(told)
+    # a request that is never given room to draft opens no index
+    eng2 = ServingEngine(model, ServingConfig(
+        max_lanes=2, block_size=4, prefill_chunk=8, max_seq_len=32))
+    eng2.submit([1, 2, 1, 2, 1], max_new_tokens=2)
+    eng2.run()
+    assert eng2.counters["draft_indexed_tokens"] == 0
+    assert sum(eng2.counters[k] for k in _HITS) \
+        + eng2.counters["draft_misses"] == 0
 
 
 class _NullDrafter(Drafter):
