@@ -1,7 +1,9 @@
 """Flagship model families (the reference ships these via PaddleNLP/PaddleClas;
 the benchmark configs in BASELINE.md name Llama, BERT, ResNet, ERNIE —
 they live in-tree here so the framework is benchmarkable standalone)."""
-from . import bert, ernie, generation, latent_moe, llama  # noqa: F401
+from . import (  # noqa: F401
+    bert, ernie, generation, hybrid_ssm, latent_moe, llama,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertForMaskedLM, BertForSequenceClassification, BertModel,
 )
@@ -10,6 +12,7 @@ from .ernie import (  # noqa: F401
     ErnieForSequenceClassification, ErnieModel,
 )
 from .generation import generate  # noqa: F401
+from .hybrid_ssm import HybridSSMConfig, HybridSSMForCausalLM  # noqa: F401
 from .latent_moe import LatentMoEConfig, LatentMoEForCausalLM  # noqa: F401
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaForCausalLMPipe, LlamaModel,
@@ -22,6 +25,7 @@ __all__ = [
     "BertForSequenceClassification",
     "generation", "generate",
     "latent_moe", "LatentMoEConfig", "LatentMoEForCausalLM",
+    "hybrid_ssm", "HybridSSMConfig", "HybridSSMForCausalLM",
     "ernie", "ErnieConfig", "ErnieModel", "ErnieForPretraining",
     "ErnieForPretrainingPipe", "ErnieForSequenceClassification",
 ]

@@ -336,7 +336,8 @@ def generate(model, input_ids, max_new_tokens=32, do_sample=False,
             f"generate() keeps a contiguous [layers, b, len, kv_heads, "
             f"head_dim] K/V cache and scans one stacked layer body; "
             f"{type(model).__name__} brings its own cache and step "
-            f"programs (model.serving_family): serve it through "
+            f"programs (model.serving_family: the "
+            f"{model.serving_family_name!r} family): serve it through "
             f"paddle_tpu.serving.ServingEngine")
     if getattr(model.config, "moe_num_experts", 0) > 1:
         from ..framework.errors import UnimplementedError

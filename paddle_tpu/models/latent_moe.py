@@ -432,6 +432,8 @@ class LatentMoEForCausalLM(Layer):
 
     # -- serving ---------------------------------------------------------------
 
+    serving_family_name = "latent_moe"
+
     def serving_family(self, serving_config):
         """What :class:`paddle_tpu.serving.ServingEngine` asks a model
         for: its cache, its collected parameters, its step programs."""

@@ -8,8 +8,11 @@ K/V lies in the form its family takes (``read_form``): the lanes' LIVE
 ROWS — each lane's block list cut into rows of a few blocks, all lanes'
 rows end to end (:func:`pack_rows`), so a call gathers what the lanes
 hold and not every slot of every lane's table (the dense family) — or
-a ``[lanes, M]`` block table (the latent family, its one user). Three
-compiled programs serve the whole lifetime:
+a ``[lanes, M]`` block table (the latent family, its one user). A family
+that also keeps state per LANE (``lane_state``: the hybrid state-space
+family's recurrent state and conv tail) has its one-lane prefill chunk
+told which lane the request holds. Three compiled programs serve the
+whole lifetime:
 
 - **prefill chunk** ``[1, C]``: one lane's context enters the pool C
   tokens at a time (padded tail chunks write only below the context
@@ -31,10 +34,12 @@ compiled programs serve the whole lifetime:
   short/empty drafts pad up to ``k`` with writes redirected to the
   null block (``wlimit``), so a no-draft lane verifies exactly one
   token and churn in draft lengths never retraces. Rejected positions
-  roll back by rewinding ``pool_len`` only — the tail blocks are
-  lane-private (shared prefix blocks are full + frozen), so
-  over-written K/V was never shared and the next accepted write simply
-  overwrites it.
+  roll back per kind of cache: K/V by rewinding ``pool_len`` only — the
+  tail blocks are lane-private (shared prefix blocks are full + frozen),
+  so over-written K/V was never shared and the next accepted write
+  simply overwrites it; a family's LANE-indexed recurrent state by the
+  verify program itself, which takes up the pending token and the
+  accepted drafts and nothing else (:meth:`ServingEngine._verify_round`).
 
 All three compile through :func:`paddle_tpu.jit.exec_cache.get_or_compile`
 (keyed on generation config, param avals, pool geometry, lane count and
@@ -43,8 +48,9 @@ compiles. The programs themselves — the layer math, the cache a token
 takes in a layer, how the weights are collected — are the model's
 FAMILY's (``serving/families``: the dense grouped-query decoder whose
 outputs are token-identical to per-request ``generate()`` calls, the
-latent-attention sparse-expert decoder); this module is what every
-family shares and names no architecture.
+latent-attention sparse-expert decoder, the hybrid state-space /
+attention decoder); this module is what every family shares and names no
+architecture.
 
 Reference lineage: the static-graph serving surface this replaces is
 `paddle_infer.Predictor` (`paddle/fluid/inference/api/
@@ -279,11 +285,16 @@ class ServingEngine:
         # the device state every step program threads through (the
         # family's pools; an entry may be None), replaced after each call
         self._pools = tuple(fam.make_pools(num_blocks, cfg.block_size))
+        # device state by how it is indexed: by token (layer, block,
+        # offset: what the block pool manages) and by lane
         self.kv_pool_bytes = fam.kv_pool_bytes(self._pools)
+        self.lane_pool_bytes = fam.lane_pool_bytes(self._pools)
+        # a family whose requests need more than a prefix's blocks to
+        # start from it (recurrent state) acquires none: cached_len 0
         self.scheduler = FCFSScheduler(
             BlockPool(num_blocks, cfg.block_size), cfg.max_lanes,
             self.blocks_per_lane, self.max_seq_len,
-            prefix_cache=cfg.prefix_cache)
+            prefix_cache=cfg.prefix_cache and fam.prefix_reuse)
         # live (waiting/running) requests only; finished ones move to
         # _finished until collected — a long-running server must not
         # grow with its request history
@@ -554,16 +565,21 @@ class ServingEngine:
         form = self._rows_form(kind, lanes)
         if form is None:
             return jax.ShapeDtypeStruct((lanes, self.blocks_per_lane), i32)
-        return (jax.ShapeDtypeStruct((form[2], 2 + form[0]), i32),
+        spec = (jax.ShapeDtypeStruct((form[2], 2 + form[0]), i32),
                 jax.ShapeDtypeStruct((lanes, width), i32))
+        if kind == "prefill" and self._family.lane_state:
+            spec += (jax.ShapeDtypeStruct((1,), i32),)
+        return spec
 
-    def _pack_read(self, kind, lanes, width, items, ph=None):
+    def _pack_read(self, kind, lanes, width, items, ph=None, slot=None):
         """Program ``kind``'s read operand for one call, as numpy, in
         the form its family takes: LIVE ROWS ``(rows, wblk)``
         (:func:`pack_rows`; ``items`` as there), or — the latent family
         only — a block TABLE ``[lanes, M]`` (every lane's whole list,
-        null-padded). Bills the call to the three ``kv_*`` read
-        counters."""
+        null-padded). A ``lane_state`` family's prefill chunk gets
+        ``slot [1]`` as a third entry: the lane its request holds (the
+        chunk runs as lane 0 of a one-lane call). Bills the call to the
+        three ``kv_*`` read counters."""
         B, M = self.config.block_size, self.blocks_per_lane
         c = self.counters
         c["kv_read_tokens"] += sum(it[3] for it in items)
@@ -580,6 +596,8 @@ class ServingEngine:
         c["kv_gathered_tokens"] += -(-n // tile) * tile * w * B
         if ph is not None and _spans is not None:
             ph.args.update(rows=n, live_blocks=live)
+        if kind == "prefill" and self._family.lane_state:
+            return rows, wblk, np.asarray([slot], np.int32)
         return rows, wblk
 
     def _prefill(self, req) -> None:
@@ -593,7 +611,9 @@ class ServingEngine:
         >= ctx). A re-admitted (preempted) request only rebuilds the
         pool — its pending token is already known, and greedy recompute
         reproduces the continuation exactly as long as the prefill and
-        decode programs round K/V identically (proven token-identical
+        decode programs round K/V (and a ``lane_state`` family's
+        recurrent state, which a chunk at position 0 starts from zero)
+        identically (proven token-identical
         on the CPU tier in tests/test_serving.py; the two programs fuse
         differently, so a TPU near-tie argmax flip is possible —
         hardware recompute-parity A/B queued in ROADMAP)."""
@@ -617,7 +637,8 @@ class ServingEngine:
                 # the chunk sees its lane's slots below its own end
                 read = self._pack_read(
                     "prefill", 1, C,
-                    [(0, req.blocks, start, min(start + C, ctx))])
+                    [(0, req.blocks, start, min(start + C, ctx))],
+                    slot=req.lane)
                 tok, *self._pools = self._prefill_exec(
                     self._params, *self._pools, *jax.device_put(
                         (read, chunk, np.int32(start), np.int32(ctx),
@@ -742,10 +763,22 @@ class ServingEngine:
         """One [L, k+1] verify step for every occupied lane: score the
         pending token + draft, accept each lane's longest prefix that
         matches the program's own greedy picks plus one bonus token.
-        Rejected positions roll back by REWINDING ``pool_len`` only:
-        their K/V sits above the lane's valid length in lane-private
-        blocks (masked out of every later attend) until the next
-        accepted write overwrites it."""
+        The rollback contract, per kind of cache a family keeps:
+
+        - TOKEN-indexed (K/V, the latent entry): rejected positions roll
+          back by rewinding ``pool_len`` — their entries sit above the
+          lane's valid length in lane-private blocks (masked out of
+          every later attend) until the next accepted write overwrites
+          them. The dense and latent families keep nothing else.
+        - LANE-indexed (a recurrent state and its conv tail, the hybrid
+          state-space family): a rejected position folded into a state
+          cannot be masked later, so the family's verify program applies
+          the state update only after it has computed each lane's
+          acceptance ITSELF, by this method's rule (:meth:`_accept`, the
+          judge of what is emitted; the two agree or
+          ``spec_rolled_back_tokens`` differs from proposed - accepted):
+          after the round the lane's slot holds the state after the
+          pending token and the accepted drafts, nothing else."""
         L, K = self.config.max_lanes, self.config.spec_k
         with self._phase("pack", "pack_s") as ph:
             cur = np.zeros((L,), np.int32)
@@ -987,10 +1020,18 @@ class ServingEngine:
             prefill_chunk=self.config.prefill_chunk,
             int8_weights=self.config.int8_weights,
             kv_int8=self.config.kv_int8,
+            # device state by how it is indexed: by token (the block
+            # pool's: K/V or latent entries and their scales) and by
+            # lane (a family's recurrent state); the weights are neither
             kv_pool_bytes=self.kv_pool_bytes,
+            lane_pool_bytes=self.lane_pool_bytes,
+            device_state_bytes=self.kv_pool_bytes + self.lane_pool_bytes,
             # a constant: benchmarks/chip/chiplib/serve.py reads the key
             paged_attention=False,
             prefix_cache=self.config.prefix_cache,
+            # False: the family's requests cannot start from a prefix's
+            # blocks alone, so none is acquired whatever prefix_cache says
+            prefix_reuse=self._family.prefix_reuse,
             shared_blocks=self.scheduler.pool.shared_count,
             cold_blocks=self.scheduler.pool.cold_count,
             indexed_blocks=self.scheduler.pool.indexed_count,

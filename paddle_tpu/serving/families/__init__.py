@@ -24,6 +24,20 @@ and asks the model's *family* for the three things that differ:
     [lanes, width])`` (``engine.pack_rows``), the dense family's read;
     ``None`` — a ``[lanes, M]`` block table, the latent family's.
 
+Two more kinds of state than (a) may live in a family, both told to the
+engine by attributes: ``lane_state`` — besides its token-indexed pools the
+family keeps pools indexed by LANE (``[layers, lanes, ...]``: a recurrent
+state, a conv tail; ``lane_pool_bytes(pools)`` their size, 0 elsewhere).
+Decode and verify index them by the batch row; the one-lane prefill chunk
+is told its request's lane, the STATE SLOT, as a third entry of its read
+operand, ``(rows, wblk, slot [1])``, and a chunk at position 0 starts the
+slot from zero, so an admitted or re-admitted request never sees its
+predecessor's. Such a family's verify program owes the engine the
+rollback contract of ``ServingEngine._verify_round``. ``prefix_reuse`` —
+False where a request cannot start from a prefix's blocks alone (it
+would need the recurrent state at that boundary): the engine then has
+the scheduler acquire none, and ``stats()`` says so.
+
 Plus ``absorb(out, counters)``: the round's ONE fetched array goes
 through it — a family that rides its own counters on that array strips
 them into ``counters`` (initial values: ``counters``) and returns the
@@ -33,6 +47,19 @@ A model names its family by a ``serving_family(serving_config)`` method;
 one without it is the dense grouped-query decoder the engine began with.
 """
 from __future__ import annotations
+
+
+def absorb_accumulator(out, names, seen, counters):
+    """For a family that rides a device accumulator on the round's fetched
+    vector (its last ``len(names)`` entries, int32, running totals): add
+    what each slot grew by since the last fetch to ``counters`` (modulo
+    2^32; ``seen`` holds the last totals) and return the tokens."""
+    n = len(names)
+    for i, name in enumerate(names):
+        now = int(out[out.size - n + i])
+        counters[name] += (now - seen[i]) & 0xFFFFFFFF
+        seen[i] = now
+    return out[:out.size - n]
 
 
 def family_for(model, serving_config):

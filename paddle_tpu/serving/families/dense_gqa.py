@@ -326,6 +326,8 @@ class DenseGQAFamily:
     family for (see ``families/__init__.py``)."""
 
     name = "dense_gqa"
+    lane_state = False   # every pool is indexed by (layer, block, offset)
+    prefix_reuse = True  # a prefix's K/V blocks are all a new request needs
 
     def __init__(self, model, config):
         if getattr(model.config, "moe_num_experts", 0) > 1:
@@ -363,6 +365,9 @@ class DenseGQAFamily:
 
     def kv_pool_bytes(self, pools):
         return int(sum(a.nbytes for a in pools if a is not None))
+
+    def lane_pool_bytes(self, pools):
+        return 0
 
     def read_form(self, kind):
         """How program ``kind`` is told where its lanes' K/V lies

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from ...models.generation import _rms
 from ...models.latent_moe import attend_absorbed, latent_qkv, mlp_block
+from . import absorb_accumulator
 
 __all__ = ["LatentMoEFamily"]
 
@@ -131,6 +132,8 @@ class LatentMoEFamily:
 
     name = "latent_moe"
     donate_argnums = (1, 2)
+    lane_state = False   # the one pool is indexed by (layer, block, offset)
+    prefix_reuse = True  # a prefix's latent blocks are all a request needs
 
     def __init__(self, model, config):
         from ...framework.errors import UnimplementedError
@@ -177,6 +180,9 @@ class LatentMoEFamily:
     def kv_pool_bytes(self, pools):
         return int(pools[0].nbytes)
 
+    def lane_pool_bytes(self, pools):
+        return 0
+
     def read_form(self, kind):
         """Every program takes a ``[lanes, M]`` block table and gathers
         all of it (what waits on the dense family's row read: PERF.md 7)."""
@@ -196,14 +202,8 @@ class LatentMoEFamily:
                          str(pools[0].dtype))}
 
     def absorb(self, out, counters):
-        """Strip the accumulator off the fetched vector into ``counters``
-        (int32 on the device, so differences are taken modulo 2^32)."""
-        n = len(ACC)
-        for i, name in enumerate(ACC):
-            now = int(out[out.size - n + i])
-            counters[name] += (now - self._seen[i]) & 0xFFFFFFFF
-            self._seen[i] = now
-        return out[:out.size - n]
+        """Strip the accumulator off the fetched vector into ``counters``."""
+        return absorb_accumulator(out, ACC, self._seen, counters)
 
     def stats(self):
         itemsize = jnp.dtype(self.gcfg.dtype).itemsize

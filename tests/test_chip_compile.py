@@ -173,10 +173,10 @@ def engines():
     return _engines(_POOL, 4, _LANES, _TABLE)
 
 
-def _dense_program_text(topo, eng, kind):
-    """The dense family's program ``kind`` as the chip's compiler leaves
-    it, lowered as ``ServingEngine._ensure_compiled`` lowers it on the
-    chip (pools donated)."""
+def _compiled_program(topo, eng, kind):
+    """Program ``kind`` of a rows-form family (the dense one, the hybrid
+    state-space one) compiled for the described chip, lowered as
+    ``ServingEngine._ensure_compiled`` lowers it there (pools donated)."""
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def spec(a):
@@ -198,7 +198,13 @@ def _dense_program_text(topo, eng, kind):
         fn, static_argnames=tuple(static),
         donate_argnums=eng._family.donate_argnums,
     ).lower(*jax.tree_util.tree_map(spec, (eng._params, *eng._pools)),
-            read, *rest, **static).compile().as_text()
+            read, *rest, **static).compile()
+
+
+def _dense_program_text(topo, eng, kind):
+    """The dense family's program ``kind`` as the chip's compiler leaves
+    it."""
+    return _compiled_program(topo, eng, kind).as_text()
 
 
 def _results_shaped(text, dims):
@@ -425,3 +431,129 @@ def test_latent_attention_reader_picks_the_cache_not_the_new_entries(
                            and "576" not in n], lanes, {
         "kv_lora_rank": 512, "qk_rope_head_dim": 64,
         "num_attention_heads": 4}) is None
+
+
+# -- the hybrid state-space / attention family's programs -----------------------
+
+# lanes, state-space heads; d_head 64 and d_state 128 are the published
+# sizes, and so is the K/V row of 8 heads x 64: what decides the layouts
+_HYBRID_LANES, _HYBRID_HEADS = 8, 4
+_HYBRID_STATE = (_HYBRID_LANES, _HYBRID_HEADS, 64, 128)
+_HYBRID_KV = (1, 2049, 16, 8 * 64)
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    """Two state-space layers around one attention layer, bf16, built on
+    the CPU for its shapes."""
+    from paddle_tpu.models import HybridSSMConfig, HybridSSMForCausalLM
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    model = HybridSSMForCausalLM(HybridSSMConfig(
+        vocab_size=512, hidden_size=512, shared_intermediate_size=512,
+        num_hidden_layers=3, layer_types=["mamba", "attention", "mamba"],
+        num_attention_heads=8, num_key_value_heads=8,
+        mamba_n_heads=_HYBRID_HEADS, mamba_d_head=64, mamba_d_state=128,
+        attention_multiplier=0.015625, embedding_multiplier=12,
+        residual_multiplier=0.22, logits_scaling=8,
+        initializer_range=0.0, dtype="bfloat16"))
+    model.eval()
+    return ServingEngine(model, ServingConfig(
+        max_lanes=_HYBRID_LANES, block_size=16, num_blocks=_HYBRID_KV[1],
+        prefill_chunk=32, max_seq_len=20 * 16))
+
+
+def _hybrid_program_names(topo, eng, kind):
+    """The family's program ``kind`` as the chip's compiler leaves it:
+    the entry computation's instructions, each with its operands' shapes
+    as the device trace names its events."""
+    from jax._src.lib import xla_client as xc
+
+    compiled = _compiled_program(topo, eng, kind)
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_backend_config = False
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [ln.strip() for ln in text[text.index("\nENTRY"):].splitlines()
+            if " = " in ln]
+
+
+def _dims(shape):
+    return ",".join(str(d) for d in shape)
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+def test_hybrid_program_never_copies_a_pool(topo, hybrid_engine, kind):
+    """Device state of three kinds, none of which a program call may
+    copy: K and V pools whose last axis is the 8 heads x 64 merged (with
+    ``[.., 8, 64]`` the TPU lays the pool out blocks-minor and every call
+    copied both pools in and out: 4 x 285 MB at the benchmark's size); a
+    conv pool with a lane's 3 rows side by side; one float32 state array
+    a state-space layer."""
+    eng = hybrid_engine
+    assert eng._pools[0].shape == _HYBRID_KV
+    assert all(p.shape == _HYBRID_STATE for p in eng._pools[4:])
+    names = _hybrid_program_names(topo, eng, kind)
+    pools = "|".join(rf"\w+\[{_dims(p.shape)}\]" for p in
+                     (eng._pools[0], eng._pools[2], eng._pools[4]))
+    copies = [n[:200] for n in names
+              if re.search(rf"= ({pools})\S* copy\(", n)]
+    assert not copies, "\n".join(copies[:4])
+
+
+def test_hybrid_decode_reads_and_writes_each_state_once(topo,
+                                                        hybrid_engine):
+    """The plain round's state update and the layer's output ``S C`` are
+    ONE fusion with two results a layer — the state read once and written
+    once. On a stacked ``[layers, lanes, ...]`` pool the update is an
+    in-place dynamic-update-slice fusion and the output a second fusion
+    that reads the layer's state again (3 x 134 MB a layer a round where
+    2 are required: PERF.md section 6, PR 31)."""
+    names = _hybrid_program_names(topo, hybrid_engine, "decode")
+    state = rf"f32\[{_dims(_HYBRID_STATE)}\]"
+    out = rf"f32\[{_dims(_HYBRID_STATE[:3])}\]"
+    touching = [n for n in names if re.search(state, n)
+                and re.search(r" (fusion|copy|dynamic-update-slice)\(", n)]
+    both = [n for n in touching
+            if re.search(rf"= \(({out}\S*, {state}|{state}\S*, {out})", n)]
+    assert len(touching) == len(both) == 2, [n[:160] for n in touching]
+
+
+@pytest.mark.parametrize("kind,per_layer", [("decode", 1), ("verify", 2)])
+def test_ssm_update_reader_picks_the_state_and_nothing_else(
+        topo, hybrid_engine, kind, per_layer):
+    """The benchmark's ``ssm_update_roofline`` picks operations by the
+    state's shape in their instruction text (the device trace's events
+    are named by it). Held here to the compiled programs' own
+    instructions: a layer's one read-and-write fusion in a plain round; in
+    a verify round the product that reads the state for the round's
+    outputs and the update that applies what was accepted; nothing of the
+    attention layer, the MLPs or the head."""
+    import importlib.util
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmarks", "chip"))
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "ssm_update_roofline_reader", os.path.join(
+                root, "benchmarks/chip/metrics/ssm_update_roofline.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.pop(0)
+    names = _hybrid_program_names(topo, hybrid_engine, kind)
+    m = {"mamba_n_heads": _HYBRID_HEADS, "mamba_d_head": 64,
+         "mamba_d_state": 128}
+    picked = reader.pattern(names, _HYBRID_LANES, m, 2)
+    assert rf"f32\[{_dims(_HYBRID_STATE)}\]" in picked.replace("\\,", ",")
+    # (at this size the compiler also prefetches slices of a state into
+    # fast memory, slice-start / slice-done: a 134 MB state has none)
+    hit = [n for n in names if re.search(picked, n)
+           and re.search(r"[\s)]fusion\(", n)]
+    assert len(hit) == 2 * per_layer, [n[:200] for n in hit]
+    assert all("ssm/state_update" in n for n in hit), \
+        [n[:200] for n in hit if "ssm/state_update" not in n]
+    # a program without a state (the parent's): nothing to read
+    assert reader.pattern([n for n in names if ",64,128]" not in n],
+                          _HYBRID_LANES, m, 2) is None

@@ -284,7 +284,10 @@ def test_watchdog_trips_and_blackbox_names_hung_step(
                            poll_s=0.02).start()
     try:
         deadline = time.time() + 5.0
-        while wd._trips == 0 and time.time() < deadline:
+        # (the trip is counted before its artifact is written: wait for
+        # both, or a loaded machine reads the file before it exists)
+        while (wd._trips == 0 or not os.path.exists(art)) \
+                and time.time() < deadline:
             time.sleep(0.02)
         assert wd._trips >= 1
         st = wd.state()

@@ -1,14 +1,15 @@
-"""Compile a latent-attention sparse-expert serving cell's three step
-programs at the configuration's real sizes for ONE chip of a described
-``v5e:2x2`` — no chip attached, nothing runs — and print what each needs
-of the device's memory. By hand, before chip calls:
+"""Compile a serving cell's three step programs — the latent-attention
+sparse-expert family's or the hybrid state-space family's, by the
+configuration's ``arch`` — at the configuration's real sizes for ONE chip
+of a described ``v5e:2x2`` — no chip attached, nothing runs — and print
+what each needs of the device's memory. By hand, before chip calls:
 
     JAX_PLATFORMS=cpu python tools/rehearse_latent_serving.py \
         [--config benchmarks/chip/configs/<name>.json] [--text-dir DIR]
 
 The parameters are shapes only (the benchmark adapter's leaf list laid
-out as ``serving/families/latent_moe.py`` lays its collected parameters
-out), so no weight is made. What the TPU compiler refuses (a program
+out as the family lays its collected parameters out), so no weight is
+made. What the TPU compiler refuses (a program
 that does not fit 16 GB, a layout it cannot tile) shows here and costs no
 chip time; a compile that passes is not a chip run.
 """
@@ -27,6 +28,64 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def _latent(arch, cfg, layers, s, sds, i32, geom):
+    """(family module, static kwargs by program, pools, read operands by
+    program)
+    of ``serving/families/latent_moe.py``: one padded latent pool, a
+    block table for every program."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import LatentMoEConfig
+    from paddle_tpu.serving.families import latent_moe as fam
+
+    L, B, _, _, M = geom
+    static = LatentMoEConfig(**arch.config_kwargs(
+        cfg, layers, s["max_seq_len"])).static()
+    width = cfg["model"]["kv_lora_rank"] + cfg["model"]["qk_rope_head_dim"]
+    width = -(-width // fam.LANES) * fam.LANES  # as make_pools pads it
+    pools = (sds((layers, s["num_blocks"], B, width)),
+             sds((len(fam.ACC),), jnp.int32))
+    kinds = ("decode", "verify", "prefill")
+    return fam, dict.fromkeys(kinds, {"cfg": static}), pools, {
+        "decode": i32(L, M), "verify": i32(L, M), "prefill": i32(1, M)}
+
+
+def _hybrid(arch, cfg, layers, s, sds, i32, geom):
+    """The same of ``serving/families/hybrid_ssm.py``: K and V pools by
+    block for the attention layers, a conv pool and one float32 state
+    array a state-space layer by LANE, the dense family's live-rows read (the prefill chunk's with
+    its state slot)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import HybridSSMConfig
+    from paddle_tpu.serving.engine import fit_rows
+    from paddle_tpu.serving.families import hybrid_ssm as fam
+
+    L, B, C, K, M = geom
+    g = HybridSSMConfig(**arch.config_kwargs(
+        cfg, layers, s["max_seq_len"])).static()
+    n_ssm = sum(k == "mamba" for k in g.layer_types)
+    kv = sds((layers - n_ssm, s["num_blocks"], B,
+              g.num_key_value_heads * g.head_dim))
+    pools = (kv, kv, sds((n_ssm, L, (g.mamba_d_conv - 1) * g.conv_dim)),
+             sds((len(fam.ACC),), jnp.int32),
+             *(sds((L, g.mamba_n_heads, g.mamba_d_head, g.mamba_d_state),
+                   jnp.float32) for _ in range(n_ssm)))
+
+    reads, statics = {}, {}
+    for kind, lanes, width in (("decode", L, 1), ("verify", L, K + 1),
+                               ("prefill", 1, C)):
+        tile = fam.PREFILL_TILE if kind == "prefill" else fam.ROW_TILE
+        w, tile, cap = fit_rows((fam.ROW_BLOCKS, tile), lanes, M)
+        reads[kind] = (i32(cap, 2 + w), i32(lanes, width))
+        statics[kind] = {"cfg": g, "tile": tile}
+    reads["prefill"] += (i32(1),)
+    return fam, statics, pools, reads
+
+
+FAMILIES = {"mla_moe": _latent, "hybrid_ssm": _hybrid}
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", default=os.path.join(
@@ -41,9 +100,7 @@ def main():
     from jax.sharding import SingleDeviceSharding
 
     import paddle_tpu.framework.device as device
-    from paddle_tpu.models import LatentMoEConfig
     from paddle_tpu.serving import ServingConfig
-    from paddle_tpu.serving.families import latent_moe as fam
 
     # steer the code's ONE rule for "am I on the chip" here, in the
     # script: the described chip gets the real kernels, not interpret mode
@@ -69,37 +126,33 @@ def main():
         (params if li < 0 else params["layers"][li])[name] = sds(shape)
         weights += math.prod(shape) * dt.itemsize
     params["layers"] = tuple(params["layers"])
-    static = LatentMoEConfig(**arch.config_kwargs(
-        cfg, layers, s["max_seq_len"])).static()
     sc = ServingConfig(max_lanes=s["max_lanes"],
                        max_seq_len=s["max_seq_len"],
                        num_blocks=s["num_blocks"])
     L, B, C, K = sc.max_lanes, sc.block_size, sc.prefill_chunk, sc.spec_k
     M = -(-s["max_seq_len"] // B)
-    width = cfg["model"]["kv_lora_rank"] + cfg["model"]["qk_rope_head_dim"]
-    width = -(-width // fam.LANES) * fam.LANES  # as make_pools pads it
-    pool = sds((layers, s["num_blocks"], B, width))
-    acc = sds((len(fam.ACC),), jnp.int32)
 
     def i32(*shape):
         return sds(shape, jnp.int32)
 
+    fam, statics, pools, reads = FAMILIES[cfg["arch"]](
+        arch, cfg, layers, s, sds, i32, (L, B, C, K, M))
     programs = {
-        "decode": (fam._decode_step, (i32(L, M), i32(L), i32(L))),
+        "decode": (fam._decode_step, (reads["decode"], i32(L), i32(L))),
         "verify": (fam._verify_step,
-                   (i32(L, M), i32(L), i32(L, K + 1), i32(L))),
+                   (reads["verify"], i32(L), i32(L, K + 1), i32(L))),
         "prefill": (fam._prefill_chunk,
-                    (i32(1, M), i32(1, C), i32(), i32(), i32())),
+                    (reads["prefill"], i32(1, C), i32(), i32(), i32())),
     }
-    print(json.dumps({"weights_bytes": weights,
-                      "pool_bytes": layers * s["num_blocks"] * B * width
-                      * dt.itemsize, "lanes": L, "blocks_per_lane": M,
-                      "chunk": C, "spec_k": K}))
+    print(json.dumps({"weights_bytes": weights, "pools_bytes": sum(
+        math.prod(p.shape) * p.dtype.itemsize for p in pools), "lanes": L, "blocks_per_lane": M, "chunk": C,
+        "spec_k": K}))
     for kind, (fn, rest) in programs.items():
         t = time.perf_counter()
-        compiled = jax.jit(fn, static_argnames=("cfg",),
-                           donate_argnums=(1, 2)).lower(
-            params, pool, acc, *rest, cfg=static).compile()
+        compiled = jax.jit(
+            fn, static_argnames=tuple(statics[kind]),
+            donate_argnums=tuple(range(1, 1 + len(pools)))).lower(
+            params, *pools, *rest, **statics[kind]).compile()
         ma = compiled.memory_analysis()
         print(json.dumps({
             "program": kind, "compile_s": round(time.perf_counter() - t, 1),
