@@ -137,8 +137,16 @@ class ServingConfig:
     - ``num_blocks`` (``PT_SERVE_BLOCKS``): pool size incl. the reserved
       null block; default sizes every lane for ``max_seq_len`` (no
       preemption pressure — shrink it to trade HBM for requeues).
-    - ``prefill_chunk`` (``PT_SERVE_PREFILL_CHUNK``, 32): prefill
-      program width; prompts enter in ceil(len/chunk) calls.
+    - ``prefill_chunk`` (``PT_SERVE_PREFILL_CHUNK``; default
+      :data:`PREFILL_CHUNK`, 128): prefill program width; prompts enter
+      in ceil(len/chunk) calls, and a call reads every weight whatever
+      its width, so the default is as wide as that read pays for on the
+      chip (PERF.md section 6, PR 32). Left unset (``None`` here) the
+      engine fits the default to its geometry — whole blocks, never past
+      ``max_seq_len`` rounded down to whole blocks
+      (:func:`default_prefill_chunk`; ``ServingEngine.prefill_chunk`` is
+      the width in use); a width given here or in the environment is
+      taken as given.
     - ``max_seq_len`` (``PT_SERVE_MAX_LEN``): per-request prompt+output
       ceiling; defaults to the model's max_position_embeddings.
     - ``int8_weights`` (``PT_DECODE_INT8``): weight-only int8 matmuls,
@@ -175,8 +183,9 @@ class ServingConfig:
             else _env_int("PT_SERVE_BLOCK", 16)
         self.num_blocks = num_blocks if num_blocks is not None \
             else _env_int("PT_SERVE_BLOCKS", 0) or None
+        # None: the engine's own default, fitted to its geometry
         self.prefill_chunk = prefill_chunk if prefill_chunk is not None \
-            else _env_int("PT_SERVE_PREFILL_CHUNK", 32)
+            else _env_int("PT_SERVE_PREFILL_CHUNK", 0) or None
         self.max_seq_len = max_seq_len if max_seq_len is not None \
             else _env_int("PT_SERVE_MAX_LEN", 0) or None
         if int8_weights is None:
@@ -201,9 +210,24 @@ class ServingConfig:
         if self.spec_k == 0:
             self.spec = False  # k=0 IS plain decode; skip the program
         for name in ("max_lanes", "block_size", "prefill_chunk"):
-            if getattr(self, name) < 1:
-                raise ValueError(f"{name} must be >= 1, "
-                                 f"got {getattr(self, name)}")
+            v = getattr(self, name)
+            if v is not None and v < 1:
+                raise ValueError(f"{name} must be >= 1, got {v}")
+
+
+# The prefill call's default width, chosen on the chip (PERF.md section 6,
+# PR 32): a call reads all the weights to push its tokens, and up to about
+# this width it costs what a 32-token call costs in every family.
+PREFILL_CHUNK = 128
+
+
+def default_prefill_chunk(max_seq_len, block_size):
+    """:data:`PREFILL_CHUNK` fitted to an engine's geometry: whole blocks
+    (at least one), and no wider than ``max_seq_len`` rounded down to
+    whole blocks — a model that serves 48 tokens gets a 48-token call,
+    not 80 positions of padding in every one."""
+    cap = min(PREFILL_CHUNK, max_seq_len) // block_size
+    return max(cap, 1) * block_size
 
 
 def fit_rows(form, lanes, blocks_per_lane):
@@ -280,6 +304,8 @@ class ServingEngine:
                                or fam.max_position_embeddings)
         self.blocks_per_lane = blocks_needed(self.max_seq_len,
                                              cfg.block_size)
+        self.prefill_chunk = int(cfg.prefill_chunk or default_prefill_chunk(
+            self.max_seq_len, cfg.block_size))
         num_blocks = int(cfg.num_blocks
                          or cfg.max_lanes * self.blocks_per_lane + 1)
         # the device state every step program threads through (the
@@ -336,6 +362,9 @@ class ServingEngine:
         # hit = tokens served by acquired shared blocks (no compute),
         # miss = tokens actually pushed through the prefill program —
         # the bench's prefix_hit_rate numerator/denominator.
+        # prefill_fed_tokens = prefill_chunks x the program's width, pad
+        # positions included: miss / fed is how full the prefill calls
+        # run, prefill_chunks / admits how many calls a prompt takes.
         # spec_{proposed,accepted}_tokens are post-trim (what the verify
         # step actually speculated) so accepted/proposed IS the accept
         # rate; bonus counts the +1 token a drafted lane's verification
@@ -353,6 +382,7 @@ class ServingEngine:
             "draft_hits_ngram2": 0, "draft_hits_ngram1": 0,
             "draft_misses": 0,
             "prefix_hit_tokens": 0, "prefix_miss_tokens": 0,
+            "prefill_fed_tokens": 0,
             "kv_read_tokens": 0, "kv_gathered_tokens": 0,
             "kv_dense_read_tokens": 0,
             "kv_quant_writes": 0, "kv_quant_tokens": 0,
@@ -410,7 +440,7 @@ class ServingEngine:
         from ..jit import exec_cache
 
         cfgv, fam = self.config, self._family
-        L, M, C = cfgv.max_lanes, self.blocks_per_lane, cfgv.prefill_chunk
+        L, M, C = cfgv.max_lanes, self.blocks_per_lane, self.prefill_chunk
         i32 = jnp.int32
         # donation halves pool HBM traffic; XLA:CPU can't donate these
         # and would warn per call. Which operands churn write-for-write
@@ -623,7 +653,7 @@ class ServingEngine:
         with self._phase("prefill", "prefill_s", "serving_prefill",
                          f"req/{req.trace_id}", request=req.trace_id,
                          hit_tokens=cached, miss_tokens=ctx - cached) as ph:
-            C = self.config.prefill_chunk
+            C = self.prefill_chunk
             sp = _spans
             p_t0 = req._t_mark  # admission stamped it just before this call
             nchunks = 0
@@ -656,6 +686,7 @@ class ServingEngine:
             req.pool_len = ctx
             self.scheduler.publish_prefix(req)
             self.counters["prefill_chunks"] += nchunks
+            self.counters["prefill_fed_tokens"] += nchunks * C
             self.counters["prefix_hit_tokens"] += cached
             self.counters["prefix_miss_tokens"] += ctx - cached
             if self.config.kv_int8:
@@ -990,7 +1021,7 @@ class ServingEngine:
                 "max_lanes": self.config.max_lanes,
                 "block_size": self.config.block_size,
                 "num_blocks": self.scheduler.pool.num_blocks,
-                "prefill_chunk": self.config.prefill_chunk,
+                "prefill_chunk": self.prefill_chunk,
                 "max_seq_len": self.max_seq_len,
                 "spec": self.spec_active,
                 "spec_k": self.config.spec_k,
@@ -1003,7 +1034,12 @@ class ServingEngine:
         }
 
     def stats(self) -> dict:
-        """Plain-int account of the engine's lifetime (always on)."""
+        """Plain-int account of the engine's lifetime (always on):
+        ``counters`` plus the geometry in use. ``prefill_chunk`` is the
+        prefill program's width as the engine resolved it;
+        ``prefix_miss_tokens / prefill_fed_tokens`` is how full its calls
+        ran (fed = ``prefill_chunks`` x that width) and ``prefill_chunks
+        / admits`` how many calls a prompt took."""
         out = dict(self.counters)
         out.update(
             decode_rounds=(self.counters["decode_steps"]
@@ -1017,7 +1053,7 @@ class ServingEngine:
             allocatable_blocks=self.scheduler.pool.allocatable,
             blocks_per_lane=self.blocks_per_lane,
             max_seq_len=self.max_seq_len,
-            prefill_chunk=self.config.prefill_chunk,
+            prefill_chunk=self.prefill_chunk,
             int8_weights=self.config.int8_weights,
             kv_int8=self.config.kv_int8,
             # device state by how it is indexed: by token (the block

@@ -10,7 +10,9 @@ as the serving engine sees it.
   (``dense_gqa._pool_forward``'s form).
 - **Attention** reads the latent directly (``attend_absorbed``) in all
   three programs: at a 32-token chunk the up-projection of a whole block
-  table costs ~13x the absorbed scores (PERF.md section 6, PR 27).
+  table costs ~13x the absorbed scores (PERF.md section 6, PR 27). A
+  prefill chunk wider than ``QUERY_TILE`` attends its positions a tile
+  at a time and skips the tiles that are all pad (below).
 - **Weights once**: ``params`` is a tuple of per-layer dicts whose leaves
   ARE the model's arrays, and each program is a Python loop over the
   layers (a dense layer followed by expert layers cannot be one scan
@@ -28,6 +30,8 @@ second copy.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -39,16 +43,48 @@ __all__ = ["LatentMoEFamily"]
 
 LANES = 128  # the TPU's lane tile: the pool's last axis is padded to it
 
+# A prefill chunk attends QUERY_TILE of its positions at a time, under a
+# loop that runs the tiles holding a real token: the absorbed attention's
+# float32 scores are [positions, heads, every table slot], written,
+# reduced and read again, which the chunk's weight reads do not amortise —
+# 0.08 ms a position at the published widths, so a 128-wide call on a
+# 20-token prompt cost 18.6 ms where a 32-wide one cost 9.8 (PERF.md
+# section 6, PR 32). The matmuls run at the call's width; the attention
+# at what the call was fed.
+QUERY_TILE = 32
+
 # the device accumulator's slots, in the order the programs fill them
 ACC = ("moe_assignments", "moe_assignments_held", "moe_expert_calls",
        "moe_load_max_sum")
 
 
-def _pool_forward(params, pool, acc, tables, ids, pos, wlimit, valid, cfg):
+def _attend_tiles(q_nope, q_rope, cache, vis, lp, cfg, *, n_tiles):
+    """``attend_absorbed`` over the first ``n_tiles`` (data) tiles of
+    ``QUERY_TILE`` positions; the positions past them read 0."""
+    b, s, nh, _ = q_nope.shape
+
+    def one(t, out):
+        def cut(a):
+            return jax.lax.dynamic_slice_in_dim(a, t * QUERY_TILE,
+                                                QUERY_TILE, axis=1)
+
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, attend_absorbed(cut(q_nope), cut(q_rope), cache, cut(vis),
+                                 lp, cfg), t * QUERY_TILE, axis=1)
+
+    return jax.lax.fori_loop(
+        0, n_tiles, one,
+        jnp.zeros((b, s, nh * cfg.v_head_dim), q_nope.dtype))
+
+
+def _pool_forward(params, pool, acc, tables, ids, pos, wlimit, valid, cfg,
+                  n_tiles=None):
     """Forward ``ids`` [b, s] at absolute positions ``pos`` [b, s] against
     the latent block pool: per layer, write each token's cache entry into
     its lane's block at ``pos`` (positions >= ``wlimit[b]`` go to null
-    block 0), then attend over the lane's whole gathered table. ``valid``
+    block 0), then attend over the lane's whole gathered table — every
+    position at once, or (``n_tiles``, the prefill chunk's) the tiles of
+    ``QUERY_TILE`` positions that hold a real token. ``valid``
     [b, s] marks real tokens for the expert layers' counts. Returns
     (x [b, s, hidden], pool, acc)."""
     b, s = ids.shape
@@ -63,6 +99,8 @@ def _pool_forward(params, pool, acc, tables, ids, pos, wlimit, valid, cfg):
     off = jnp.where(ok, pos % B, 0)
     vis = jnp.arange(M * B)[None, None, :] <= pos[:, :, None]
     n_valid = jnp.sum(valid, dtype=jnp.int32)
+    attend = attend_absorbed if n_tiles is None \
+        else functools.partial(_attend_tiles, n_tiles=n_tiles)
     for li, lp in enumerate(params["layers"]):
         q_nope, q_rope, entry = latent_qkv(_rms(x, lp["ln_in"], eps), lp,
                                            pos, cfg)
@@ -72,7 +110,7 @@ def _pool_forward(params, pool, acc, tables, ids, pos, wlimit, valid, cfg):
         # would make the TPU materialise the layer's whole pool first
         rows = tables + li * pool.shape[1]
         cache = pool.reshape(-1, B, W)[rows].reshape(b, M * B, W)
-        att = attend_absorbed(q_nope, q_rope, cache, vis, lp, cfg)
+        att = attend(q_nope, q_rope, cache, vis, lp, cfg)
         x = x + _rms(att @ lp["o"], lp["ln_attn_out"], eps)
         y, counts = mlp_block(_rms(x, lp["ln_mlp_in"], eps), lp, cfg,
                               valid=valid)
@@ -96,9 +134,14 @@ def _prefill_chunk(params, pool, acc, table, ids, start, ctx_len, last_idx,
     greedy-samples at ``last_idx``. Returns ([token, *acc], pool, acc)."""
     C = ids.shape[1]
     pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
+    # a chunk of several whole query tiles attends the fed ones alone
+    n_tiles = None
+    if C > QUERY_TILE and C % QUERY_TILE == 0:
+        n_tiles = (jnp.clip(ctx_len - start, 0, C) + QUERY_TILE - 1) \
+            // QUERY_TILE
     x, pool, acc = _pool_forward(
         params, pool, acc, table, ids, pos, jnp.reshape(ctx_len, (1,)),
-        pos < ctx_len, cfg)
+        pos < ctx_len, cfg, n_tiles=n_tiles)
     h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
     return jnp.concatenate([_head(h, params, cfg), acc]), pool, acc
 

@@ -34,6 +34,7 @@ from jax.sharding import (  # noqa: E402
 import paddle_tpu.ops.pallas  # noqa: E402,F401 — registers the kernels
 from paddle_tpu.ops import registry  # noqa: E402
 from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+from paddle_tpu.serving.engine import PREFILL_CHUNK  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -173,10 +174,12 @@ def engines():
     return _engines(_POOL, 4, _LANES, _TABLE)
 
 
-def _compiled_program(topo, eng, kind):
+def _compiled_program(topo, eng, kind, chunk=None):
     """Program ``kind`` of a rows-form family (the dense one, the hybrid
     state-space one) compiled for the described chip, lowered as
-    ``ServingEngine._ensure_compiled`` lowers it there (pools donated)."""
+    ``ServingEngine._ensure_compiled`` lowers it there (pools donated);
+    the prefill program at ``chunk`` positions, the engine's own width
+    unless given."""
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def spec(a):
@@ -186,7 +189,7 @@ def _compiled_program(topo, eng, kind):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     cfg = eng.config
-    L, S, C = cfg.max_lanes, cfg.spec_k + 1, cfg.prefill_chunk
+    L, S, C = cfg.max_lanes, cfg.spec_k + 1, chunk or eng.prefill_chunk
     lanes, width, rest = {
         "decode": (L, 1, (i32(L), i32(L))),
         "verify": (L, S, (i32(L), i32(L, S), i32(L))),
@@ -201,10 +204,19 @@ def _compiled_program(topo, eng, kind):
             read, *rest, **static).compile()
 
 
-def _dense_program_text(topo, eng, kind):
+def _dense_program_text(topo, eng, kind, chunk=None):
     """The dense family's program ``kind`` as the chip's compiler leaves
     it."""
-    return _compiled_program(topo, eng, kind).as_text()
+    return _compiled_program(topo, eng, kind, chunk).as_text()
+
+
+def _programs():
+    """Every step program, the prefill chunk at the width the tests'
+    engines pin (32) and at the engine's default."""
+    return [pytest.param("decode", None, id="decode"),
+            pytest.param("verify", None, id="verify"),
+            pytest.param("prefill", None, id="prefill"),
+            pytest.param("prefill", PREFILL_CHUNK, id="prefill_default")]
 
 
 def _results_shaped(text, dims):
@@ -224,9 +236,9 @@ def _holds_no_layers_pool(text, pool):
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+@pytest.mark.parametrize("kind,chunk", _programs())
 def test_engine_program_never_holds_one_layers_pool(
-        topo, engines, kind, kv_int8):
+        topo, engines, kind, chunk, kv_int8):
     """The K/V read gathers from the STACKED pool by (layer, block).
     Indexing the layer first (``kp[li][tables]``) compiles, for the
     chip, to a fusion that writes out that layer's whole pool before the
@@ -236,7 +248,8 @@ def test_engine_program_never_holds_one_layers_pool(
     int8 mode, one layer's scale-pool shape)."""
     eng = engines[kv_int8]
     assert eng._pools[0].shape[1:] == _POOL
-    _holds_no_layers_pool(_dense_program_text(topo, eng, kind), _POOL)
+    _holds_no_layers_pool(_dense_program_text(topo, eng, kind, chunk),
+                          _POOL)
 
 
 # (``_engines``' arguments: pool [num_blocks, block, kv_heads,
@@ -273,9 +286,9 @@ def test_row_read_compiles_at_served_geometries(
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+@pytest.mark.parametrize("kind,chunk", _programs())
 def test_engine_program_never_holds_every_lanes_table(
-        topo, engines, kind, kv_int8):
+        topo, engines, kind, chunk, kv_int8):
     """The K/V read gathers the rows the lanes hold, a tile at a time
     (PERF.md section 6, PR 28): no instruction's result is shaped like
     every lane's whole table — ``[L * M, block, kv_heads, head_dim]`` as
@@ -287,7 +300,7 @@ def test_engine_program_never_holds_every_lanes_table(
     assert eng.blocks_per_lane == _TABLE
     w, tile, cap = eng._rows_form(kind, lanes)
     assert cap > tile  # several tiles: a tile is not the table
-    text = _dense_program_text(topo, eng, kind)
+    text = _dense_program_text(topo, eng, kind, chunk)
     _, block, nkv, d = _POOL
     for dims in (rf"{lanes * _TABLE},{block},{nkv}(,{d})?",
                  rf"{lanes},{_TABLE * block},{nkv}(,{d})?"):
@@ -325,8 +338,10 @@ def latent_engine():
         max_seq_len=5 * block))
 
 
-def _latent_program_text(topo, eng, kind, monkeypatch):
-    """The family's program ``kind`` as the chip's compiler leaves it."""
+def _latent_program_text(topo, eng, kind, monkeypatch, chunk=None):
+    """The family's program ``kind`` as the chip's compiler leaves it;
+    the prefill program at ``chunk`` positions, the engine's own width
+    unless given."""
     import paddle_tpu.framework.device as device
 
     monkeypatch.setattr(device, "platform", lambda: "tpu")
@@ -343,8 +358,8 @@ def _latent_program_text(topo, eng, kind, monkeypatch):
     L, M = cfg.max_lanes, eng.blocks_per_lane
     rest = {"decode": (i32(L, M), i32(L), i32(L)),
             "verify": (i32(L, M), i32(L), i32(L, cfg.spec_k + 1), i32(L)),
-            "prefill": (i32(1, M), i32(1, cfg.prefill_chunk), i32(), i32(),
-                        i32())}[kind]
+            "prefill": (i32(1, M), i32(1, chunk or eng.prefill_chunk),
+                        i32(), i32(), i32())}[kind]
     fn, static = fam.program(kind)
     return jax.jit(
         fn, static_argnames=tuple(static), donate_argnums=fam.donate_argnums,
@@ -352,9 +367,9 @@ def _latent_program_text(topo, eng, kind, monkeypatch):
             *rest, **static).compile().as_text()
 
 
-@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+@pytest.mark.parametrize("kind,chunk", _programs())
 def test_latent_program_never_copies_its_pool(topo, latent_engine, kind,
-                                              monkeypatch):
+                                              chunk, monkeypatch):
     """A ``[layers, blocks, block, 576]`` pool the TPU lays out
     blocks-minor (576 is 4.5 lane tiles: row-major pads 11%, blocks-minor
     2049 -> 2176 only 6%), and each program then copied the whole pool
@@ -366,7 +381,8 @@ def test_latent_program_never_copies_its_pool(topo, latent_engine, kind,
     produced either), and the expert products are the grouped-matmul
     kernel, not its interpreter."""
     assert latent_engine._pools[0].shape == _LATENT_POOL
-    text = _latent_program_text(topo, latent_engine, kind, monkeypatch)
+    text = _latent_program_text(topo, latent_engine, kind, monkeypatch,
+                                chunk)
     layers, nb, block, width = _LATENT_POOL
     whole = rf"\w+\[{layers},{nb},{block},{width}\]"
     copies = [ln.strip()[:200] for ln in text.splitlines()
@@ -426,6 +442,19 @@ def test_latent_attention_reader_picks_the_cache_not_the_new_entries(
     stray = [n[:160] for n in hit
              if re.search(r"moe/|mla/q/|mla/kv_write", n)]
     assert not stray, stray
+    # a trace holds the prefill chunk's events too, and the pattern is
+    # made from all of its names: with a chunk of the default width
+    # among them (``[1, W, heads, 640]`` queries, ``[1, W, heads,
+    # slots]`` scores) the round's picks are the same operations
+    wide = _latent_program_text(topo, latent_engine, "prefill", monkeypatch,
+                                PREFILL_CHUNK)
+    wide = [ln.strip() for ln in wide[wide.index("\nENTRY"):].splitlines()
+            if " = " in ln]
+    assert any(re.search(rf"\[1,{PREFILL_CHUNK},", n) for n in wide)
+    with_chunk = reader.pattern(names + wide, lanes, {
+        "kv_lora_rank": 512, "qk_rope_head_dim": 64,
+        "num_attention_heads": 4})
+    assert [n for n in names if re.search(with_chunk, n)] == hit
     # no cache among the names (the parent's programs): nothing to read
     assert reader.pattern([n for n in names if "640" not in n
                            and "576" not in n], lanes, {
@@ -463,13 +492,13 @@ def hybrid_engine():
         prefill_chunk=32, max_seq_len=20 * 16))
 
 
-def _hybrid_program_names(topo, eng, kind):
+def _hybrid_program_names(topo, eng, kind, chunk=None):
     """The family's program ``kind`` as the chip's compiler leaves it:
     the entry computation's instructions, each with its operands' shapes
     as the device trace names its events."""
     from jax._src.lib import xla_client as xc
 
-    compiled = _compiled_program(topo, eng, kind)
+    compiled = _compiled_program(topo, eng, kind, chunk)
     opts = xc._xla.HloPrintOptions()
     opts.print_operand_shape = True
     opts.print_backend_config = False
@@ -482,18 +511,21 @@ def _dims(shape):
     return ",".join(str(d) for d in shape)
 
 
-@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
-def test_hybrid_program_never_copies_a_pool(topo, hybrid_engine, kind):
+@pytest.mark.parametrize("kind,chunk", _programs())
+def test_hybrid_program_never_copies_a_pool(topo, hybrid_engine, kind,
+                                            chunk):
     """Device state of three kinds, none of which a program call may
     copy: K and V pools whose last axis is the 8 heads x 64 merged (with
     ``[.., 8, 64]`` the TPU lays the pool out blocks-minor and every call
     copied both pools in and out: 4 x 285 MB at the benchmark's size); a
     conv pool with a lane's 3 rows side by side; one float32 state array
-    a state-space layer."""
+    a state-space layer. The prefill chunk too, at both widths: it once
+    copied its LAST state-space layer's array in and out at one pool
+    size (PERF.md section 7 (aa))."""
     eng = hybrid_engine
     assert eng._pools[0].shape == _HYBRID_KV
     assert all(p.shape == _HYBRID_STATE for p in eng._pools[4:])
-    names = _hybrid_program_names(topo, eng, kind)
+    names = _hybrid_program_names(topo, eng, kind, chunk)
     pools = "|".join(rf"\w+\[{_dims(p.shape)}\]" for p in
                      (eng._pools[0], eng._pools[2], eng._pools[4]))
     copies = [n[:200] for n in names
@@ -554,6 +586,15 @@ def test_ssm_update_reader_picks_the_state_and_nothing_else(
     assert len(hit) == 2 * per_layer, [n[:200] for n in hit]
     assert all("ssm/state_update" in n for n in hit), \
         [n[:200] for n in hit if "ssm/state_update" not in n]
+    # a trace holds the prefill chunk's events too: with a chunk of the
+    # default width among the names (no ``[W, ...]`` intermediate of it
+    # has a slab's element count) the round's picks are the same
+    wide = _hybrid_program_names(topo, hybrid_engine, "prefill",
+                                 PREFILL_CHUNK)
+    assert any(re.search(rf"\[1,{PREFILL_CHUNK},", n) for n in wide)
+    with_chunk = reader.pattern(names + wide, _HYBRID_LANES, m, 2)
+    assert [n for n in names if re.search(with_chunk, n)
+            and re.search(r"[\s)]fusion\(", n)] == hit
     # a program without a state (the parent's): nothing to read
     assert reader.pattern([n for n in names if ",64,128]" not in n],
                           _HYBRID_LANES, m, 2) is None

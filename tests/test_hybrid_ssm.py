@@ -238,12 +238,15 @@ def served_gap(ref, model, prompt, out):
     return (logits.max(-1) - logits[np.arange(len(out)), out]).max()
 
 
-def test_chunked_prefill_and_plain_decode_equal_the_full_forward(ref,
-                                                                  model):
+@pytest.mark.parametrize("chunk", [8, 128], ids=["chunk8", "chunk128"])
+def test_chunked_prefill_and_plain_decode_equal_the_full_forward(
+        ref, model, chunk):
     """Prompts shorter than, equal to and several times the chunk (8),
     decoded with speculation off: every served token is the reference's
-    first choice to within ``TOL`` at its position."""
-    eng = engine(model, spec=False)
+    first choice to within ``TOL`` at its position. At chunk 128 every
+    prompt is ONE padded call whose pad runs past the lane's table and
+    ``max_seq_len`` (96)."""
+    eng = engine(model, spec=False, prefill_chunk=chunk)
     work = prompts(5) + [np.arange(8, dtype=np.int32),
                          np.arange(3, dtype=np.int32)]
     reqs = [eng.submit(p, max_new_tokens=12) for p in work]
@@ -371,12 +374,14 @@ def test_a_reused_lane_gives_what_a_fresh_engine_gives(model):
     assert eng.stats()["ssm_slot_resets"] == 2
 
 
-def test_a_preempted_request_resumes_token_identically(model):
+@pytest.mark.parametrize("chunk", [8, 128], ids=["chunk8", "chunk128"])
+def test_a_preempted_request_resumes_token_identically(model, chunk):
     """A pool too small for three growing requests: the newest is
     preempted, its slot handed on, and its re-admission's prefill
-    rebuilds state and K/V from chunk 0."""
+    rebuilds state and K/V from chunk 0 — in calls of 8, or in one
+    padded call of 128, against the roomy engine's calls of 8."""
     work = prompts(3, seed=31, lo=9, hi=12)
-    tight = engine(model, num_blocks=13, spec=False)
+    tight = engine(model, num_blocks=13, spec=False, prefill_chunk=chunk)
     roomy = engine(model, spec=False)
     out = {}
     for name, eng in (("tight", tight), ("roomy", roomy)):
@@ -388,6 +393,92 @@ def test_a_preempted_request_resumes_token_identically(model):
     assert out["tight"] == out["roomy"]
     assert tight.stats()["ssm_slot_resets"] \
         == len(work) + tight.counters["preemptions"]
+
+
+# a prefill call wider than what it is fed (PR 32): 1, W - 1, W, W + 1 and
+# 3W + 5 tokens around a chunk of W = 8
+_LENGTHS = [1, 7, 8, 9, 29]
+
+
+@pytest.fixture(scope="module")
+def chunk_engines(model):
+    """One engine a prefill width: a block (the narrowest), 8, and 128 —
+    wider than every prompt, a lane's table and ``max_seq_len`` (96)."""
+    return {c: engine(model, spec=False, prefill_chunk=c)
+            for c in (4, 8, 128)}
+
+
+@pytest.mark.parametrize("chunk", [8, 128], ids=["chunk8", "chunk128"])
+@pytest.mark.parametrize("length", _LENGTHS)
+def test_a_wider_prefill_call_serves_the_same_tokens(ref, model,
+                                                     chunk_engines, chunk,
+                                                     length):
+    """The same prompt through a ``chunk``-wide call and through
+    block-wide ones: the same tokens, each the reference's first choice,
+    and the lane's state and conv tail agree when the request is done —
+    the pad is the identity on both (``dt`` 0; the tail ends at the last
+    REAL position)."""
+    prompt = prompts(1, seed=100 + length, lo=length, hi=length + 1)[0]
+    got, left = {}, {}
+    for c in (4, chunk):
+        eng = chunk_engines[c]
+        r = eng.submit(prompt, max_new_tokens=8)
+        eng.step()
+        lane = r.lane
+        eng.run()
+        got[c], left[c] = r, lane_state(eng, lane)
+        eng.scheduler.pool.check_invariant()
+    assert got[chunk].output == got[4].output
+    assert served_gap(ref, model, prompt,
+                      np.asarray(got[chunk].output)) < TOL
+    for a, b in zip(left[chunk], left[4]):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("padded", [16, 128, 8],
+                         ids=["pad3", "pad115", "two_calls"])
+def test_a_padded_final_chunk_leaves_the_exact_ones_state(model, padded):
+    """A 13-token prompt prefilled (and nothing decoded: one new token)
+    in ONE exact-width call of 13, against a padded call of 16, one of
+    128 (past the table and ``max_seq_len``) and two calls of 8 with
+    the second padded: the same first token, the same state to float32
+    rounding (the chunked scan sums in another order), and the same conv
+    tail: the 3 rows that end at position 12, not at the call's end."""
+    prompt = prompts(1, seed=77, lo=13, hi=14)[0]
+    out = {}
+    for c in (13, padded):
+        eng = engine(model, max_lanes=1, spec=False, prefill_chunk=c)
+        r = eng.submit(prompt, max_new_tokens=1)
+        eng.run()
+        assert eng.counters["decode_steps"] == 0
+        assert eng.counters["prefill_fed_tokens"] == -(-13 // c) * c
+        out[c] = (r.output, *lane_state(eng, 0))
+    assert out[padded][0] == out[13][0]
+    np.testing.assert_allclose(out[padded][1], out[13][1], rtol=1e-4,
+                               atol=1e-5)
+    assert np.abs(out[13][1]).max() > 1e-3
+    np.testing.assert_allclose(out[padded][2], out[13][2], rtol=1e-4,
+                               atol=1e-5)
+    assert np.abs(out[13][2]).max() > 1e-3
+
+
+def test_a_wide_calls_pad_writes_the_null_block_alone(model):
+    """5 real tokens in a 128-wide call on a fresh engine, and no round
+    after it (a round advances every lane's slot, idle ones too): the
+    pad's K/V lands in block 0, so besides it just the prompt's two
+    blocks of 4 are written; and one lane's state and conv tail."""
+    eng = engine(model, spec=False, prefill_chunk=128)
+    eng.submit(np.arange(5, dtype=np.int32), max_new_tokens=1)
+    eng.run()
+    assert eng.counters["prefill_chunks"] == 1 \
+        and eng.counters["decode_steps"] == 0
+    for pool in eng._pools[:2]:
+        written = np.asarray(pool).any(axis=(0, 2, 3)).nonzero()[0]
+        assert len(set(written) - {0}) == 2 and 0 in written
+    touched = [lane for lane in range(3)
+               if any(a.any() for a in lane_state(eng, lane))]
+    assert len(touched) == 1
+    eng.scheduler.pool.check_invariant()
 
 
 def test_prefix_cache_on_acquires_nothing(model):

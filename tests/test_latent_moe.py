@@ -257,10 +257,11 @@ def test_expert_layer_checks_what_it_is_told_it_holds():
 
 # -- through the serving engine ------------------------------------------------
 
+GEOM = dict(max_lanes=3, block_size=16, prefill_chunk=32, max_seq_len=160)
+
+
 def _serve(model, requests, **cfg):
-    eng = ServingEngine(model, ServingConfig(
-        max_lanes=3, block_size=16, prefill_chunk=32, max_seq_len=160,
-        **cfg))
+    eng = ServingEngine(model, ServingConfig(**{**GEOM, **cfg}))
     handles = [eng.submit(p, max_new_tokens=n) for p, n in requests]
     eng.run()
     return eng, handles
@@ -295,15 +296,23 @@ def _traffic():
     ]
 
 
-def test_served_logits_match_the_references_full_forward(ref):
+@pytest.mark.parametrize("chunk", [32, 256], ids=["chunk32", "chunk256"])
+def test_served_logits_match_the_references_full_forward(ref, chunk):
     """Prefill in chunks, then decode and verify rounds over the latent
     block pool, against the reference's full forward — with a prompt of
     several blocks and chunks, a prefix-cache hit, and (pool of 14
-    blocks for 3 lanes) a request preempted and resumed."""
+    blocks for 3 lanes) a request preempted and resumed. At chunk 256
+    every prompt, each hit's remainder and each recompute is ONE padded
+    call whose positions run past the table (10 blocks) and
+    ``max_seq_len``."""
     model = seeded(LatentMoEForCausalLM(tiny_config()))
-    eng, handles = _serve(model, _traffic(), num_blocks=15)
+    eng, handles = _serve(model, _traffic(), num_blocks=15,
+                          prefill_chunk=chunk)
     c = eng.counters
-    assert c["prefill_chunks"] >= 6 and c["verify_steps"] > 0
+    assert c["prefill_chunks"] >= (6 if chunk == 32 else 5)
+    assert c["prefill_fed_tokens"] == chunk * c["prefill_chunks"] \
+        > c["prefix_miss_tokens"]
+    assert c["verify_steps"] > 0
     assert c["decode_steps"] > 0
     assert c["prefix_hit_tokens"] >= 48
     assert c["preemptions"] >= 1
@@ -323,6 +332,68 @@ def test_served_logits_match_the_references_full_forward(ref):
     assert eng._params["layers"][1]["experts_down"] \
         is model.layers[1].mlp.experts_down._data
     assert eng._params["embed"] is model.embed._data
+
+
+_LENGTHS = [1, 31, 32, 33, 101]  # around a chunk of W = 32: 1, W - 1, W, W + 1, 3W + 5
+
+
+@pytest.fixture(scope="module")
+def chunk_engines():
+    """The same float32 model behind one engine a prefill width: a block
+    (the narrowest), 32, and 256 — wider than every prompt, a lane's
+    table and ``max_seq_len``."""
+    model = seeded(LatentMoEForCausalLM(tiny_config()))
+    return model, {c: ServingEngine(model, ServingConfig(
+        **{**GEOM, "prefill_chunk": c})) for c in (16, 32, 256)}
+
+
+@pytest.mark.parametrize("chunk", [32, 256], ids=["chunk32", "chunk256"])
+@pytest.mark.parametrize("length", _LENGTHS)
+def test_a_wider_prefill_call_serves_the_same_tokens(ref, chunk_engines,
+                                                     chunk, length):
+    """A prompt of ``length`` tokens through a ``chunk``-wide call and
+    through block-wide ones: the same tokens, each the reference's first
+    choice, whatever pad the call carries."""
+    model, engines = chunk_engines
+    prompt = np.random.default_rng(length).integers(0, 256, length)
+    got = {}
+    for c in (16, chunk):
+        h = engines[c].submit(prompt, max_new_tokens=8)
+        engines[c].run()
+        got[c] = h
+        engines[c].scheduler.pool.check_invariant()
+    assert got[chunk].output == got[16].output
+    assert max(_served_gaps(ref, model, [got[chunk]])) < TOL
+
+
+def test_a_wide_calls_pad_writes_the_null_block_alone(chunk_engines):
+    """5 real tokens in a 256-wide call on a fresh engine: the pad's
+    cache entries (positions past the prompt, the 10-block table and
+    ``max_seq_len``) land in block 0; no other block the request does
+    not hold is written. Then a 17-token prompt twice: the second
+    admission acquires a block of 16 and prefills ONE token, at position
+    16, in a call 255 positions of which are pad."""
+    model, _ = chunk_engines
+    eng = ServingEngine(model, ServingConfig(
+        **{**GEOM, "prefill_chunk": 256}))
+    req = eng.submit(np.arange(5), max_new_tokens=3)
+    eng.step()
+    held = set(req.blocks) | {0}
+    others = [b for b in range(eng.scheduler.pool.num_blocks)
+              if b not in held]
+    pool = np.asarray(eng._pools[0])
+    assert not pool[:, others].any() and pool[:, req.blocks[0]].any()
+    eng.run()
+    prompt = np.random.default_rng(3).integers(0, 256, 17)
+    first = eng.submit(prompt, max_new_tokens=6)
+    eng.run()
+    before = eng.counters["prefix_miss_tokens"]
+    second = eng.submit(prompt, max_new_tokens=6)
+    eng.run()
+    assert second.cached_len == 16
+    assert eng.counters["prefix_miss_tokens"] - before == 1
+    assert second.output == first.output
+    eng.scheduler.pool.check_invariant()
 
 
 def test_a_lower_precision_fails_the_tolerance(ref):

@@ -32,6 +32,7 @@ from paddle_tpu.serving import (
     FINISHED, RUNNING, WAITING, BlockPool, FCFSScheduler, Request,
     ServingConfig, ServingEngine, blocks_needed, prefix_keys,
 )
+from paddle_tpu.serving.engine import PREFILL_CHUNK, default_prefill_chunk
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -561,6 +562,43 @@ class TestServingConfig:
         monkeypatch.setenv("PT_SERVE_PREFIX_CACHE", "0")
         assert ServingConfig().prefix_cache is False
         assert ServingConfig(prefix_cache=True).prefix_cache is True
+
+    @pytest.mark.parametrize("max_seq_len,block,want", [
+        (4096, 16, PREFILL_CHUNK),     # the chip-chosen width as it is
+        (160, 16, 128), (100, 16, 96),  # whole blocks under max_seq_len
+        (48, 4, 48), (20, 2, 20), (33, 4, 32),
+        (10, 16, 16),                  # at least one block
+        (4096, 48, 96), (4096, 256, 256),
+    ])
+    def test_default_chunk_is_fitted_to_the_geometry(
+            self, monkeypatch, max_seq_len, block, want):
+        """Unset, the prefill width is the engine's: PREFILL_CHUNK in
+        whole blocks, never past ``max_seq_len`` rounded down to whole
+        blocks. ``PT_SERVE_PREFILL_CHUNK`` and the argument still
+        override it, taken as given (wider than the table too)."""
+        monkeypatch.delenv("PT_SERVE_PREFILL_CHUNK", raising=False)
+        assert ServingConfig().prefill_chunk is None
+        assert default_prefill_chunk(max_seq_len, block) == want
+        assert want % block == 0
+        assert want <= max(max_seq_len // block, 1) * block
+        monkeypatch.setenv("PT_SERVE_PREFILL_CHUNK", "32")
+        assert ServingConfig().prefill_chunk == 32
+        assert ServingConfig(prefill_chunk=200).prefill_chunk == 200
+
+    def test_engine_resolves_the_default_chunk(self, monkeypatch):
+        monkeypatch.delenv("PT_SERVE_PREFILL_CHUNK", raising=False)
+        pt.seed(0)
+        m = LlamaForCausalLM(LlamaConfig.tiny(num_hidden_layers=1))
+        m.eval()
+        geom = dict(max_lanes=2, block_size=4, max_seq_len=50)
+        eng = ServingEngine(m, ServingConfig(**geom))
+        assert eng.prefill_chunk == eng.stats()["prefill_chunk"] == 48
+        monkeypatch.setenv("PT_SERVE_PREFILL_CHUNK", "6")
+        assert ServingEngine(m, ServingConfig(**geom)).prefill_chunk == 6
+        assert ServingEngine(m, ServingConfig(
+            prefill_chunk=70, **geom)).prefill_chunk == 70
+        with pytest.raises(ValueError):
+            ServingConfig(prefill_chunk=0)
 
     def test_explicit_beats_env(self, monkeypatch):
         monkeypatch.setenv("PT_SERVE_LANES", "5")

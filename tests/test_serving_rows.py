@@ -141,6 +141,15 @@ def small_rows(request, monkeypatch):
     return request.param
 
 
+@pytest.fixture(params=[4, 40], ids=["chunk4", "chunk40"])
+def chunk(request):
+    """The prefill program's width: one the prompts take several calls
+    of, and one wider than every prompt, than a lane's whole table (16
+    blocks of 2) and than ``max_seq_len`` — every call of it is padded,
+    and its pad positions run past all three."""
+    return request.param
+
+
 class _OracleDrafter:
     """Proposes each request's true continuation, every second proposal
     with its second token wrong: accepted prefixes AND rejections."""
@@ -184,9 +193,9 @@ def _hold(model, got, reqs, **kw):
             err_msg=f"request {i} diverged from generate()")
 
 
-def test_engine_decode_is_generate(model, small_rows):
+def test_engine_decode_is_generate(model, small_rows, chunk):
     eng = ServingEngine(model, ServingConfig(
-        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32,
+        max_lanes=3, block_size=2, prefill_chunk=chunk, max_seq_len=32,
         spec=False))
     reqs = _requests(model, 7, seed=1)
     _hold(model, _serve(eng, reqs), reqs)
@@ -195,13 +204,13 @@ def test_engine_decode_is_generate(model, small_rows):
     assert (w, tile) == small_rows[:2] and cap > tile  # several tiles
 
 
-def test_engine_verify_accepts_rejects_and_overwrites(model, small_rows):
+def test_engine_verify_accepts_rejects_and_overwrites(model, small_rows, chunk):
     reqs = _requests(model, 6, seed=2, new=(8, 14))
     refs = [(p, np.concatenate([p, _reference(model, p, k)]))
             for p, k in reqs]
     drafter = _OracleDrafter(refs, model.config.vocab_size)
     eng = ServingEngine(model, ServingConfig(
-        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32,
+        max_lanes=3, block_size=2, prefill_chunk=chunk, max_seq_len=32,
         spec_k=3), drafter=drafter)
     _hold(model, _serve(eng, reqs), reqs)
     c = eng.counters
@@ -211,43 +220,43 @@ def test_engine_verify_accepts_rejects_and_overwrites(model, small_rows):
     assert 0 < c["spec_accepted_tokens"] < c["spec_proposed_tokens"]
 
 
-def test_engine_prefill_over_a_prefix_hit(model, small_rows):
+def test_engine_prefill_over_a_prefix_hit(model, small_rows, chunk):
     rng = np.random.RandomState(4)
     system = rng.randint(0, model.config.vocab_size, (11,)).astype(np.int32)
     reqs = [(np.concatenate([system, rng.randint(
         0, model.config.vocab_size, (int(n),)).astype(np.int32)]), 6)
         for n in (3, 9, 1, 6)]
     eng = ServingEngine(model, ServingConfig(
-        max_lanes=2, block_size=2, prefill_chunk=4, max_seq_len=32))
+        max_lanes=2, block_size=2, prefill_chunk=chunk, max_seq_len=32))
     _hold(model, _serve(eng, reqs), reqs)
     # later requests started their chunks above the shared blocks
     assert eng.counters["prefix_hit_tokens"] >= 10
 
 
-def test_engine_preempt_and_recompute(model, small_rows):
+def test_engine_preempt_and_recompute(model, small_rows, chunk):
     eng = ServingEngine(model, ServingConfig(
-        max_lanes=3, block_size=2, num_blocks=14, prefill_chunk=4,
+        max_lanes=3, block_size=2, num_blocks=14, prefill_chunk=chunk,
         max_seq_len=24))
     reqs = _requests(model, 6, seed=5, lo=2, hi=9, new=(6, 12))
     _hold(model, _serve(eng, reqs), reqs)
     assert eng.counters["preemptions"] > 0, "never preempted: vacuous"
 
 
-def test_engine_int8_pool_is_generate_kv_int8(model, small_rows):
+def test_engine_int8_pool_is_generate_kv_int8(model, small_rows, chunk):
     eng = ServingEngine(model, ServingConfig(
-        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32,
+        max_lanes=3, block_size=2, prefill_chunk=chunk, max_seq_len=32,
         kv_int8=True))
     reqs = _requests(model, 5, seed=6)
     _hold(model, _serve(eng, reqs), reqs, kv_int8=True)
     assert eng.counters["kv_quant_tokens"] > 0
 
 
-def test_engine_sliding_window_reads_rows(small_rows):
+def test_engine_sliding_window_reads_rows(small_rows, chunk):
     pt.seed(7)
     m = LlamaForCausalLM(LlamaConfig.tiny(sliding_window=5))
     m.eval()
     eng = ServingEngine(m, ServingConfig(
-        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32))
+        max_lanes=3, block_size=2, prefill_chunk=chunk, max_seq_len=32))
     reqs = _requests(m, 5, seed=8, lo=6, hi=15)
     _hold(m, _serve(eng, reqs), reqs)
 
@@ -300,3 +309,85 @@ def test_a_table_form_program_bills_its_whole_table():
     c = eng.counters
     assert c["kv_read_tokens"] < c["kv_gathered_tokens"] \
         == c["kv_dense_read_tokens"]
+
+
+# -- (d) a prefill call wider than what it is fed (PR 32) ------------------------
+
+W = 8  # the chunk the prompt lengths below sit around
+_LENGTHS = [1, W - 1, W, W + 1, 3 * W + 5]
+
+
+@pytest.fixture(scope="module", params=[W, 64], ids=["chunk8", "chunk64"])
+def wide_engine(request, model):
+    """One engine a width, every length through it: 64 is wider than
+    the longest prompt, a lane's table (20 blocks of 2) and
+    ``max_seq_len`` (40), so a call at ANY start runs past all three."""
+    return ServingEngine(model, ServingConfig(
+        max_lanes=2, block_size=2, prefill_chunk=request.param,
+        max_seq_len=40))
+
+
+@pytest.mark.parametrize("length", _LENGTHS)
+def test_engine_prompt_lengths_around_the_chunk(model, wide_engine, length):
+    """Prompts of 1, W - 1, W, W + 1 and 3W + 5 tokens: ``last_idx``
+    picks the last REAL position whatever the call's pad, and the fed /
+    real counters say how full the calls ran."""
+    eng = wide_engine
+    rng = np.random.RandomState(40 + length)
+    p = rng.randint(0, model.config.vocab_size, (length,)).astype(np.int32)
+    before = dict(eng.counters)
+    _hold(model, _serve(eng, [(p, 6)]), [(p, 6)])
+    C = eng.prefill_chunk
+    d = {k: eng.counters[k] - before[k] for k in
+         ("prefill_chunks", "prefill_fed_tokens", "prefix_miss_tokens")}
+    assert d == {"prefill_chunks": -(-length // C),
+                 "prefill_fed_tokens": -(-length // C) * C,
+                 "prefix_miss_tokens": length}
+    eng.scheduler.pool.check_invariant()
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+def test_pad_positions_write_the_null_block_alone(model, kv_int8):
+    """A 5-token prompt through a 64-wide call on a fresh engine: the
+    59 pad positions (past the prompt, the lane's table and
+    ``max_seq_len``) land in block 0; every block the request does not
+    hold stays as it was made, in K, V and the int8 scales."""
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=2, block_size=2, prefill_chunk=64, max_seq_len=40,
+        kv_int8=kv_int8))
+    p = np.arange(5, dtype=np.int32)
+    req = eng.submit(p, max_new_tokens=4)
+    eng.step()  # the one prefill call, and a round
+    assert eng.counters["prefill_chunks"] == 1
+    held = set(req.blocks) | {0}
+    others = [b for b in range(eng.scheduler.pool.num_blocks)
+              if b not in held]
+    for pool in eng._pools:
+        if pool is not None:
+            assert not np.asarray(pool)[:, others].any()
+    assert np.asarray(eng._pools[0])[:, req.blocks[0]].any()
+    eng.scheduler.pool.check_invariant()
+    eng.run()
+    _hold(model, [np.asarray(req.output)], [(p, 4)], kv_int8=kv_int8)
+
+
+def test_a_prefix_hit_that_leaves_one_token(model, chunk):
+    """The same 17-token prompt twice at block 2: the second acquires 16
+    cached tokens and prefills ONE — a call fed a single real position,
+    starting at 16, whose pad (at chunk 40) runs past the table's end."""
+    rng = np.random.RandomState(12)
+    p = rng.randint(0, model.config.vocab_size, (17,)).astype(np.int32)
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=2, block_size=2, prefill_chunk=chunk, max_seq_len=32))
+    first = eng.submit(p, max_new_tokens=5)
+    eng.run()
+    before = dict(eng.counters)
+    second = eng.submit(p, max_new_tokens=5)
+    eng.run()
+    assert second.cached_len == 16
+    assert eng.counters["prefix_miss_tokens"] \
+        - before["prefix_miss_tokens"] == 1
+    assert eng.counters["prefill_chunks"] - before["prefill_chunks"] == 1
+    _hold(model, [np.asarray(first.output), np.asarray(second.output)],
+          [(p, 5), (p, 5)])
+    eng.scheduler.pool.check_invariant()

@@ -101,6 +101,7 @@ def main():
 
     import paddle_tpu.framework.device as device
     from paddle_tpu.serving import ServingConfig
+    from paddle_tpu.serving.engine import default_prefill_chunk
 
     # steer the code's ONE rule for "am I on the chip" here, in the
     # script: the described chip gets the real kernels, not interpret mode
@@ -129,7 +130,8 @@ def main():
     sc = ServingConfig(max_lanes=s["max_lanes"],
                        max_seq_len=s["max_seq_len"],
                        num_blocks=s["num_blocks"])
-    L, B, C, K = sc.max_lanes, sc.block_size, sc.prefill_chunk, sc.spec_k
+    L, B, K = sc.max_lanes, sc.block_size, sc.spec_k
+    C = sc.prefill_chunk or default_prefill_chunk(s["max_seq_len"], B)
     M = -(-s["max_seq_len"] // B)
 
     def i32(*shape):
