@@ -47,16 +47,23 @@ def swiglu(x, w_gate_up, w_down):
             * up) @ w_down
 
 
-def route_top_k(u, w_router, top_k, scaling):
+def route_top_k(u, w_router, top_k, scaling, bias=None):
     """Sigmoid-scored top-k over ALL the router's experts, in float32 as
     the published gate computes it: ``s = sigmoid(float32(u) W_r)``, the
     ``top_k`` largest, ``g = s_top / (sum(s_top) + 1e-20) * scaling``.
+    With a per-expert selection ``bias`` [E] the ``top_k`` largest of ``s
+    + bias`` are chosen and the gates are still their ``s``: the bias
+    chooses, it does not weigh.
     Returns (expert ids ``[T, k]`` int32, gates ``[T, k]`` float32)."""
     with jax.named_scope("moe/route"):
         s = jax.nn.sigmoid(jnp.dot(
             u.astype(jnp.float32), w_router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
-        top_s, top_i = jax.lax.top_k(s, top_k)
+        if bias is None:
+            top_s, top_i = jax.lax.top_k(s, top_k)
+        else:
+            _, top_i = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+            top_s = jnp.take_along_axis(s, top_i, axis=-1)
         g = top_s / (jnp.sum(top_s, -1, keepdims=True) + 1e-20) * scaling
     return top_i.astype(jnp.int32), g
 
@@ -133,8 +140,10 @@ def sparse_expert_block(u, p, *, top_k, scaling, first_held, valid=None):
     """The whole expert layer on normed tokens ``u`` [T, H]: route, the
     held experts' share, plus the shared expert (whole, on every chip).
     ``p``: ``router`` [H, E], ``experts_gate_up``, ``experts_down``,
-    ``shared_gate_up``, ``shared_down``. Returns (y, counts)."""
-    idx, g = route_top_k(u, p["router"], top_k, scaling)
+    ``shared_gate_up``, ``shared_down`` and, where the router has one,
+    its selection bias ``router_bias`` [E]. Returns (y, counts)."""
+    idx, g = route_top_k(u, p["router"], top_k, scaling,
+                         p.get("router_bias"))
     y, counts = held_experts(u, idx, g, p["experts_gate_up"],
                              p["experts_down"], first_held, valid)
     with jax.named_scope("moe/shared"):
@@ -146,15 +155,17 @@ class HeldExperts(Layer):
     """``sparse_expert_block`` as a layer: ``router_experts`` experts are
     routed over, ``n_held`` of them (``first_held`` on) live here, stacked
     ``[n_held, in, out]``; ``n_shared`` shared experts are one SwiGLU of
-    ``n_shared * width``. ``forward`` returns the output; the call's
-    per-held-expert assignment counts are left on ``last_counts``."""
+    ``n_shared * width``. ``selection_bias`` adds the router's per-expert
+    ``router_bias`` (born zero; ``route_top_k`` says what it does).
+    ``forward`` returns the output; the call's per-held-expert assignment
+    counts are left on ``last_counts``."""
 
     _NAMES = ("router", "experts_gate_up", "experts_down",
               "shared_gate_up", "shared_down")
 
     def __init__(self, hidden, width, router_experts, n_held, first_held=0,
                  top_k=8, n_shared=1, scaling=1.0, dtype="float32",
-                 init_std=0.02):
+                 init_std=0.02, selection_bias=False):
         super().__init__(dtype=dtype)  # parameters are born in it
         if not 0 <= first_held <= router_experts - n_held:
             raise ValueError(
@@ -175,6 +186,10 @@ class HeldExperts(Layer):
             [hidden, 2 * n_shared * width], default_initializer=init)
         self.shared_down = self.create_parameter(
             [n_shared * width, hidden], default_initializer=init)
+        if selection_bias:
+            self.router_bias = self.create_parameter(
+                [router_experts], default_initializer=I.Constant(0.0))
+            self._NAMES = HeldExperts._NAMES + ("router_bias",)
         self.last_counts = None
 
     def arrays(self):

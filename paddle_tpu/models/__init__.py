@@ -2,7 +2,8 @@
 the benchmark configs in BASELINE.md name Llama, BERT, ResNet, ERNIE —
 they live in-tree here so the framework is benchmarkable standalone)."""
 from . import (  # noqa: F401
-    bert, ernie, generation, hybrid_ssm, latent_moe, llama,
+    bert, ernie, generation, hybrid_ssm, latent_moe, linear_latent_moe,
+    llama,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertForMaskedLM, BertForSequenceClassification, BertModel,
@@ -14,6 +15,9 @@ from .ernie import (  # noqa: F401
 from .generation import generate  # noqa: F401
 from .hybrid_ssm import HybridSSMConfig, HybridSSMForCausalLM  # noqa: F401
 from .latent_moe import LatentMoEConfig, LatentMoEForCausalLM  # noqa: F401
+from .linear_latent_moe import (  # noqa: F401
+    LinearLatentMoEConfig, LinearLatentMoEForCausalLM,
+)
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaForCausalLMPipe, LlamaModel,
 )
@@ -26,6 +30,8 @@ __all__ = [
     "generation", "generate",
     "latent_moe", "LatentMoEConfig", "LatentMoEForCausalLM",
     "hybrid_ssm", "HybridSSMConfig", "HybridSSMForCausalLM",
+    "linear_latent_moe", "LinearLatentMoEConfig",
+    "LinearLatentMoEForCausalLM",
     "ernie", "ErnieConfig", "ErnieModel", "ErnieForPretraining",
     "ErnieForPretrainingPipe", "ErnieForSequenceClassification",
 ]

@@ -155,23 +155,31 @@ class _Static:
 
 # -- the layer's mathematics, on arrays ---------------------------------------
 
-def latent_qkv(a, lp, pos, cfg):
+def latent_qkv(a, lp, pos, cfg, rope=True):
     """From normed input ``a`` [b, s, h] at positions ``pos`` [b, s]:
     (q_nope [b, s, nh, dn], q_rope [b, s, nh, dr] rotated, and the cache
-    entry ``[c_kv | k_rope]`` [b, s, dc + dr], normed and rotated)."""
+    entry ``[c_kv | k_rope]`` [b, s, dc + dr], normed and rotated). A
+    layer with a ``q`` leaf in place of ``q_a`` / ``q_norm`` / ``q_b``
+    projects its query directly; ``rope`` False leaves the ``dr`` columns
+    of query and key as projected (no position embedding)."""
     b, s, _ = a.shape
     nh, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                   cfg.qk_rope_head_dim)
     with jax.named_scope("mla/q"):
-        c_q = _rms(a @ lp["q_a"], lp["q_norm"], cfg.rms_norm_eps)
-        q = (c_q @ lp["q_b"]).reshape(b, s, nh, dn + dr)
+        if "q" in lp:
+            q = (a @ lp["q"]).reshape(b, s, nh, dn + dr)
+        else:
+            c_q = _rms(a @ lp["q_a"], lp["q_norm"], cfg.rms_norm_eps)
+            q = (c_q @ lp["q_b"]).reshape(b, s, nh, dn + dr)
         q_nope, q_rope = q[..., :dn], q[..., dn:]
     with jax.named_scope("mla/kv_write"):
         kv = a @ lp["kv_a"]
         c_kv = _rms(kv[..., :cfg.kv_lora_rank], lp["kv_norm"],
                     cfg.rms_norm_eps)
-        q_rope, k_rope = _rope(q_rope, kv[..., None, cfg.kv_lora_rank:],
-                               cfg.rope_theta, a.dtype, pos=pos)
+        k_rope = kv[..., None, cfg.kv_lora_rank:]
+        if rope:
+            q_rope, k_rope = _rope(q_rope, k_rope, cfg.rope_theta, a.dtype,
+                                   pos=pos)
         entry = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
     return q_nope, q_rope, entry
 

@@ -8,11 +8,12 @@ K/V lies in the form its family takes (``read_form``): the lanes' LIVE
 ROWS — each lane's block list cut into rows of a few blocks, all lanes'
 rows end to end (:func:`pack_rows`), so a call gathers what the lanes
 hold and not every slot of every lane's table (the dense family) — or
-a ``[lanes, M]`` block table (the latent family, its one user). A family
-that also keeps state per LANE (``lane_state``: the hybrid state-space
-family's recurrent state and conv tail) has its one-lane prefill chunk
-told which lane the request holds. Three compiled programs serve the
-whole lifetime:
+a ``[lanes, M]`` block table (the latent and the linear-attention
+families). A family that also keeps state per LANE (``lane_state``: a
+recurrent state and conv tail, the hybrid state-space family's and the
+linear-attention family's) has its one-lane prefill chunk told which
+lane the request holds, whichever form its read takes. Three compiled
+programs serve the whole lifetime:
 
 - **prefill chunk** ``[1, C]``: one lane's context enters the pool C
   tokens at a time (padded tail chunks write only below the context
@@ -588,26 +589,35 @@ class ServingEngine:
         form = self._family.read_form(kind)
         return form and fit_rows(form, lanes, self.blocks_per_lane)
 
+    def _tells_slot(self, kind):
+        """A ``lane_state`` family's one-lane prefill chunk is told the
+        lane its request holds, as the last entry of its read operand."""
+        return kind == "prefill" and self._family.lane_state
+
     def _read_spec(self, kind, lanes, width):
         """Shapes of program ``kind``'s read operand (:meth:`_pack_read`)
         at ``lanes`` lanes of ``width`` positions."""
         i32 = jnp.int32
         form = self._rows_form(kind, lanes)
+        slot = self._tells_slot(kind)
         if form is None:
-            return jax.ShapeDtypeStruct((lanes, self.blocks_per_lane), i32)
+            table = jax.ShapeDtypeStruct((lanes, self.blocks_per_lane), i32)
+            return (table, jax.ShapeDtypeStruct((1,), i32)) if slot \
+                else table
         spec = (jax.ShapeDtypeStruct((form[2], 2 + form[0]), i32),
                 jax.ShapeDtypeStruct((lanes, width), i32))
-        if kind == "prefill" and self._family.lane_state:
+        if slot:
             spec += (jax.ShapeDtypeStruct((1,), i32),)
         return spec
 
     def _pack_read(self, kind, lanes, width, items, ph=None, slot=None):
         """Program ``kind``'s read operand for one call, as numpy, in
         the form its family takes: LIVE ROWS ``(rows, wblk)``
-        (:func:`pack_rows`; ``items`` as there), or — the latent family
-        only — a block TABLE ``[lanes, M]`` (every lane's whole list,
-        null-padded). A ``lane_state`` family's prefill chunk gets
-        ``slot [1]`` as a third entry: the lane its request holds (the
+        (:func:`pack_rows`; ``items`` as there), or — the families whose
+        ``read_form`` is ``None`` — a block TABLE ``[lanes, M]`` (every
+        lane's whole list, null-padded). A ``lane_state`` family's
+        prefill chunk gets ``slot [1]`` as a last entry, ``(rows, wblk,
+        slot)`` or ``(table, slot)``: the lane its request holds (the
         chunk runs as lane 0 of a one-lane call). Bills the call to the
         three ``kv_*`` read counters."""
         B, M = self.config.block_size, self.blocks_per_lane
@@ -615,18 +625,21 @@ class ServingEngine:
         c["kv_read_tokens"] += sum(it[3] for it in items)
         c["kv_dense_read_tokens"] += lanes * M * B
         form = self._rows_form(kind, lanes)
+        to_slot = self._tells_slot(kind)
         if form is None:
             tables = np.zeros((lanes, M), np.int32)
             for lane, blocks, _, _ in items:
                 tables[lane, :len(blocks)] = blocks
             c["kv_gathered_tokens"] += lanes * M * B
+            if to_slot:
+                return tables, np.asarray([slot], np.int32)
             return tables
         w, tile, cap = form
         rows, wblk, n, live = pack_rows(items, lanes, width, B, w, cap)
         c["kv_gathered_tokens"] += -(-n // tile) * tile * w * B
         if ph is not None and _spans is not None:
             ph.args.update(rows=n, live_blocks=live)
-        if kind == "prefill" and self._family.lane_state:
+        if to_slot:
             return rows, wblk, np.asarray([slot], np.int32)
         return rows, wblk
 
@@ -801,15 +814,20 @@ class ServingEngine:
           lane's valid length in lane-private blocks (masked out of
           every later attend) until the next accepted write overwrites
           them. The dense and latent families keep nothing else.
-        - LANE-indexed (a recurrent state and its conv tail, the hybrid
-          state-space family): a rejected position folded into a state
-          cannot be masked later, so the family's verify program applies
-          the state update only after it has computed each lane's
-          acceptance ITSELF, by this method's rule (:meth:`_accept`, the
-          judge of what is emitted; the two agree or
-          ``spec_rolled_back_tokens`` differs from proposed - accepted):
-          after the round the lane's slot holds the state after the
-          pending token and the accepted drafts, nothing else."""
+        - LANE-indexed (a recurrent state and its conv tail: the hybrid
+          state-space and the linear-attention families): a rejected
+          position folded into a state cannot be masked later, so the
+          family's verify program applies the state update only after
+          it has computed each lane's acceptance ITSELF, by this
+          method's rule (:meth:`_accept`, the judge of what is emitted;
+          the two agree or ``spec_rolled_back_tokens`` differs from
+          proposed - accepted), with every position from the first
+          rejected one on MASKED — and a masked position is the
+          identity on the family's lane state, bit for bit, in whatever
+          terms the family's recurrence has one (a step size of 0; a
+          log-decay and a correction strength of 0). After the round
+          the lane's slot holds the state after the pending token and
+          the accepted drafts, nothing else."""
         L, K = self.config.max_lanes, self.config.spec_k
         with self._phase("pack", "pack_s") as ph:
             cur = np.zeros((L,), np.int32)

@@ -22,18 +22,22 @@ and asks the model's *family* for the three things that differ:
     lanes' live rows of ``W`` blocks, which the program runs ``tile`` at a
     time, and each fed token's write block: ``(rows [R, 2 + W], wblk
     [lanes, width])`` (``engine.pack_rows``), the dense family's read;
-    ``None`` — a ``[lanes, M]`` block table, the latent family's.
+    ``None`` — a ``[lanes, M]`` block table, the latent family's and
+    the linear-attention family's.
 
 Two more kinds of state than (a) may live in a family, both told to the
 engine by attributes: ``lane_state`` — besides its token-indexed pools the
 family keeps pools indexed by LANE (``[layers, lanes, ...]``: a recurrent
 state, a conv tail; ``lane_pool_bytes(pools)`` their size, 0 elsewhere).
 Decode and verify index them by the batch row; the one-lane prefill chunk
-is told its request's lane, the STATE SLOT, as a third entry of its read
-operand, ``(rows, wblk, slot [1])``, and a chunk at position 0 starts the
-slot from zero, so an admitted or re-admitted request never sees its
-predecessor's. Such a family's verify program owes the engine the
-rollback contract of ``ServingEngine._verify_round``. ``prefix_reuse`` —
+is told its request's lane, the STATE SLOT, as the last entry of its read
+operand — ``(rows, wblk, slot [1])`` in the rows form, ``(table, slot
+[1])`` where the family reads by block table (``read_form`` ``None``) —
+and a chunk at position 0 starts the slot from zero, so an admitted or
+re-admitted request never sees its predecessor's. Such a family's verify
+program owes the engine the rollback contract of
+``ServingEngine._verify_round``: a masked position is the identity on
+the family's lane state. ``prefix_reuse`` —
 False where a request cannot start from a prefix's blocks alone (it
 would need the recurrent state at that boundary): the engine then has
 the scheduler acquire none, and ``stats()`` says so.

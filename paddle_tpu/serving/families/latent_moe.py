@@ -77,6 +77,57 @@ def _attend_tiles(q_nope, q_rope, cache, vis, lp, cfg, *, n_tiles):
         jnp.zeros((b, s, nh * cfg.v_head_dim), q_nope.dtype))
 
 
+def table_slots(tables, pos, wlimit, block):
+    """Where the fed positions ``pos`` [b, s] are written and what each
+    may see of its lane's gathered table ``tables`` [b, M]: (write block
+    and offset [b, s] — positions >= ``wlimit[b]`` go to null block 0 —
+    and ``vis`` [b, s, M * block])."""
+    M = tables.shape[1]
+    idx = jnp.minimum(pos // block, M - 1)  # pad pos can run past the table
+    blk = jnp.take_along_axis(tables, idx, axis=1)
+    ok = pos < wlimit[:, None]
+    blk = jnp.where(ok, blk, 0)
+    off = jnp.where(ok, pos % block, 0)
+    vis = jnp.arange(M * block)[None, None, :] <= pos[:, :, None]
+    return blk, off, vis
+
+
+def attend_pool(u, lp, li, pool, tables, pos, blk, off, vis, cfg, attend,
+                rope=True):
+    """Latent layer ``li``'s attention on normed ``u`` [b, s, h] against
+    the block pool: write each token's cache entry at (``li``, ``blk``,
+    ``off``), gather the lanes' whole tables, attend. Returns (att [b, s,
+    heads x v], pool)."""
+    b = u.shape[0]
+    B, W = pool.shape[2], pool.shape[3]
+    q_nope, q_rope, entry = latent_qkv(u, lp, pos, cfg, rope)
+    pool = pool.at[li, blk, off].set(
+        jnp.pad(entry, ((0, 0), (0, 0), (0, W - entry.shape[-1]))))
+    # ONE gather on the stacked pool, by (layer, block): pool[li]
+    # would make the TPU materialise the layer's whole pool first
+    rows = tables + li * pool.shape[1]
+    cache = pool.reshape(-1, B, W)[rows].reshape(b, tables.shape[1] * B, W)
+    return attend(q_nope, q_rope, cache, vis, lp, cfg), pool
+
+
+def chunk_attend(n_tiles):
+    """The attention a program's positions go through: every position at
+    once, or (``n_tiles``, the prefill chunk's) the tiles of
+    ``QUERY_TILE`` positions that hold a real token."""
+    return attend_absorbed if n_tiles is None \
+        else functools.partial(_attend_tiles, n_tiles=n_tiles)
+
+
+def chunk_tiles(C, start, ctx_len):
+    """How many query tiles of a ``C``-wide prefill chunk at ``start``
+    hold a real token; ``None`` where the chunk is not several whole
+    tiles (it then attends all its positions at once)."""
+    if C > QUERY_TILE and C % QUERY_TILE == 0:
+        return (jnp.clip(ctx_len - start, 0, C) + QUERY_TILE - 1) \
+            // QUERY_TILE
+    return None
+
+
 def _pool_forward(params, pool, acc, tables, ids, pos, wlimit, valid, cfg,
                   n_tiles=None):
     """Forward ``ids`` [b, s] at absolute positions ``pos`` [b, s] against
@@ -87,39 +138,28 @@ def _pool_forward(params, pool, acc, tables, ids, pos, wlimit, valid, cfg,
     ``QUERY_TILE`` positions that hold a real token. ``valid``
     [b, s] marks real tokens for the expert layers' counts. Returns
     (x [b, s, hidden], pool, acc)."""
-    b, s = ids.shape
-    B, W = pool.shape[2], pool.shape[3]
-    M = tables.shape[1]
     eps = cfg.rms_norm_eps
     x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
-    idx = jnp.minimum(pos // B, M - 1)  # pad pos can run past the table
-    blk = jnp.take_along_axis(tables, idx, axis=1)
-    ok = pos < wlimit[:, None]
-    blk = jnp.where(ok, blk, 0)
-    off = jnp.where(ok, pos % B, 0)
-    vis = jnp.arange(M * B)[None, None, :] <= pos[:, :, None]
+    blk, off, vis = table_slots(tables, pos, wlimit, pool.shape[2])
     n_valid = jnp.sum(valid, dtype=jnp.int32)
-    attend = attend_absorbed if n_tiles is None \
-        else functools.partial(_attend_tiles, n_tiles=n_tiles)
+    attend = chunk_attend(n_tiles)
     for li, lp in enumerate(params["layers"]):
-        q_nope, q_rope, entry = latent_qkv(_rms(x, lp["ln_in"], eps), lp,
-                                           pos, cfg)
-        pool = pool.at[li, blk, off].set(
-            jnp.pad(entry, ((0, 0), (0, 0), (0, W - entry.shape[-1]))))
-        # ONE gather on the stacked pool, by (layer, block): pool[li]
-        # would make the TPU materialise the layer's whole pool first
-        rows = tables + li * pool.shape[1]
-        cache = pool.reshape(-1, B, W)[rows].reshape(b, M * B, W)
-        att = attend(q_nope, q_rope, cache, vis, lp, cfg)
+        att, pool = attend_pool(_rms(x, lp["ln_in"], eps), lp, li, pool,
+                                tables, pos, blk, off, vis, cfg, attend)
         x = x + _rms(att @ lp["o"], lp["ln_attn_out"], eps)
         y, counts = mlp_block(_rms(x, lp["ln_mlp_in"], eps), lp, cfg,
                               valid=valid)
         x = x + _rms(y, lp["ln_mlp_out"], eps)
         if counts is not None:
-            acc = acc + jnp.stack([
-                n_valid * cfg.num_experts_per_tok, jnp.sum(counts),
-                jnp.int32(1), jnp.max(counts)])
+            acc = acc + expert_counts(n_valid, counts,
+                                      cfg.num_experts_per_tok)
     return x, pool, acc
+
+
+def expert_counts(n_valid, counts, top_k):
+    """What one expert-layer call adds to the accumulator's ``ACC``."""
+    return jnp.stack([n_valid * top_k, jnp.sum(counts), jnp.int32(1),
+                      jnp.max(counts)])
 
 
 def _head(x, params, cfg):
@@ -135,10 +175,7 @@ def _prefill_chunk(params, pool, acc, table, ids, start, ctx_len, last_idx,
     C = ids.shape[1]
     pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
     # a chunk of several whole query tiles attends the fed ones alone
-    n_tiles = None
-    if C > QUERY_TILE and C % QUERY_TILE == 0:
-        n_tiles = (jnp.clip(ctx_len - start, 0, C) + QUERY_TILE - 1) \
-            // QUERY_TILE
+    n_tiles = chunk_tiles(C, start, ctx_len)
     x, pool, acc = _pool_forward(
         params, pool, acc, table, ids, pos, jnp.reshape(ctx_len, (1,)),
         pos < ctx_len, cfg, n_tiles=n_tiles)

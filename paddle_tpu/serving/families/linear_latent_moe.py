@@ -1,0 +1,386 @@
+"""The linear-attention / latent-attention sparse-expert family
+(``models/linear_latent_moe.py``) as the serving engine sees it: THREE
+kinds of device state in one family.
+
+- **By token**: the latent pool ``[latent layers, blocks, block, 640]`` of
+  ``families/latent_moe.py`` — laid out, written and read by ITS functions
+  (block table, ONE gather from the stacked pool, ``attend_absorbed``),
+  for the few latent-attention layers only; no position embedding.
+- **By LANE, float32**: the delta rule's matrix state, ``[lanes, heads, d,
+  d]`` (key x value) a linear-attention layer — ONE ARRAY A LAYER, not one
+  stacked pool (``families/hybrid_ssm.py`` says what a stacked one cost) —
+  as large for 16 tokens as for 16,000.
+- **By LANE, model dtype**: a conv pool ``[linear-attention layers, lanes,
+  (K - 1) x 3 x heads x d]``: the last K - 1 rows of ``[q~ | k~ | v~]``.
+
+Decode and verify index the lane pools by the batch row; the one-lane
+prefill chunk is told its request's lane (``lane_state``: the engine gives
+a block-table family ``(table, slot [1])``) and a chunk that starts at
+position 0 starts from ZERO state and tail.
+
+- **A plain round** goes through each layer's state twice: one read gives
+  both products with the old state (``S'^T k`` for the correction, ``S'^T
+  q`` for the output: ``kda_step``), a second read and the one write apply
+  the update. The correction depends on a reduction over the whole state,
+  so the two cannot be one fusion; a kernel that keeps a state tile in
+  fast memory over both is ROADMAP B-m4.
+- **A verify round's rejected drafts leave no trace in the state** (the
+  contract of ``ServingEngine._verify_round``). The forward reads each
+  layer's state ONCE for the k+1 positions' outputs — the chunked form:
+  ``kda_wy`` needs no state, ``kda_read`` is one product with it — and
+  writes none of it; it keeps the positions' keys, log-decays and
+  pseudo-values ``u`` (a few KB a lane a layer). After the head the
+  program computes each lane's acceptance itself (the engine's ``_accept``
+  rule; the engine stays the judge of what is emitted) and applies the
+  update in ONE pass, ``S <- Diag(exp G) S + sum_s (k_s exp(G - G_s))
+  u_s^T`` (``kda_apply``), with ``g`` and ``u`` (= ``beta`` x the
+  correction) set to 0 from the first rejected position on: such a
+  position is the identity, bit for bit, and the kept positions'
+  pseudo-values do not depend on the later ones (the system they solve is
+  lower-triangular). No k+1 copies of a state are ever live. The conv tail
+  becomes the window's rows that end at the last kept position; latent
+  entries above a lane's valid length are masked as in the latent family.
+  Position by position (k+1 ``kda_step`` calls) is the slower form: PERF.md
+  section 6, PR 34.
+- **No prefix reuse** (``prefix_reuse`` False): a prefix hit hands over
+  block-aligned latent entries, and the state at that boundary is not
+  kept (ROADMAP B-m4); preemption recomputes from the prompt.
+- **Weights once**: ``params`` references the model's arrays; each
+  program is a Python loop over the layers with three bodies (linear
+  attention + dense, linear attention + experts, latent + experts).
+- **Counters** ride on the round's token array: the expert layer's four
+  (the latent family's ``ACC``), the state's five and the rounds' count
+  of held experts hit (``LIN_ACC``), one accumulator.
+
+``kv_int8`` and ``int8_weights`` raise ``UnimplementedError``.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ...models import linear_latent_moe as M
+from ...models.generation import _rms
+from . import absorb_accumulator
+from .hybrid_ssm import _carried, _keeps, _take_rows
+from .latent_moe import ACC as MOE_ACC
+from .latent_moe import (
+    LANES, _head, attend_pool, chunk_attend, chunk_tiles, expert_counts,
+    table_slots,
+)
+
+__all__ = ["LinearLatentMoEFamily"]
+
+F32 = jnp.float32
+
+# the state's slots of the device accumulator: times a round's program
+# went through the lanes' state (1 a plain round, 2 a verify round: the
+# read for the outputs, then the update); live lanes summed over rounds;
+# live lanes x (state reads + writes the algorithm requires: 2 a plain
+# round — read once, written once — 3 a verify round); prefill chunks
+# that started a slot from zero; drafted positions whose update was
+# discarded; and, of the expert layer, the held experts that got at least
+# one assignment, summed over the expert-layer calls of decode and verify
+# rounds (what such a round has to read of the held experts' weights:
+# repeating outputs route alike, so it is far from every expert)
+LIN_ACC = ("lin_state_passes", "lin_lane_rounds", "lin_state_lane_moves",
+           "lin_slot_resets", "spec_rolled_back_tokens",
+           "moe_round_experts_hit")
+ACC = MOE_ACC + LIN_ACC
+
+
+def _bump(acc, **by):
+    return acc.at[len(MOE_ACC):].add(jnp.stack(
+        [jnp.asarray(by.get(n, 0), jnp.int32) for n in LIN_ACC]))
+
+
+def _tail(cpool, ki, cfg):
+    """Layer ``ki``'s conv tails as ``[lanes, K - 1, channels]``."""
+    return cpool[ki].reshape(cpool.shape[1], cfg.kda_taps - 1, -1)
+
+
+def _heads_first(a):
+    """[b, T, H, ...] <-> [b, H, T, ...]."""
+    return jnp.swapaxes(a, 1, 2)
+
+
+def _stack(params, ids, pos, wlimit, valid, tables, pool, acc, cfg, kda,
+           n_tiles=None):
+    """The layer stack over ``ids`` [b, s] at positions ``pos``: latent
+    layers against the block pool here, each linear-attention layer
+    through ``kda(ki, u, lp) -> mix`` (the program's own: what it does
+    with the lane-indexed pools differs by program). Returns (x, pool,
+    acc, the held experts hit summed over the expert layers)."""
+    eps = cfg.rms_norm_eps
+    x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
+    blk, off, vis = table_slots(tables, pos, wlimit, pool.shape[2])
+    n_valid = jnp.sum(valid, dtype=jnp.int32)
+    attend = chunk_attend(n_tiles)
+    ki = ai = 0
+    hit = jnp.int32(0)
+    for kind, lp in zip(cfg.layer_kinds, params["layers"]):
+        u = _rms(x, lp["ln_in"], eps)
+        if kind == M.KDA:
+            mix = kda(ki, u, lp)
+            ki += 1
+        else:
+            att, pool = attend_pool(u, lp, ai, pool, tables, pos, blk, off,
+                                    vis, cfg, attend, rope=False)
+            mix = att @ lp["o"]
+            ai += 1
+        x = x + mix
+        y, counts = M.ffn_block(_rms(x, lp["ln_post"], eps), lp, cfg,
+                                valid=valid)
+        x = x + y
+        if counts is not None:
+            acc = acc.at[:len(MOE_ACC)].add(expert_counts(
+                n_valid, counts, cfg.num_experts_per_token))
+            hit = hit + jnp.sum(counts > 0, dtype=jnp.int32)
+    return x, pool, acc, hit
+
+
+def _unpack(args, cfg):
+    """A program's positional operands after ``params``: (latent pool,
+    acc, conv pool, [one state array a linear-attention layer], the
+    engine's operands)."""
+    n = sum(k == M.KDA for k in cfg.layer_kinds)
+    return (*args[:3], list(args[3:3 + n]), args[3 + n:])
+
+
+def _prefill_chunk(params, *args, cfg):
+    """One request's prefill chunk ``ids`` [1, C] at [start, start + C),
+    ``read`` = (its lane's block table, ``slot`` [1]: the lane it holds).
+    The slot's state and conv tail carry on from the previous chunk, or
+    from ZERO where ``start`` is 0; pad positions (>= ``ctx_len``) are
+    the identity on both. Greedy-samples at ``last_idx``. Returns
+    ([token, *acc], pools...)."""
+    pool, acc, cpool, states, ((table, slot), ids, start, ctx_len,
+                               last_idx) = _unpack(args, cfg)
+    C, K1 = ids.shape[1], cfg.kda_taps - 1
+    pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
+    slot = slot[0]
+    fresh = start == 0
+    real = pos < ctx_len
+    n_real = jnp.clip(ctx_len - start, 0, C)
+    conv = [cpool]
+
+    def kda(ki, u, lp):
+        raw = M.kda_project(u, lp, cfg)
+        with jax.named_scope("kda/state_update"):
+            S0 = _carried(fresh, jax.lax.dynamic_slice_in_dim(
+                states[ki], slot, 1))
+            tail = _carried(fresh, jax.lax.dynamic_slice(
+                conv[0], (ki, slot, 0), (1, 1, cpool.shape[2]))[0]
+            ).reshape(1, K1, -1)
+        window = jnp.concatenate([tail, raw], axis=1)
+        q, k, v = M.kda_conv(window, lp, cfg)
+        g, beta = M.kda_gates(u, lp, cfg)
+        with jax.named_scope("kda/state_update"):
+            o, S = M.kda_chunk(
+                q, k, v, jnp.where(real[..., None, None], g, 0.0),
+                jnp.where(real[..., None], beta, 0.0), S0,
+                cfg.kda_chunk_size)
+            states[ki] = jax.lax.dynamic_update_slice_in_dim(
+                states[ki], S, slot, 0)
+            conv[0] = jax.lax.dynamic_update_slice(
+                conv[0], _take_rows(window, n_real[None], K1).reshape(
+                    1, 1, -1), (ki, slot, 0))
+        return M.kda_gate_out(o, u, lp, cfg)
+
+    x, pool, acc, _ = _stack(
+        params, ids, pos, jnp.reshape(ctx_len, (1,)), real, table, pool,
+        acc, cfg, kda, n_tiles=chunk_tiles(C, start, ctx_len))
+    acc = _bump(acc, lin_slot_resets=fresh)
+    h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
+    return (jnp.concatenate([_head(h, params, cfg), acc]), pool, acc,
+            conv[0], *states)
+
+
+def _decode_step(params, *args, cfg):
+    """Every lane feeds its pending token at ``cur_len``: the latent
+    entry written then attended, each lane's state advanced one position
+    and its conv tail shifted by one row, in place. Idle lanes
+    (``cur_len`` 0) write the null block; their slots hold nothing
+    anyone reads (a slot starts from zero at its next request's first
+    chunk). Returns ([L tokens, *acc], pools...)."""
+    pool, acc, cpool, states, (tables, cur_len,
+                               last_tok) = _unpack(args, cfg)
+    conv = [cpool]
+
+    def kda(ki, u, lp):
+        raw = M.kda_project(u, lp, cfg)
+        window = jnp.concatenate([_tail(conv[0], ki, cfg), raw], axis=1)
+        q, k, v = M.kda_conv(window, lp, cfg)
+        g, beta = M.kda_gates(u, lp, cfg)
+        with jax.named_scope("kda/state_update"):
+            states[ki], o = M.kda_step(states[ki], q[:, 0], k[:, 0],
+                                       v[:, 0], g[:, 0], beta[:, 0])
+            conv[0] = conv[0].at[ki].set(
+                window[:, 1:].reshape(window.shape[0], -1))
+        return M.kda_gate_out(o[:, None], u, lp, cfg)
+
+    live = cur_len > 0
+    x, pool, acc, n_hit = _stack(params, last_tok[:, None],
+                                 cur_len[:, None], cur_len + 1,
+                                 live[:, None], tables, pool, acc, cfg, kda)
+    n = jnp.sum(live)
+    acc = _bump(acc, lin_state_passes=1, lin_lane_rounds=n,
+                lin_state_lane_moves=2 * n, moe_round_experts_hit=n_hit)
+    return (jnp.concatenate([_head(x[:, -1], params, cfg), acc]), pool,
+            acc, conv[0], *states)
+
+
+def _verify_step(params, *args, cfg):
+    """``toks`` [L, k+1]: each lane's pending token and its draft at
+    ``cur_len + j``; positions >= ``wlimit[b]`` are pad. The forward
+    reads every linear-attention layer's state once and writes none;
+    after the head the lane's acceptance ``a`` (module docstring) decides
+    what the state and the conv tail take: the pending token and the
+    first ``a`` drafts, nothing else. Returns ([L * (k+1) picks
+    row-major, *acc], pools...)."""
+    pool, acc, cpool, states, (tables, cur_len, toks,
+                               wlimit) = _unpack(args, cfg)
+    L, S1 = toks.shape
+    K1 = cfg.kda_taps - 1
+    pos = cur_len[:, None] + jnp.arange(S1, dtype=jnp.int32)[None, :]
+    kept = []  # per layer: (conv window, keys, log-decays, pseudo-values)
+
+    def kda(ki, u, lp):
+        raw = M.kda_project(u, lp, cfg)
+        window = jnp.concatenate([_tail(cpool, ki, cfg), raw], axis=1)
+        q, k, v = (_heads_first(a) for a in M.kda_conv(window, lp, cfg))
+        g, beta = (_heads_first(a) for a in M.kda_gates(u, lp, cfg))
+        with jax.named_scope("kda/state_update"):
+            o, pseudo = M.kda_read(M.kda_wy(q, k, v, g, beta), states[ki])
+        kept.append((window, k, g, pseudo))
+        return M.kda_gate_out(_heads_first(o), u, lp, cfg)
+
+    x, pool, acc, n_hit = _stack(params, toks, pos, wlimit,
+                                 pos < wlimit[:, None], tables, pool, acc,
+                                 cfg, kda)
+    picks = _head(x, params, cfg)
+    # a lane keeps its pending token and the longest prefix of its draft
+    # that equals the program's own picks (engine._accept's rule)
+    n_draft = wlimit - cur_len - 1                      # -1: an idle lane
+    hit = (picks[:, :-1] == toks[:, 1:]) \
+        & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
+    accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1), axis=1)
+    live = n_draft >= 0
+    n_keep = _keeps(live, accepted)
+    keep = (jnp.arange(S1)[None, :] < n_keep[:, None])[:, None, :, None]
+    with jax.named_scope("kda/state_update"):
+        for ki, (window, k, g, pseudo) in enumerate(kept):
+            # g and u 0 from the first rejected position on: the identity
+            states[ki] = M.kda_apply(states[ki], k, jnp.where(keep, g, 0.0),
+                                     jnp.where(keep, pseudo, 0.0))
+            cpool = cpool.at[ki].set(
+                _take_rows(window, n_keep, K1).reshape(L, -1))
+    acc = _bump(acc, lin_state_passes=2, lin_lane_rounds=jnp.sum(live),
+                lin_state_lane_moves=3 * jnp.sum(live),
+                spec_rolled_back_tokens=jnp.sum(
+                    jnp.where(live, n_draft - accepted, 0)),
+                moe_round_experts_hit=n_hit)
+    return (jnp.concatenate([picks.reshape(-1), acc]), pool, acc, cpool,
+            *states)
+
+
+class LinearLatentMoEFamily:
+    """See ``families/__init__.py`` for what the engine asks of it."""
+
+    name = "linear_latent_moe"
+    lane_state = True
+    prefix_reuse = False
+    prefix_reuse_why = (
+        "a prefix hit hands over block-aligned latent entries and this "
+        "family's linear-attention layers would need their recurrent "
+        "state at that boundary, which nothing snapshots yet (ROADMAP "
+        "B-m4)")
+
+    def __init__(self, model, config):
+        from ...framework.errors import UnimplementedError
+
+        for flag, why in (
+                (config.kv_int8, "kv_int8: most of its device state is the "
+                 "float32 recurrent state, and the int8 scale pools are "
+                 "[.., kv_heads] beside [.., kv_heads, head_dim] pools"),
+                (config.int8_weights, "int8_weights: the pack would be a "
+                 "second copy of the weights")):
+            if flag:
+                raise UnimplementedError(
+                    f"the linear-attention family does not serve with "
+                    f"{why}")
+        c = model.config
+        self.gcfg = c.static()
+        self.max_position_embeddings = c.max_position_embeddings
+        self.lanes = config.max_lanes
+        self.n_kda = sum(k == M.KDA for k in c.layer_kinds)
+        self.n_latent = c.num_hidden_layers - self.n_kda
+        self._width = c.latent_width
+        self.donate_argnums = tuple(range(1, 4 + self.n_kda))
+        if not self.n_latent:
+            raise UnimplementedError(
+                "a stack with no latent-attention layer has no block pool: "
+                "the engine's block pool would manage nothing")
+        # the model's own arrays: ONE copy of the weights on the device
+        self.params = {
+            "embed": model.embed._data, "norm": model.norm._data,
+            "lm_head": model.lm_head._data,
+            "layers": tuple({k: p._data for k, p in blk.leaves().items()}
+                            for blk in model.layers)}
+        self.counters = dict.fromkeys(ACC, 0)
+        self._seen = [0] * len(ACC)
+
+    def make_pools(self, num_blocks, block_size):
+        """(latent pool by (latent layer, block, offset), the counters'
+        device accumulator, conv pool by (linear-attention layer, LANE),
+        then one state array a linear-attention layer, by LANE)."""
+        g = self.gcfg
+        dt = jnp.dtype(g.dtype)
+        return (jnp.zeros((self.n_latent, num_blocks, block_size,
+                           -(-self._width // LANES) * LANES), dt),
+                jnp.zeros((len(ACC),), jnp.int32),
+                jnp.zeros((self.n_kda, self.lanes,
+                           (g.kda_taps - 1) * 3 * g.kda_width), dt),
+                *(jnp.zeros((self.lanes, g.kda_heads, g.kda_head_dim,
+                             g.kda_head_dim), F32)
+                  for _ in range(self.n_kda)))
+
+    def kv_pool_bytes(self, pools):
+        return int(pools[0].nbytes)
+
+    def lane_pool_bytes(self, pools):
+        return int(pools[2].nbytes + sum(p.nbytes for p in pools[3:]))
+
+    def read_form(self, kind):
+        """Every program takes a ``[lanes, M]`` block table, as the latent
+        family's do; ``lane_state`` adds the request's lane to the
+        prefill chunk's."""
+        return None
+
+    def program(self, kind):
+        return {"prefill": _prefill_chunk, "decode": _decode_step,
+                "verify": _verify_step}[kind], {"cfg": self.gcfg}
+
+    def exec_key(self, pools):
+        from ...jit import exec_cache
+
+        return {"family": self.name, "gen_cfg": self.gcfg._key(),
+                "params": [exec_cache.array_spec(a) for a in
+                           jax.tree_util.tree_leaves(self.params)],
+                "pools": [(tuple(int(x) for x in p.shape), str(p.dtype))
+                          for p in pools[:4]], "state_arrays": self.n_kda}
+
+    def absorb(self, out, counters):
+        """Strip the accumulator (the expert layer's slots and the
+        state's) off the fetched vector into ``counters``."""
+        return absorb_accumulator(out, ACC, self._seen, counters)
+
+    def stats(self):
+        g = self.gcfg
+        itemsize = jnp.dtype(g.dtype).itemsize
+        state = g.kda_heads * g.kda_head_dim * g.kda_head_dim * 4
+        tail = (g.kda_taps - 1) * 3 * g.kda_width * itemsize
+        return {"lin_state_bytes_per_lane": self.n_kda * state,
+                "lin_conv_bytes_per_lane": self.n_kda * tail,
+                "latent_kv_bytes_per_token": self._width * itemsize,
+                "prefix_reuse_why": self.prefix_reuse_why}

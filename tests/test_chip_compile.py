@@ -598,3 +598,126 @@ def test_ssm_update_reader_picks_the_state_and_nothing_else(
     # a program without a state (the parent's): nothing to read
     assert reader.pattern([n for n in names if ",64,128]" not in n],
                           _HYBRID_LANES, m, 2) is None
+
+
+# -- the linear-attention / latent-attention family's programs ------------------
+
+# lanes, linear-attention heads; the head size 128 and the latent entry of
+# 512 + 64 numbers (stored as 640) are the published sizes: what decides
+# the layouts
+_LINEAR_LANES, _LINEAR_HEADS = 8, 2
+_LINEAR_STATE = (_LINEAR_LANES, _LINEAR_HEADS, 128, 128)
+_LINEAR_POOL = (1, 2049, 16, 640)
+
+
+@pytest.fixture(scope="module")
+def linear_engine():
+    """Two gated delta-rule layers around one latent layer, the first
+    layer dense, bf16, built on the CPU for its shapes."""
+    from paddle_tpu.models import (
+        LinearLatentMoEConfig, LinearLatentMoEForCausalLM,
+    )
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    model = LinearLatentMoEForCausalLM(LinearLatentMoEConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=3,
+        linear_attn_config={
+            "kda_layers": [1, 3], "full_attn_layers": [2],
+            "num_heads": _LINEAR_HEADS, "head_dim": 128,
+            "short_conv_kernel_size": 4},
+        num_attention_heads=4, kv_lora_rank=512, qk_nope_head_dim=32,
+        qk_rope_head_dim=64, v_head_dim=32, num_experts=4,
+        router_experts=16, num_experts_per_token=4,
+        initializer_range=0.0, dtype="bfloat16"))
+    model.eval()
+    return ServingEngine(model, ServingConfig(
+        max_lanes=_LINEAR_LANES, block_size=16,
+        num_blocks=_LINEAR_POOL[1], prefill_chunk=32, max_seq_len=20 * 16))
+
+
+def _linear_program_names(topo, eng, kind, monkeypatch, chunk=None):
+    """The family's program ``kind`` as the chip's compiler leaves it:
+    the entry computation's instructions, each with its operands' shapes
+    as the device trace names its events."""
+    import paddle_tpu.framework.device as device
+    from jax._src.lib import xla_client as xc
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    compiled = _compiled_program(topo, eng, kind, chunk)
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_backend_config = False
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [ln.strip() for ln in text[text.index("\nENTRY"):].splitlines()
+            if " = " in ln]
+
+
+@pytest.mark.parametrize("kind,chunk", _programs())
+def test_linear_program_never_copies_a_pool(topo, linear_engine, kind,
+                                            chunk, monkeypatch):
+    """Device state of three kinds, none of which a program call may
+    copy: the latent family's padded pool (for the latent layers alone),
+    a conv pool with a lane's 3 rows side by side, one float32 state
+    array a linear-attention layer. The prefill chunk (a block-table
+    program that is told its state slot) too, at both widths."""
+    eng = linear_engine
+    assert eng._pools[0].shape == _LINEAR_POOL
+    assert all(p.shape == _LINEAR_STATE for p in eng._pools[3:])
+    names = _linear_program_names(topo, eng, kind, monkeypatch, chunk)
+    pools = "|".join(rf"\w+\[{_dims(p.shape)}\]" for p in
+                     (eng._pools[0], eng._pools[2], eng._pools[3]))
+    copies = [n[:200] for n in names
+              if re.search(rf"= ({pools})\S* copy\(", n)]
+    assert not copies, "\n".join(copies[:4])
+    # the expert products are the grouped-matmul kernel (2 expert layers)
+    assert len([n for n in names if re.match(r"%gmm[\.\d]* = ", n)]) == 4
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_kda_update_reader_picks_the_state_and_nothing_else(
+        topo, linear_engine, kind, monkeypatch):
+    """The benchmark's ``kda_update_roofline`` picks operations by the
+    state's shape in their instruction text. Held here to the compiled
+    programs' own instructions: TWO fusions a layer a round — a plain
+    round's one read for both products with the old state (a
+    multi-output reduction) and its read-and-write update; a verify
+    round's one product for the k+1 positions' outputs and its one-pass
+    update of what was accepted — nothing of the latent layer, the expert
+    layers or the head."""
+    import importlib.util
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmarks", "chip"))
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "kda_update_roofline_reader", os.path.join(
+                root, "benchmarks/chip/metrics/kda_update_roofline.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.pop(0)
+    names = _linear_program_names(topo, linear_engine, kind, monkeypatch)
+    m = {"linear_attn_config": {"num_heads": _LINEAR_HEADS,
+                                "head_dim": 128}}
+    picked = reader.pattern(names, _LINEAR_LANES, m)
+    assert rf"f32\[{_dims(_LINEAR_STATE)}\]" in picked.replace("\\,", ",")
+    hit = [n for n in names if re.search(picked, n)
+           and re.search(r"[\s)]fusion\(", n)]
+    assert len(hit) == 2 * 2, [n[:200] for n in hit]
+    assert all("kda/state_update" in n for n in hit), \
+        [n[:200] for n in hit if "kda/state_update" not in n]
+    # the state is written once a layer: one fusion's result has its shape
+    state = rf"f32\[{_dims(_LINEAR_STATE)}\]"
+    assert len([n for n in hit if re.match(rf"%\S+ = {state}", n)]) == 2
+    # a trace holds the prefill chunk's events too: the round's picks are
+    # the same with a chunk of the default width among the names
+    wide = _linear_program_names(topo, linear_engine, "prefill",
+                                 monkeypatch, PREFILL_CHUNK)
+    with_chunk = reader.pattern(names + wide, _LINEAR_LANES, m)
+    assert [n for n in names if re.search(with_chunk, n)
+            and re.search(r"[\s)]fusion\(", n)] == hit
+    # a program without a state (the parent's): nothing to read
+    assert reader.pattern([n for n in names if ",128,128]" not in n],
+                          _LINEAR_LANES, m) is None

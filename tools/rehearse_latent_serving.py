@@ -1,6 +1,6 @@
 """Compile a serving cell's three step programs — the latent-attention
-sparse-expert family's or the hybrid state-space family's, by the
-configuration's ``arch`` — at the configuration's real sizes for ONE chip
+sparse-expert family's, the hybrid state-space family's or the
+linear-attention family's, by the configuration's ``arch`` — at the configuration's real sizes for ONE chip
 of a described ``v5e:2x2`` — no chip attached, nothing runs — and print
 what each needs of the device's memory. By hand, before chip calls:
 
@@ -83,7 +83,35 @@ def _hybrid(arch, cfg, layers, s, sds, i32, geom):
     return fam, statics, pools, reads
 
 
-FAMILIES = {"mla_moe": _latent, "hybrid_ssm": _hybrid}
+def _linear(arch, cfg, layers, s, sds, i32, geom):
+    """The same of ``serving/families/linear_latent_moe.py``: the latent
+    family's padded pool for the latent layers, a conv pool and one
+    float32 state array a linear-attention layer by LANE, a block table
+    for every program (the prefill chunk's with its state slot)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import LinearLatentMoEConfig
+    from paddle_tpu.serving.families import linear_latent_moe as fam
+
+    L, B, _, _, M = geom
+    g = LinearLatentMoEConfig(**arch.config_kwargs(
+        cfg, layers, s["max_seq_len"])).static()
+    n_kda = sum(k == "kda" for k in g.layer_kinds)
+    width = -(-(g.kv_lora_rank + g.qk_rope_head_dim) // fam.LANES) \
+        * fam.LANES
+    pools = (sds((layers - n_kda, s["num_blocks"], B, width)),
+             sds((len(fam.ACC),), jnp.int32),
+             sds((n_kda, L, (g.kda_taps - 1) * 3 * g.kda_width)),
+             *(sds((L, g.kda_heads, g.kda_head_dim, g.kda_head_dim),
+                   jnp.float32) for _ in range(n_kda)))
+    kinds = ("decode", "verify", "prefill")
+    return fam, dict.fromkeys(kinds, {"cfg": g}), pools, {
+        "decode": i32(L, M), "verify": i32(L, M),
+        "prefill": (i32(1, M), i32(1))}
+
+
+FAMILIES = {"mla_moe": _latent, "hybrid_ssm": _hybrid,
+            "kda_mla_moe": _linear}
 
 
 def main():
