@@ -217,34 +217,53 @@ def attend_upprojected(q_nope, q_rope, cache, vis, lp, cfg):
     return out.astype(q_nope.dtype).reshape(b, s, nh * cfg.v_head_dim)
 
 
+def absorb_query(q_nope, q_rope, lp, cfg, stored):
+    """``kv_b``'s key half folded into the query: ``[q_nope W_k | q_rope
+    | 0]`` per head, ``stored`` wide — a cache entry's width as it is
+    kept (the serving pool pads it to whole lane tiles: zeros on the
+    query's side too)."""
+    b, s, nh, _ = q_nope.shape
+    dt = q_nope.dtype
+    w_k, _ = _kvb_halves(lp, cfg)
+    q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_k,
+                       preferred_element_type=jnp.float32).astype(dt)
+    pad = jnp.zeros(
+        (b, s, nh, stored - cfg.kv_lora_rank - q_rope.shape[-1]), dt)
+    return jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+
+
+def unabsorb_output(o_lat, lp, cfg, dtype=None):
+    """``kv_b``'s value half applied to the per-head weighted sums of the
+    latent ``o_lat`` [b, s, nh, dc]. Returns [b, s, nh * dv] in ``dtype``
+    (``o_lat``'s own unless given)."""
+    b, s, nh, _ = o_lat.shape
+    _, w_v = _kvb_halves(lp, cfg)
+    out = jnp.einsum("bshc,chd->bshd", o_lat, w_v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(dtype or o_lat.dtype).reshape(
+        b, s, nh * cfg.v_head_dim)
+
+
 def attend_absorbed(q_nope, q_rope, cache, vis, lp, cfg):
     """The same function with ``kv_b`` absorbed: ``q_lat = q_nope W_k``
     per head, scores over the latent and the rotary key as cached, ``o =
     (P c_kv) W_v``. No key or value is ever expanded: per cached slot the
     work is ``2 * nh * (2 dc + dr)`` FLOP on ``dc + dr`` numbers read.
     Matmuls in the model dtype with float32 accumulation, float32
-    softmax."""
-    b, s, nh, _ = q_nope.shape
+    softmax. The serving programs run this arithmetic over the rows
+    their lanes hold (``serving/families/latent_moe.py:_attend_rows``,
+    held to this function by tests/test_serving_rows.py)."""
     dc = cfg.kv_lora_rank
-    w_k, w_v = _kvb_halves(lp, cfg)
     f32 = jnp.float32
     dt = q_nope.dtype
     with jax.named_scope("mla/attend"):
-        q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_k,
-                           preferred_element_type=f32).astype(dt)
-        # the cache's entries may be padded past dc + dr (the serving
-        # pool pads to whole lane tiles): zeros on the query's side too
-        pad = jnp.zeros((b, s, nh, cache.shape[-1] - dc - q_rope.shape[-1]),
-                        dt)
-        qq = jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+        qq = absorb_query(q_nope, q_rope, lp, cfg, cache.shape[-1])
         scores = jnp.einsum("bshe,ble->bshl", qq, cache,
                             preferred_element_type=f32)
         p = _masked_softmax(scores, vis, cfg).astype(dt)
         o_lat = jnp.einsum("bshl,blc->bshc", p, cache[..., :dc],
                            preferred_element_type=f32).astype(dt)
-        out = jnp.einsum("bshc,chd->bshd", o_lat, w_v,
-                         preferred_element_type=f32).astype(dt)
-    return out.reshape(b, s, nh * cfg.v_head_dim)
+        return unabsorb_output(o_lat, lp, cfg)
 
 
 def mlp_block(u, lp, cfg, valid=None):

@@ -7,13 +7,13 @@ and growth never retrace. Every program call is told where its lanes'
 K/V lies in the form its family takes (``read_form``): the lanes' LIVE
 ROWS — each lane's block list cut into rows of a few blocks, all lanes'
 rows end to end (:func:`pack_rows`), so a call gathers what the lanes
-hold and not every slot of every lane's table (the dense family) — or
-a ``[lanes, M]`` block table (the latent and the linear-attention
-families). A family that also keeps state per LANE (``lane_state``: a
-recurrent state and conv tail, the hybrid state-space family's and the
-linear-attention family's) has its one-lane prefill chunk told which
-lane the request holds, whichever form its read takes. Three compiled
-programs serve the whole lifetime:
+hold and not every slot of every lane's table (every family since PR
+35) — or a ``[lanes, M]`` block table (a family whose ``read_form`` is
+``None``: none is left, ROADMAP C). A family that also keeps state per
+LANE (``lane_state``: a recurrent state and conv tail, the hybrid
+state-space family's and the linear-attention family's) has its one-lane
+prefill chunk told which lane the request holds, whichever form its read
+takes. Three compiled programs serve the whole lifetime:
 
 - **prefill chunk** ``[1, C]``: one lane's context enters the pool C
   tokens at a time (padded tail chunks write only below the context
@@ -585,7 +585,7 @@ class ServingEngine:
     def _rows_form(self, kind, lanes):
         """:func:`fit_rows` of program ``kind``'s live-rows operand at
         ``lanes`` lanes, or ``None``: the program takes a block table
-        (the latent family's three do)."""
+        (no family's does since the latent families read rows too)."""
         form = self._family.read_form(kind)
         return form and fit_rows(form, lanes, self.blocks_per_lane)
 

@@ -21,9 +21,9 @@ and asks the model's *family* for the three things that differ:
     lies, in the form ``read_form(kind)`` names: ``(W, tile)`` — the
     lanes' live rows of ``W`` blocks, which the program runs ``tile`` at a
     time, and each fed token's write block: ``(rows [R, 2 + W], wblk
-    [lanes, width])`` (``engine.pack_rows``), the dense family's read;
-    ``None`` — a ``[lanes, M]`` block table, the latent family's and
-    the linear-attention family's.
+    [lanes, width])`` (``engine.pack_rows``), every family's read since
+    PR 35; ``None`` — a ``[lanes, M]`` block table, which no family
+    takes any more (ROADMAP C14).
 
 Two more kinds of state than (a) may live in a family, both told to the
 engine by attributes: ``lane_state`` — besides its token-indexed pools the

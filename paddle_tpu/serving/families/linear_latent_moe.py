@@ -4,8 +4,9 @@ kinds of device state in one family.
 
 - **By token**: the latent pool ``[latent layers, blocks, block, 640]`` of
   ``families/latent_moe.py`` — laid out, written and read by ITS functions
-  (block table, ONE gather from the stacked pool, ``attend_absorbed``),
-  for the few latent-attention layers only; no position embedding.
+  (the lanes' live rows gathered a tile at a time from the stacked pool,
+  the absorbed attention row by row: ``attend_pool``), for the few
+  latent-attention layers only; no position embedding.
 - **By LANE, float32**: the delta rule's matrix state, ``[lanes, heads, d,
   d]`` (key x value) a linear-attention layer — ONE ARRAY A LAYER, not one
   stacked pool (``families/hybrid_ssm.py`` says what a stacked one cost) —
@@ -15,7 +16,7 @@ kinds of device state in one family.
 
 Decode and verify index the lane pools by the batch row; the one-lane
 prefill chunk is told its request's lane (``lane_state``: the engine gives
-a block-table family ``(table, slot [1])``) and a chunk that starts at
+it ``(rows, wblk, slot [1])``) and a chunk that starts at
 position 0 starts from ZERO state and tail.
 
 - **A plain round** goes through each layer's state twice: one read gives
@@ -65,8 +66,8 @@ from . import absorb_accumulator
 from .hybrid_ssm import _carried, _keeps, _take_rows
 from .latent_moe import ACC as MOE_ACC
 from .latent_moe import (
-    LANES, _head, attend_pool, chunk_attend, chunk_tiles, expert_counts,
-    table_slots,
+    LANES, _head, attend_pool, chunk_tiles, expert_counts, read_form,
+    write_slots,
 )
 
 __all__ = ["LinearLatentMoEFamily"]
@@ -104,18 +105,20 @@ def _heads_first(a):
     return jnp.swapaxes(a, 1, 2)
 
 
-def _stack(params, ids, pos, wlimit, valid, tables, pool, acc, cfg, kda,
+def _stack(params, ids, pos, wlimit, valid, read, pool, acc, cfg, kda, tile,
            n_tiles=None):
     """The layer stack over ``ids`` [b, s] at positions ``pos``: latent
-    layers against the block pool here, each linear-attention layer
+    layers against the block pool here (``read`` = the engine's live rows
+    and the fed positions' blocks, run ``tile`` rows at a time: the latent
+    family's ``attend_pool``), each linear-attention layer
     through ``kda(ki, u, lp) -> mix`` (the program's own: what it does
     with the lane-indexed pools differs by program). Returns (x, pool,
     acc, the held experts hit summed over the expert layers)."""
     eps = cfg.rms_norm_eps
     x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
-    blk, off, vis = table_slots(tables, pos, wlimit, pool.shape[2])
+    rows, wblk = read
+    blk, off = write_slots(wblk, pos, wlimit, pool.shape[2])
     n_valid = jnp.sum(valid, dtype=jnp.int32)
-    attend = chunk_attend(n_tiles)
     ki = ai = 0
     hit = jnp.int32(0)
     for kind, lp in zip(cfg.layer_kinds, params["layers"]):
@@ -124,8 +127,8 @@ def _stack(params, ids, pos, wlimit, valid, tables, pool, acc, cfg, kda,
             mix = kda(ki, u, lp)
             ki += 1
         else:
-            att, pool = attend_pool(u, lp, ai, pool, tables, pos, blk, off,
-                                    vis, cfg, attend, rope=False)
+            att, pool = attend_pool(u, lp, ai, pool, rows, pos, blk, off,
+                                    cfg, tile, n_tiles, rope=False)
             mix = att @ lp["o"]
             ai += 1
         x = x + mix
@@ -147,14 +150,15 @@ def _unpack(args, cfg):
     return (*args[:3], list(args[3:3 + n]), args[3 + n:])
 
 
-def _prefill_chunk(params, *args, cfg):
+def _prefill_chunk(params, *args, cfg, tile):
     """One request's prefill chunk ``ids`` [1, C] at [start, start + C),
-    ``read`` = (its lane's block table, ``slot`` [1]: the lane it holds).
+    ``read`` = (its lane's rows live up to the chunk's end, the fed
+    positions' blocks, ``slot`` [1]: the lane it holds).
     The slot's state and conv tail carry on from the previous chunk, or
     from ZERO where ``start`` is 0; pad positions (>= ``ctx_len``) are
     the identity on both. Greedy-samples at ``last_idx``. Returns
     ([token, *acc], pools...)."""
-    pool, acc, cpool, states, ((table, slot), ids, start, ctx_len,
+    pool, acc, cpool, states, ((*read, slot), ids, start, ctx_len,
                                last_idx) = _unpack(args, cfg)
     C, K1 = ids.shape[1], cfg.kda_taps - 1
     pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
@@ -188,22 +192,22 @@ def _prefill_chunk(params, *args, cfg):
         return M.kda_gate_out(o, u, lp, cfg)
 
     x, pool, acc, _ = _stack(
-        params, ids, pos, jnp.reshape(ctx_len, (1,)), real, table, pool,
-        acc, cfg, kda, n_tiles=chunk_tiles(C, start, ctx_len))
+        params, ids, pos, jnp.reshape(ctx_len, (1,)), real, read, pool,
+        acc, cfg, kda, tile, n_tiles=chunk_tiles(C, start, ctx_len))
     acc = _bump(acc, lin_slot_resets=fresh)
     h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
     return (jnp.concatenate([_head(h, params, cfg), acc]), pool, acc,
             conv[0], *states)
 
 
-def _decode_step(params, *args, cfg):
+def _decode_step(params, *args, cfg, tile):
     """Every lane feeds its pending token at ``cur_len``: the latent
     entry written then attended, each lane's state advanced one position
     and its conv tail shifted by one row, in place. Idle lanes
     (``cur_len`` 0) write the null block; their slots hold nothing
     anyone reads (a slot starts from zero at its next request's first
     chunk). Returns ([L tokens, *acc], pools...)."""
-    pool, acc, cpool, states, (tables, cur_len,
+    pool, acc, cpool, states, (read, cur_len,
                                last_tok) = _unpack(args, cfg)
     conv = [cpool]
 
@@ -222,7 +226,8 @@ def _decode_step(params, *args, cfg):
     live = cur_len > 0
     x, pool, acc, n_hit = _stack(params, last_tok[:, None],
                                  cur_len[:, None], cur_len + 1,
-                                 live[:, None], tables, pool, acc, cfg, kda)
+                                 live[:, None], read, pool, acc, cfg, kda,
+                                 tile)
     n = jnp.sum(live)
     acc = _bump(acc, lin_state_passes=1, lin_lane_rounds=n,
                 lin_state_lane_moves=2 * n, moe_round_experts_hit=n_hit)
@@ -230,7 +235,7 @@ def _decode_step(params, *args, cfg):
             acc, conv[0], *states)
 
 
-def _verify_step(params, *args, cfg):
+def _verify_step(params, *args, cfg, tile):
     """``toks`` [L, k+1]: each lane's pending token and its draft at
     ``cur_len + j``; positions >= ``wlimit[b]`` are pad. The forward
     reads every linear-attention layer's state once and writes none;
@@ -238,7 +243,7 @@ def _verify_step(params, *args, cfg):
     what the state and the conv tail take: the pending token and the
     first ``a`` drafts, nothing else. Returns ([L * (k+1) picks
     row-major, *acc], pools...)."""
-    pool, acc, cpool, states, (tables, cur_len, toks,
+    pool, acc, cpool, states, (read, cur_len, toks,
                                wlimit) = _unpack(args, cfg)
     L, S1 = toks.shape
     K1 = cfg.kda_taps - 1
@@ -256,8 +261,8 @@ def _verify_step(params, *args, cfg):
         return M.kda_gate_out(_heads_first(o), u, lp, cfg)
 
     x, pool, acc, n_hit = _stack(params, toks, pos, wlimit,
-                                 pos < wlimit[:, None], tables, pool, acc,
-                                 cfg, kda)
+                                 pos < wlimit[:, None], read, pool, acc,
+                                 cfg, kda, tile)
     picks = _head(x, params, cfg)
     # a lane keeps its pending token and the longest prefix of its draft
     # that equals the program's own picks (engine._accept's rule)
@@ -352,14 +357,15 @@ class LinearLatentMoEFamily:
         return int(pools[2].nbytes + sum(p.nbytes for p in pools[3:]))
 
     def read_form(self, kind):
-        """Every program takes a ``[lanes, M]`` block table, as the latent
-        family's do; ``lane_state`` adds the request's lane to the
-        prefill chunk's."""
-        return None
+        """The latent family's ``(W, tile)``: the latent layers read
+        their lanes' live rows through its functions; ``lane_state`` adds
+        the request's lane to the prefill chunk's operand."""
+        return read_form(kind)
 
     def program(self, kind):
         return {"prefill": _prefill_chunk, "decode": _decode_step,
-                "verify": _verify_step}[kind], {"cfg": self.gcfg}
+                "verify": _verify_step}[kind], {
+            "cfg": self.gcfg, "tile": read_form(kind)[1]}
 
     def exec_key(self, pools):
         from ...jit import exec_cache

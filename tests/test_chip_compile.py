@@ -210,6 +210,37 @@ def _dense_program_text(topo, eng, kind, chunk=None):
     return _compiled_program(topo, eng, kind, chunk).as_text()
 
 
+def _program_names(compiled, loops=False):
+    """A compiled program's instructions, each with its operands' shapes
+    as the device trace names its events: the entry computation's and,
+    with ``loops``, those of every ``while`` body and condition reachable
+    from it (a loop body's operations are events of their own; a fused
+    computation's are not)."""
+    from jax._src.lib import xla_client as xc
+
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_backend_config = False
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    comps, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(ENTRY )?(%[\w.\-]+) \(.*\{$", ln)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            comps[name] = []
+        elif name and " = " in ln:
+            comps[name].append(ln.strip())
+    out, todo = [], ["ENTRY"]
+    while todo:
+        lines = comps[todo.pop()]
+        out += lines
+        if loops:
+            todo += [c for n in lines if re.search(r"[\s)]while\(", n)
+                     for c in re.findall(r"(?:body|condition)=(%[\w.\-]+)",
+                                         n)]
+    return out
+
+
 def _programs():
     """Every step program, the prefill chunk at the width the tests'
     engines pin (32) and at the engine's default."""
@@ -317,6 +348,14 @@ def test_engine_program_never_holds_every_lanes_table(
 _LATENT_POOL = (3, 2049, 16, 640)
 
 
+# lanes, blocks a lane: the benchmark cell's 64 lanes, so that a round's
+# tile of ``ROW_TILE`` rows has as many rows as there are lanes (what the
+# benchmark's reader finds the attention by), and 5 rows of 16 blocks a
+# lane: rounds run 320 rows in tiles of 64 and a chunk 5 in tiles of 4, so
+# no tile is a whole table. No width is 256 (a row's slots)
+_LATENT_LANES, _LATENT_TABLE = 64, 80
+
+
 @pytest.fixture(scope="module")
 def latent_engine():
     """A 1-dense + 2-expert-layer bf16 engine, built on the CPU for its
@@ -326,45 +365,31 @@ def latent_engine():
 
     layers, nb, block, _ = _LATENT_POOL
     model = LatentMoEForCausalLM(LatentMoEConfig(
-        vocab_size=512, hidden_size=256, intermediate_size=512,
-        moe_intermediate_size=128, num_hidden_layers=layers,
+        vocab_size=512, hidden_size=384, intermediate_size=512,
+        moe_intermediate_size=384, num_hidden_layers=layers,
         first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=128,
         kv_lora_rank=512, qk_nope_head_dim=32, qk_rope_head_dim=64,
         v_head_dim=32, n_routed_experts=4, router_experts=16,
         num_experts_per_tok=4, initializer_range=0.0, dtype="bfloat16"))
     model.eval()
     return ServingEngine(model, ServingConfig(
-        max_lanes=4, block_size=block, num_blocks=nb, prefill_chunk=32,
-        max_seq_len=5 * block))
+        max_lanes=_LATENT_LANES, block_size=block, num_blocks=nb,
+        prefill_chunk=32, max_seq_len=_LATENT_TABLE * block))
 
 
-def _latent_program_text(topo, eng, kind, monkeypatch, chunk=None):
-    """The family's program ``kind`` as the chip's compiler leaves it;
-    the prefill program at ``chunk`` positions, the engine's own width
-    unless given."""
+def _latent_program(topo, eng, kind, monkeypatch, chunk=None):
+    """The family's program ``kind`` compiled for the described chip (the
+    expert products as the Mosaic kernel, not its interpreter); the
+    prefill program at ``chunk`` positions, the engine's own width unless
+    given."""
     import paddle_tpu.framework.device as device
 
     monkeypatch.setattr(device, "platform", lambda: "tpu")
-    fam = eng._family
-    one_chip = SingleDeviceSharding(topo.devices[0])
+    return _compiled_program(topo, eng, kind, chunk)
 
-    def spec(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    cfg = eng.config
-    L, M = cfg.max_lanes, eng.blocks_per_lane
-    rest = {"decode": (i32(L, M), i32(L), i32(L)),
-            "verify": (i32(L, M), i32(L), i32(L, cfg.spec_k + 1), i32(L)),
-            "prefill": (i32(1, M), i32(1, chunk or eng.prefill_chunk),
-                        i32(), i32(), i32())}[kind]
-    fn, static = fam.program(kind)
-    return jax.jit(
-        fn, static_argnames=tuple(static), donate_argnums=fam.donate_argnums,
-    ).lower(*jax.tree_util.tree_map(spec, (eng._params, *eng._pools)),
-            *rest, **static).compile().as_text()
+def _latent_program_text(topo, eng, kind, monkeypatch, chunk=None):
+    return _latent_program(topo, eng, kind, monkeypatch, chunk).as_text()
 
 
 @pytest.mark.parametrize("kind,chunk", _programs())
@@ -376,13 +401,14 @@ def test_latent_program_never_copies_its_pool(topo, latent_engine, kind,
     into row-major and back, every call (2 x 1.95 ms at the benchmark's
     0.57 GB: PERF.md section 6, PR 27; with width 576 here this test
     finds both copies). The family pads the entry to 640, whose own
-    layout is row-major: no instruction is a copy of the pool, the
-    stacked pool is what the gather reads (no one layer's pool is
+    layout is row-major: no instruction is a copy of the pool — in the
+    entry computation or in a loop that gathers a tile of rows from it —,
+    the stacked pool is what the gather reads (no one layer's pool is
     produced either), and the expert products are the grouped-matmul
     kernel, not its interpreter."""
     assert latent_engine._pools[0].shape == _LATENT_POOL
-    text = _latent_program_text(topo, latent_engine, kind, monkeypatch,
-                                chunk)
+    compiled = _latent_program(topo, latent_engine, kind, monkeypatch, chunk)
+    text = compiled.as_text()
     layers, nb, block, width = _LATENT_POOL
     whole = rf"\w+\[{layers},{nb},{block},{width}\]"
     copies = [ln.strip()[:200] for ln in text.splitlines()
@@ -392,23 +418,43 @@ def test_latent_program_never_copies_its_pool(topo, latent_engine, kind,
                  if re.search(rf"= \w+\[{nb},{block},{width}\]", ln)]
     assert not one_layer, "\n".join(one_layer[:4])
     assert re.search(whole, text)
+    # the loops' instructions were among those lines
+    assert any("/while/body/" in n
+               for n in _program_names(compiled, loops=True))
     # two grouped products an expert layer, as Mosaic kernels
     assert len(re.findall(r"%gmm[\.\d]* = [^\n]*custom_call_target="
                           r"\"tpu_custom_call\"", text)) == 4
 
 
-@pytest.mark.parametrize("kind", ["decode", "verify"])
-def test_latent_attention_reader_picks_the_cache_not_the_new_entries(
-        topo, latent_engine, kind, monkeypatch):
-    """The benchmark's ``mla_attend_roofline`` picks operations by the
-    shapes in their instruction text (the device trace's events are named
-    by it). It once took the gathered cache's slot count from the first
-    ``[lanes, N, 576]`` it met: with the pool stored 640 wide that is the
-    round's k + 1 NEW entries, so it timed the write path (review of PR
-    27). Held here to the compiled programs' own instructions: the slots
-    are the table's (5 blocks of 16), and what is picked is each layer's
-    gather, score product and weighted sum and nothing of the expert
-    layer, the query path or the cache write."""
+def _holds_rows_not_tables(text, eng, kind, lanes, table, width):
+    """No instruction's result is shaped like every lane's whole table —
+    ``[lanes * M, block, width]`` as a full-table gather wrote it, or
+    ``[lanes, M * block, width]`` as the attention then read it — while a
+    tile of rows is."""
+    assert eng.blocks_per_lane == table
+    w, tile, cap = eng._rows_form(kind, lanes)
+    assert cap > tile  # several tiles: a tile is not the table
+    block = eng.config.block_size
+    for dims in (rf"{lanes * table},{block},{width}",
+                 rf"{lanes},{table * block},{width}(,1)?"):
+        lines = _results_shaped(text, dims)
+        assert not lines, "\n".join(lines[:6])
+    assert _results_shaped(
+        text, rf"({tile * w},{block}|{tile},{w * block}),{width}")
+
+
+@pytest.mark.parametrize("kind,chunk", _programs())
+def test_latent_program_never_holds_every_lanes_table(
+        topo, latent_engine, kind, chunk, monkeypatch):
+    """The latent read gathers the rows the lanes hold, a tile at a time
+    (PERF.md section 6, PR 35), as the dense family's does."""
+    lanes = 1 if kind == "prefill" else _LATENT_LANES
+    _holds_rows_not_tables(
+        _latent_program_text(topo, latent_engine, kind, monkeypatch, chunk),
+        latent_engine, kind, lanes, _LATENT_TABLE, _LATENT_POOL[3])
+
+
+def _mla_reader():
     import importlib.util
     import sys
 
@@ -422,44 +468,118 @@ def test_latent_attention_reader_picks_the_cache_not_the_new_entries(
         spec.loader.exec_module(reader)
     finally:
         sys.path.pop(0)
-    text = _latent_program_text(topo, latent_engine, kind, monkeypatch)
-    names = [ln.strip() for ln in text[text.index("\nENTRY"):].splitlines()
-             if " = " in ln]
-    cfg = latent_engine.config
-    lanes, slots = cfg.max_lanes, latent_engine.blocks_per_lane * 16
-    picked = reader.pattern(names, lanes, {
-        "kv_lora_rank": 512, "qk_rope_head_dim": 64,
-        "num_attention_heads": 4})
+    return reader
+
+
+# what the reader is to pick, a latent layer: the operation's role, by the
+# ``jax.named_scope`` path in its metadata (which the trace does not hold)
+_MLA_ROLES = {
+    "the row gather": r"= bf16\[\d+,16,640\].*mla/attend/while/body/gather",
+    "the scores": r"mla/attend/while/body/tshe,tle->tshl",
+    "the weighted sum": r"mla/attend/while/body/tshl,tlc->tshc",
+    "a row's sum through W_v": r"mla/attend/while/body/bshc,chd->bshd",
+    "the per-head latent queries": r"mla/attend/bshd,chd->bshc",
+}
+_OPERATION = re.compile(r"[\s)](fusion|copy|custom-call|gather|scatter|"
+                        r"convolution|reduce|dynamic-update-slice)\(")
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_latent_attention_reader_picks_the_cache_not_the_new_entries(
+        topo, latent_engine, kind, monkeypatch):
+    """The benchmark's ``mla_attend_roofline`` picks operations by the
+    shapes in their instruction text (the device trace's events are named
+    by it), taking the gathered cache for the ``[lanes, slots, 640]``
+    with the most slots. It once read the round's k + 1 NEW entries for
+    the cache and so timed the write path (review of PR 27). Since PR 35
+    the cache is gathered a tile of ``ROW_TILE`` rows at a time under a
+    loop: the reader finds it because a tile has as many rows as the
+    served engine has lanes (PERF.md section 7 (r)). Held here to the
+    compiled programs' own instructions, the loop bodies' among them: the
+    slots are a ROW's (16 blocks of 16), and what is picked is, by role,
+    each layer's row gather, score product (the softmax's reductions
+    fused into it and into the weighted sum), weighted sum, that sum
+    through ``W_v`` and the per-head latent queries — and nothing of the
+    expert layer, the query path, the cache write or the head."""
+    from paddle_tpu.serving.families import latent_moe as fam
+
+    reader = _mla_reader()
+    names = _program_names(
+        _latent_program(topo, latent_engine, kind, monkeypatch), loops=True)
+    lanes, block = _LATENT_LANES, _LATENT_POOL[2]
+    assert fam.ROW_TILE == lanes  # what the reader hangs on
+    slots = fam.ROW_BLOCKS * block
+    m = {"kv_lora_rank": 512, "qk_rope_head_dim": 64,
+         "num_attention_heads": 4}
+    picked = reader.pattern(names, lanes, m)
     assert rf"\[{lanes},{slots},640" in picked, picked
-    hit = [n for n in names if re.search(picked, n)]
+    assert re.escape(f"[{lanes * fam.ROW_BLOCKS},{block},640]") in picked
+    hit = [n for n in names if re.search(picked, n)
+           and _OPERATION.search(n)]
     layers = _LATENT_POOL[0]
-    for what in ("/gather", "bshl,blc->bshc"):
-        assert sum(what in n for n in hit) == layers, (what, hit)
-    # the float32 scores over every slot, one product a layer at least
+    for role, rx in _MLA_ROLES.items():
+        got = [n for n in hit if re.search(rx, n)]
+        assert len(got) == layers, (role, [n[:160] for n in got])
+    # the float32 scores over a row's slots, one product a layer
     scores = (rf"= \(?(f32\[[\d,]*\]\S* )?f32\[{lanes},(\d+,)*{slots}\]"
               r"\S* fusion")
     assert sum(bool(re.search(scores, n)) for n in hit) >= layers, hit
-    stray = [n[:160] for n in hit
-             if re.search(r"moe/|mla/q/|mla/kv_write", n)]
+    stray = [n[:200] for n in hit if 'op_name="' in n
+             and "mla/attend" not in n]
     assert not stray, stray
+    # what of the loop's body the reader does NOT pick: the fold by lane
+    # (its float32 ``[lanes, positions, heads, dv]`` operands carry no
+    # shape the reader knows: PERF.md section 7 (r)), the per-row
+    # statistics and the index arithmetic — nothing as wide as a row's
+    # slots, the latent or a stored entry
+    left = [n for n in names if "mla/attend/while/body" in n
+            and _OPERATION.search(n) and n not in hit]
+    assert sum("bt,tshd->bshd" in n for n in left) == layers
+    wide = [n[:200] for n in left
+            if re.search(r"\[(\d+,)*(256|512|640)(,1)?\]", n)]
+    assert not wide, wide
     # a trace holds the prefill chunk's events too, and the pattern is
     # made from all of its names: with a chunk of the default width
-    # among them (``[1, W, heads, 640]`` queries, ``[1, W, heads,
-    # slots]`` scores) the round's picks are the same operations
-    wide = _latent_program_text(topo, latent_engine, "prefill", monkeypatch,
-                                PREFILL_CHUNK)
-    wide = [ln.strip() for ln in wide[wide.index("\nENTRY"):].splitlines()
-            if " = " in ln]
+    # among them (``[1, W, heads, 640]`` queries, ``[4, 32, heads,
+    # slots]`` scores of its own row tile) the round's picks are the
+    # same operations
+    wide = _program_names(_latent_program(
+        topo, latent_engine, "prefill", monkeypatch, PREFILL_CHUNK),
+        loops=True)
     assert any(re.search(rf"\[1,{PREFILL_CHUNK},", n) for n in wide)
-    with_chunk = reader.pattern(names + wide, lanes, {
-        "kv_lora_rank": 512, "qk_rope_head_dim": 64,
-        "num_attention_heads": 4})
-    assert [n for n in names if re.search(with_chunk, n)] == hit
+    with_chunk = reader.pattern(names + wide, lanes, m)
+    assert [n for n in names if re.search(with_chunk, n)
+            and _OPERATION.search(n)] == hit
     # no cache among the names (the parent's programs): nothing to read
     assert reader.pattern([n for n in names if "640" not in n
-                           and "576" not in n], lanes, {
-        "kv_lora_rank": 512, "qk_rope_head_dim": 64,
-        "num_attention_heads": 4}) is None
+                           and "576" not in n], lanes, m) is None
+
+
+@pytest.mark.parametrize("heads,width", [(128, 7680), (32, 2304)],
+                         ids=["openpangu", "kimi"])
+def test_latent_attention_reader_on_the_served_shapes(heads, width):
+    """The same reader fed synthetic names of the served cells' shapes
+    (64 lanes, 5 positions, rows of 256 slots): it returns the pattern
+    ISSUE 35 reckoned and passes over the new entries, the pool scatter,
+    the grouped products and the head."""
+    reader = _mla_reader()
+    picks = ["%f.1 = bf16[1024,16,640] fusion(bf16[21765,16,640] %p)",
+             f"%f.2 = (f32[64,5,{heads}], f32[64,5,{heads},256]) "
+             f"fusion(bf16[64,256,640,1] %b, bf16[64,5,{heads},640] %q)",
+             f"%f.3 = f32[64,5,{heads},512] fusion(bf16[64,256,640] %b)",
+             f"%f.4 = bf16[64,5,{heads},512] fusion(bf16[64,5,{heads},128])"]
+    passed = ["%f.5 = bf16[64,5,640] fusion(bf16[64,5,576] %e)",
+              "%f.6 = bf16[5,4353,16,640] scatter(bf16[5,4353,16,640] %p, "
+              "bf16[64,5,640] %e)",
+              f"%gmm.1 = bf16[2560,{width}] custom-call(bf16[2560,2048])",
+              f"%f.7 = f32[64,5,19200] fusion(bf16[64,5,{width}] %x)"]
+    m = {"kv_lora_rank": 512, "qk_rope_head_dim": 64,
+         "num_attention_heads": heads}
+    got = reader.pattern(picks + passed, 64, m)
+    assert got == (rf"\[64,256,640(,1)?\]|\[64,(\d+,)*256\]|"
+                   rf"\[64,(\d+,)*{heads},(512|640)\]|"
+                   r"\[1024,16,640\]|\[64,256,640\]")
+    assert [n for n in picks + passed if re.search(got, n)] == picks
 
 
 # -- the hybrid state-space / attention family's programs -----------------------
@@ -608,6 +728,8 @@ def test_ssm_update_reader_picks_the_state_and_nothing_else(
 _LINEAR_LANES, _LINEAR_HEADS = 8, 2
 _LINEAR_STATE = (_LINEAR_LANES, _LINEAR_HEADS, 128, 128)
 _LINEAR_POOL = (1, 2049, 16, 640)
+_LINEAR_TABLE = 144  # blocks a lane: 9 rows of 16, so rounds run 72 rows
+#                      in tiles of 64 and a chunk 9 in tiles of 4
 
 
 @pytest.fixture(scope="module")
@@ -633,24 +755,20 @@ def linear_engine():
     model.eval()
     return ServingEngine(model, ServingConfig(
         max_lanes=_LINEAR_LANES, block_size=16,
-        num_blocks=_LINEAR_POOL[1], prefill_chunk=32, max_seq_len=20 * 16))
+        num_blocks=_LINEAR_POOL[1], prefill_chunk=32,
+        max_seq_len=_LINEAR_TABLE * 16))
 
 
-def _linear_program_names(topo, eng, kind, monkeypatch, chunk=None):
+def _linear_program_names(topo, eng, kind, monkeypatch, chunk=None,
+                          loops=False):
     """The family's program ``kind`` as the chip's compiler leaves it:
-    the entry computation's instructions, each with its operands' shapes
-    as the device trace names its events."""
+    the entry computation's instructions (with ``loops``: the latent
+    read's loop bodies' too), each with its operands' shapes as the
+    device trace names its events."""
     import paddle_tpu.framework.device as device
-    from jax._src.lib import xla_client as xc
 
     monkeypatch.setattr(device, "platform", lambda: "tpu")
-    compiled = _compiled_program(topo, eng, kind, chunk)
-    opts = xc._xla.HloPrintOptions()
-    opts.print_operand_shape = True
-    opts.print_backend_config = False
-    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
-    return [ln.strip() for ln in text[text.index("\nENTRY"):].splitlines()
-            if " = " in ln]
+    return _program_names(_compiled_program(topo, eng, kind, chunk), loops)
 
 
 @pytest.mark.parametrize("kind,chunk", _programs())
@@ -659,12 +777,21 @@ def test_linear_program_never_copies_a_pool(topo, linear_engine, kind,
     """Device state of three kinds, none of which a program call may
     copy: the latent family's padded pool (for the latent layers alone),
     a conv pool with a lane's 3 rows side by side, one float32 state
-    array a linear-attention layer. The prefill chunk (a block-table
-    program that is told its state slot) too, at both widths."""
+    array a linear-attention layer. The prefill chunk (told its state
+    slot beside its lane's rows) too, at both widths; the loops that
+    gather a tile of rows from the latent pool are read too. And the
+    latent read gathers rows, never every lane's whole table (PERF.md
+    section 6, PR 35)."""
     eng = linear_engine
     assert eng._pools[0].shape == _LINEAR_POOL
     assert all(p.shape == _LINEAR_STATE for p in eng._pools[3:])
-    names = _linear_program_names(topo, eng, kind, monkeypatch, chunk)
+    names = _linear_program_names(topo, eng, kind, monkeypatch, chunk,
+                                  loops=True)
+    assert any("/while/body/" in n for n in names)
+    _holds_rows_not_tables(
+        "\n".join(names), eng, kind,
+        1 if kind == "prefill" else _LINEAR_LANES, _LINEAR_TABLE,
+        _LINEAR_POOL[3])
     pools = "|".join(rf"\w+\[{_dims(p.shape)}\]" for p in
                      (eng._pools[0], eng._pools[2], eng._pools[3]))
     copies = [n[:200] for n in names

@@ -287,9 +287,7 @@ def test_read_counters_on_a_ragged_run(model, small_rows, spec):
         + c["prefill_chunks"])
 
 
-def test_a_table_form_program_bills_its_whole_table():
-    """The latent family's programs (the table form's one user) gather a
-    [lanes, M] table whole: gathered == the table there, in every kind."""
+def _tiny_latent():
     from paddle_tpu.models import LatentMoEConfig, LatentMoEForCausalLM
 
     pt.seed(3)
@@ -300,15 +298,56 @@ def test_a_table_form_program_bills_its_whole_table():
         kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
         v_head_dim=12, n_routed_experts=4, num_experts_per_tok=2))
     model.eval()
-    eng = ServingEngine(model, ServingConfig(
+    return model
+
+
+@pytest.mark.parametrize("slot", [False, True], ids=["table", "table_slot"])
+def test_a_table_form_program_bills_its_whole_table(monkeypatch, slot):
+    """The engine's TABLE form (a family whose ``read_form`` is ``None``;
+    since PR 35 no family's is — ROADMAP C): the operand is every lane's
+    whole ``[lanes, M]`` list, null-padded (a ``lane_state`` family's
+    prefill chunk: with its slot), and gathered == the table in every
+    kind."""
+    eng = ServingEngine(_tiny_latent(), ServingConfig(
         max_lanes=2, block_size=2, prefill_chunk=4, max_seq_len=16,
         spec=False))
+    fam = eng._family
+    monkeypatch.setattr(type(fam), "read_form", lambda self, kind: None)
+    monkeypatch.setattr(type(fam), "lane_state", slot)
     assert eng._rows_form("decode", 2) is None
     assert eng._rows_form("prefill", 1) is None
-    _serve(eng, _requests(model, 3, seed=10, hi=8, new=(3, 6)))
+    M = eng.blocks_per_lane
+    got = eng._pack_read("decode", 2, 1, [(1, [5, 3, 7], 5, 6)])
+    want = np.zeros((2, M), np.int32)
+    want[1, :3] = [5, 3, 7]
+    np.testing.assert_array_equal(got, want)
+    assert eng._read_spec("decode", 2, 1).shape == (2, M)
+    got = eng._pack_read("prefill", 1, 4, [(0, [4, 9], 0, 3)], slot=1)
+    spec = eng._read_spec("prefill", 1, 4)
+    if slot:
+        (got, at), (spec, at_spec) = got, spec
+        assert at.tolist() == [1] and at_spec.shape == (1,)
+    assert got.tolist() == [[4, 9] + [0] * (M - 2)]
+    assert spec.shape == (1, M)
+    c = eng.counters
+    assert c["kv_read_tokens"] == 6 + 3
+    assert c["kv_gathered_tokens"] == c["kv_dense_read_tokens"] \
+        == (2 + 1) * M * 2
+
+
+def test_the_latent_family_bills_its_live_rows():
+    """The latent family reads by rows (PR 35): what its programs gather
+    follows what the lanes hold, in every kind."""
+    eng = ServingEngine(_tiny_latent(), ServingConfig(
+        max_lanes=4, block_size=2, prefill_chunk=4, max_seq_len=1024,
+        spec=False))
+    # rows of 16 blocks: 32 a lane, run 64 (a prefill chunk: 4) at a time
+    assert eng._rows_form("decode", 4) == (16, 64, 128)
+    assert eng._rows_form("prefill", 1) == (16, 4, 32)
+    _serve(eng, _requests(eng.model, 3, seed=10, hi=8, new=(3, 6)))
     c = eng.counters
     assert c["kv_read_tokens"] < c["kv_gathered_tokens"] \
-        == c["kv_dense_read_tokens"]
+        < c["kv_dense_read_tokens"]
 
 
 # -- (d) a prefill call wider than what it is fed (PR 32) ------------------------
@@ -391,3 +430,85 @@ def test_a_prefix_hit_that_leaves_one_token(model, chunk):
     _hold(model, [np.asarray(first.output), np.asarray(second.output)],
           [(p, 5), (p, 5)])
     eng.scheduler.pool.check_invariant()
+
+
+# -- (e) the latent family's read over live rows (PR 35) ----------------------
+
+NH, DC, DR, DN, DV, STORED = 3, 16, 4, 8, 6, 24  # heads; latent, rope,
+#                      nope, value widths; an entry as stored (padded)
+
+
+class _LatentCfg:
+    kv_lora_rank, qk_rope_head_dim, qk_nope_head_dim = DC, DR, DN
+    v_head_dim, num_attention_heads = DV, NH
+
+
+def _latent_operands(lens, s, seed):
+    rng = np.random.RandomState(seed)
+    L = len(lens)
+    nb = 1 + L * M
+    pool = np.zeros((nb, B, STORED), np.float32)
+    pool[..., :DC + DR] = rng.randn(nb, B, DC + DR)
+    tables = rng.permutation(np.arange(1, nb)).reshape(L, M).astype(np.int32)
+    q_nope = jnp.asarray(rng.randn(L, s, NH, DN).astype(np.float32))
+    q_rope = jnp.asarray(rng.randn(L, s, NH, DR).astype(np.float32))
+    lp = {"kv_b": jnp.asarray(
+        rng.randn(DC, NH * (DN + DV)).astype(np.float32) * 0.3)}
+    pos = jnp.asarray(np.asarray(lens)[:, None] + np.arange(s)[None, :],
+                      jnp.int32)
+    return jnp.asarray(pool), tables, q_nope, q_rope, lp, pos
+
+
+def _latent_rows_read(pool, rows, w, tile, q_nope, q_rope, lp, pos):
+    from paddle_tpu.models import latent_moe as model
+    from paddle_tpu.serving.families import latent_moe as fam
+
+    def gather(blocks):
+        return pool[blocks].reshape(blocks.shape[0], w * B, STORED)
+
+    qq = model.absorb_query(q_nope, q_rope, lp, _LatentCfg, STORED)
+    return np.asarray(fam._attend_rows(
+        qq, pos, jnp.asarray(rows), gather, tile, lp, _LatentCfg))
+
+
+_LATENT_CASES = sorted(c for c in _CASES if "window" not in c)
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("case", _LATENT_CASES)
+def test_latent_rows_read_is_the_absorbed_table_read(case, s):
+    """``families/latent_moe._attend_rows`` against the model's own
+    ``attend_absorbed`` over every lane's whole gathered table."""
+    from paddle_tpu.models import latent_moe as model
+
+    c = _CASES[case]
+    lens, L = c["lens"], len(c["lens"])
+    pool, tables, q_nope, q_rope, lp, pos = _latent_operands(
+        lens, s, seed=len(case))
+    vis = jnp.arange(M * B)[None, None, :] <= pos[:, :, None]
+    want = np.asarray(model.attend_absorbed(
+        q_nope, q_rope, pool[tables].reshape(L, M * B, STORED), vis, lp,
+        _LatentCfg))
+    w, tile, cap = fit_rows((c["W"], c["tile"]), L, M)
+    rows, _, n, _ = pack_rows(
+        [(i, list(tables[i]), lens[i], lens[i] + s)
+         for i in range(L) if lens[i]], L, s, B, w, cap)
+    got = _latent_rows_read(pool, rows, w, tile, q_nope, q_rope, lp, pos)
+    held = np.asarray(lens) > 0
+    np.testing.assert_allclose(got[held], want[held], rtol=2e-5, atol=2e-6)
+    assert (got[~held] == 0).all()  # idle lanes read 0, not NaN
+
+
+def test_a_latent_row_above_its_lanes_positions_weighs_nothing():
+    """Rows over the lane's WHOLE table, the ones above its positions
+    too: poisoning their blocks with huge values moves nothing."""
+    lens, s = [21], 2
+    pool, tables, q_nope, q_rope, lp, pos = _latent_operands(lens, s, 3)
+    w, tile, cap = fit_rows((2, 2), 1, M)
+    rows, *_ = pack_rows([(0, list(tables[0]), lens[0], M * B)], 1, s, B,
+                         w, cap)
+    dead = tables[0, 6:]  # slots 24.. lie above positions 21-22
+    args = (rows, w, tile, q_nope, q_rope, lp, pos)
+    np.testing.assert_array_equal(
+        _latent_rows_read(pool.at[dead].set(3e4), *args),
+        _latent_rows_read(pool, *args))

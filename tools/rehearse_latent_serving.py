@@ -28,26 +28,43 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def _row_reads(form, g, i32, geom, slot):
+    """(static kwargs by program, read operands by program) of a family
+    whose programs read their lanes' live rows: ``form(kind)`` its ``(W,
+    tile)``; ``slot``: the prefill chunk is told its state slot too."""
+    from paddle_tpu.serving.engine import fit_rows
+
+    L, _, C, K, M = geom
+    reads, statics = {}, {}
+    for kind, lanes, width in (("decode", L, 1), ("verify", L, K + 1),
+                               ("prefill", 1, C)):
+        w, tile, cap = fit_rows(form(kind), lanes, M)
+        reads[kind] = (i32(cap, 2 + w), i32(lanes, width))
+        statics[kind] = {"cfg": g, "tile": tile}
+    if slot:
+        reads["prefill"] += (i32(1),)
+    return statics, reads
+
+
 def _latent(arch, cfg, layers, s, sds, i32, geom):
     """(family module, static kwargs by program, pools, read operands by
     program)
-    of ``serving/families/latent_moe.py``: one padded latent pool, a
-    block table for every program."""
+    of ``serving/families/latent_moe.py``: one padded latent pool, the
+    lanes' live rows for every program."""
     import jax.numpy as jnp
 
     from paddle_tpu.models import LatentMoEConfig
     from paddle_tpu.serving.families import latent_moe as fam
 
-    L, B, _, _, M = geom
+    B = geom[1]
     static = LatentMoEConfig(**arch.config_kwargs(
         cfg, layers, s["max_seq_len"])).static()
     width = cfg["model"]["kv_lora_rank"] + cfg["model"]["qk_rope_head_dim"]
     width = -(-width // fam.LANES) * fam.LANES  # as make_pools pads it
     pools = (sds((layers, s["num_blocks"], B, width)),
              sds((len(fam.ACC),), jnp.int32))
-    kinds = ("decode", "verify", "prefill")
-    return fam, dict.fromkeys(kinds, {"cfg": static}), pools, {
-        "decode": i32(L, M), "verify": i32(L, M), "prefill": i32(1, M)}
+    statics, reads = _row_reads(fam.read_form, static, i32, geom, False)
+    return fam, statics, pools, reads
 
 
 def _hybrid(arch, cfg, layers, s, sds, i32, geom):
@@ -58,7 +75,6 @@ def _hybrid(arch, cfg, layers, s, sds, i32, geom):
     import jax.numpy as jnp
 
     from paddle_tpu.models import HybridSSMConfig
-    from paddle_tpu.serving.engine import fit_rows
     from paddle_tpu.serving.families import hybrid_ssm as fam
 
     L, B, C, K, M = geom
@@ -72,22 +88,18 @@ def _hybrid(arch, cfg, layers, s, sds, i32, geom):
              *(sds((L, g.mamba_n_heads, g.mamba_d_head, g.mamba_d_state),
                    jnp.float32) for _ in range(n_ssm)))
 
-    reads, statics = {}, {}
-    for kind, lanes, width in (("decode", L, 1), ("verify", L, K + 1),
-                               ("prefill", 1, C)):
-        tile = fam.PREFILL_TILE if kind == "prefill" else fam.ROW_TILE
-        w, tile, cap = fit_rows((fam.ROW_BLOCKS, tile), lanes, M)
-        reads[kind] = (i32(cap, 2 + w), i32(lanes, width))
-        statics[kind] = {"cfg": g, "tile": tile}
-    reads["prefill"] += (i32(1),)
+    statics, reads = _row_reads(
+        lambda kind: (fam.ROW_BLOCKS, fam.PREFILL_TILE if kind == "prefill"
+                      else fam.ROW_TILE), g, i32, geom, True)
     return fam, statics, pools, reads
 
 
 def _linear(arch, cfg, layers, s, sds, i32, geom):
     """The same of ``serving/families/linear_latent_moe.py``: the latent
     family's padded pool for the latent layers, a conv pool and one
-    float32 state array a linear-attention layer by LANE, a block table
-    for every program (the prefill chunk's with its state slot)."""
+    float32 state array a linear-attention layer by LANE, the lanes'
+    live rows for every program (the prefill chunk's with its state
+    slot)."""
     import jax.numpy as jnp
 
     from paddle_tpu.models import LinearLatentMoEConfig
@@ -104,10 +116,8 @@ def _linear(arch, cfg, layers, s, sds, i32, geom):
              sds((n_kda, L, (g.kda_taps - 1) * 3 * g.kda_width)),
              *(sds((L, g.kda_heads, g.kda_head_dim, g.kda_head_dim),
                    jnp.float32) for _ in range(n_kda)))
-    kinds = ("decode", "verify", "prefill")
-    return fam, dict.fromkeys(kinds, {"cfg": g}), pools, {
-        "decode": i32(L, M), "verify": i32(L, M),
-        "prefill": (i32(1, M), i32(1))}
+    statics, reads = _row_reads(fam.read_form, g, i32, geom, True)
+    return fam, statics, pools, reads
 
 
 FAMILIES = {"mla_moe": _latent, "hybrid_ssm": _hybrid,
