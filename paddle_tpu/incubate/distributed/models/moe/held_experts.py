@@ -65,7 +65,7 @@ def route_top_k(u, w_router, top_k, scaling, bias=None):
             _, top_i = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
             top_s = jnp.take_along_axis(s, top_i, axis=-1)
         g = top_s / (jnp.sum(top_s, -1, keepdims=True) + 1e-20) * scaling
-    return top_i.astype(jnp.int32), g
+        return top_i.astype(jnp.int32), g
 
 
 # rows, contraction and output tile of the grouped product: of the tilings
@@ -112,15 +112,16 @@ def held_experts(u, idx, g, w_gate_up, w_down, first_held, valid=None):
     T, H = u.shape
     K = idx.shape[1]
     n = w_gate_up.shape[0]
-    local = idx - first_held
-    held = (local >= 0) & (local < n)
-    if valid is not None:
-        held = held & valid[:, None]
-    flat = jnp.where(held, local, n).reshape(T * K)
-    order = jnp.argsort(flat, stable=True)
-    counts = jnp.sum(flat[:, None] == jnp.arange(n)[None, :], axis=0,
-                     dtype=jnp.int32)
-    rows = u[order // K]  # [T*K, H], the held assignments first
+    with jax.named_scope("moe/dispatch"):
+        local = idx - first_held
+        held = (local >= 0) & (local < n)
+        if valid is not None:
+            held = held & valid[:, None]
+        flat = jnp.where(held, local, n).reshape(T * K)
+        order = jnp.argsort(flat, stable=True)
+        counts = jnp.sum(flat[:, None] == jnp.arange(n)[None, :], axis=0,
+                         dtype=jnp.int32)
+        rows = u[order // K]  # [T*K, H], the held assignments first
     with jax.named_scope("moe/experts"):
         gu = grouped_matmul(rows, w_gate_up, counts)
         gate, up = jnp.split(gu, 2, axis=-1)
@@ -133,7 +134,7 @@ def held_experts(u, idx, g, w_gate_up, w_down, first_held, valid=None):
         back = out[jnp.argsort(order)].reshape(T, K, H)
         y = jnp.einsum("tkh,tk->th", back.astype(jnp.float32),
                        jnp.where(held, g, 0.0))
-    return y.astype(u.dtype), counts
+        return y.astype(u.dtype), counts
 
 
 def sparse_expert_block(u, p, *, top_k, scaling, first_held, valid=None):

@@ -64,6 +64,7 @@ import numpy as np
 
 from ..framework import jax_compat as _jc
 from ..monitor import _register as _monitor_register
+from ..monitor import scopes as _scopes
 
 __all__ = [
     "get_or_compile", "ExecEntry", "enable", "disable", "enabled",
@@ -698,7 +699,9 @@ def get_or_compile(key, lower_fn, label: str | None = None) -> ExecEntry:
     disabled, so no key is ever built for nothing). ``lower_fn``: zero-arg
     callable returning a ``jax.stages.Lowered`` (trace+lower happens
     inside it, so a hit skips tracing too on the mem tier and everything
-    but deserialization on the disk tier).
+    but deserialization on the disk tier). Every executable handed out
+    for the first time leaves its text with ``monitor/scopes`` (which
+    layer issued each instruction: what a device trace is joined to).
     """
     au = _audit
     if key is not None and enabled():
@@ -723,6 +726,7 @@ def get_or_compile(key, lower_fn, label: str | None = None) -> ExecEntry:
             e = _disk_load(sha, rep)
             if e is not None:
                 _mem_put(sha, e)
+                _scopes.record(label, e.compiled)
                 if au is not None:
                     au.on_hit(e, key, label)
                 return e
@@ -738,6 +742,7 @@ def get_or_compile(key, lower_fn, label: str | None = None) -> ExecEntry:
             if m is not None:
                 m.on_compile_ms(ms)
             entry = ExecEntry(compiled, sha, "compile", ms)
+            _scopes.record(label, compiled)
             _mem_put(sha, entry)
             _disk_store(sha, rep, compiled, ms, label)
             if au is not None:
@@ -750,6 +755,7 @@ def get_or_compile(key, lower_fn, label: str | None = None) -> ExecEntry:
     if m is not None:
         m.on_compile_ms(ms)
     entry = ExecEntry(compiled, None, "compile", ms)
+    _scopes.record(label, compiled)
     if au is not None:
         au.on_compiled(entry, key, label)
     return entry

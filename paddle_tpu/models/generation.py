@@ -89,10 +89,12 @@ def _collect_params(model, int8_weights=False):
 
 
 def _rms(x, w, eps):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
-                   keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)).astype(
-        x.dtype) * w
+    # every family's norm: the ``norm`` scope (monitor/scopes.py)
+    with jax.named_scope("norm"):
+        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                       keepdims=True)
+        return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)).astype(
+            x.dtype) * w
 
 
 def _rope_at(q, k, pos, theta):

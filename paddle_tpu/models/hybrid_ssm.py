@@ -172,8 +172,8 @@ def ssm_project(u, lp, cfg):
     xBC [b, T, conv_dim], dt_raw [b, T, heads])."""
     with jax.named_scope("ssm/in_proj"):
         p = u @ lp["in_proj"]
-    d, c = cfg.d_inner, cfg.conv_dim
-    return p[..., :d], p[..., d:d + c], p[..., d + c:]
+        d, c = cfg.d_inner, cfg.conv_dim
+        return p[..., :d], p[..., d:d + c], p[..., d + c:]
 
 
 def ssm_conv(window, lp, cfg):
@@ -199,12 +199,14 @@ def ssm_inputs(c, dt_raw, lp, cfg):
     b, T, _ = c.shape
     H, P = cfg.mamba_n_heads, cfg.mamba_d_head
     G, N = cfg.mamba_n_groups, cfg.mamba_d_state
-    c = c.astype(F32)
-    x = c[..., :H * P].reshape(b, T, H, P)
-    B = c[..., H * P:H * P + G * N].reshape(b, T, G, N)
-    C = c[..., H * P + G * N:].reshape(b, T, G, N)
-    dt = jax.nn.softplus(dt_raw.astype(F32) + lp["dt_bias"].astype(F32))
-    return x, B, C, dt, -jnp.exp(lp["A_log"].astype(F32))
+    with jax.named_scope("ssm/inputs"):
+        c = c.astype(F32)
+        x = c[..., :H * P].reshape(b, T, H, P)
+        B = c[..., H * P:H * P + G * N].reshape(b, T, G, N)
+        C = c[..., H * P + G * N:].reshape(b, T, G, N)
+        dt = jax.nn.softplus(dt_raw.astype(F32)
+                             + lp["dt_bias"].astype(F32))
+        return x, B, C, dt, -jnp.exp(lp["A_log"].astype(F32))
 
 
 def ssm_step(S, x, B, dt, A):
@@ -291,7 +293,8 @@ def ssm_mix(u, lp, cfg):
     (normed) from a zero state and a zero conv tail."""
     z, xBC, dt_raw = ssm_project(u, lp, cfg)
     b = u.shape[0]
-    window = jnp.pad(xBC, ((0, 0), (cfg.mamba_d_conv - 1, 0), (0, 0)))
+    with jax.named_scope("ssm/conv"):
+        window = jnp.pad(xBC, ((0, 0), (cfg.mamba_d_conv - 1, 0), (0, 0)))
     x, B, C, dt, A = ssm_inputs(ssm_conv(window, lp, cfg), dt_raw, lp, cfg)
     with jax.named_scope("ssm/state_update"):
         S0 = jnp.zeros((b, cfg.mamba_n_heads, cfg.mamba_d_head,
@@ -306,9 +309,11 @@ def attention_qkv(u, lp, cfg):
     b, T, _ = u.shape
     nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                   cfg.head_dim)
-    q, k, v = jnp.split(u @ lp["qkv"], [nh * d, (nh + nkv) * d], axis=-1)
-    return (q.reshape(b, T, nh, d), k.reshape(b, T, nkv, d),
-            v.reshape(b, T, nkv, d))
+    with jax.named_scope("attn/qkv"):
+        q, k, v = jnp.split(u @ lp["qkv"], [nh * d, (nh + nkv) * d],
+                            axis=-1)
+        return (q.reshape(b, T, nh, d), k.reshape(b, T, nkv, d),
+                v.reshape(b, T, nkv, d))
 
 
 def attention_mix(u, lp, cfg):
@@ -318,18 +323,23 @@ def attention_mix(u, lp, cfg):
     nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                   cfg.head_dim)
     q, k, v = attention_qkv(u, lp, cfg)
-    qg = q.reshape(b, T, nkv, nh // nkv, d).astype(F32)
-    s = jnp.einsum("btkgd,bskd->btkgs", qg, k.astype(F32)) \
-        * cfg.attention_multiplier
-    vis = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
-    p = jax.nn.softmax(jnp.where(vis[None, :, None, None, :], s, -1e30), -1)
-    out = jnp.einsum("btkgs,bskd->btkgd", p, v.astype(F32))
-    return out.reshape(b, T, nh * d).astype(u.dtype) @ lp["o"]
+    with jax.named_scope("attn/rows"):
+        qg = q.reshape(b, T, nkv, nh // nkv, d).astype(F32)
+        s = jnp.einsum("btkgd,bskd->btkgs", qg, k.astype(F32)) \
+            * cfg.attention_multiplier
+        vis = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+        p = jax.nn.softmax(
+            jnp.where(vis[None, :, None, None, :], s, -1e30), -1)
+        out = jnp.einsum("btkgs,bskd->btkgd", p, v.astype(F32))
+    with jax.named_scope("attn/out"):
+        return out.reshape(b, T, nh * d).astype(u.dtype) @ lp["o"]
 
 
 def mlp(u, lp):
-    gate, up = jnp.split(u @ lp["gate_up"], 2, axis=-1)
-    return (jax.nn.silu(gate.astype(F32)).astype(u.dtype) * up) @ lp["down"]
+    with jax.named_scope("mlp"):
+        gate, up = jnp.split(u @ lp["gate_up"], 2, axis=-1)
+        return (jax.nn.silu(gate.astype(F32)).astype(u.dtype)
+                * up) @ lp["down"]
 
 
 def layer_on_sequence(x, lp, cfg):

@@ -270,14 +270,18 @@ def mlp_block(u, lp, cfg, valid=None):
     """Dense SwiGLU or the expert layer, told apart by the layer's leaves.
     ``u`` [b, s, h]. Returns (y, counts or None)."""
     if "router" not in lp:
-        return swiglu(u, lp["gate_up"], lp["down"]), None
+        with jax.named_scope("mlp"):
+            return swiglu(u, lp["gate_up"], lp["down"]), None
     b, s, h = u.shape
+    with jax.named_scope("moe/dispatch"):
+        u = u.reshape(b * s, h)
+        valid = None if valid is None else valid.reshape(b * s)
     y, counts = sparse_expert_block(
-        u.reshape(b * s, h), lp, top_k=cfg.num_experts_per_tok,
+        u, lp, top_k=cfg.num_experts_per_tok,
         scaling=cfg.routed_scaling_factor,
-        first_held=cfg.first_held_expert,
-        valid=None if valid is None else valid.reshape(b * s))
-    return y.reshape(b, s, h), counts
+        first_held=cfg.first_held_expert, valid=valid)
+    with jax.named_scope("moe/combine"):
+        return y.reshape(b, s, h), counts
 
 
 def layer_on_sequence(x, lp, cfg):

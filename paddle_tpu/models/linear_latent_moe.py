@@ -380,7 +380,8 @@ def kda_mix(u, lp, cfg):
     """The mixer over whole sequences ``u`` [b, T, hidden] (normed) from a
     zero state and a zero conv tail."""
     raw = kda_project(u, lp, cfg)
-    window = jnp.pad(raw, ((0, 0), (cfg.kda_taps - 1, 0), (0, 0)))
+    with jax.named_scope("kda/conv"):
+        window = jnp.pad(raw, ((0, 0), (cfg.kda_taps - 1, 0), (0, 0)))
     q, k, v = kda_conv(window, lp, cfg)
     g, beta = kda_gates(u, lp, cfg)
     with jax.named_scope("kda/state_update"):
@@ -398,21 +399,28 @@ def latent_mix(u, lp, cfg):
     q_nope, q_pe, entry = latent_qkv(u, lp, pos, cfg, rope=False)
     vis = jnp.broadcast_to(
         (jnp.arange(s)[None, :] <= jnp.arange(s)[:, None])[None], (b, s, s))
-    return attend_upprojected(q_nope, q_pe, entry, vis, lp, cfg) @ lp["o"]
+    with jax.named_scope("mla/attend"):
+        att = attend_upprojected(q_nope, q_pe, entry, vis, lp, cfg)
+    with jax.named_scope("mla/out"):
+        return att @ lp["o"]
 
 
 def ffn_block(u, lp, cfg, valid=None):
     """Dense SwiGLU or the expert layer, told apart by the layer's leaves.
     ``u`` [b, s, h]. Returns (y, counts or None)."""
     if "router" not in lp:
-        return swiglu(u, lp["gate_up"], lp["down"]), None
+        with jax.named_scope("mlp"):
+            return swiglu(u, lp["gate_up"], lp["down"]), None
     b, s, h = u.shape
+    with jax.named_scope("moe/dispatch"):
+        u = u.reshape(b * s, h)
+        valid = None if valid is None else valid.reshape(b * s)
     y, counts = sparse_expert_block(
-        u.reshape(b * s, h), lp, top_k=cfg.num_experts_per_token,
+        u, lp, top_k=cfg.num_experts_per_token,
         scaling=cfg.routed_scaling_factor,
-        first_held=cfg.first_held_expert,
-        valid=None if valid is None else valid.reshape(b * s))
-    return y.reshape(b, s, h), counts
+        first_held=cfg.first_held_expert, valid=valid)
+    with jax.named_scope("moe/combine"):
+        return y.reshape(b, s, h), counts
 
 
 def layer_on_sequence(x, lp, cfg):

@@ -180,11 +180,14 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
     B = kpool.shape[2]
     dt = jnp.dtype(cfg.dtype)
     quant = kscale is not None
-    x = params["embed"][ids].astype(dt)
+    scope = jax.named_scope  # the scopes: monitor/scopes.py
+    with scope("embed"):
+        x = params["embed"][ids].astype(dt)
     rows, blk = read
-    ok = pos < wlimit[:, None]
-    blk = jnp.where(ok, blk, 0)
-    off = jnp.where(ok, pos % B, 0)
+    with scope("attn/kv_write"):
+        ok = pos < wlimit[:, None]
+        blk = jnp.where(ok, blk, 0)
+        off = jnp.where(ok, pos % B, 0)
     n_layers = params["ln1"].shape[0]
 
     def body(carry, li):
@@ -193,25 +196,31 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
         else:
             x, kp, vp = carry
             ks = vs = None
-        layer_p = {k: jax.tree_util.tree_map(lambda a: a[li], params[k])
-                   for k in
-                   ("ln1", "qkv", "o", "ln2", "gate_up", "down")}
+        layer_p = {}
+        for k, where in (("ln1", "norm"), ("qkv", "attn/qkv"),
+                         ("o", "attn/out"), ("ln2", "norm"),
+                         ("gate_up", "mlp"), ("down", "mlp")):
+            with scope(where):  # a leaf's slice: its consumer's scope
+                layer_p[k] = jax.tree_util.tree_map(lambda a: a[li],
+                                                    params[k])
         h = _rms(x, layer_p["ln1"], cfg.rms_norm_eps)
-        qkv = _mm(h, layer_p["qkv"])
-        q, k, v = jnp.split(qkv, [nh * d, nh * d + nkv * d], axis=-1)
-        q = q.reshape(b, s, nh, d)
-        k = k.reshape(b, s, nkv, d)
-        v = v.reshape(b, s, nkv, d)
-        q, k = _rope_at(q, k, pos, cfg.rope_theta)
-        if quant:
-            from ...quantization import quantize_kv
+        with scope("attn/qkv"):
+            qkv = _mm(h, layer_p["qkv"])
+            q, k, v = jnp.split(qkv, [nh * d, nh * d + nkv * d], axis=-1)
+            q = q.reshape(b, s, nh, d)
+            k = k.reshape(b, s, nkv, d)
+            v = v.reshape(b, s, nkv, d)
+            q, k = _rope_at(q, k, pos, cfg.rope_theta)
+        with scope("attn/kv_write"):
+            if quant:
+                from ...quantization import quantize_kv
 
-            k, k_s = quantize_kv(k)
-            v, v_s = quantize_kv(v)
-            ks = ks.at[li, blk, off].set(k_s)
-            vs = vs.at[li, blk, off].set(v_s)
-        kp = kp.at[li, blk, off].set(k)
-        vp = vp.at[li, blk, off].set(v)
+                k, k_s = quantize_kv(k)
+                v, v_s = quantize_kv(v)
+                ks = ks.at[li, blk, off].set(k_s)
+                vs = vs.at[li, blk, off].set(v_s)
+            kp = kp.at[li, blk, off].set(k)
+            vp = vp.at[li, blk, off].set(v)
         # a tile's blocks come from the STACKED pool by (layer,
         # block): kp[li][...] makes the TPU materialise kp[li], the
         # layer's whole pool, before every gather. Which of the two
@@ -235,14 +244,18 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
                 return tuple(
                     c.reshape(-1, B, nkv, d)[at].reshape(
                         T, W * B, nkv, d) for c in (kp, vp))
-        out = _attend_rows(q, pos, rows, gather, tile, nkv,
-                           sliding_window=cfg.sliding_window)
-        x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])
+        with scope("attn/rows"):
+            out = _attend_rows(q, pos, rows, gather, tile, nkv,
+                               sliding_window=cfg.sliding_window)
+        with scope("attn/out"):
+            x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])
         h2 = _rms(x, layer_p["ln2"], cfg.rms_norm_eps)
-        gu = _mm(h2, layer_p["gate_up"])
-        gate, up = jnp.split(gu, 2, axis=-1)
-        x = x + _mm(jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
-                    * up, layer_p["down"])
+        with scope("mlp"):
+            gu = _mm(h2, layer_p["gate_up"])
+            gate, up = jnp.split(gu, 2, axis=-1)
+            x = x + _mm(
+                jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
+                * up, layer_p["down"])
         if quant:
             return (x, kp, vp, ks, vs), None
         return (x, kp, vp), None
@@ -255,6 +268,14 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
         (x, kpool, vpool), _ = jax.lax.scan(
             body, (x, kpool, vpool), jnp.arange(n_layers))
     return x, kpool, vpool, kscale, vscale
+
+
+def _pick(h, params):
+    """The head product on normed ``h`` and the greedy pick."""
+    with jax.named_scope("head"):
+        logits = _mm(h, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _prefill_chunk(params, kpool, vpool, kscale, vscale, read, ids,
@@ -270,11 +291,11 @@ def _prefill_chunk(params, kpool, vpool, kscale, vscale, read, ids,
     x, kpool, vpool, kscale, vscale = _pool_forward(
         params, kpool, vpool, kscale, vscale, read, ids, pos,
         jnp.reshape(ctx_len, (1,)), cfg, tile=tile)
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
-    logits = _mm(h, params["lm_head"]).astype(jnp.float32)
-    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
-            kscale, vscale)
+    with jax.named_scope("head"):
+        x = _rms(x, params["norm"], cfg.rms_norm_eps)
+        h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
+                                         keepdims=False)
+    return _pick(h, params), kpool, vpool, kscale, vscale
 
 
 def _decode_step(params, kpool, vpool, kscale, vscale, read, cur_len,
@@ -289,10 +310,9 @@ def _decode_step(params, kpool, vpool, kscale, vscale, read, cur_len,
     x, kpool, vpool, kscale, vscale = _pool_forward(
         params, kpool, vpool, kscale, vscale, read, last_tok[:, None],
         pos, cur_len + 1, cfg, tile=tile)
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    logits = _mm(x[:, -1], params["lm_head"]).astype(jnp.float32)
-    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
-            kscale, vscale)
+    with jax.named_scope("head"):
+        x = _rms(x, params["norm"], cfg.rms_norm_eps)[:, -1]
+    return _pick(x, params), kpool, vpool, kscale, vscale
 
 
 def _verify_step(params, kpool, vpool, kscale, vscale, read, cur_len,
@@ -313,10 +333,9 @@ def _verify_step(params, kpool, vpool, kscale, vscale, read, cur_len,
     x, kpool, vpool, kscale, vscale = _pool_forward(
         params, kpool, vpool, kscale, vscale, read, toks, pos, wlimit,
         cfg, tile=tile)
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    logits = _mm(x, params["lm_head"]).astype(jnp.float32)
-    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kpool, vpool,
-            kscale, vscale)
+    with jax.named_scope("head"):
+        x = _rms(x, params["norm"], cfg.rms_norm_eps)
+    return _pick(x, params), kpool, vpool, kscale, vscale
 
 
 # -- the family ---------------------------------------------------------------

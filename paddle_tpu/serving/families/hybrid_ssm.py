@@ -85,8 +85,9 @@ ACC = ("ssm_state_passes", "ssm_lane_rounds", "ssm_state_lane_moves",
 
 
 def _bump(acc, **by):
-    return acc + jnp.stack([jnp.asarray(by.get(n, 0), jnp.int32)
-                            for n in ACC])
+    with jax.named_scope("acc"):
+        return acc + jnp.stack([jnp.asarray(by.get(n, 0), jnp.int32)
+                                for n in ACC])
 
 
 def _attention(u, lp, ai, kpool, vpool, read, pos, blk, off, cfg, tile):
@@ -98,8 +99,9 @@ def _attention(u, lp, ai, kpool, vpool, read, pos, blk, off, cfg, tile):
                   cfg.head_dim)
     B = kpool.shape[2]
     q, k, v = M.attention_qkv(u, lp, cfg)
-    kpool = kpool.at[ai, blk, off].set(k.reshape(b, s, nkv * d))
-    vpool = vpool.at[ai, blk, off].set(v.reshape(b, s, nkv * d))
+    with jax.named_scope("attn/kv_write"):
+        kpool = kpool.at[ai, blk, off].set(k.reshape(b, s, nkv * d))
+        vpool = vpool.at[ai, blk, off].set(v.reshape(b, s, nkv * d))
 
     def gather(blocks):  # dense_gqa._pool_forward's bf16 form
         T, W = blocks.shape
@@ -112,7 +114,9 @@ def _attention(u, lp, ai, kpool, vpool, read, pos, blk, off, cfg, tile):
         out = _attend_rows(
             q.astype(F32) * (cfg.attention_multiplier * np.sqrt(d)),
             pos, read[0], gather, tile, nkv)
-    return out.reshape(b, s, nh * d).astype(u.dtype) @ lp["o"], kpool, vpool
+    with jax.named_scope("attn/out"):
+        return (out.reshape(b, s, nh * d).astype(u.dtype) @ lp["o"], kpool,
+                vpool)
 
 
 def _carried(fresh, kept):
@@ -147,29 +151,45 @@ def _stack(params, ids, pos, wlimit, read, kpool, vpool, cfg, tile, ssm):
     B = kpool.shape[2]
     eps, rm = cfg.rms_norm_eps, cfg.residual_multiplier
     dt = jnp.dtype(cfg.dtype)
-    x = (params["embed"][ids] * cfg.embedding_multiplier).astype(dt)
-    ok = pos < wlimit[:, None]
-    blk = jnp.where(ok, read[1], 0)
-    off = jnp.where(ok, pos % B, 0)
+    scope = jax.named_scope  # the scopes: monitor/scopes.py
+    with scope("embed"):
+        x = (params["embed"][ids] * cfg.embedding_multiplier).astype(dt)
+    with scope("attn/kv_write"):
+        ok = pos < wlimit[:, None]
+        blk = jnp.where(ok, read[1], 0)
+        off = jnp.where(ok, pos % B, 0)
     si = ai = 0
     for kind, lp in zip(cfg.layer_types, params["layers"]):
         u = _rms(x, lp["ln_in"], eps)
         if kind == M.SSM:
             mix = ssm(si, u, lp)
             si += 1
+            out = "ssm/out_proj"
         else:
             mix, kpool, vpool = _attention(u, lp, ai, kpool, vpool, read,
                                            pos, blk, off, cfg, tile)
             ai += 1
-        x = x + (rm * mix).astype(dt)
-        x = x + (rm * M.mlp(_rms(x, lp["ln_post"], eps), lp)).astype(dt)
+            out = "attn/out"
+        with scope(out):  # a residual add: its producer's scope
+            x = x + (rm * mix).astype(dt)
+        y = M.mlp(_rms(x, lp["ln_post"], eps), lp)
+        with scope("mlp"):
+            x = x + (rm * y).astype(dt)
     return x, kpool, vpool
 
 
 def _picks(x, params, cfg):
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    logits = (x @ params["embed"].T).astype(F32) / cfg.logits_scaling
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("head"):
+        x = _rms(x, params["norm"], cfg.rms_norm_eps)
+        logits = (x @ params["embed"].T).astype(F32) / cfg.logits_scaling
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _out(picks, acc):
+    """A program's fetched vector: its picks, then the accumulator."""
+    with jax.named_scope("acc"):
+        return jnp.concatenate([picks.reshape(-1), acc])
 
 
 def _unpack(args, cfg):
@@ -190,10 +210,12 @@ def _prefill_chunk(params, *args, cfg, tile):
     kpool, vpool, cpool, acc, states, (read, ids, start, ctx_len,
                                        last_idx) = _unpack(args, cfg)
     C, K1 = ids.shape[1], cfg.mamba_d_conv - 1
-    pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
-    slot = read[2][0]
-    fresh = start == 0
-    n_real = jnp.clip(ctx_len - start, 0, C)
+    with jax.named_scope("embed"):  # the fed positions
+        pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
+    with jax.named_scope("ssm/state_update"):
+        slot = read[2][0]
+        fresh = start == 0
+        n_real = jnp.clip(ctx_len - start, 0, C)
     conv = [cpool]
 
     def ssm(si, u, lp):
@@ -204,7 +226,8 @@ def _prefill_chunk(params, *args, cfg, tile):
             tail = _carried(fresh, jax.lax.dynamic_slice(
                 conv[0], (si, slot, 0), (1, 1, cpool.shape[2]))[0]
             ).reshape(1, K1, -1)
-        window = jnp.concatenate([tail, xBC], axis=1)
+        with jax.named_scope("ssm/conv"):
+            window = jnp.concatenate([tail, xBC], axis=1)
         x, Bm, Cm, dt, A = M.ssm_inputs(M.ssm_conv(window, lp, cfg),
                                         dt_raw, lp, cfg)
         with jax.named_scope("ssm/state_update"):
@@ -221,9 +244,11 @@ def _prefill_chunk(params, *args, cfg, tile):
     x, kpool, vpool = _stack(params, ids, pos, jnp.reshape(ctx_len, (1,)),
                              read, kpool, vpool, cfg, tile, ssm)
     acc = _bump(acc, ssm_slot_resets=fresh)
-    h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
-    return (jnp.concatenate([_picks(h, params, cfg), acc]), kpool, vpool,
-            conv[0], acc, *states)
+    with jax.named_scope("head"):
+        h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
+                                         keepdims=False)
+    return (_out(_picks(h, params, cfg), acc), kpool, vpool, conv[0], acc,
+            *states)
 
 
 def _decode_step(params, *args, cfg, tile):
@@ -239,7 +264,9 @@ def _decode_step(params, *args, cfg, tile):
 
     def ssm(si, u, lp):
         z, xBC, dt_raw = M.ssm_project(u, lp, cfg)
-        window = jnp.concatenate([_tail(conv[0], si, cfg), xBC], axis=1)
+        with jax.named_scope("ssm/conv"):
+            window = jnp.concatenate([_tail(conv[0], si, cfg), xBC],
+                                     axis=1)
         x, Bm, Cm, dt, A = M.ssm_inputs(M.ssm_conv(window, lp, cfg),
                                         dt_raw, lp, cfg)
         with jax.named_scope("ssm/state_update"):
@@ -251,14 +278,18 @@ def _decode_step(params, *args, cfg, tile):
                 window[:, 1:].reshape(window.shape[0], -1))
         return M.ssm_gate_out(y[:, None], z, lp, cfg)
 
-    x, kpool, vpool = _stack(params, last_tok[:, None], cur_len[:, None],
-                             cur_len + 1, read, kpool, vpool, cfg, tile,
+    with jax.named_scope("embed"):  # the fed tokens and where
+        fed = last_tok[:, None], cur_len[:, None], cur_len + 1
+    x, kpool, vpool = _stack(params, *fed, read, kpool, vpool, cfg, tile,
                              ssm)
-    live = jnp.sum(cur_len > 0)
-    acc = _bump(acc, ssm_state_passes=1, ssm_lane_rounds=live,
-                ssm_state_lane_moves=2 * live)
-    return (jnp.concatenate([_picks(x[:, -1], params, cfg), acc]), kpool,
-            vpool, conv[0], acc, *states)
+    with jax.named_scope("acc"):
+        live = jnp.sum(cur_len > 0)
+        by = dict(ssm_lane_rounds=live, ssm_state_lane_moves=2 * live)
+    acc = _bump(acc, ssm_state_passes=1, **by)
+    with jax.named_scope("head"):
+        x = x[:, -1]
+    return (_out(_picks(x, params, cfg), acc), kpool, vpool, conv[0], acc,
+            *states)
 
 
 def _verify_step(params, *args, cfg, tile):
@@ -273,12 +304,14 @@ def _verify_step(params, *args, cfg, tile):
                                        wlimit) = _unpack(args, cfg)
     L, S1 = toks.shape
     K1 = cfg.mamba_d_conv - 1
-    pos = cur_len[:, None] + jnp.arange(S1, dtype=jnp.int32)[None, :]
+    with jax.named_scope("embed"):
+        pos = cur_len[:, None] + jnp.arange(S1, dtype=jnp.int32)[None, :]
     kept = []  # per state-space layer: (conv window, dt_raw) of the round
 
     def ssm(si, u, lp):
         z, xBC, dt_raw = M.ssm_project(u, lp, cfg)
-        window = jnp.concatenate([_tail(cpool, si, cfg), xBC], axis=1)
+        with jax.named_scope("ssm/conv"):
+            window = jnp.concatenate([_tail(cpool, si, cfg), xBC], axis=1)
         kept.append((window, dt_raw))
         x, Bm, Cm, dt, A = M.ssm_inputs(M.ssm_conv(window, lp, cfg),
                                         dt_raw, lp, cfg)
@@ -293,12 +326,14 @@ def _verify_step(params, *args, cfg, tile):
     picks = _picks(x, params, cfg)
     # a lane keeps its pending token and the longest prefix of its draft
     # that equals the program's own picks (engine._accept's rule)
-    n_draft = wlimit - cur_len - 1                      # -1: an idle lane
-    hit = (picks[:, :-1] == toks[:, 1:]) \
-        & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
-    accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1), axis=1)
-    live = n_draft >= 0
-    n_keep = _keeps(live, accepted)
+    with jax.named_scope("spec"):
+        n_draft = wlimit - cur_len - 1                  # -1: an idle lane
+        hit = (picks[:, :-1] == toks[:, 1:]) \
+            & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
+        accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1),
+                           axis=1)
+        live = n_draft >= 0
+        n_keep = _keeps(live, accepted)
     with jax.named_scope("ssm/state_update"):
         si = 0
         for kind, lp in zip(cfg.layer_types, params["layers"]):
@@ -316,12 +351,13 @@ def _verify_step(params, *args, cfg, tile):
             cpool = cpool.at[si].set(
                 _take_rows(window, n_keep, K1).reshape(L, -1))
             si += 1
-    acc = _bump(acc, ssm_state_passes=2, ssm_lane_rounds=jnp.sum(live),
-                ssm_state_lane_moves=3 * jnp.sum(live),
-                spec_rolled_back_tokens=jnp.sum(
-                    jnp.where(live, n_draft - accepted, 0)))
-    return (jnp.concatenate([picks.reshape(-1), acc]), kpool, vpool, cpool,
-            acc, *states)
+    with jax.named_scope("acc"):
+        by = dict(ssm_lane_rounds=jnp.sum(live),
+                  ssm_state_lane_moves=3 * jnp.sum(live),
+                  spec_rolled_back_tokens=jnp.sum(
+                      jnp.where(live, n_draft - accepted, 0)))
+    acc = _bump(acc, ssm_state_passes=2, **by)
+    return _out(picks, acc), kpool, vpool, cpool, acc, *states
 
 
 class HybridSSMFamily:

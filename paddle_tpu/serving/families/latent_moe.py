@@ -179,8 +179,9 @@ def write_slots(wblk, pos, wlimit, block):
     [b, s] — ``wblk`` the block each position falls in (the host's lookup
     in the lane's block list), positions >= ``wlimit[b]`` redirected to
     null block 0."""
-    ok = pos < wlimit[:, None]
-    return jnp.where(ok, wblk, 0), jnp.where(ok, pos % block, 0)
+    with jax.named_scope("mla/kv_write"):
+        ok = pos < wlimit[:, None]
+        return jnp.where(ok, wblk, 0), jnp.where(ok, pos % block, 0)
 
 
 def attend_pool(u, lp, li, pool, rows, pos, blk, off, cfg, tile,
@@ -194,8 +195,9 @@ def attend_pool(u, lp, li, pool, rows, pos, blk, off, cfg, tile,
     heads x v], pool)."""
     nb, B, W = pool.shape[1:]
     q_nope, q_rope, entry = latent_qkv(u, lp, pos, cfg, rope)
-    pool = pool.at[li, blk, off].set(
-        jnp.pad(entry, ((0, 0), (0, 0), (0, W - entry.shape[-1]))))
+    with jax.named_scope("mla/kv_write"):
+        pool = pool.at[li, blk, off].set(
+            jnp.pad(entry, ((0, 0), (0, 0), (0, W - entry.shape[-1]))))
 
     def gather(blocks):
         # from the STACKED pool, by (layer, block): pool[li] would make
@@ -219,8 +221,9 @@ def chunk_tiles(C, start, ctx_len):
     hold a real token; ``None`` where the chunk is not several whole
     tiles (it then attends all its positions at once)."""
     if C > QUERY_TILE and C % QUERY_TILE == 0:
-        return (jnp.clip(ctx_len - start, 0, C) + QUERY_TILE - 1) \
-            // QUERY_TILE
+        with jax.named_scope("mla/attend"):
+            return (jnp.clip(ctx_len - start, 0, C) + QUERY_TILE - 1) \
+                // QUERY_TILE
     return None
 
 
@@ -235,20 +238,30 @@ def _pool_forward(params, pool, acc, read, ids, pos, wlimit, valid, cfg,
     tokens for the expert layers' counts. Returns (x [b, s, hidden],
     pool, acc)."""
     eps = cfg.rms_norm_eps
-    x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
+    scope = jax.named_scope  # the scopes: monitor/scopes.py
+    with scope("embed"):
+        x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
     rows, wblk = read
     blk, off = write_slots(wblk, pos, wlimit, pool.shape[2])
-    n_valid = jnp.sum(valid, dtype=jnp.int32)
+    with scope("acc"):
+        n_valid = jnp.sum(valid, dtype=jnp.int32)
     for li, lp in enumerate(params["layers"]):
         att, pool = attend_pool(_rms(x, lp["ln_in"], eps), lp, li, pool,
                                 rows, pos, blk, off, cfg, tile, n_tiles)
-        x = x + _rms(att @ lp["o"], lp["ln_attn_out"], eps)
+        with scope("mla/out"):
+            att = att @ lp["o"]
+        att = _rms(att, lp["ln_attn_out"], eps)
+        with scope("norm"):  # a residual add: its producer's scope
+            x = x + att
         y, counts = mlp_block(_rms(x, lp["ln_mlp_in"], eps), lp, cfg,
                               valid=valid)
-        x = x + _rms(y, lp["ln_mlp_out"], eps)
+        y = _rms(y, lp["ln_mlp_out"], eps)
+        with scope("norm"):
+            x = x + y
         if counts is not None:
-            acc = acc + expert_counts(n_valid, counts,
-                                      cfg.num_experts_per_tok)
+            with scope("acc"):
+                acc = acc + expert_counts(n_valid, counts,
+                                          cfg.num_experts_per_tok)
     return x, pool, acc
 
 
@@ -261,14 +274,23 @@ def read_form(kind):
 
 def expert_counts(n_valid, counts, top_k):
     """What one expert-layer call adds to the accumulator's ``ACC``."""
-    return jnp.stack([n_valid * top_k, jnp.sum(counts), jnp.int32(1),
-                      jnp.max(counts)])
+    with jax.named_scope("acc"):
+        return jnp.stack([n_valid * top_k, jnp.sum(counts), jnp.int32(1),
+                          jnp.max(counts)])
 
 
 def _head(x, params, cfg):
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("head"):
+        x = _rms(x, params["norm"], cfg.rms_norm_eps)
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _out(picks, acc):
+    """A program's fetched vector: its picks, then the accumulator."""
+    with jax.named_scope("acc"):
+        return jnp.concatenate([picks.reshape(-1), acc])
 
 
 def _prefill_chunk(params, pool, acc, read, ids, start, ctx_len, last_idx,
@@ -277,14 +299,18 @@ def _prefill_chunk(params, pool, acc, read, ids, start, ctx_len, last_idx,
     ``read`` its lane's rows live up to the chunk's end; greedy-samples
     at ``last_idx``. Returns ([token, *acc], pool, acc)."""
     C = ids.shape[1]
-    pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
+    with jax.named_scope("embed"):  # the fed positions
+        pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
     # a chunk of several whole query tiles attends the fed ones alone
     n_tiles = chunk_tiles(C, start, ctx_len)
-    x, pool, acc = _pool_forward(
-        params, pool, acc, read, ids, pos, jnp.reshape(ctx_len, (1,)),
-        pos < ctx_len, cfg, tile, n_tiles=n_tiles)
-    h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
-    return jnp.concatenate([_head(h, params, cfg), acc]), pool, acc
+    with jax.named_scope("embed"):  # ... how far they go, which are real
+        fed = jnp.reshape(ctx_len, (1,)), pos < ctx_len
+    x, pool, acc = _pool_forward(params, pool, acc, read, ids, pos, *fed,
+                                 cfg, tile, n_tiles=n_tiles)
+    with jax.named_scope("head"):
+        h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
+                                         keepdims=False)
+    return _out(_head(h, params, cfg), acc), pool, acc
 
 
 def _decode_step(params, pool, acc, read, cur_len, last_tok, *, cfg, tile):
@@ -292,10 +318,13 @@ def _decode_step(params, pool, acc, read, cur_len, last_tok, *, cfg, tile):
     attend) and greedy-samples the next; idle lanes (``cur_len`` 0, no
     row) write to the null block and count for nothing. Returns ([L
     tokens, *acc], pool, acc)."""
-    x, pool, acc = _pool_forward(
-        params, pool, acc, read, last_tok[:, None], cur_len[:, None],
-        cur_len + 1, (cur_len > 0)[:, None], cfg, tile)
-    return jnp.concatenate([_head(x[:, -1], params, cfg), acc]), pool, acc
+    with jax.named_scope("embed"):  # the fed tokens, where, which are real
+        fed = (last_tok[:, None], cur_len[:, None], cur_len + 1,
+               (cur_len > 0)[:, None])
+    x, pool, acc = _pool_forward(params, pool, acc, read, *fed, cfg, tile)
+    with jax.named_scope("head"):
+        x = x[:, -1]
+    return _out(_head(x, params, cfg), acc), pool, acc
 
 
 def _verify_step(params, pool, acc, read, cur_len, toks, wlimit, *, cfg,
@@ -305,11 +334,12 @@ def _verify_step(params, pool, acc, read, cur_len, toks, wlimit, *, cfg,
     block. Returns ([L * (k+1) greedy picks row-major, *acc], pool,
     acc)."""
     S = toks.shape[1]
-    pos = cur_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    with jax.named_scope("embed"):
+        pos = cur_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+        valid = pos < wlimit[:, None]
     x, pool, acc = _pool_forward(params, pool, acc, read, toks, pos,
-                                 wlimit, pos < wlimit[:, None], cfg, tile)
-    return (jnp.concatenate([_head(x, params, cfg).reshape(-1), acc]),
-            pool, acc)
+                                 wlimit, valid, cfg, tile)
+    return _out(_head(x, params, cfg), acc), pool, acc
 
 
 class LatentMoEFamily:

@@ -66,7 +66,7 @@ from . import absorb_accumulator
 from .hybrid_ssm import _carried, _keeps, _take_rows
 from .latent_moe import ACC as MOE_ACC
 from .latent_moe import (
-    LANES, _head, attend_pool, chunk_tiles, expert_counts, read_form,
+    LANES, _head, _out, attend_pool, chunk_tiles, expert_counts, read_form,
     write_slots,
 )
 
@@ -91,8 +91,9 @@ ACC = MOE_ACC + LIN_ACC
 
 
 def _bump(acc, **by):
-    return acc.at[len(MOE_ACC):].add(jnp.stack(
-        [jnp.asarray(by.get(n, 0), jnp.int32) for n in LIN_ACC]))
+    with jax.named_scope("acc"):
+        return acc.at[len(MOE_ACC):].add(jnp.stack(
+            [jnp.asarray(by.get(n, 0), jnp.int32) for n in LIN_ACC]))
 
 
 def _tail(cpool, ki, cfg):
@@ -115,30 +116,39 @@ def _stack(params, ids, pos, wlimit, valid, read, pool, acc, cfg, kda, tile,
     with the lane-indexed pools differs by program). Returns (x, pool,
     acc, the held experts hit summed over the expert layers)."""
     eps = cfg.rms_norm_eps
-    x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
+    scope = jax.named_scope  # the scopes: monitor/scopes.py
+    with scope("embed"):
+        x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
     rows, wblk = read
     blk, off = write_slots(wblk, pos, wlimit, pool.shape[2])
-    n_valid = jnp.sum(valid, dtype=jnp.int32)
+    with scope("acc"):
+        n_valid = jnp.sum(valid, dtype=jnp.int32)
+        hit = jnp.int32(0)
     ki = ai = 0
-    hit = jnp.int32(0)
     for kind, lp in zip(cfg.layer_kinds, params["layers"]):
         u = _rms(x, lp["ln_in"], eps)
         if kind == M.KDA:
             mix = kda(ki, u, lp)
             ki += 1
+            out = "kda/out_proj"
         else:
             att, pool = attend_pool(u, lp, ai, pool, rows, pos, blk, off,
                                     cfg, tile, n_tiles, rope=False)
-            mix = att @ lp["o"]
             ai += 1
-        x = x + mix
+            out = "mla/out"
+            with scope(out):
+                mix = att @ lp["o"]
+        with scope(out):  # a residual add: its producer's scope
+            x = x + mix
         y, counts = M.ffn_block(_rms(x, lp["ln_post"], eps), lp, cfg,
                                 valid=valid)
-        x = x + y
+        with scope("mlp" if counts is None else "moe/combine"):
+            x = x + y
         if counts is not None:
-            acc = acc.at[:len(MOE_ACC)].add(expert_counts(
-                n_valid, counts, cfg.num_experts_per_token))
-            hit = hit + jnp.sum(counts > 0, dtype=jnp.int32)
+            with scope("acc"):
+                acc = acc.at[:len(MOE_ACC)].add(expert_counts(
+                    n_valid, counts, cfg.num_experts_per_token))
+                hit = hit + jnp.sum(counts > 0, dtype=jnp.int32)
     return x, pool, acc, hit
 
 
@@ -161,11 +171,17 @@ def _prefill_chunk(params, *args, cfg, tile):
     pool, acc, cpool, states, ((*read, slot), ids, start, ctx_len,
                                last_idx) = _unpack(args, cfg)
     C, K1 = ids.shape[1], cfg.kda_taps - 1
-    pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
-    slot = slot[0]
-    fresh = start == 0
-    real = pos < ctx_len
-    n_real = jnp.clip(ctx_len - start, 0, C)
+    # (the statements keep the order they had before they wore scopes, so
+    # the lowered program is the one it was, to the byte)
+    with jax.named_scope("embed"):  # the fed positions
+        pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
+    with jax.named_scope("kda/state_update"):
+        slot = slot[0]
+        fresh = start == 0
+    with jax.named_scope("embed"):  # ... and which are real
+        real = pos < ctx_len
+    with jax.named_scope("kda/state_update"):
+        n_real = jnp.clip(ctx_len - start, 0, C)
     conv = [cpool]
 
     def kda(ki, u, lp):
@@ -176,7 +192,8 @@ def _prefill_chunk(params, *args, cfg, tile):
             tail = _carried(fresh, jax.lax.dynamic_slice(
                 conv[0], (ki, slot, 0), (1, 1, cpool.shape[2]))[0]
             ).reshape(1, K1, -1)
-        window = jnp.concatenate([tail, raw], axis=1)
+        with jax.named_scope("kda/conv"):
+            window = jnp.concatenate([tail, raw], axis=1)
         q, k, v = M.kda_conv(window, lp, cfg)
         g, beta = M.kda_gates(u, lp, cfg)
         with jax.named_scope("kda/state_update"):
@@ -195,9 +212,10 @@ def _prefill_chunk(params, *args, cfg, tile):
         params, ids, pos, jnp.reshape(ctx_len, (1,)), real, read, pool,
         acc, cfg, kda, tile, n_tiles=chunk_tiles(C, start, ctx_len))
     acc = _bump(acc, lin_slot_resets=fresh)
-    h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
-    return (jnp.concatenate([_head(h, params, cfg), acc]), pool, acc,
-            conv[0], *states)
+    with jax.named_scope("head"):
+        h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
+                                         keepdims=False)
+    return _out(_head(h, params, cfg), acc), pool, acc, conv[0], *states
 
 
 def _decode_step(params, *args, cfg, tile):
@@ -213,7 +231,9 @@ def _decode_step(params, *args, cfg, tile):
 
     def kda(ki, u, lp):
         raw = M.kda_project(u, lp, cfg)
-        window = jnp.concatenate([_tail(conv[0], ki, cfg), raw], axis=1)
+        with jax.named_scope("kda/conv"):
+            window = jnp.concatenate([_tail(conv[0], ki, cfg), raw],
+                                     axis=1)
         q, k, v = M.kda_conv(window, lp, cfg)
         g, beta = M.kda_gates(u, lp, cfg)
         with jax.named_scope("kda/state_update"):
@@ -223,16 +243,19 @@ def _decode_step(params, *args, cfg, tile):
                 window[:, 1:].reshape(window.shape[0], -1))
         return M.kda_gate_out(o[:, None], u, lp, cfg)
 
-    live = cur_len > 0
-    x, pool, acc, n_hit = _stack(params, last_tok[:, None],
-                                 cur_len[:, None], cur_len + 1,
-                                 live[:, None], read, pool, acc, cfg, kda,
+    with jax.named_scope("embed"):  # the fed tokens, where, which are real
+        live = cur_len > 0
+        fed = (last_tok[:, None], cur_len[:, None], cur_len + 1,
+               live[:, None])
+    x, pool, acc, n_hit = _stack(params, *fed, read, pool, acc, cfg, kda,
                                  tile)
-    n = jnp.sum(live)
-    acc = _bump(acc, lin_state_passes=1, lin_lane_rounds=n,
-                lin_state_lane_moves=2 * n, moe_round_experts_hit=n_hit)
-    return (jnp.concatenate([_head(x[:, -1], params, cfg), acc]), pool,
-            acc, conv[0], *states)
+    with jax.named_scope("acc"):
+        n = jnp.sum(live)
+        by = dict(lin_lane_rounds=n, lin_state_lane_moves=2 * n)
+    acc = _bump(acc, lin_state_passes=1, moe_round_experts_hit=n_hit, **by)
+    with jax.named_scope("head"):
+        x = x[:, -1]
+    return _out(_head(x, params, cfg), acc), pool, acc, conv[0], *states
 
 
 def _verify_step(params, *args, cfg, tile):
@@ -247,32 +270,42 @@ def _verify_step(params, *args, cfg, tile):
                                wlimit) = _unpack(args, cfg)
     L, S1 = toks.shape
     K1 = cfg.kda_taps - 1
-    pos = cur_len[:, None] + jnp.arange(S1, dtype=jnp.int32)[None, :]
+    with jax.named_scope("embed"):
+        pos = cur_len[:, None] + jnp.arange(S1, dtype=jnp.int32)[None, :]
+        valid = pos < wlimit[:, None]
     kept = []  # per layer: (conv window, keys, log-decays, pseudo-values)
 
     def kda(ki, u, lp):
         raw = M.kda_project(u, lp, cfg)
-        window = jnp.concatenate([_tail(cpool, ki, cfg), raw], axis=1)
-        q, k, v = (_heads_first(a) for a in M.kda_conv(window, lp, cfg))
-        g, beta = (_heads_first(a) for a in M.kda_gates(u, lp, cfg))
+        with jax.named_scope("kda/conv"):
+            window = jnp.concatenate([_tail(cpool, ki, cfg), raw], axis=1)
+        q, k, v = M.kda_conv(window, lp, cfg)
+        with jax.named_scope("kda/conv"):
+            q, k, v = (_heads_first(a) for a in (q, k, v))
+        g, beta = M.kda_gates(u, lp, cfg)
+        with jax.named_scope("kda/gates"):
+            g, beta = (_heads_first(a) for a in (g, beta))
         with jax.named_scope("kda/state_update"):
             o, pseudo = M.kda_read(M.kda_wy(q, k, v, g, beta), states[ki])
+            o = _heads_first(o)
         kept.append((window, k, g, pseudo))
-        return M.kda_gate_out(_heads_first(o), u, lp, cfg)
+        return M.kda_gate_out(o, u, lp, cfg)
 
-    x, pool, acc, n_hit = _stack(params, toks, pos, wlimit,
-                                 pos < wlimit[:, None], read, pool, acc,
-                                 cfg, kda, tile)
+    x, pool, acc, n_hit = _stack(params, toks, pos, wlimit, valid, read,
+                                 pool, acc, cfg, kda, tile)
     picks = _head(x, params, cfg)
     # a lane keeps its pending token and the longest prefix of its draft
     # that equals the program's own picks (engine._accept's rule)
-    n_draft = wlimit - cur_len - 1                      # -1: an idle lane
-    hit = (picks[:, :-1] == toks[:, 1:]) \
-        & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
-    accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1), axis=1)
-    live = n_draft >= 0
-    n_keep = _keeps(live, accepted)
-    keep = (jnp.arange(S1)[None, :] < n_keep[:, None])[:, None, :, None]
+    with jax.named_scope("spec"):
+        n_draft = wlimit - cur_len - 1                  # -1: an idle lane
+        hit = (picks[:, :-1] == toks[:, 1:]) \
+            & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
+        accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1),
+                           axis=1)
+        live = n_draft >= 0
+        n_keep = _keeps(live, accepted)
+        keep = (jnp.arange(S1)[None, :]
+                < n_keep[:, None])[:, None, :, None]
     with jax.named_scope("kda/state_update"):
         for ki, (window, k, g, pseudo) in enumerate(kept):
             # g and u 0 from the first rejected position on: the identity
@@ -280,13 +313,13 @@ def _verify_step(params, *args, cfg, tile):
                                      jnp.where(keep, pseudo, 0.0))
             cpool = cpool.at[ki].set(
                 _take_rows(window, n_keep, K1).reshape(L, -1))
-    acc = _bump(acc, lin_state_passes=2, lin_lane_rounds=jnp.sum(live),
-                lin_state_lane_moves=3 * jnp.sum(live),
-                spec_rolled_back_tokens=jnp.sum(
-                    jnp.where(live, n_draft - accepted, 0)),
-                moe_round_experts_hit=n_hit)
-    return (jnp.concatenate([picks.reshape(-1), acc]), pool, acc, cpool,
-            *states)
+    with jax.named_scope("acc"):
+        by = dict(lin_lane_rounds=jnp.sum(live),
+                  lin_state_lane_moves=3 * jnp.sum(live),
+                  spec_rolled_back_tokens=jnp.sum(
+                      jnp.where(live, n_draft - accepted, 0)))
+    acc = _bump(acc, lin_state_passes=2, moe_round_experts_hit=n_hit, **by)
+    return _out(picks, acc), pool, acc, cpool, *states
 
 
 class LinearLatentMoEFamily:

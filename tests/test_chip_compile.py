@@ -582,6 +582,46 @@ def test_latent_attention_reader_on_the_served_shapes(heads, width):
     assert [n for n in picks + passed if re.search(got, n)] == picks
 
 
+# -- the scope map the compiled programs leave (monitor/scopes.py) -------------
+
+def _fusions_without_a_group(compiled):
+    """(the fusion instructions of the chip's program that fall to no
+    declared group, all its fusion instructions) — what a device trace's
+    busy time is joined to (benchmarks/chip/chiplib/devscopes.py)."""
+    from paddle_tpu.monitor import scopes
+
+    fusions = {name: row for name, row in
+               scopes.parse(compiled.as_text())[1].items()
+               if row[3] == "fusion"}
+    return sorted(n for n, row in fusions.items() if not row[1]), fusions
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+def test_dense_program_fusions_carry_a_group(topo, engines, kv_int8, kind):
+    bare, fusions = _fusions_without_a_group(
+        _compiled_program(topo, engines[kv_int8], kind))
+    assert len(fusions) > 20
+    assert len(bare) <= 0.05 * len(fusions), bare
+    assert {row[1] for row in fusions.values()} >= {"attn", "ffn", "norm",
+                                                    "head"}
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+def test_latent_program_fusions_carry_a_group(topo, latent_engine, kind,
+                                              monkeypatch):
+    bare, fusions = _fusions_without_a_group(
+        _latent_program(topo, latent_engine, kind, monkeypatch))
+    assert len(fusions) > 100
+    assert len(bare) <= 0.05 * len(fusions), bare
+    paths = {row[0] for row in fusions.values()}
+    # (no ``sample``: the chip fuses the greedy pick INTO the head
+    # product, one ``iota_reduce_fusion`` whose root is ``head``'s)
+    assert paths >= {"mla/attend", "mla/kv_write", "moe/route",
+                     "moe/dispatch", "moe/combine", "moe/shared", "norm",
+                     "head", "acc"}, paths
+
+
 # -- the hybrid state-space / attention family's programs -----------------------
 
 # lanes, state-space heads; d_head 64 and d_state 128 are the published
