@@ -229,13 +229,17 @@ def ssm_read(S, C):
                       C).reshape(b, H, P)
 
 
-def ssm_scan(x, B, C, dt, A, S0, chunk):
+def ssm_scan(x, B, C, dt, A, S0, chunk, slab=False):
     """The recurrence over T positions in its chunked form, float32. x
     [b, T, H, P], B / C [b, T, G, N], dt [b, T, H], A [H], S0 [b, H, P,
     N] the state before the first position. Returns (y [b, T, H, P] with
     ``y_t = S_t C_t``, the ``D x`` term NOT added; S_T). Inside a chunk
     the masked quadratic product, between chunks the carried state; a
-    length that is no multiple of the chunk is padded with ``dt`` 0."""
+    length that is no multiple of the chunk is padded with ``dt`` 0.
+    ``slab``: ``S0`` and ``S_T`` in the serving kernel's layout [b, G, N,
+    H/G x P] (``ops/pallas/ssm_state.py``), in which the state's two
+    products are plain matrix products — the same sums, with no
+    transpose of the state in or out."""
     b, T, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
     R = H // G
@@ -257,20 +261,32 @@ def ssm_scan(x, B, C, dt, A, S0, chunk):
     y = jnp.einsum("bctsgr,bcsgrp->bctgrp", decay * cb[..., None], xs)
     # what each chunk adds to the state at its end, and the carry
     to_end = jnp.exp(cum[:, :, -1:, :] - cum).reshape(b, nc, Q, G, R)
-    add = jnp.einsum("bcsgrp,bcsgn->bcgrpn", xs * to_end[..., None], Bc)
+    grow = xs * to_end[..., None]
+    if slab:
+        add = jnp.einsum("bcsgn,bcsgm->bcgnm", Bc,
+                         grow.reshape(b, nc, Q, G, R * P))
+    else:
+        add = jnp.einsum("bcsgrp,bcsgn->bcgrpn", grow, Bc)
     whole = jnp.exp(cum[:, :, -1, :]).reshape(b, nc, G, R)
+    if slab:  # a head's number over its channels, as the state has them
+        whole = jnp.repeat(whole, P, axis=-1)
 
     def carry(S, inp):
         d, a = inp
-        return S * d[..., None, None] + a, S
+        return S * (d[..., None, :] if slab else d[..., None, None]) + a, S
 
     S_T, starts = jax.lax.scan(
-        carry, S0.reshape(b, G, R, P, N),
+        carry, S0 if slab else S0.reshape(b, G, R, P, N),
         (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(add, 1, 0)))
-    y = y + jnp.einsum(
-        "bctgn,bcgrpn->bctgrp", Cc, jnp.moveaxis(starts, 0, 1)) \
-        * jnp.exp(cum).reshape(b, nc, Q, G, R)[..., None]
-    return (y.reshape(b, nc * Q, H, P)[:, :T], S_T.reshape(b, H, P, N))
+    starts = jnp.moveaxis(starts, 0, 1)
+    if slab:
+        from_start = jnp.einsum("bctgn,bcgnm->bctgm", Cc, starts).reshape(
+            b, nc, Q, G, R, P)
+    else:
+        from_start = jnp.einsum("bctgn,bcgrpn->bctgrp", Cc, starts)
+    y = y + from_start * jnp.exp(cum).reshape(b, nc, Q, G, R)[..., None]
+    return (y.reshape(b, nc * Q, H, P)[:, :T],
+            S_T if slab else S_T.reshape(b, H, P, N))
 
 
 def ssm_gate_out(y, z, lp, cfg):

@@ -627,7 +627,9 @@ def test_latent_program_fusions_carry_a_group(topo, latent_engine, kind,
 # lanes, state-space heads; d_head 64 and d_state 128 are the published
 # sizes, and so is the K/V row of 8 heads x 64: what decides the layouts
 _HYBRID_LANES, _HYBRID_HEADS = 8, 4
-_HYBRID_STATE = (_HYBRID_LANES, _HYBRID_HEADS, 64, 128)
+# a layer's state in the kernel's slab layout (ops/pallas/ssm_state.py):
+# [lanes, groups, d_state, heads x d_head]
+_HYBRID_STATE = (_HYBRID_LANES, 1, 128, _HYBRID_HEADS * 64)
 _HYBRID_KV = (1, 2049, 16, 8 * 64)
 
 
@@ -652,12 +654,17 @@ def hybrid_engine():
         prefill_chunk=32, max_seq_len=20 * 16))
 
 
-def _hybrid_program_names(topo, eng, kind, chunk=None):
-    """The family's program ``kind`` as the chip's compiler leaves it:
-    the entry computation's instructions, each with its operands' shapes
-    as the device trace names its events."""
+def _hybrid_program_names(topo, eng, kind, monkeypatch, chunk=None):
+    """The family's program ``kind`` as the chip's compiler leaves it
+    (the state kernel compiled, not interpreted: the code's one rule for
+    "am I on the chip" is steered here): the entry computation's
+    instructions, each with its operands' shapes as the device trace
+    names its events."""
     from jax._src.lib import xla_client as xc
 
+    import paddle_tpu.framework.device as device
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
     compiled = _compiled_program(topo, eng, kind, chunk)
     opts = xc._xla.HloPrintOptions()
     opts.print_operand_shape = True
@@ -673,54 +680,98 @@ def _dims(shape):
 
 @pytest.mark.parametrize("kind,chunk", _programs())
 def test_hybrid_program_never_copies_a_pool(topo, hybrid_engine, kind,
-                                            chunk):
-    """Device state of three kinds, none of which a program call may
+                                            chunk, monkeypatch):
+    """Device state of four kinds, none of which a program call may
     copy: K and V pools whose last axis is the 8 heads x 64 merged (with
     ``[.., 8, 64]`` the TPU lays the pool out blocks-minor and every call
     copied both pools in and out: 4 x 285 MB at the benchmark's size); a
-    conv pool with a lane's 3 rows side by side; one float32 state array
-    a state-space layer. The prefill chunk too, at both widths: it once
-    copied its LAST state-space layer's array in and out at one pool
-    size (PERF.md section 7 (aa))."""
+    conv pool with a lane's 3 rows side by side; what a verify round
+    leaves for the next call to commit (the small ``B | dt_raw`` pool,
+    written layer by layer in place, and an array of ``x`` planes a
+    state-space layer, which the next call's kernel reads where it lies
+    and a verify round replaces whole — as ONE stacked pool the compiler
+    moved all of it through fast memory and back a layer: PERF.md
+    section 6, PR 37); one float32 state array a state-space layer,
+    which the state kernel takes and returns in ONE buffer. The prefill
+    chunk too, at both widths: it once copied its LAST state-space
+    layer's array in and out at one pool size (PERF.md section 7 (aa))."""
+    from paddle_tpu.serving.families.hybrid_ssm import N_POOLS
+
     eng = hybrid_engine
-    assert eng._pools[0].shape == _HYBRID_KV
-    assert all(p.shape == _HYBRID_STATE for p in eng._pools[4:])
-    names = _hybrid_program_names(topo, eng, kind, chunk)
+    states = eng._pools[N_POOLS:N_POOLS + 2]
+    assert eng._pools[0].shape == _HYBRID_KV and len(eng._pools) \
+        == N_POOLS + 4
+    assert all(p.shape == _HYBRID_STATE for p in states)
+    names = _hybrid_program_names(topo, eng, kind, monkeypatch, chunk)
+    # (a verify round makes its arrays of planes anew: there a ``copy``
+    # to that shape is the compiler's way of writing them)
     pools = "|".join(rf"\w+\[{_dims(p.shape)}\]" for p in
-                     (eng._pools[0], eng._pools[2], eng._pools[4]))
+                     (eng._pools[0], eng._pools[2], eng._pools[4],
+                      states[0], *eng._pools[-1:][:kind != "verify"]))
     copies = [n[:200] for n in names
               if re.search(rf"= ({pools})\S* copy\(", n)]
     assert not copies, "\n".join(copies[:4])
 
 
-def test_hybrid_decode_reads_and_writes_each_state_once(topo,
-                                                        hybrid_engine):
-    """The plain round's state update and the layer's output ``S C`` are
-    ONE fusion with two results a layer — the state read once and written
-    once. On a stacked ``[layers, lanes, ...]`` pool the update is an
-    in-place dynamic-update-slice fusion and the output a second fusion
-    that reads the layer's state again (3 x 134 MB a layer a round where
-    2 are required: PERF.md section 6, PR 31)."""
-    names = _hybrid_program_names(topo, hybrid_engine, "decode")
-    state = rf"f32\[{_dims(_HYBRID_STATE)}\]"
-    out = rf"f32\[{_dims(_HYBRID_STATE[:3])}\]"
-    touching = [n for n in names if re.search(state, n)
-                and re.search(r" (fusion|copy|dynamic-update-slice)\(", n)]
-    both = [n for n in touching
-            if re.search(rf"= \(({out}\S*, {state}|{state}\S*, {out})", n)]
-    assert len(touching) == len(both) == 2, [n[:160] for n in touching]
+# what computes or copies on the chip: fusions, kernels, plain copies
+# and in-place updates (at the test's size the compiler also stages a
+# 1 MB state through fast memory, slice-start / slice-done / copy-start /
+# copy-done around an unnamed concatenating custom-call: a 134 MB state
+# has none, tools/rehearse_latent_serving.py)
+_WORK = re.compile(r"[\s)](fusion|copy|convolution|dynamic-update-slice)\("
+                   r"|custom_call_target=\"tpu_custom_call\"")
 
 
-@pytest.mark.parametrize("kind,per_layer", [("decode", 1), ("verify", 2)])
+def _slab_sized(names, shape):
+    """The working instructions (``_WORK``) that touch a float32 array
+    with as many elements as ``shape``, whatever reshape the compiler
+    made of it."""
+    n = int(np.prod(shape))
+    f32 = re.compile(r"f32\[([\d,]+)\]")
+    return [ln for ln in names if _WORK.search(ln)
+            and any(int(np.prod([int(d) for d in g.split(",")])) == n
+                    for g in f32.findall(ln))]
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_hybrid_round_touches_each_state_in_one_kernel_call(
+        topo, hybrid_engine, kind, monkeypatch):
+    """In a plain round AND in a verify round exactly one instruction a
+    state-space layer touches a slab-sized float32 array: the state
+    kernel's call, with the state aliased in and out (one buffer, read
+    once and written once) and ``ssm/state_update`` in its metadata. No
+    fusion reads the state a second time for the round's outputs and
+    none applies a verify round's accepted positions after the head —
+    they enter the state in the next call's pass (PERF.md section 6,
+    PR 37; before it: one two-result fusion a layer in a plain round,
+    two fusions a layer, 3 x 134 MB, in a verify round)."""
+    names = _hybrid_program_names(topo, hybrid_engine, kind, monkeypatch)
+    touching = _slab_sized(names, _HYBRID_STATE)
+    assert len(touching) == 2, [n[:160] for n in touching]
+    state = rf"f32\[{_dims(_HYBRID_STATE[0:1] + _HYBRID_STATE[2:])}\]"
+    for n in touching:
+        assert re.search(r"[\s)]custom-call\(", n) \
+            and 'custom_call_target="tpu_custom_call"' in n, n[:200]
+        assert "ssm/state_update" in n and "ssm_state_round" in n, n[:300]
+        # the call's second result IS its last operand's buffer
+        assert re.match(rf"%\S+ = \(f32\[[\d,]+\]\S*, {state}", n), n[:200]
+        aliased = re.search(
+            r"output_to_operand_aliasing=\{\{1\}: \((\d+), \{\}\)\}", n)
+        operands = re.findall(r"%[\w.\-]+", n[n.index("custom-call("):n.index(
+            "), custom_call_target")])
+        assert aliased and int(aliased.group(1)) == len(operands) - 1, \
+            n[:400]
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
 def test_ssm_update_reader_picks_the_state_and_nothing_else(
-        topo, hybrid_engine, kind, per_layer):
+        topo, hybrid_engine, kind, monkeypatch):
     """The benchmark's ``ssm_update_roofline`` picks operations by the
-    state's shape in their instruction text (the device trace's events
-    are named by it). Held here to the compiled programs' own
-    instructions: a layer's one read-and-write fusion in a plain round; in
-    a verify round the product that reads the state for the round's
-    outputs and the update that applies what was accepted; nothing of the
-    attention layer, the MLPs or the head."""
+    state's element count in their instruction text (the device trace's
+    events are named by it). Held here to the compiled programs' own
+    instructions: a layer's one kernel call in a plain round and in a
+    verify round, nothing of the attention layer, the MLPs or the
+    head."""
     import importlib.util
     import sys
 
@@ -734,30 +785,83 @@ def test_ssm_update_reader_picks_the_state_and_nothing_else(
         spec.loader.exec_module(reader)
     finally:
         sys.path.pop(0)
-    names = _hybrid_program_names(topo, hybrid_engine, kind)
+    names = _hybrid_program_names(topo, hybrid_engine, kind, monkeypatch)
     m = {"mamba_n_heads": _HYBRID_HEADS, "mamba_d_head": 64,
          "mamba_d_state": 128}
     picked = reader.pattern(names, _HYBRID_LANES, m, 2)
-    assert rf"f32\[{_dims(_HYBRID_STATE)}\]" in picked.replace("\\,", ",")
-    # (at this size the compiler also prefetches slices of a state into
-    # fast memory, slice-start / slice-done: a 134 MB state has none)
-    hit = [n for n in names if re.search(picked, n)
-           and re.search(r"[\s)]fusion\(", n)]
-    assert len(hit) == 2 * per_layer, [n[:200] for n in hit]
-    assert all("ssm/state_update" in n for n in hit), \
-        [n[:200] for n in hit if "ssm/state_update" not in n]
+    assert picked is not None
+    ops = _WORK
+    hit = [n for n in names if re.search(picked, n) and ops.search(n)]
+    assert len(hit) == 2, [n[:200] for n in hit]
+    assert all("ssm/state_update" in n and "ssm_state_round" in n
+               for n in hit), [n[:200] for n in hit]
     # a trace holds the prefill chunk's events too: with a chunk of the
     # default width among the names (no ``[W, ...]`` intermediate of it
     # has a slab's element count) the round's picks are the same
     wide = _hybrid_program_names(topo, hybrid_engine, "prefill",
-                                 PREFILL_CHUNK)
+                                 monkeypatch, PREFILL_CHUNK)
     assert any(re.search(rf"\[1,{PREFILL_CHUNK},", n) for n in wide)
     with_chunk = reader.pattern(names + wide, _HYBRID_LANES, m, 2)
     assert [n for n in names if re.search(with_chunk, n)
-            and re.search(r"[\s)]fusion\(", n)] == hit
+            and ops.search(n)] == hit
     # a program without a state (the parent's): nothing to read
-    assert reader.pattern([n for n in names if ",64,128]" not in n],
+    assert reader.pattern([n for n in names
+                           if f",128,{_HYBRID_HEADS * 64}]" not in n],
                           _HYBRID_LANES, m, 2) is None
+
+
+def test_state_kernel_compiles_at_the_served_sizes(topo, monkeypatch):
+    """The state kernel at the benchmark cell's sizes — 64 lanes, 64
+    heads x 64, state 128, a verify round's 5 owed (the last layer's
+    gains and ``B`` rows, its ``x`` planes in bfloat16) and 5 read
+    positions with their scale and mix, and a plain round's 5 + 1 owed
+    and 1 read — compiled by Mosaic for the described chip: the 2 MB
+    block a lane fits its stated VMEM, the in-kernel transposes, lane
+    broadcasts and the chunk's slices at a computed lane offset lower,
+    and the state is aliased (no second 134 MB buffer: temporaries
+    0)."""
+    import paddle_tpu.framework.device as device
+    from paddle_tpu.ops.pallas import ssm_state
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, H, P, N, T, layers = 64, 64, 64, 128, 5, 36
+
+    def rows(a):
+        return ssm_state.head_rows(a, 1, P)
+
+    def planes(t):
+        return sd(L, t, 1, 32, 128, dtype=jnp.bfloat16)
+
+    owed = (sd(layers, L, 1 + T, H), planes(T), sd(layers, L, 1, 8, N))
+
+    def verify(S, C, g, x, B, scale, mix, x_now):
+        return ssm_state.state_round(
+            S, [ssm_state.Commit(rows(g), x, B, 35)], C,
+            scale=rows(scale), mix=(rows(mix), x_now))
+
+    def decode(S, C, g, x, B, g1, x1, B1, D):
+        own = ssm_state.Commit(rows(g1)[None], x1,
+                               ssm_state.b_rows(B1)[None])
+        return ssm_state.state_round(
+            S, [ssm_state.Commit(rows(g), x, B, 35), own], C,
+            mix=(rows(D), x1))
+
+    slab = sd(*ssm_state.slab_shape(L, H, P, N, 1))
+    for fn, args in (
+            (verify, (slab, sd(L, T, 1, N), *owed, sd(L, T, H),
+                      sd(L, T * T, H), planes(T))),
+            (decode, (slab, sd(L, 1, 1, N), *owed, sd(L, 2, H), planes(1),
+                      sd(L, 1, 1, N), sd(L, 1, H)))):
+        compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == L * H * P * N * 4
+        assert mem.temp_size_in_bytes < 32 << 20
 
 
 # -- the linear-attention / latent-attention family's programs ------------------

@@ -11,6 +11,7 @@ cannot show it). The program runs in float32 here, so what separates it
 from the float32 reference is the order of summation: ``TOL`` = 2e-4 on
 logits of magnitude ~1.
 """
+import functools
 import importlib.util
 import os
 
@@ -24,7 +25,9 @@ from paddle_tpu.framework.errors import UnimplementedError
 from paddle_tpu.models import (
     HybridSSMConfig, HybridSSMForCausalLM, generate, hybrid_ssm,
 )
+from paddle_tpu.ops.pallas import ssm_state
 from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.serving.families import hybrid_ssm as family
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 2e-4
@@ -214,6 +217,150 @@ def test_generate_raises_and_names_the_family(model):
                  max_new_tokens=2)
 
 
+# -- the one-pass state kernel (interpret mode) against the step recurrence ------
+
+def _kernel_case(T, reads, n_keep, seed=0, b=3):
+    """Inputs of one kernel call: a state, ``T`` owed positions of which
+    each row keeps ``n_keep[row]``, ``reads`` read vectors."""
+    c = tiny_config()
+    H, P, N, G = (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
+                  c.mamba_n_groups)
+    x, B, _, dt, A, S0 = _scan_inputs(T, seed=seed, b=b)
+    rng = np.random.default_rng(seed + 1)
+    C = jnp.asarray(rng.normal(0, 1, (b, reads, G, N)), jnp.float32)
+    kept = jnp.arange(T)[None, :] < jnp.asarray(n_keep)[:, None]
+    return (H, P, G), x, B, C, jnp.where(kept[..., None], dt, 0.0), A, S0
+
+
+def _commit(x, B, dt, A, G):
+    """The positions as the kernel takes them: their gains and their
+    ``B`` rows as arrays of one layer, their ``x`` as planes."""
+    return ssm_state.Commit(
+        ssm_state.head_rows(ssm_state.gains(dt, A), G, x.shape[-1])[None],
+        ssm_state.x_planes(x, G), ssm_state.b_rows(B)[None])
+
+
+@pytest.mark.parametrize("n_keep", range(6))
+@pytest.mark.parametrize("reads", [1, 5])
+def test_state_kernel_equals_the_step_recurrence(reads, n_keep):
+    """5 owed positions of which a row keeps ``n_keep`` (its neighbours
+    another number), then ``reads`` read vectors: the state the kernel
+    writes is ``ssm_step`` over the kept positions, its outputs
+    ``ssm_read`` of that state. With one read (a plain round) the
+    round's own position is committed in the same pass and the read
+    comes from the result."""
+    keeps = [n_keep, (n_keep + 2) % 6, 5 - n_keep]
+    (H, P, G), x, B, C, dt, A, S0 = _kernel_case(5, reads, keeps,
+                                                 seed=10 * reads + n_keep)
+    owed = [_commit(x, B, dt, A, G)]
+    S = S0
+    for t in range(5):
+        S = hybrid_ssm.ssm_step(S, x[:, t], B[:, t], dt[:, t], A)
+    if reads == 1:  # the plain round's own position
+        _, x1, B1, _, dt1, _, _ = _kernel_case(1, 1, [1, 1, 1], seed=99)
+        owed.append(_commit(x1, B1, dt1, A, G))
+        S = hybrid_ssm.ssm_step(S, x1[:, 0], B1[:, 0], dt1[:, 0], A)
+    Y, Sn = ssm_state.state_round(ssm_state.to_slab(S0, G), owed, C)
+    want = jnp.stack([hybrid_ssm.ssm_read(S, C[:, t])
+                      for t in range(reads)], 1)
+    np.testing.assert_allclose(ssm_state.from_slab(Sn, H, P), S, rtol=2e-5,
+                               atol=2e-6)
+    np.testing.assert_allclose(Y.reshape(want.shape), want, rtol=2e-5,
+                               atol=2e-5)
+    assert np.abs(np.asarray(S - S0)).max() > 1e-2 or max(keeps) == 0
+
+
+@pytest.mark.parametrize("reads", [1, 5])
+def test_state_kernel_with_nothing_owed_is_the_identity(reads):
+    """A lane that owes nothing (``dt`` 0 at every owed position) gets
+    its state back BIT FOR BIT, whatever the owed positions' inputs hold
+    — a verify round's rejected drafts, a finished request's last round —
+    and reads from exactly that state."""
+    (H, P, G), x, B, C, dt, A, S0 = _kernel_case(5, reads, [0, 0, 0],
+                                                 seed=reads)
+    slab = ssm_state.to_slab(S0, G)
+    Y, Sn = ssm_state.state_round(slab, [_commit(x, B, dt, A, G)], C)
+    assert (np.asarray(Sn) == np.asarray(slab)).all()
+    Y2, Sn2 = ssm_state.state_round(
+        slab, [_commit(7 * x + 1, B[:, ::-1], dt, A, G)], C)
+    assert (np.asarray(Sn2) == np.asarray(slab)).all()
+    assert (np.asarray(Y2) == np.asarray(Y)).all()
+    want = jnp.stack([hybrid_ssm.ssm_read(S0, C[:, t])
+                      for t in range(reads)], 1)
+    np.testing.assert_allclose(Y.reshape(want.shape), want, rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_slab_layout_round_trips():
+    c = tiny_config()
+    _, _, _, _, _, S0 = _scan_inputs(1)
+    slab = ssm_state.to_slab(S0, c.mamba_n_groups)
+    assert slab.shape == ssm_state.slab_shape(
+        2, c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
+        c.mamba_n_groups)
+    back = ssm_state.from_slab(slab, c.mamba_n_heads, c.mamba_d_head)
+    assert (np.asarray(back) == np.asarray(S0)).all()
+    # at the published sizes: a lane's 64 heads x 64 side by side, which
+    # the kernel goes through in 32 chunks of a register's 128 lanes
+    assert ssm_state.slab_shape(64, 64, 64, 128, 1) == (64, 1, 128, 4096)
+    assert ssm_state.x_planes(jnp.zeros((64, 5, 64, 64)), 1).shape \
+        == (64, 5, 1, 32, 128)
+
+
+@pytest.mark.parametrize("T", [5, 13, 37])
+def test_chunked_form_on_a_slab_equals_the_models(T):
+    """The prefill chunk's scan reads and writes the state in the
+    kernel's layout: the same outputs and the same state as the model's
+    own, from a non-zero carried state, over one chunk and several."""
+    c = tiny_config()
+    x, B, C, dt, A, S0 = _scan_inputs(T, seed=T)
+    y, S = hybrid_ssm.ssm_scan(x, B, C, dt, A, S0, 8)
+    ys, Ss = hybrid_ssm.ssm_scan(
+        x, B, C, dt, A, ssm_state.to_slab(S0, c.mamba_n_groups), 8,
+        slab=True)
+    assert Ss.shape == ssm_state.slab_shape(
+        2, c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
+        c.mamba_n_groups)
+    np.testing.assert_allclose(ys, y, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        ssm_state.from_slab(Ss, c.mamba_n_heads, c.mamba_d_head), S,
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n_keep", [0, 3, 5])
+def test_a_verify_rounds_outputs_are_the_step_recurrences(n_keep):
+    """The verify round's call: 5 owed positions of which a row keeps
+    ``n_keep``, then this round's 5 positions read with the family's
+    scale and mix — ``y_t = S_t C_t + D x_t`` of the step recurrence
+    advanced over the round's positions from the committed state, of
+    which advance the kernel writes NOTHING."""
+    keeps = [n_keep, (n_keep + 2) % 6, 5 - n_keep]
+    (H, P, G), x, B, _, dt, A, S0 = _kernel_case(5, 5, keeps, seed=n_keep)
+    _, x2, B2, C2, dt2, _, _ = _kernel_case(5, 5, [5, 5, 5],
+                                            seed=70 + n_keep)
+    x2 = x2.astype(jnp.bfloat16)
+    D = jnp.linspace(0.5, 1.5, H)
+    S = S0
+    for t in range(5):
+        S = hybrid_ssm.ssm_step(S, x[:, t], B[:, t], dt[:, t], A)
+    committed, want = S, []
+    for t in range(5):
+        xt = x2[:, t].astype(jnp.float32)
+        S = hybrid_ssm.ssm_step(S, xt, B2[:, t], dt2[:, t], A)
+        want.append(hybrid_ssm.ssm_read(S, C2[:, t]) + D[:, None] * xt)
+    cum = jnp.cumsum(dt2 * A, axis=1)
+    rows = functools.partial(ssm_state.head_rows, groups=G, d_head=P)
+    Y, Sn = ssm_state.state_round(
+        ssm_state.to_slab(S0, G), [_commit(x, B, dt, A, G)], C2,
+        scale=rows(jnp.exp(cum)),
+        mix=(rows(family._own_mix(B2, C2, dt2, cum, D)),
+             ssm_state.x_planes(x2, G)))
+    np.testing.assert_allclose(ssm_state.from_slab(Sn, H, P), committed,
+                               rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(Y.reshape(3, 5, H, P), jnp.stack(want, 1),
+                               rtol=1e-4, atol=1e-4)
+
+
 # -- through ServingEngine ------------------------------------------------------
 
 GEOM = dict(max_lanes=3, block_size=4, prefill_chunk=8, max_seq_len=96)
@@ -277,11 +424,42 @@ class Oracle:
         return d[:k]
 
 
-def lane_state(eng, lane=0):
-    """(state [state-space layers, H, P, N], conv tail) of one lane."""
-    return (np.stack([np.asarray(s[lane]) for s in eng._pools[4:]]),
-            np.asarray(eng._pools[2][:, lane]).reshape(len(eng._pools) - 4,
-                                                       3, -1))
+def lane_owes(eng, lane=0):
+    """Positions of its last verify round the lane's state still owes."""
+    return int(eng._pools[5][lane])
+
+
+def lane_state(eng, lane=0, owed=True):
+    """(state [state-space layers, H, P, N], conv tail) of one lane: what
+    its state-space layers HOLD — each layer's slab with the positions
+    the lane still owes applied (``owed=False``: the slab alone), by the
+    step recurrence under the program's mask."""
+    fam = eng._family
+    cfg, pools = fam.gcfg, eng._pools
+    states = pools[family.N_POOLS:family.N_POOLS + fam.n_ssm]
+    pend_x = pools[family.N_POOLS + fam.n_ssm:]
+    lps = [lp for kind, lp in zip(cfg.layer_types, fam.params["layers"])
+           if kind == hybrid_ssm.SSM]
+    out = []
+    for si, (slab, lp) in enumerate(zip(states, lps)):
+        S = ssm_state.from_slab(slab[lane:lane + 1], cfg.mamba_n_heads,
+                                cfg.mamba_d_head)
+        # the pending arrays hold the convolved x (as the kernel's
+        # planes) and B | dt_raw of the lane's last verify round:
+        # ssm_inputs wants x | B | C side by side (the C it returns is
+        # not used)
+        B, dt_raw = ssm_state.pending_rows(
+            pools[4][si, lane][None], cfg.mamba_n_heads, cfg.mamba_d_state,
+            cfg.mamba_n_groups)
+        B = B.reshape(1, B.shape[1], -1)
+        c = jnp.concatenate(
+            [pend_x[si][lane].reshape(1, B.shape[1], -1), B, B], axis=-1)
+        x, B, _, dt, A = hybrid_ssm.ssm_inputs(c, dt_raw, lp, cfg)
+        for t in range(lane_owes(eng, lane) if owed else 0):
+            S = hybrid_ssm.ssm_step(S, x[:, t], B[:, t], dt[:, t], A)
+        out.append(np.asarray(S[0]))
+    return (np.stack(out),
+            np.asarray(pools[2][:, lane]).reshape(len(states), 3, -1))
 
 
 def run_until(eng, req, n_out):
@@ -311,13 +489,15 @@ def plain_run(model):
 @pytest.mark.parametrize("a", range(K + 1))
 def test_rejected_drafts_leave_no_trace_in_the_state(model, plain_run, a):
     """A verify round whose draft is right for ``a`` of ``k`` tokens: the
-    lane emits ``a + 1`` tokens, and its state and conv tail are BIT FOR
+    lane emits ``a + 1`` tokens and owes its state ``a + 1`` positions;
+    what it HOLDS (slab + owed positions) and its conv tail are BIT FOR
     BIT what the same round leaves with other rejected tokens, or with
     the ``a`` right tokens alone — nothing of a rejected position is in
     them — and equal plain decoding's after as many tokens up to the
-    order of summation (the round reads the state once, in the chunked
-    form over its positions, which is not the step recurrence bit for
-    bit). Every later token is plain decoding's."""
+    order of summation. ONE CALL LATER the slab itself holds them (the
+    next round's pass committed what was owed, in its closed form, then
+    its own position), and the lane owes nothing. Every later token is
+    plain decoding's."""
     prompt, truth, states = plain_run
     seq = np.concatenate([prompt, truth])
     at = prompt.size + 3  # the round after 3 emitted tokens
@@ -327,7 +507,17 @@ def test_rejected_drafts_leave_no_trace_in_the_state(model, plain_run, a):
         req = eng.submit(prompt, max_new_tokens=16)
         got = run_until(eng, req, 3 + a + 1)
         assert eng.counters["verify_steps"] == 1
+        assert lane_owes(eng, req.lane) == a + 1
+        # the slab alone is still the state BEFORE the round
+        np.testing.assert_allclose(lane_state(eng, req.lane, owed=False)[0],
+                                   states[3][0], rtol=2e-5, atol=1e-6)
         rolled = eng.counters["spec_rolled_back_tokens"]
+        eng.step()  # a plain round: commits what is owed, then its own
+        assert lane_owes(eng, req.lane) == 0
+        for got1, want1 in zip(lane_state(eng, req.lane, owed=False),
+                               states[3 + a + 2]):
+            np.testing.assert_allclose(got1, want1, rtol=2e-5, atol=1e-6)
+        assert eng.counters["ssm_deferred_positions"] == a + 1
         eng.run()
         assert (np.asarray(req.output) == truth).all()
         # the engine's acceptance and the program's agree
@@ -372,6 +562,38 @@ def test_a_reused_lane_gives_what_a_fresh_engine_gives(model):
     for got, want in zip(lane_state(eng, 0), lane_state(fresh, 0)):
         assert (got == want).all()
     assert eng.stats()["ssm_slot_resets"] == 2
+
+
+def test_a_prefilling_lane_never_carries_its_predecessors_pending_round(
+        model, plain_run):
+    """One lane, two requests. The first one's LAST round is a verify
+    round, so it finishes owing its state 4 positions, which nothing
+    applies; the second's first chunk zeroes the lane's count with the
+    slot, its rounds start from what its prefill left, and it is served
+    what a fresh engine serves."""
+    prompt, truth, _ = plain_run
+    seq = np.concatenate([prompt, truth])
+    second = prompts(1, seed=23)[0]
+    eng = engine(model, Oracle(seq, prompt.size + 6, 3, 0), max_lanes=1,
+                 spec_k=K)
+    first = eng.submit(prompt, max_new_tokens=10)
+    eng.run()
+    assert first.output == list(truth[:10])
+    assert eng.counters["verify_steps"] == 1 and lane_owes(eng) == 4
+    r2 = eng.submit(second, max_new_tokens=10)
+    eng.step()  # its prefill, and nothing else yet
+    assert lane_owes(eng) == 0
+    fresh = engine(model, max_lanes=1, spec=False)
+    f2 = fresh.submit(second, max_new_tokens=10)
+    fresh.step()
+    for got, want in zip(lane_state(eng, owed=False),
+                         lane_state(fresh, owed=False)):
+        assert (got == want).all()
+    eng.run()
+    fresh.run()
+    assert r2.output == f2.output
+    # the predecessor's 4 positions were never committed
+    assert eng.counters["ssm_deferred_positions"] == 0
 
 
 @pytest.mark.parametrize("chunk", [8, 128], ids=["chunk8", "chunk128"])
@@ -517,8 +739,13 @@ def test_speculation_is_token_identical_to_plain_decoding(model):
     assert st["verify_steps"] > 0
     assert st["spec_rolled_back_tokens"] \
         == st["spec_proposed_tokens"] - st["spec_accepted_tokens"] > 0
+    # one pass through the state a round, plain or verify; every kept
+    # position of a verify round entered the state a call late
     assert st["ssm_state_passes"] \
-        == st["decode_steps"] + 2 * st["verify_steps"]
+        == st["decode_steps"] + st["verify_steps"]
+    assert st["ssm_state_lane_moves"] == 2 * st["ssm_lane_rounds"]
+    assert 0 < st["ssm_deferred_positions"] \
+        <= st["spec_accepted_tokens"] + st["ssm_lane_rounds"]
 
 
 def test_stats_tell_pools_by_kind(model):
@@ -529,8 +756,12 @@ def test_stats_tell_pools_by_kind(model):
     state = n_ssm * c.mamba_n_heads * c.mamba_d_head * c.mamba_d_state * 4
     tail = n_ssm * 3 * c.conv_dim * 4  # float32 here
     assert st["family"] == "hybrid_ssm"
+    owed = n_ssm * 5 * (c.d_inner + c.mamba_n_groups * c.mamba_d_state
+                        + c.mamba_n_heads) * 4 + 4
     assert st["ssm_state_bytes_per_lane"] == state
-    assert st["lane_pool_bytes"] == GEOM["max_lanes"] * (state + tail)
+    assert st["ssm_pending_bytes_per_lane"] == owed
+    assert st["lane_pool_bytes"] == GEOM["max_lanes"] * (state + tail
+                                                         + owed)
     blocks = eng.scheduler.pool.num_blocks
     assert st["kv_pool_bytes"] == 1 * 2 * blocks * 4 * 2 * 16 * 4
     assert st["device_state_bytes"] \

@@ -163,9 +163,19 @@ def test_every_instruction_falls_to_a_declared_group(texts, kind):
     assert len(unscoped) <= max(0.02 * len(work), LOOP_BOOKKEEPING), \
         unscoped
     # the program's own lines wear their scopes themselves: what falls to
-    # a group by dataflow alone is what XLA inserted
+    # a group by dataflow alone is what XLA inserted. One exception, in
+    # the hybrid family alone: its state kernel runs INTERPRETED on the
+    # CPU, as loops around which this backend puts ``copy`` instructions
+    # that fall to the kernel's scope by dataflow (97 of the decode
+    # program's 345 rows, 89 of verify's 402; 4 of the kernel-less
+    # prefill's 197 share the scope; none in the three other families,
+    # which have no such scope, and none on the chip, where the kernel is
+    # one custom call): those are not counted
+    kernel_copies = sum(
+        row[3] == "copy" and row[0] == "ssm/state_update"
+        and row[4] in ("user", "operand") for row in work.values())
     own = sum(row[4] in ("own", "fused") for row in work.values())
-    assert own >= 0.7 * len(work), (own, len(work))
+    assert own >= 0.7 * (len(work) - kernel_copies), (own, len(work))
     # and nothing the program issued outside a loop is left without one
     stray = [name for name, row in work.items() if row[4] == "none"]
     assert len(stray) <= LOOP_BOOKKEEPING, stray

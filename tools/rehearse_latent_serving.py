@@ -69,12 +69,15 @@ def _latent(arch, cfg, layers, s, sds, i32, geom):
 
 def _hybrid(arch, cfg, layers, s, sds, i32, geom):
     """The same of ``serving/families/hybrid_ssm.py``: K and V pools by
-    block for the attention layers, a conv pool and one float32 state
-    array a state-space layer by LANE, the dense family's live-rows read (the prefill chunk's with
-    its state slot)."""
+    block for the attention layers, a conv pool, the small pending pool
+    with the lanes' counts, and one float32 state array (the kernel's
+    slab layout) and one array of pending planes a state-space layer by
+    LANE, the dense family's live-rows read (the prefill chunk's with its
+    state slot)."""
     import jax.numpy as jnp
 
     from paddle_tpu.models import HybridSSMConfig
+    from paddle_tpu.ops.pallas import ssm_state
     from paddle_tpu.serving.families import hybrid_ssm as fam
 
     L, B, C, K, M = geom
@@ -83,10 +86,15 @@ def _hybrid(arch, cfg, layers, s, sds, i32, geom):
     n_ssm = sum(k == "mamba" for k in g.layer_types)
     kv = sds((layers - n_ssm, s["num_blocks"], B,
               g.num_key_value_heads * g.head_dim))
+    sizes = (g.mamba_n_heads, g.mamba_d_head, g.mamba_d_state,
+             g.mamba_n_groups)
+    planes, small = ssm_state.pending_shapes(L, K + 1, *sizes)
     pools = (kv, kv, sds((n_ssm, L, (g.mamba_d_conv - 1) * g.conv_dim)),
-             sds((len(fam.ACC),), jnp.int32),
-             *(sds((L, g.mamba_n_heads, g.mamba_d_head, g.mamba_d_state),
-                   jnp.float32) for _ in range(n_ssm)))
+             sds((len(fam.ACC),), jnp.int32), sds((n_ssm, *small)),
+             sds((L,), jnp.int32),
+             *(sds(ssm_state.slab_shape(L, *sizes), jnp.float32)
+               for _ in range(n_ssm)),
+             *(sds(planes) for _ in range(n_ssm)))
 
     statics, reads = _row_reads(
         lambda kind: (fam.ROW_BLOCKS, fam.PREFILL_TILE if kind == "prefill"
