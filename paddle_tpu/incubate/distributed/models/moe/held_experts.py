@@ -19,7 +19,8 @@ whose row groups are the experts' token lists; chosen over
 expert by measurement on the chip, PERF.md section 6, PR 27); sigmoid
 scores, top-k
 normalised over ALL chosen experts (held or not) times a scaling factor;
-SwiGLU experts; a shared expert every token passes through.
+SwiGLU experts; a shared expert every token passes through, or none
+(``n_shared=0``: no shared leaves, no ``moe/shared`` work in the program).
 
 Everything is a pure function of arrays, so the serving step programs
 (``serving/families/latent_moe.py``) and the model's ``forward`` (one
@@ -139,16 +140,18 @@ def held_experts(u, idx, g, w_gate_up, w_down, first_held, valid=None):
 
 def sparse_expert_block(u, p, *, top_k, scaling, first_held, valid=None):
     """The whole expert layer on normed tokens ``u`` [T, H]: route, the
-    held experts' share, plus the shared expert (whole, on every chip).
-    ``p``: ``router`` [H, E], ``experts_gate_up``, ``experts_down``,
-    ``shared_gate_up``, ``shared_down`` and, where the router has one,
-    its selection bias ``router_bias`` [E]. Returns (y, counts)."""
+    held experts' share, plus the shared expert (whole, on every chip)
+    where the layer has one. ``p``: ``router`` [H, E], ``experts_gate_up``,
+    ``experts_down``, ``shared_gate_up`` and ``shared_down`` (a layer
+    without a shared expert has neither leaf) and, where the router has
+    one, its selection bias ``router_bias`` [E]. Returns (y, counts)."""
     idx, g = route_top_k(u, p["router"], top_k, scaling,
                          p.get("router_bias"))
     y, counts = held_experts(u, idx, g, p["experts_gate_up"],
                              p["experts_down"], first_held, valid)
-    with jax.named_scope("moe/shared"):
-        y = y + swiglu(u, p["shared_gate_up"], p["shared_down"])
+    if "shared_gate_up" in p:
+        with jax.named_scope("moe/shared"):
+            y = y + swiglu(u, p["shared_gate_up"], p["shared_down"])
     return y, counts
 
 
@@ -156,7 +159,8 @@ class HeldExperts(Layer):
     """``sparse_expert_block`` as a layer: ``router_experts`` experts are
     routed over, ``n_held`` of them (``first_held`` on) live here, stacked
     ``[n_held, in, out]``; ``n_shared`` shared experts are one SwiGLU of
-    ``n_shared * width``. ``selection_bias`` adds the router's per-expert
+    ``n_shared * width`` (``n_shared=0``: a layer without one, and without
+    its two leaves). ``selection_bias`` adds the router's per-expert
     ``router_bias`` (born zero; ``route_top_k`` says what it does).
     ``forward`` returns the output; the call's per-held-expert assignment
     counts are left on ``last_counts``."""
@@ -183,14 +187,18 @@ class HeldExperts(Layer):
             [n_held, hidden, 2 * width], default_initializer=init)
         self.experts_down = self.create_parameter(
             [n_held, width, hidden], default_initializer=init)
-        self.shared_gate_up = self.create_parameter(
-            [hidden, 2 * n_shared * width], default_initializer=init)
-        self.shared_down = self.create_parameter(
-            [n_shared * width, hidden], default_initializer=init)
+        names = ["router", "experts_gate_up", "experts_down"]
+        if n_shared:  # else no zero-width product is left in the program
+            self.shared_gate_up = self.create_parameter(
+                [hidden, 2 * n_shared * width], default_initializer=init)
+            self.shared_down = self.create_parameter(
+                [n_shared * width, hidden], default_initializer=init)
+            names += ["shared_gate_up", "shared_down"]
         if selection_bias:
             self.router_bias = self.create_parameter(
                 [router_experts], default_initializer=I.Constant(0.0))
-            self._NAMES = HeldExperts._NAMES + ("router_bias",)
+            names.append("router_bias")
+        self._NAMES = tuple(names)
         self.last_counts = None
 
     def arrays(self):

@@ -3,7 +3,7 @@ the benchmark configs in BASELINE.md name Llama, BERT, ResNet, ERNIE —
 they live in-tree here so the framework is benchmarkable standalone)."""
 from . import (  # noqa: F401
     bert, ernie, generation, hybrid_ssm, latent_moe, linear_latent_moe,
-    llama,
+    llama, window_moe,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertForMaskedLM, BertForSequenceClassification, BertModel,
@@ -21,6 +21,7 @@ from .linear_latent_moe import (  # noqa: F401
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaForCausalLMPipe, LlamaModel,
 )
+from .window_moe import WindowMoEConfig, WindowMoEForCausalLM  # noqa: F401
 
 __all__ = [
     "llama", "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
@@ -32,6 +33,7 @@ __all__ = [
     "hybrid_ssm", "HybridSSMConfig", "HybridSSMForCausalLM",
     "linear_latent_moe", "LinearLatentMoEConfig",
     "LinearLatentMoEForCausalLM",
+    "window_moe", "WindowMoEConfig", "WindowMoEForCausalLM",
     "ernie", "ErnieConfig", "ErnieModel", "ErnieForPretraining",
     "ErnieForPretrainingPipe", "ErnieForSequenceClassification",
 ]

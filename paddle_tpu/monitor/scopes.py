@@ -54,7 +54,7 @@ GROUPS = {
 # anywhere (``head/norm``, ``mla/q/norm``) and falls to its outermost
 # scope's group.
 SCOPES = {
-    "attn": ("qkv", "kv_write", "rows", "out"),
+    "attn": ("qkv", "rope", "kv_write", "rows", "window", "out"),
     "mla": ("q", "kv_write", "attend", "out"),
     "mlp": (),
     "moe": ("route", "dispatch", "experts", "combine", "shared"),

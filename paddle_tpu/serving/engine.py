@@ -11,7 +11,8 @@ hold and not every slot of every lane's table (every family since PR
 35) — or a ``[lanes, M]`` block table (a family whose ``read_form`` is
 ``None``: none is left, ROADMAP C). A family that also keeps state per
 LANE (``lane_state``: a recurrent state and conv tail, the hybrid
-state-space family's and the linear-attention family's) has its one-lane
+state-space family's and the linear-attention family's; a ring of a
+window layer's last keys, the window-attention family's) has its one-lane
 prefill chunk told which lane the request holds, whichever form its read
 takes. Three compiled programs serve the whole lifetime:
 
@@ -50,8 +51,9 @@ takes in a layer, how the weights are collected — are the model's
 FAMILY's (``serving/families``: the dense grouped-query decoder whose
 outputs are token-identical to per-request ``generate()`` calls, the
 latent-attention sparse-expert decoder, the hybrid state-space /
-attention decoder); this module is what every family shares and names no
-architecture.
+attention decoder, the linear-attention and the window-attention
+sparse-expert decoders); this module is what every family shares and
+names no architecture.
 
 Reference lineage: the static-graph serving surface this replaces is
 `paddle_infer.Predictor` (`paddle/fluid/inference/api/
@@ -827,7 +829,17 @@ class ServingEngine:
           terms the family's recurrence has one (a step size of 0; a
           log-decay and a correction strength of 0). After the round
           the lane's slot holds the state after the pending token and
-          the accepted drafts, nothing else."""
+          the accepted drafts, nothing else.
+        - LANE-indexed, a WINDOW of the lane's own K/V (a ring of ``R``
+          slots, position ``p`` in slot ``p mod R``: the
+          window-attention family): nothing is folded, so nothing needs
+          undoing — a rejected position ``c + j'`` sits in a slot that
+          every query at ``t >= c + j`` with ``j < j'`` reads as position
+          ``c + j' - R``, outside a band of ``W`` positions as long as
+          ``R >= W + k`` (``k`` = ``spec_k``), and the next accepted
+          write to that slot comes before the band reaches it. A masked
+          position (pad of a short draft, an idle lane) is not written.
+          The family checks the inequality when it sizes its rings."""
         L, K = self.config.max_lanes, self.config.spec_k
         with self._phase("pack", "pack_s") as ph:
             cur = np.zeros((L,), np.int32)

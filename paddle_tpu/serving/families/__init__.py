@@ -28,19 +28,26 @@ and asks the model's *family* for the three things that differ:
 Two more kinds of state than (a) may live in a family, both told to the
 engine by attributes: ``lane_state`` — besides its token-indexed pools the
 family keeps pools indexed by LANE (``[layers, lanes, ...]``: a recurrent
-state, a conv tail; ``lane_pool_bytes(pools)`` their size, 0 elsewhere).
+state, a conv tail, or a WINDOW of the lane's own K/V — a ring of a
+window-attention layer's last positions, ``families/window_moe.py``;
+``lane_pool_bytes(pools)`` their size, 0 elsewhere).
 Decode and verify index them by the batch row; the one-lane prefill chunk
 is told its request's lane, the STATE SLOT, as the last entry of its read
 operand — ``(rows, wblk, slot [1])`` in the rows form, ``(table, slot
 [1])`` where the family reads by block table (``read_form`` ``None``) —
-and a chunk at position 0 starts the slot from zero, so an admitted or
-re-admitted request never sees its predecessor's. Such a family's verify
-program owes the engine the rollback contract of
+and a chunk at position 0 starts the slot from zero (a ring: empty), so
+an admitted or re-admitted request never sees its predecessor's. Such a
+family's verify program owes the engine the rollback contract of
 ``ServingEngine._verify_round``: a masked position is the identity on
-the family's lane state. ``prefix_reuse`` —
+the family's lane state. For a ring of ``R`` slots (position ``p`` in
+slot ``p mod R``) under a window of ``W`` positions and ``k`` drafts a
+round that is an inequality, not an update: with ``R >= W + k`` what a
+rejected draft wrote reads, to every later query, as a position outside
+the band, and is overwritten before the band reaches it (the family
+takes ``R >= W + k + 1``). ``prefix_reuse`` —
 False where a request cannot start from a prefix's blocks alone (it
-would need the recurrent state at that boundary): the engine then has
-the scheduler acquire none, and ``stats()`` says so.
+would need the recurrent state, or the ring, at that boundary): the
+engine then has the scheduler acquire none, and ``stats()`` says so.
 
 Plus ``absorb(out, counters)``: the round's ONE fetched array goes
 through it — a family that rides its own counters on that array strips

@@ -73,7 +73,8 @@ def _attend_lanes(q, kc, vc, pos, nh, nkv, sliding_window=0):
     return out.reshape(b, s, nh, d).astype(q.dtype)
 
 
-def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0):
+def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0,
+                 dv=None):
     """``_attend_lanes`` over LIVE ROWS: what each lane holds, cut into
     rows of ``W`` blocks, and nothing else of its table. ``rows``
     [R, 2 + W] int32 is one row a line, live rows first: the lane whose
@@ -81,7 +82,8 @@ def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0):
     tile, answering to nobody), the absolute position of its first slot,
     its ``W`` block ids (a lane's last row padded with null block 0).
     ``gather(blocks [T, W])`` returns those blocks' K and V as
-    ``[T, W * B, nkv, d]``. q [b, s, nh, d], pos [b, s].
+    ``[T, W * B, nkv, d]`` (V ``dv`` wide where a family's values are
+    narrower than its keys). q [b, s, nh, d], pos [b, s].
 
     Rows run ``tile`` at a time under a device-side loop whose trip count
     is data (the live rows, counted here): per row the fp32 scores of its
@@ -96,6 +98,7 @@ def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0):
     after it. A lane with no row (idle) reads 0."""
     b, s, nh, d = q.shape
     g = nh // nkv
+    dv = d if dv is None else dv
     f32 = jnp.float32
     lane, first, blocks = rows[:, 0], rows[:, 1], rows[:, 2:]
     tile = min(tile, rows.shape[0])  # engine.fit_rows: fewer rows, one tile
@@ -143,9 +146,9 @@ def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0):
         0, n_tiles, one_tile,
         (jnp.full((b, s, nkv, g), -1e30, f32),
          jnp.zeros((b, s, nkv, g), f32),
-         jnp.zeros((b, s, nkv, g, d), f32)))
+         jnp.zeros((b, s, nkv, g, dv), f32)))
     out = o / jnp.where(l > 0, l, 1.0)[..., None]
-    return out.reshape(b, s, nh, d).astype(q.dtype)
+    return out.reshape(b, s, nh, dv).astype(q.dtype)
 
 
 def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,

@@ -992,3 +992,74 @@ def test_kda_update_reader_picks_the_state_and_nothing_else(
     # a program without a state (the parent's): nothing to read
     assert reader.pattern([n for n in names if ",128,128]" not in n],
                           _LINEAR_LANES, m) is None
+
+
+# -- the window-attention / full-attention family's programs --------------------
+
+# lanes; the head sizes (keys 192, values 128), the key/value heads (4 full,
+# 8 window) and the window (128) are the published ones: what decides the
+# layouts of the pools and the rings
+_WINDOW_LANES, _WINDOW_TABLE = 8, 144  # 9 rows of 16 blocks a lane
+_WINDOW_K = (1, 2049, 16, 4 * 192)
+_WINDOW_V = (1, 2049, 16, 4 * 128)
+_WINDOW_RINGS = ((_WINDOW_LANES, 144, 8 * 192), (_WINDOW_LANES, 144, 8 * 128))
+
+
+@pytest.fixture(scope="module")
+def window_engine():
+    """One full layer over a dense SwiGLU, then two window layers over
+    expert layers, bf16, built on the CPU for its shapes."""
+    from paddle_tpu.models import WindowMoEConfig, WindowMoEForCausalLM
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    model = WindowMoEForCausalLM(WindowMoEConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=3,
+        hybrid_layer_pattern=[0, 1, 1], moe_layer_freq=[0, 1, 1],
+        num_attention_heads=16, num_key_value_heads=4,
+        swa_num_key_value_heads=8, head_dim=192, v_head_dim=128,
+        sliding_window=128, n_routed_experts=4, router_experts=16,
+        num_experts_per_tok=4, initializer_range=0.0, dtype="bfloat16"))
+    model.eval()
+    return ServingEngine(model, ServingConfig(
+        max_lanes=_WINDOW_LANES, block_size=16, num_blocks=_WINDOW_K[1],
+        prefill_chunk=32, max_seq_len=_WINDOW_TABLE * 16))
+
+
+@pytest.mark.parametrize("kind,chunk", _programs())
+def test_window_program_never_copies_a_pool(topo, window_engine, kind,
+                                            chunk, monkeypatch):
+    """Device state of two kinds by LAYER TYPE, none of which a program
+    call may copy: the full layer's K and V pools, of DIFFERENT last axis
+    (4 heads x 192 and 4 x 128 merged: 6 and 4 lane tiles), and a K ring
+    and a V ring a window layer by lane (144 slots at a window of 128 and
+    4 drafts a round). Every one is donated and written where it lies: a
+    round scatters its fed positions, the prefill chunk (told its lane
+    beside its rows) updates its lane's slice. The full layers read rows,
+    never every lane's whole table; the expert products are the
+    grouped-matmul kernel."""
+    import paddle_tpu.framework.device as device
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    eng = window_engine
+    assert (eng._pools[0].shape, eng._pools[1].shape) \
+        == (_WINDOW_K, _WINDOW_V)
+    assert [p.shape for p in eng._pools[3:]] \
+        == [_WINDOW_RINGS[0]] * 2 + [_WINDOW_RINGS[1]] * 2
+    compiled = _compiled_program(topo, eng, kind, chunk)
+    state = [p for i, p in enumerate(eng._pools) if i != 2]
+    # donation holds: every pool and ring comes back in its own buffer
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= sum(p.nbytes for p in state)
+    names = _program_names(compiled, loops=True)
+    assert any("/while/body/" in n for n in names)
+    lanes = 1 if kind == "prefill" else _WINDOW_LANES
+    for width in (_WINDOW_K[3], _WINDOW_V[3]):
+        _holds_rows_not_tables("\n".join(names), eng, kind, lanes,
+                               _WINDOW_TABLE, width)
+    pools = "|".join(rf"\w+\[{_dims(s)}\]" for s in
+                     (_WINDOW_K, _WINDOW_V, *_WINDOW_RINGS))
+    copies = [n[:200] for n in names
+              if re.search(rf"= ({pools})\S* copy\(", n)]
+    assert not copies, "\n".join(copies[:4])
+    assert len([n for n in names if re.match(r"%gmm[\.\d]* = ", n)]) == 4

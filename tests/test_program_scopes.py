@@ -21,7 +21,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KINDS = ("decode", "verify", "prefill")
 
 
-# -- the four families at the sizes their own tests use ------------------------
+# -- the five families at the sizes their own tests use ------------------------
 
 def _dense():
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
@@ -83,8 +83,24 @@ def _linear():
                        max_seq_len=96)
 
 
+def _window():
+    from paddle_tpu.models import WindowMoEConfig, WindowMoEForCausalLM
+
+    model = WindowMoEForCausalLM(WindowMoEConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=80,
+        moe_intermediate_size=24, num_hidden_layers=5,
+        hybrid_layer_pattern=[0, 1, 1, 1, 0],
+        moe_layer_freq=[0, 1, 1, 1, 1], num_attention_heads=8,
+        num_key_value_heads=2, swa_num_key_value_heads=4, head_dim=24,
+        v_head_dim=16, sliding_window=8, n_routed_experts=4,
+        router_experts=8, first_held_expert=2, num_experts_per_tok=3))
+    return model, dict(max_lanes=3, block_size=4, prefill_chunk=8,
+                       max_seq_len=96)
+
+
 FAMILIES = {"dense_gqa": _dense, "latent_moe": _latent,
-            "hybrid_ssm": _hybrid, "linear_latent_moe": _linear}
+            "hybrid_ssm": _hybrid, "linear_latent_moe": _linear,
+            "window_moe": _window}
 
 
 def _engine(family):

@@ -1,6 +1,7 @@
 """Compile a serving cell's three step programs — the latent-attention
-sparse-expert family's, the hybrid state-space family's or the
-linear-attention family's, by the configuration's ``arch`` — at the configuration's real sizes for ONE chip
+sparse-expert family's, the hybrid state-space family's, the
+linear-attention family's or the window-attention family's, by the
+configuration's ``arch`` — at the configuration's real sizes for ONE chip
 of a described ``v5e:2x2`` — no chip attached, nothing runs — and print
 what each needs of the device's memory. By hand, before chip calls:
 
@@ -128,8 +129,33 @@ def _linear(arch, cfg, layers, s, sds, i32, geom):
     return fam, statics, pools, reads
 
 
+def _window(arch, cfg, layers, s, sds, i32, geom):
+    """The same of ``serving/families/window_moe.py``: K and V pools by
+    block for the full layers (keys wider than values), and a K ring and
+    a V ring a window layer by LANE; the dense family's live-rows read
+    (the prefill chunk's with its lane)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import WindowMoEConfig
+    from paddle_tpu.serving.families import window_moe as fam
+
+    L, B, _, K, _ = geom
+    c = WindowMoEConfig(**arch.config_kwargs(cfg, layers, s["max_seq_len"]))
+    g = c.static()
+    n_win = sum(g.hybrid_layer_pattern)
+    R = c.window_ring_len or fam.ring_len(c, K)
+    full, swa = g.num_key_value_heads, g.swa_num_key_value_heads
+    pools = (sds((layers - n_win, s["num_blocks"], B, full * g.head_dim)),
+             sds((layers - n_win, s["num_blocks"], B, full * g.v_head_dim)),
+             sds((len(fam.ACC),), jnp.int32),
+             *(sds((L, R, swa * g.head_dim)) for _ in range(n_win)),
+             *(sds((L, R, swa * g.v_head_dim)) for _ in range(n_win)))
+    statics, reads = _row_reads(fam.read_form, g, i32, geom, True)
+    return fam, statics, pools, reads
+
+
 FAMILIES = {"mla_moe": _latent, "hybrid_ssm": _hybrid,
-            "kda_mla_moe": _linear}
+            "kda_mla_moe": _linear, "swa_gqa_moe": _window}
 
 
 def main():
