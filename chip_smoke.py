@@ -431,8 +431,11 @@ class LogitRows:
 
         @jax.jit
         def eng_row(params, ids, n):
-            pool = jnp.zeros((layers, nblk + 1, block, nkv, d),
-                             jnp.int8 if kv_int8 else dt)
+            # as DenseGQAFamily.make_pools lays them out: the bf16 pool
+            # merges the heads into its last axis
+            pool = jnp.zeros((layers, nblk + 1, block, nkv, d), jnp.int8) \
+                if kv_int8 else jnp.zeros(
+                    (layers, nblk + 1, block, nkv * d), dt)
             scale = (jnp.zeros((layers, nblk + 1, block, nkv),
                                jnp.float32) if kv_int8 else None)
             pos = jnp.arange(bucket, dtype=jnp.int32)[None, :]

@@ -245,8 +245,9 @@ def fit_rows(form, lanes, blocks_per_lane):
 
 
 def pack_rows(items, lanes, width, block, w, cap):
-    """The live-rows read operand of one program call (the dense
-    family's ``_attend_rows`` reads it), as numpy. ``items``: per
+    """The live-rows read operand of one program call (the kernel
+    ``ops/pallas/row_attention.py`` and the families' ``_attend_rows``
+    read it), as numpy. ``items``: per
     occupied lane ``(lane, blocks, first, upto)`` — its block list, the
     position of its first token this call, and the slots ``[0, upto)``
     its queries may see. Returns ``(rows [cap, 2 + w], wblk [lanes,
@@ -360,7 +361,9 @@ class ServingEngine:
         # lane's whole table reads (idle lanes' too: the full-table
         # read this engine had gathered those). gathered / read is the
         # read's amplification, gathered / dense the share of the table
-        # still read (_pack_read bills all three).
+        # still read (_pack_read bills all three, and kv_kernel_rows:
+        # the live rows handed to a program whose read is the fused
+        # kernel, ops/pallas/row_attention.py; 0 where XLA reads them).
         # prefix_{hit,miss}_tokens split every (re-)prefilled context:
         # hit = tokens served by acquired shared blocks (no compute),
         # miss = tokens actually pushed through the prefill program —
@@ -387,7 +390,7 @@ class ServingEngine:
             "prefix_hit_tokens": 0, "prefix_miss_tokens": 0,
             "prefill_fed_tokens": 0,
             "kv_read_tokens": 0, "kv_gathered_tokens": 0,
-            "kv_dense_read_tokens": 0,
+            "kv_dense_read_tokens": 0, "kv_kernel_rows": 0,
             "kv_quant_writes": 0, "kv_quant_tokens": 0,
             # wall seconds per phase of step() (monitor/spans.Phase):
             # they telescope to step_s up to the statements between
@@ -591,6 +594,13 @@ class ServingEngine:
         form = self._family.read_form(kind)
         return form and fit_rows(form, lanes, self.blocks_per_lane)
 
+    @property
+    def _row_read(self):
+        """``"kernel"`` where the family's programs read their live rows
+        through ``ops/pallas/row_attention.py``; ``"xla"`` otherwise
+        (the int8 pool; a family with a read of its own)."""
+        return getattr(self._family, "row_read", "xla")
+
     def _tells_slot(self, kind):
         """A ``lane_state`` family's one-lane prefill chunk is told the
         lane its request holds, as the last entry of its read operand."""
@@ -638,9 +648,14 @@ class ServingEngine:
             return tables
         w, tile, cap = form
         rows, wblk, n, live = pack_rows(items, lanes, width, B, w, cap)
-        c["kv_gathered_tokens"] += -(-n // tile) * tile * w * B
+        # the kernel's grid is the live rows; the XLA read runs whole tiles
+        by_kernel = n if self._row_read == "kernel" else 0
+        c["kv_kernel_rows"] += by_kernel
+        c["kv_gathered_tokens"] += (by_kernel
+                                    or -(-n // tile) * tile) * w * B
         if ph is not None and _spans is not None:
-            ph.args.update(rows=n, live_blocks=live)
+            ph.args.update(rows=n, live_blocks=live,
+                           kv_kernel_rows=by_kernel)
         if to_slot:
             return rows, wblk, np.asarray([slot], np.int32)
         return rows, wblk
@@ -1094,6 +1109,10 @@ class ServingEngine:
             device_state_bytes=self.kv_pool_bytes + self.lane_pool_bytes,
             # a constant: benchmarks/chip/chiplib/serve.py reads the key
             paged_attention=False,
+            # what reads each program's live rows: "kernel" or "xla"
+            row_read=dict.fromkeys(
+                ("prefill", "decode") + ("verify",) * self.spec_active,
+                self._row_read),
             prefix_cache=self.config.prefix_cache,
             # False: the family's requests cannot start from a prefix's
             # blocks alone, so none is acquired whatever prefix_cache says

@@ -49,6 +49,11 @@ False where a request cannot start from a prefix's blocks alone (it
 would need the recurrent state, or the ring, at that boundary): the
 engine then has the scheduler acquire none, and ``stats()`` says so.
 
+``row_read`` — ``"kernel"`` where the family's programs read their live
+rows through ``ops/pallas/row_attention.py`` (the engine then bills
+``kv_kernel_rows``, and ``stats()["row_read"]`` says so); a family without
+the attribute reads them itself (``"xla"``).
+
 Plus ``absorb(out, counters)``: the round's ONE fetched array goes
 through it — a family that rides its own counters on that array strips
 them into ``counters`` (initial values: ``counters``) and returns the

@@ -1,18 +1,31 @@
 """The dense grouped-query decoder family (``models/llama.py``) as the
 serving engine sees it: a K pool and a V pool ``[layers, blocks, block,
-kv_heads, head_dim]`` (int8 mode: paired float32 scale pools), the
-weights stacked per leaf so each program scans over layers, and the three
-step programs (``tests/test_chip_compile.py`` holds what they compile to).
+kv_heads x head_dim]`` (int8 mode: ``[.., kv_heads, head_dim]`` with paired
+float32 scale pools), the weights stacked per leaf so each program scans
+over layers, and the three step programs (``tests/test_chip_compile.py``
+holds what they compile to).
 
-**The K/V read follows what the lanes hold** (``_attend_rows``): the
-engine's ``pack`` phase cuts each running lane's block list into rows of
-``ROW_BLOCKS`` blocks and lays all lanes' rows end to end; a program
-gathers the live rows a tile at a time from the stacked pool, attends row
-by row and recombines per lane as one softmax. No program gathers a
-table slot that holds nothing, so a call's cost follows the live blocks,
-not ``max_seq_len`` (PERF.md section 6, PR 28). ``_attend_lanes``, the
-read over a lane's whole gathered table, stays as the definition the row
-read is held to (``tests/test_serving_rows.py``).
+**The K/V read follows what the lanes hold**: the engine's ``pack`` phase
+cuts each running lane's block list into rows of ``ROW_BLOCKS`` blocks and
+lays all lanes' rows end to end, and a program reads the LIVE rows and
+nothing else of any table, so a call's cost follows the live blocks, not
+``max_seq_len`` (PERF.md section 6, PR 28). Who reads them, by the pool's
+dtype:
+
+- **bf16 pools: the fused kernel** ``ops/pallas/row_attention.py`` (PR 39;
+  the hybrid state-space and the window-attention families' attention
+  layers call it too): one call a layer whose grid is the live rows; it
+  copies a row's blocks out of the stacked pool into fast memory by
+  (layer, block) id, scores them against the lane's queries with the
+  operands as stored (bfloat16 on the chip, float32 products), masks, and
+  folds a lane's rows into one softmax there: no gathered tile, float32
+  copy of K/V or score tensor reaches HBM;
+- **int8 pools: ``_attend_rows``**, the same read as plain XLA a tile of
+  rows at a time (the paired scales are dequantized a gathered tile at a
+  time; no benchmark cell serves it).
+
+``_attend_lanes``, the read over a lane's whole gathered table, stays as
+the definition both are held to (``tests/test_serving_rows.py``).
 
 The attention/RoPE/MLP math reuses ``models/generation.py``'s helpers
 (``_rms``/``_mm``/``_rope_at``) and mirrors its ``_attend`` — engine
@@ -30,6 +43,7 @@ import numpy as np
 from ...models.generation import (
     _GenCfg, _collect_params, _mm, _rms, _rope_at,
 )
+from ...ops.pallas.row_attention import row_attention
 
 __all__ = ["DenseGQAFamily"]
 
@@ -48,7 +62,8 @@ PREFILL_TILE = 4
 
 def _attend_lanes(q, kc, vc, pos, nh, nkv, sliding_window=0):
     """``models/generation.py:_attend`` with PER-TOKEN positions: q
-    [b, s, nh, d] against the gathered block slots kc/vc [b, L, nkv, d].
+    [b, s, nh, d] against the gathered block slots kc/vc [b, L, nkv, d]
+    (vc: as wide as a family's values are).
     Slot ``l`` is visible to the query at absolute position ``p =
     pos[b, t]`` iff ``l <= p`` — block tables lay a lane's positions out
     in order, so slot index == absolute position for every allocated
@@ -70,11 +85,10 @@ def _attend_lanes(q, kc, vc, pos, nh, nkv, sliding_window=0):
     logits = jnp.where(vis[:, :, None, None, :], logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bskgl,blkd->bskgd", p, vc.astype(jnp.float32))
-    return out.reshape(b, s, nh, d).astype(q.dtype)
+    return out.reshape(b, s, nh, -1).astype(q.dtype)
 
 
-def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0,
-                 dv=None):
+def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0):
     """``_attend_lanes`` over LIVE ROWS: what each lane holds, cut into
     rows of ``W`` blocks, and nothing else of its table. ``rows``
     [R, 2 + W] int32 is one row a line, live rows first: the lane whose
@@ -82,8 +96,7 @@ def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0,
     tile, answering to nobody), the absolute position of its first slot,
     its ``W`` block ids (a lane's last row padded with null block 0).
     ``gather(blocks [T, W])`` returns those blocks' K and V as
-    ``[T, W * B, nkv, d]`` (V ``dv`` wide where a family's values are
-    narrower than its keys). q [b, s, nh, d], pos [b, s].
+    ``[T, W * B, nkv, d]``. q [b, s, nh, d], pos [b, s].
 
     Rows run ``tile`` at a time under a device-side loop whose trip count
     is data (the live rows, counted here): per row the fp32 scores of its
@@ -98,7 +111,6 @@ def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0,
     after it. A lane with no row (idle) reads 0."""
     b, s, nh, d = q.shape
     g = nh // nkv
-    dv = d if dv is None else dv
     f32 = jnp.float32
     lane, first, blocks = rows[:, 0], rows[:, 1], rows[:, 2:]
     tile = min(tile, rows.shape[0])  # engine.fit_rows: fewer rows, one tile
@@ -146,9 +158,9 @@ def _attend_rows(q, pos, rows, gather, tile, nkv, sliding_window=0,
         0, n_tiles, one_tile,
         (jnp.full((b, s, nkv, g), -1e30, f32),
          jnp.zeros((b, s, nkv, g), f32),
-         jnp.zeros((b, s, nkv, g, dv), f32)))
+         jnp.zeros((b, s, nkv, g, d), f32)))
     out = o / jnp.where(l > 0, l, 1.0)[..., None]
-    return out.reshape(b, s, nh, dv).astype(q.dtype)
+    return out.reshape(b, s, nh, d).astype(q.dtype)
 
 
 def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
@@ -161,8 +173,8 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
     the blocks the lanes HOLD: ``read`` is ``(rows, wblk)`` — the live
     rows of ``_attend_rows`` and, per token, the block its position falls
     in (``wblk`` [b, s], the host's lookup in the lane's block list) —
-    and each tile of rows is gathered from the stacked pool by
-    (layer, block): no value of one layer's pool shape is produced, nor
+    and each row's blocks come out of the stacked pool by (layer, block):
+    no value of one layer's pool shape is produced, nor
     one of every lane's whole table (tests/test_chip_compile.py holds the
     compiled programs to both). What a call reads follows the live
     blocks, not ``max_seq_len``. Layer math is
@@ -214,25 +226,23 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
             k = k.reshape(b, s, nkv, d)
             v = v.reshape(b, s, nkv, d)
             q, k = _rope_at(q, k, pos, cfg.rope_theta)
-        with scope("attn/kv_write"):
-            if quant:
-                from ...quantization import quantize_kv
+        # a row's blocks come from the STACKED pool by (layer, block):
+        # kp[li][...] makes the TPU materialise kp[li], the layer's whole
+        # pool, before every gather (PERF.md section 6, PR 25). The pool's
+        # dtype says which read: the bf16 pool keeps the heads merged
+        # into its last axis and the kernel copies a row's blocks out of
+        # it; the int8 pool and its scales are indexed by the (layer,
+        # block) pair and dequantized a gathered tile at a time
+        if quant:
+            from ...quantization import dequantize_kv, quantize_kv
 
+            with scope("attn/kv_write"):
                 k, k_s = quantize_kv(k)
                 v, v_s = quantize_kv(v)
                 ks = ks.at[li, blk, off].set(k_s)
                 vs = vs.at[li, blk, off].set(v_s)
-            kp = kp.at[li, blk, off].set(k)
-            vp = vp.at[li, blk, off].set(v)
-        # a tile's blocks come from the STACKED pool by (layer,
-        # block): kp[li][...] makes the TPU materialise kp[li], the
-        # layer's whole pool, before every gather. Which of the two
-        # forms without it follows the pool's dtype, as the chip
-        # ran them (PERF.md section 6, PR 25): bf16 pools flattened
-        # over (layer, block), int8 pools and their scales indexed
-        # by the pair
-        if quant:
-            from ...quantization import dequantize_kv
+                kp = kp.at[li, blk, off].set(k)
+                vp = vp.at[li, blk, off].set(v)
 
             def gather(blocks):
                 T, W = blocks.shape
@@ -240,16 +250,18 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
                     c[li, blocks].reshape(T, W * B, nkv, d),
                     sc[li, blocks].reshape(T, W * B, nkv), dt)
                     for c, sc in ((kp, ks), (vp, vs)))
+
+            with scope("attn/rows"):
+                out = _attend_rows(q, pos, rows, gather, tile, nkv,
+                                   sliding_window=cfg.sliding_window)
         else:
-            def gather(blocks):
-                T, W = blocks.shape
-                at = blocks + li * kp.shape[1]
-                return tuple(
-                    c.reshape(-1, B, nkv, d)[at].reshape(
-                        T, W * B, nkv, d) for c in (kp, vp))
-        with scope("attn/rows"):
-            out = _attend_rows(q, pos, rows, gather, tile, nkv,
-                               sliding_window=cfg.sliding_window)
+            with scope("attn/kv_write"):
+                kp = kp.at[li, blk, off].set(k.reshape(b, s, nkv * d))
+                vp = vp.at[li, blk, off].set(v.reshape(b, s, nkv * d))
+            with scope("attn/rows"):
+                out = row_attention(q, pos, rows, kp, vp, li, nkv,
+                                    d ** -0.5,
+                                    sliding_window=cfg.sliding_window)
         with scope("attn/out"):
             x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])
         h2 = _rms(x, layer_p["ln2"], cfg.rms_norm_eps)
@@ -375,12 +387,14 @@ class DenseGQAFamily:
         nh = self.gcfg.num_attention_heads
         nkv = self.gcfg.num_key_value_heads or nh
         d = self.gcfg.hidden_size // nh
-        int8 = self.config.kv_int8
-        dt = jnp.int8 if int8 else jnp.dtype(self.gcfg.dtype)
-        kpool = jnp.zeros((self.layers, num_blocks, block_size, nkv, d), dt)
+        if not self.config.kv_int8:
+            # the heads merged into the last axis, as the kernel takes it
+            kpool = jnp.zeros((self.layers, num_blocks, block_size, nkv * d),
+                              jnp.dtype(self.gcfg.dtype))
+            return kpool, jnp.zeros_like(kpool), None, None
+        kpool = jnp.zeros((self.layers, num_blocks, block_size, nkv, d),
+                          jnp.int8)
         vpool = jnp.zeros_like(kpool)
-        if not int8:
-            return kpool, vpool, None, None
         kscale = jnp.zeros((self.layers, num_blocks, block_size, nkv),
                            jnp.float32)
         return kpool, vpool, kscale, jnp.zeros_like(kscale)
@@ -396,6 +410,13 @@ class DenseGQAFamily:
         (``ServingEngine._pack_read`` builds it): ``(W, tile)`` — live
         rows of ``W`` blocks, run ``tile`` at a time."""
         return ROW_BLOCKS, PREFILL_TILE if kind == "prefill" else ROW_TILE
+
+    @property
+    def row_read(self):
+        """What reads its programs' live rows: ``"kernel"``
+        (``ops/pallas/row_attention.py``), or ``"xla"`` — the int8 pool's
+        read (``_attend_rows``)."""
+        return "xla" if self.config.kv_int8 else "kernel"
 
     def program(self, kind):
         """(function, static keyword arguments) of one step program."""

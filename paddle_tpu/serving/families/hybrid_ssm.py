@@ -4,15 +4,14 @@ the serving engine sees it: TWO kinds of cache in one family.
 - **By token**: a K pool and a V pool ``[attention layers, blocks, block,
   kv_heads x head_dim]`` for the few attention layers, written by
   (layer, block, offset) with the null-block redirect and read by the
-  dense family's live-rows read (``dense_gqa._attend_rows``; no rotary
-  embedding here, and the model's score multiplier goes onto the query,
-  since that read divides by ``sqrt(head_dim)`` itself). The dense
-  family's pools end in ``[kv_heads, head_dim]``; with a head of 64, half
-  a 128-lane tile, the TPU compiler lays such a pool out blocks-minor and
-  every program call then copied both pools in and out (4 x 285 MB; the
-  latent family's finding, PERF.md section 6, PR 27). With the heads
-  merged into the last axis (512 = 4 lane tiles) the pool's own layout is
-  row-major and no call copies it.
+  dense family's live-rows read (the fused kernel
+  ``ops/pallas/row_attention.py``; no rotary embedding here, and the
+  model's score multiplier is the read's ``scale``). A pool that ends in ``[kv_heads, head_dim]`` with a
+  head of 64, half a 128-lane tile, the TPU compiler lays out
+  blocks-minor, and every program call then copied both pools in and out
+  (4 x 285 MB; the latent family's finding, PERF.md section 6, PR 27).
+  With the heads merged into the last axis (512 = 4 lane tiles) the
+  pool's own layout is row-major and no call copies it.
 - **By LANE**: the recurrent state, one float32 array a state-space
   layer in the SLAB layout of ``ops/pallas/ssm_state.py`` (``[lanes,
   groups, d_state, heads/groups x d_head]``: the model's ``[lanes, heads,
@@ -94,8 +93,9 @@ import numpy as np
 from ...models import hybrid_ssm as M
 from ...models.generation import _rms
 from ...ops.pallas import ssm_state
+from ...ops.pallas.row_attention import row_attention
 from . import absorb_accumulator
-from .dense_gqa import PREFILL_TILE, ROW_BLOCKS, ROW_TILE, _attend_rows
+from .dense_gqa import PREFILL_TILE, ROW_BLOCKS, ROW_TILE
 
 __all__ = ["HybridSSMFamily"]
 
@@ -118,33 +118,22 @@ def _bump(acc, **by):
                                 for n in ACC])
 
 
-def _attention(u, lp, ai, kpool, vpool, read, pos, blk, off, cfg, tile):
+def _attention(u, lp, ai, kpool, vpool, read, pos, blk, off, cfg):
     """An attention layer's mixer against the block pool: write the fed
     tokens' K/V by (layer, block, offset), then the dense family's
     live-rows read. Returns (out [b, s, hidden], kpool, vpool)."""
     b, s, _ = u.shape
     nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                   cfg.head_dim)
-    B = kpool.shape[2]
     q, k, v = M.attention_qkv(u, lp, cfg)
     with jax.named_scope("attn/kv_write"):
         kpool = kpool.at[ai, blk, off].set(k.reshape(b, s, nkv * d))
         vpool = vpool.at[ai, blk, off].set(v.reshape(b, s, nkv * d))
-
-    def gather(blocks):  # dense_gqa._pool_forward's bf16 form
-        T, W = blocks.shape
-        at = blocks + ai * kpool.shape[1]
-        return tuple(c.reshape(-1, B, nkv * d)[at].reshape(
-            T, W * B, nkv, d) for c in (kpool, vpool))
-
-    with jax.named_scope("attn/rows"):
-        # the read divides by sqrt(d); the model's multiplier is stated
-        out = _attend_rows(
-            q.astype(F32) * (cfg.attention_multiplier * np.sqrt(d)),
-            pos, read[0], gather, tile, nkv)
+    with jax.named_scope("attn/rows"):  # the model states its own scale
+        out = row_attention(q, pos, read[0], kpool, vpool, ai, nkv,
+                            cfg.attention_multiplier)
     with jax.named_scope("attn/out"):
-        return (out.reshape(b, s, nh * d).astype(u.dtype) @ lp["o"], kpool,
-                vpool)
+        return out.reshape(b, s, nh * d) @ lp["o"], kpool, vpool
 
 
 def _carried(fresh, kept):
@@ -171,7 +160,7 @@ def _take_rows(window, first, n):
     return jnp.take_along_axis(window, idx[:, :, None], axis=1)
 
 
-def _stack(params, ids, pos, wlimit, read, kpool, vpool, cfg, tile, ssm):
+def _stack(params, ids, pos, wlimit, read, kpool, vpool, cfg, ssm):
     """The layer stack over ``ids`` [b, s] at positions ``pos``: attention
     layers against the block pool here, each state-space layer through
     ``ssm(si, u, lp) -> mix`` (the program's own: what it does with the
@@ -195,7 +184,7 @@ def _stack(params, ids, pos, wlimit, read, kpool, vpool, cfg, tile, ssm):
             out = "ssm/out_proj"
         else:
             mix, kpool, vpool = _attention(u, lp, ai, kpool, vpool, read,
-                                           pos, blk, off, cfg, tile)
+                                           pos, blk, off, cfg)
             ai += 1
             out = "attn/out"
         with scope(out):  # a residual add: its producer's scope
@@ -274,7 +263,7 @@ def _own_mix(B, C, dt, cum, D):
         b, T * T, H)
 
 
-def _prefill_chunk(params, *args, cfg, tile):
+def _prefill_chunk(params, *args, cfg):
     """One request's prefill chunk ``ids`` [1, C] at [start, start + C),
     ``read`` = (its lane's live rows, write blocks, ``slot`` [1]: the
     lane it holds). The slot's state and conv tail carry on from the
@@ -320,7 +309,7 @@ def _prefill_chunk(params, *args, cfg, tile):
         return M.ssm_gate_out(y, z, lp, cfg)
 
     x, kpool, vpool = _stack(params, ids, pos, jnp.reshape(ctx_len, (1,)),
-                             read, kpool, vpool, cfg, tile, ssm)
+                             read, kpool, vpool, cfg, ssm)
     acc = _bump(acc, ssm_slot_resets=fresh)
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
@@ -329,7 +318,7 @@ def _prefill_chunk(params, *args, cfg, tile):
             pend_s, n_owed, *states, *pend_x)
 
 
-def _decode_step(params, *args, cfg, tile):
+def _decode_step(params, *args, cfg):
     """Every lane feeds its pending token at ``cur_len``: K/V written
     then attended; each layer's one pass through the state applies what
     the lanes' last verify round left, then this position, and reads
@@ -368,7 +357,7 @@ def _decode_step(params, *args, cfg, tile):
 
     with jax.named_scope("embed"):  # the fed tokens and where
         fed = last_tok[:, None], cur_len[:, None], cur_len + 1
-    x, kpool, vpool = _stack(params, *fed, read, kpool, vpool, cfg, tile,
+    x, kpool, vpool = _stack(params, *fed, read, kpool, vpool, cfg,
                              ssm)
     with jax.named_scope("acc"):
         live = cur_len > 0
@@ -383,7 +372,7 @@ def _decode_step(params, *args, cfg, tile):
             pend_s, jnp.zeros_like(n_owed), *states, *pend_x)
 
 
-def _verify_step(params, *args, cfg, tile):
+def _verify_step(params, *args, cfg):
     """``toks`` [L, k+1]: each lane's pending token and its draft at
     ``cur_len + j``; positions >= ``wlimit[b]`` are pad. Each layer's one
     pass through the state applies what the lanes' LAST verify round
@@ -427,7 +416,7 @@ def _verify_step(params, *args, cfg, tile):
         return M.ssm_gate_out(y, z, lp, cfg)
 
     x, kpool, vpool = _stack(params, toks, pos, wlimit, read, kpool, vpool,
-                             cfg, tile, ssm)
+                             cfg, ssm)
     picks = _picks(x, params, cfg)
     # a lane keeps its pending token and the longest prefix of its draft
     # that equals the program's own picks (engine._accept's rule)
@@ -461,6 +450,7 @@ class HybridSSMFamily:
     name = "hybrid_ssm"
     lane_state = True
     prefix_reuse = False
+    row_read = "kernel"  # the attention layers' live rows: row_attention
     prefix_reuse_why = (
         "a prefix hit hands over block-aligned K/V and this family's "
         "state-space layers would need their recurrent state at that "
@@ -538,14 +528,15 @@ class HybridSSMFamily:
         return int(pools[2].nbytes + sum(p.nbytes for p in pools[4:]))
 
     def read_form(self, kind):
-        """The dense family's live rows ``(W, tile)``; ``lane_state``
-        adds the request's lane to the prefill chunk's."""
+        """The dense family's live rows ``(W, tile)`` (the kernel's grid is
+        the live rows: ``tile`` only rounds the operand's length);
+        ``lane_state`` adds the request's lane to the prefill chunk's."""
         return ROW_BLOCKS, PREFILL_TILE if kind == "prefill" else ROW_TILE
 
     def program(self, kind):
         fn = {"prefill": _prefill_chunk, "decode": _decode_step,
               "verify": _verify_step}[kind]
-        return fn, {"cfg": self.gcfg, "tile": self.read_form(kind)[1]}
+        return fn, {"cfg": self.gcfg}
 
     def exec_key(self, pools):
         from ...jit import exec_cache

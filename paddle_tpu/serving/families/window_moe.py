@@ -9,9 +9,10 @@ kinds by LAYER TYPE.
   the pools' own layout is row-major and no call copies them,
   ``families/hybrid_ssm.py`` says what the unmerged form cost) — written by
   (layer, block, offset) with the null-block redirect and read by the
-  dense family's live-rows read (``dense_gqa._attend_rows``): a call's
-  cost follows the live blocks. A window layer takes NOTHING in the block
-  pool.
+  dense family's live-rows read: a call's
+  cost follows the live blocks (the fused kernel
+  ``ops/pallas/row_attention.py``, which copies a row's blocks out of the
+  stacked pools itself). A window layer takes NOTHING in the block pool.
 - **By LANE, the WINDOW layers**: a RING of the lane's last ``R``
   positions, one K array ``[lanes, R, swa_kv_heads x head_dim]`` and one V
   array ``[lanes, R, swa_kv_heads x v_head_dim]`` a window layer (one
@@ -73,8 +74,8 @@ import jax.numpy as jnp
 
 from ...models import window_moe as M
 from ...models.generation import _rms
+from ...ops.pallas.row_attention import row_attention
 from . import absorb_accumulator
-from .dense_gqa import _attend_rows
 from .latent_moe import ACC as MOE_ACC
 from .latent_moe import _out, expert_counts
 
@@ -99,7 +100,9 @@ ACC = MOE_ACC + WIN_ACC
 
 
 def read_form(kind):
-    """Program ``kind``'s ``(W, tile)`` for the full layers' live rows."""
+    """Program ``kind``'s ``(W, tile)`` for the full layers' live rows (the
+    kernel's grid is the live rows: ``tile`` only rounds the operand's
+    length)."""
     return ROW_BLOCKS, PREFILL_TILE if kind == "prefill" else ROW_TILE
 
 
@@ -176,31 +179,22 @@ def ring_chunk(q, k, v, pos, start, n_real, slot, rk, rv, lp, cfg):
     return att, rk, rv
 
 
-def _full_attention(q, k, v, ai, kpool, vpool, rows, pos, blk, off, cfg,
-                    tile):
+def _full_attention(q, k, v, ai, kpool, vpool, rows, pos, blk, off, cfg):
     """A full layer against the block pool: write the fed tokens' K/V by
     (layer, block, offset), then the dense family's live-rows read.
     Returns (att [b, s, H x dv], kpool, vpool)."""
     b, s = pos.shape
     g, dk, dv = cfg.num_key_value_heads, cfg.head_dim, cfg.v_head_dim
-    nb, B = kpool.shape[1:3]
     with jax.named_scope("attn/kv_write"):
         kpool = kpool.at[ai, blk, off].set(k.reshape(b, s, g * dk))
         vpool = vpool.at[ai, blk, off].set(v.reshape(b, s, g * dv))
-
-    def gather(blocks):  # from the STACKED pools, by (layer, block)
-        T, W = blocks.shape
-        at = blocks + ai * nb
-        return (kpool.reshape(-1, B, g * dk)[at].reshape(T, W * B, g, dk),
-                vpool.reshape(-1, B, g * dv)[at].reshape(T, W * B, g, dv))
-
-    with jax.named_scope("attn/rows"):
-        out = _attend_rows(q, pos, rows, gather, tile, g, dv=dv)
+    with jax.named_scope("attn/rows"):  # from the STACKED pools
+        out = row_attention(q, pos, rows, kpool, vpool, ai, g, dk ** -0.5)
     return out.reshape(b, s, -1), kpool, vpool
 
 
 def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
-           tile, window):
+           window):
     """The layer stack over ``ids`` [b, s] at positions ``pos``: full
     layers against the block pool here (``read`` = the engine's live rows
     and the fed positions' blocks), each window layer through ``window(wi,
@@ -227,7 +221,7 @@ def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
             wi += 1
         else:
             att, kpool, vpool = _full_attention(
-                q, k, v, ai, kpool, vpool, rows, pos, blk, off, cfg, tile)
+                q, k, v, ai, kpool, vpool, rows, pos, blk, off, cfg)
             ai += 1
         with scope("attn/out"):
             x = x + att @ lp["o"]
@@ -260,7 +254,7 @@ def _unpack(args, cfg):
             args[3 + 2 * n:])
 
 
-def _prefill_chunk(params, *args, cfg, tile):
+def _prefill_chunk(params, *args, cfg):
     """One request's prefill chunk ``ids`` [1, C] at [start, start + C),
     ``read`` = (its lane's rows live up to the chunk's end, the fed
     positions' blocks, ``slot`` [1]: the lane it holds). The lane's rings
@@ -284,7 +278,7 @@ def _prefill_chunk(params, *args, cfg, tile):
 
     x, kpool, vpool, acc, _ = _stack(
         params, ids, pos, jnp.reshape(ctx_len, (1,)), real, read, kpool,
-        vpool, acc, cfg, tile, window)
+        vpool, acc, cfg, window)
     acc = _bump(acc, win_slot_resets=start == 0)
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
@@ -292,7 +286,7 @@ def _prefill_chunk(params, *args, cfg, tile):
     return _out(_head(h, params, cfg), acc), kpool, vpool, acc, *rks, *rvs
 
 
-def _decode_step(params, *args, cfg, tile):
+def _decode_step(params, *args, cfg):
     """Every lane feeds its pending token at ``cur_len``: written (pool or
     ring), then attended. Idle lanes (``cur_len`` 0) write the null block
     and nothing of their rings. Returns ([L tokens, *acc], pools...)."""
@@ -309,14 +303,14 @@ def _decode_step(params, *args, cfg, tile):
 
     x, kpool, vpool, acc, n_hit = _stack(
         params, last_tok[:, None], pos, cur_len + 1, live, read, kpool,
-        vpool, acc, cfg, tile, window)
+        vpool, acc, cfg, window)
     acc = _bump(acc, moe_round_experts_hit=n_hit)
     with jax.named_scope("head"):
         x = x[:, -1]
     return _out(_head(x, params, cfg), acc), kpool, vpool, acc, *rks, *rvs
 
 
-def _verify_step(params, *args, cfg, tile):
+def _verify_step(params, *args, cfg):
     """``toks`` [L, k+1]: each lane's pending token and its draft at
     ``cur_len + j``; positions >= ``wlimit[b]`` are pad: written nowhere.
     Rejected drafts need no undoing (module docstring); the program
@@ -335,7 +329,7 @@ def _verify_step(params, *args, cfg, tile):
 
     x, kpool, vpool, acc, n_hit = _stack(
         params, toks, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
-        tile, window)
+        window)
     picks = _head(x, params, cfg)
     with jax.named_scope("spec"):  # engine._accept's rule, to count by
         n_draft = wlimit - cur_len - 1                  # -1: an idle lane
@@ -355,6 +349,7 @@ class WindowMoEFamily:
     name = "window_moe"
     lane_state = True
     prefix_reuse = False
+    row_read = "kernel"  # the full layers' live rows: row_attention
     prefix_reuse_why = (
         "a prefix hit hands over block-aligned K/V of the full-attention "
         "layers, and this family's window layers would need their ring "
@@ -435,8 +430,7 @@ class WindowMoEFamily:
 
     def program(self, kind):
         return {"prefill": _prefill_chunk, "decode": _decode_step,
-                "verify": _verify_step}[kind], {
-            "cfg": self.gcfg, "tile": read_form(kind)[1]}
+                "verify": _verify_step}[kind], {"cfg": self.gcfg}
 
     def exec_key(self, pools):
         from ...jit import exec_cache

@@ -583,7 +583,7 @@ class RouterEngine:
         "spec_proposed_tokens", "spec_accepted_tokens",
         "spec_bonus_tokens", "prefix_hit_tokens", "prefix_miss_tokens",
         "kv_read_tokens", "kv_gathered_tokens", "kv_dense_read_tokens",
-        "step_s", "admit_s",
+        "kv_kernel_rows", "step_s", "admit_s",
         "prefill_s", "first_fetch_s", "grow_s", "draft_s", "pack_s",
         "dispatch_s", "fetch_s", "emit_s",
         "decode_rounds", "free_blocks", "allocatable_blocks",

@@ -18,6 +18,7 @@ The persistent compile cache is off around these compiles: an entry
 written for a described chip cannot be read back without one, and the
 next run would warn and compile again.
 """
+import contextlib
 import os
 import re
 
@@ -141,7 +142,8 @@ def test_headbatch_refuses_what_mosaic_refuses():
 # -- the serving engine's programs --------------------------------------------
 
 _POOL = (37, 16, 2, 64)  # [num_blocks, block, kv_heads, head_dim]: no
-#                          other value of the programs has this shape
+#                          other value of the programs has this shape (the
+#                          bf16 pool merges the heads into its last axis)
 
 
 _LANES, _TABLE = 8, 72  # lanes, blocks a lane: 5 rows of 16 blocks, so
@@ -174,12 +176,26 @@ def engines():
     return _engines(_POOL, 4, _LANES, _TABLE)
 
 
+@contextlib.contextmanager
+def _kernels_compiled():
+    """The code's one rule for "am I on the chip" steered for a compile
+    for the described chip: a program's Pallas kernels are compiled by
+    Mosaic there, not interpreted."""
+    import paddle_tpu.framework.device as device
+
+    prev, device.platform = device.platform, lambda: "tpu"
+    try:
+        yield
+    finally:
+        device.platform = prev
+
+
 def _compiled_program(topo, eng, kind, chunk=None):
     """Program ``kind`` of a rows-form family (the dense one, the hybrid
     state-space one) compiled for the described chip, lowered as
-    ``ServingEngine._ensure_compiled`` lowers it there (pools donated);
-    the prefill program at ``chunk`` positions, the engine's own width
-    unless given."""
+    ``ServingEngine._ensure_compiled`` lowers it there (pools donated,
+    kernels compiled); the prefill program at ``chunk`` positions, the
+    engine's own width unless given."""
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def spec(a):
@@ -197,11 +213,12 @@ def _compiled_program(topo, eng, kind, chunk=None):
     }[kind]
     read = jax.tree_util.tree_map(spec, eng._read_spec(kind, lanes, width))
     fn, static = eng._family.program(kind)
-    return jax.jit(
-        fn, static_argnames=tuple(static),
-        donate_argnums=eng._family.donate_argnums,
-    ).lower(*jax.tree_util.tree_map(spec, (eng._params, *eng._pools)),
-            read, *rest, **static).compile()
+    with _kernels_compiled():
+        return jax.jit(
+            fn, static_argnames=tuple(static),
+            donate_argnums=eng._family.donate_argnums,
+        ).lower(*jax.tree_util.tree_map(spec, (eng._params, *eng._pools)),
+                read, *rest, **static).compile()
 
 
 def _dense_program_text(topo, eng, kind, chunk=None):
@@ -257,13 +274,25 @@ def _results_shaped(text, dims):
     return [ln.strip()[:200] for ln in text.splitlines() if held.search(ln)]
 
 
-def _holds_no_layers_pool(text, pool):
+def _layer_pool(eng, pool):
+    """One layer's pool as ``eng``'s family stores it: the int8 pool
+    ``[num_blocks, block, kv_heads, head_dim]``, the bf16 pool with the
+    heads merged into the last axis."""
+    nb, block, nkv, d = pool
+    return pool if eng.config.kv_int8 else (nb, block, nkv * d)
+
+
+def _holds_no_layers_pool(text, eng, pool):
     """No instruction's result has one layer's pool shape (nor, in int8
     mode, one layer's scale-pool shape); the stacked pool is there."""
+    stored = _layer_pool(eng, pool)
+    assert eng._pools[0].shape[1:] == stored
     nb, block, nkv, d = pool
-    lines = _results_shaped(text, rf"{nb},{block},{nkv}(,{d})?")
+    dims = rf"{nb},{block},{nkv}(,{d})?" if eng.config.kv_int8 \
+        else _dims(stored)
+    lines = _results_shaped(text, dims)
     assert not lines, "\n".join(lines[:6])
-    assert f"[3,{nb},{block},{nkv},{d}]" in text
+    assert f"[3,{_dims(stored)}]" in text
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
@@ -278,8 +307,7 @@ def test_engine_program_never_holds_one_layers_pool(
     them, no instruction's result has one layer's pool shape (nor, in
     int8 mode, one layer's scale-pool shape)."""
     eng = engines[kv_int8]
-    assert eng._pools[0].shape[1:] == _POOL
-    _holds_no_layers_pool(_dense_program_text(topo, eng, kind, chunk),
+    _holds_no_layers_pool(_dense_program_text(topo, eng, kind, chunk), eng,
                           _POOL)
 
 
@@ -312,20 +340,70 @@ def test_row_read_compiles_at_served_geometries(
     there too no program holds one layer's pool."""
     pool = _SERVED_AT[geometry][0][0]
     eng = served_engines[geometry][kv_int8]
-    assert eng._pools[0].shape[1:] == pool
-    _holds_no_layers_pool(_dense_program_text(topo, eng, kind), pool)
+    text = _dense_program_text(topo, eng, kind)
+    _holds_no_layers_pool(text, eng, pool)
+    assert ("tpu_custom_call" in text) == (not kv_int8)
+
+
+# the benchmark cells' full-attention geometries: (heads, kv heads, head
+# dim, value dim, lanes) — Mistral-7B's, granite-4.0-h's (a head is half a
+# 128-lane tile), MiMo-V2.5's full layers (192 is no multiple of 128, and
+# values narrower than keys)
+_ROW_READ_AT = {"mistral_g4_d128": (32, 8, 128, 128, 32),
+                "granite_g4_d64": (32, 8, 64, 64, 64),
+                "mimo_g16_d192_dv128": (64, 4, 192, 128, 64)}
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+@pytest.mark.parametrize("geometry", sorted(_ROW_READ_AT))
+def test_row_kernel_compiles_at_the_cells_geometries(topo, geometry, kind):
+    """The live-rows read at the served head geometries and lanes, a
+    plain round's, a verify round's and a prefill chunk's queries: the
+    fused kernel (``ops/pallas/row_attention.py``) compiles for the chip
+    — head slices at lane offsets of 64 and 192, 2,048 query rows a KV
+    head in MiMo's chunk — and the program holds no gathered tile ``[T, W
+    x B, kv_heads, d]`` (float32 or not, heads merged or not) and no value
+    of one layer's pool shape."""
+    from paddle_tpu.ops.pallas.row_attention import row_attention
+
+    nh, nkv, d, dv, lanes = _ROW_READ_AT[geometry]
+    layers, nb, B, W, rows_a_lane = 2, 2049, 16, 16, 6
+    b, s = {"decode": (lanes, 1), "verify": (lanes, 5),
+            "prefill": (1, PREFILL_CHUNK)}[kind]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def read(q, pos, rows, kpool, vpool, li):
+        return row_attention(q, pos, rows, kpool, vpool, li, nkv, d ** -0.5)
+
+    with _kernels_compiled():
+        text = jax.jit(read).lower(
+            sd(jnp.bfloat16, b, s, nh, d), sd(jnp.int32, b, s),
+            sd(jnp.int32, b * rows_a_lane, 2 + W),
+            sd(jnp.bfloat16, layers, nb, B, nkv * d),
+            sd(jnp.bfloat16, layers, nb, B, nkv * dv),
+            sd(jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "row_attention" in text
+    for dims in (rf"\d+,{W * B},({nkv},)?\d+", rf"{nb},{B},\d+"):
+        lines = _results_shaped(text, dims)
+        assert not lines, "\n".join(lines[:6])
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("kind,chunk", _programs())
 def test_engine_program_never_holds_every_lanes_table(
         topo, engines, kind, chunk, kv_int8):
-    """The K/V read gathers the rows the lanes hold, a tile at a time
-    (PERF.md section 6, PR 28): no instruction's result is shaped like
-    every lane's whole table — ``[L * M, block, kv_heads, head_dim]`` as
-    the full-table gather wrote it, or ``[L, M * block, ...]`` as the
-    attention then read it (int8: the scales' shapes, without the head
-    dim) — while a tile of rows is."""
+    """The K/V read follows the rows the lanes hold (PERF.md section 6,
+    PR 28): no instruction's result is shaped like every lane's whole
+    table — ``[L * M, block, kv_heads, head_dim]`` as the full-table
+    gather wrote it, or ``[L, M * block, ...]`` as the attention then
+    read it (int8: the scales' shapes, without the head dim; bf16: the
+    heads merged or not). The int8 program gathers a tile of rows at a
+    time; the bf16 program's kernel copies a row's blocks into fast
+    memory itself, and NO tile of gathered rows is a value of the
+    program (PERF.md section 6, PR 39)."""
     eng = engines[kv_int8]
     lanes = 1 if kind == "prefill" else _LANES
     assert eng.blocks_per_lane == _TABLE
@@ -333,12 +411,15 @@ def test_engine_program_never_holds_every_lanes_table(
     assert cap > tile  # several tiles: a tile is not the table
     text = _dense_program_text(topo, eng, kind, chunk)
     _, block, nkv, d = _POOL
-    for dims in (rf"{lanes * _TABLE},{block},{nkv}(,{d})?",
-                 rf"{lanes},{_TABLE * block},{nkv}(,{d})?"):
+    heads = rf"({nkv}(,{d})?|{nkv * d})"
+    for dims in (rf"{lanes * _TABLE},{block},{heads}",
+                 rf"{lanes},{_TABLE * block},{heads}"):
         lines = _results_shaped(text, dims)
         assert not lines, "\n".join(lines[:6])
-    assert _results_shaped(
-        text, rf"({tile * w},{block}|{tile},{w * block}),{nkv},{d}")
+    a_tile = _results_shaped(
+        text, rf"({tile * w},{block}|{tile},{w * block}),{heads}")
+    assert bool(a_tile) == kv_int8, "\n".join(a_tile[:6])
+    assert ("tpu_custom_call" in text) == (not kv_int8)
 
 
 # -- the latent-attention sparse-expert family's programs ----------------------
@@ -426,11 +507,13 @@ def test_latent_program_never_copies_its_pool(topo, latent_engine, kind,
                           r"\"tpu_custom_call\"", text)) == 4
 
 
-def _holds_rows_not_tables(text, eng, kind, lanes, table, width):
+def _holds_rows_not_tables(text, eng, kind, lanes, table, width,
+                           a_tile=True):
     """No instruction's result is shaped like every lane's whole table —
     ``[lanes * M, block, width]`` as a full-table gather wrote it, or
     ``[lanes, M * block, width]`` as the attention then read it — while a
-    tile of rows is."""
+    tile of rows is (``a_tile`` False: nor a tile of rows — the program's
+    kernel copies a row's blocks into fast memory itself)."""
     assert eng.blocks_per_lane == table
     w, tile, cap = eng._rows_form(kind, lanes)
     assert cap > tile  # several tiles: a tile is not the table
@@ -439,8 +522,9 @@ def _holds_rows_not_tables(text, eng, kind, lanes, table, width):
                  rf"{lanes},{table * block},{width}(,1)?"):
         lines = _results_shaped(text, dims)
         assert not lines, "\n".join(lines[:6])
-    assert _results_shaped(
+    tiles = _results_shaped(
         text, rf"({tile * w},{block}|{tile},{w * block}),{width}")
+    assert bool(tiles) == a_tile, "\n".join(tiles[:6])
 
 
 @pytest.mark.parametrize("kind,chunk", _programs())
@@ -1035,9 +1119,11 @@ def test_window_program_never_copies_a_pool(topo, window_engine, kind,
     and a V ring a window layer by lane (144 slots at a window of 128 and
     4 drafts a round). Every one is donated and written where it lies: a
     round scatters its fed positions, the prefill chunk (told its lane
-    beside its rows) updates its lane's slice. The full layers read rows,
-    never every lane's whole table; the expert products are the
-    grouped-matmul kernel."""
+    beside its rows) updates its lane's slice. The full layer reads its
+    live rows in ONE call of the row kernel, with ``attn/rows`` in its
+    metadata: no value shaped like every lane's whole table, nor like a
+    tile of gathered rows; the expert products are the grouped-matmul
+    kernel."""
     import paddle_tpu.framework.device as device
 
     monkeypatch.setattr(device, "platform", lambda: "tpu")
@@ -1052,11 +1138,13 @@ def test_window_program_never_copies_a_pool(topo, window_engine, kind,
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= sum(p.nbytes for p in state)
     names = _program_names(compiled, loops=True)
-    assert any("/while/body/" in n for n in names)
+    reads = [n for n in names if "row_attention" in n
+             and 'custom_call_target="tpu_custom_call"' in n]
+    assert len(reads) == 1 and "attn/rows" in reads[0], reads
     lanes = 1 if kind == "prefill" else _WINDOW_LANES
     for width in (_WINDOW_K[3], _WINDOW_V[3]):
         _holds_rows_not_tables("\n".join(names), eng, kind, lanes,
-                               _WINDOW_TABLE, width)
+                               _WINDOW_TABLE, width, a_tile=False)
     pools = "|".join(rf"\w+\[{_dims(s)}\]" for s in
                      (_WINDOW_K, _WINDOW_V, *_WINDOW_RINGS))
     copies = [n[:200] for n in names

@@ -530,12 +530,16 @@ def test_a_router_moved_inside_the_margin_reads_no_gap(ref, walked,
 # tests/conftest.py sets it). PR 27 read them on its parent to prove that
 # moving the programs to serving/families/dense_gqa.py changed nothing;
 # PR 28 changed the programs on purpose (the K/V read goes by live rows)
-# and read them again. A change to the programs' functions changes a
-# hash: read them again when that is meant. So does another JAX.
+# and read them again; so did PR 39 (the bf16 pool merges the heads into
+# its last axis and the fused kernel reads it — lowered here as the CPU
+# lowers it, interpreted; all six were read, and the int8 pool's three,
+# whose read is the XLA one as before, came out as they were). A change
+# to the programs' functions changes a hash: read them again when that is
+# meant. So does another JAX.
 _DENSE_PROGRAMS = {
-    (False, "decode"): "d2b0e0cbb344f730",
-    (False, "verify"): "7a3e9826422a3c0e",
-    (False, "prefill"): "0507c2621efdc46b",
+    (False, "decode"): "1d44b334dad53699",
+    (False, "verify"): "98c593b7c87432fa",
+    (False, "prefill"): "29d03ef22fc13e55",
     (True, "decode"): "8e811a28ea173db7",
     (True, "verify"): "a35ff204101ef2f3",
     (True, "prefill"): "e80960dc3740385f",

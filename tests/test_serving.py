@@ -1045,8 +1045,8 @@ def test_engine_each_layer_reads_its_own_pool():
             err_msg=f"request {r.request_id} diverged from generate()")
 
     # the same programs' forward on a pool no layer shares with another
-    shape = eng._pools[0].shape
-    layer_of = np.arange(3, dtype=np.float32).reshape(3, 1, 1, 1, 1)
+    shape = eng._pools[0].shape  # the heads merged into the last axis
+    layer_of = np.arange(3, dtype=np.float32).reshape(3, 1, 1, 1)
     kpool = jnp.asarray(rng.randn(*shape).astype(np.float32)
                         * (1 + 2 * layer_of) + layer_of)
     vpool = jnp.asarray(rng.randn(*shape).astype(np.float32)
@@ -1074,8 +1074,10 @@ def test_engine_each_layer_reads_its_own_pool():
         return E._mm(x, params["lm_head"]).astype(jnp.float32)
 
     got = np.asarray(served(kpool, vpool, tables))
+    nkv = cfg.num_key_value_heads or cfg.num_attention_heads
     want = np.asarray(jax.jit(_parent_read_logits, static_argnums=7)(
-        params, kpool, vpool, tables, toks, pos, wlimit, cfg))
+        params, kpool.reshape(*shape[:3], nkv, -1),
+        vpool.reshape(*shape[:3], nkv, -1), tables, toks, pos, wlimit, cfg))
     # row by row and recombined: fp32 rounding apart, the same softmax
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     # what the assertion above can see: the neighbouring layer's pool,

@@ -2,8 +2,10 @@
 blocks cut into rows of ``W``, all lanes' rows end to end, run a tile at
 a time and recombined per lane as one softmax.
 
-(a) ``_attend_rows`` against ``_attend_lanes`` over every lane's whole
-    table, at fp32 tolerance, over the ragged shapes that decide it;
+(a) ``_attend_rows`` — and the fused kernel that reads the bf16 pools
+    since PR 39, ``ops/pallas/row_attention.py``, in interpret mode —
+    against ``_attend_lanes`` over every lane's whole table, at fp32
+    tolerance, over the ragged shapes that decide it;
 (b) engine outputs token-identical to per-request ``generate()`` with the
     read's constants steered small, so that tiny engines cut lanes into
     several rows over several tiles: decode, verify with accepted and
@@ -19,6 +21,7 @@ import paddle_tpu as pt
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, generate
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.engine import fit_rows, pack_rows
+from paddle_tpu.ops.pallas.row_attention import row_attention
 from paddle_tpu.serving.families import dense_gqa as E
 
 B, NKV, G, D = 4, 2, 2, 8  # block, kv heads, group, head dim
@@ -87,7 +90,82 @@ def test_rows_read_is_the_full_table_read(case, s):
     assert (np.asarray(got)[~held] == 0).all()
 
 
-def test_a_masked_row_weighs_nothing_whichever_side_it_lies():
+# the served attention geometries (kv heads, group, head dim, value dim):
+# Mistral-7B's, granite-4.0-h's, MiMo-V2.5's full layers (values narrower
+# than keys), each at two kv heads
+_GEOMETRIES = {"g4_d128": (2, 4, 128, 128), "g4_d64": (2, 4, 64, 64),
+               "g16_d192_dv128": (2, 16, 192, 128)}
+
+
+def _kernel_read(q, pos, rows, kp, vp, nkv, window=0):
+    """The fused kernel over layer 1 of stacked pools whose layer 0 is
+    poison, as the bf16 families hand them over: heads merged into the
+    last axis."""
+    def stacked(p):
+        flat = p.reshape(*p.shape[:2], -1)
+        return jnp.stack([jnp.full_like(flat, jnp.nan), flat])
+
+    return row_attention(q, pos, jnp.asarray(rows), stacked(kp), stacked(vp),
+                         1, nkv, q.shape[-1] ** -0.5, sliding_window=window)
+
+
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_kernel_read_is_the_full_table_read(case, s, geometry):
+    """The kernel, which reads the pools by (layer, block) itself, masks
+    and folds a lane's rows into one softmax on the chip: equal to the
+    read over every lane's whole table; a lane with no row reads 0."""
+    nkv, g, d, dv = _GEOMETRIES[geometry]
+    c = _CASES[case]
+    lens, window = c["lens"], c.get("window", 0)
+    rng = np.random.RandomState(len(case) + s)
+    L = len(lens)
+    nb = 1 + L * M
+    kp = jnp.asarray(rng.randn(nb, B, nkv, d).astype(np.float32))
+    vp = jnp.asarray(rng.randn(nb, B, nkv, dv).astype(np.float32))
+    tables = rng.permutation(np.arange(1, nb)).reshape(L, M).astype(np.int32)
+    q = jnp.asarray(rng.randn(L, s, nkv * g, d).astype(np.float32))
+    pos = jnp.asarray(np.asarray(lens)[:, None] + np.arange(s)[None, :],
+                      jnp.int32)
+    want = E._attend_lanes(
+        q, kp[tables].reshape(L, M * B, nkv, d),
+        vp[tables].reshape(L, M * B, nkv, dv), pos, nkv * g, nkv,
+        sliding_window=window)
+    w, _, cap = fit_rows((c["W"], c["tile"]), L, M)
+    rows, _, n, _ = pack_rows(
+        [(i, list(tables[i]), lens[i], lens[i] + s)
+         for i in range(L) if lens[i]], L, s, B, w, cap)
+    got = np.asarray(_kernel_read(q, pos, rows, kp, vp, nkv, window))
+    assert got.shape == (L, s, nkv * g, dv)
+    held = np.asarray(lens) > 0
+    np.testing.assert_allclose(got[held], np.asarray(want)[held],
+                               rtol=2e-5, atol=2e-6)
+    assert (got[~held] == 0).all()  # idle lanes read 0, not NaN
+
+
+def test_the_kernel_walks_no_pad_row():
+    """Pad rows (lane -1, after the live ones) are no grid step of the
+    kernel's: pointing them at a block of NaN moves nothing, and neither
+    does what the null block holds under a lane's last, half-empty row."""
+    lens, s = [9, 0, 21], 2
+    (kp, vp), tables, q, pos = _operands(lens, s, seed=5)
+    w, _, cap = fit_rows((2, 4), len(lens), M)
+    rows, _, n, _ = pack_rows(
+        [(i, list(tables[i]), lens[i], lens[i] + s)
+         for i in (0, 2)], len(lens), s, B, w, cap)
+    assert n < cap and (rows[n:, 0] == -1).all()
+    want = np.asarray(_kernel_read(q, pos, rows, kp, vp, NKV))
+    rows[n:, 2:] = tables[1, 0]  # the idle lane's: nobody's
+    got = np.asarray(_kernel_read(
+        q, pos, rows, kp.at[tables[1, 0]].set(jnp.nan).at[0].set(3e4),
+        vp.at[tables[1, 0]].set(jnp.nan).at[0].set(-7e4), NKV))
+    np.testing.assert_array_equal(got, want)
+    assert (got[1] == 0).all()
+
+
+@pytest.mark.parametrize("read", ["xla", "kernel"])
+def test_a_masked_row_weighs_nothing_whichever_side_it_lies(read):
     """A wholly masked row (under the window, or above the position)
     contributes exactly zero: poisoning its blocks with huge values moves
     nothing, before the lane's visible rows or after them."""
@@ -98,7 +176,7 @@ def test_a_masked_row_weighs_nothing_whichever_side_it_lies():
     rows, *_ = pack_rows([(0, list(tables[0]), lens[0], M * B)], 1, s, B,
                          w, cap)
 
-    def read(k, v):
+    def read_xla(k, v):
         def gather(blocks):
             T = blocks.shape[0]
             return tuple(p[blocks].reshape(T, w * B, NKV, D)
@@ -106,6 +184,11 @@ def test_a_masked_row_weighs_nothing_whichever_side_it_lies():
         return np.asarray(E._attend_rows(
             q, pos, jnp.asarray(rows), gather, tile, NKV,
             sliding_window=window))
+
+    def read_kernel(k, v):
+        return np.asarray(_kernel_read(q, pos, rows, k, v, NKV, window))
+
+    read = {"xla": read_xla, "kernel": read_kernel}[read]
 
     # rows 0-2 (slots 0..23) lie under the window of positions 37-38
     # (slots > 28), rows 5 (slots 40..47) above them
@@ -285,6 +368,33 @@ def test_read_counters_on_a_ragged_run(model, small_rows, spec):
     assert c["kv_dense_read_tokens"] == eng.blocks_per_lane * block * (
         lanes * (c["decode_steps"] + c["verify_steps"])
         + c["prefill_chunks"])
+
+
+def test_stats_say_who_reads_the_rows(model):
+    """``stats()["row_read"]`` names each program's read and
+    ``kv_kernel_rows`` counts the live rows the kernel was handed: all of
+    a bf16 engine's, none of an int8 engine's (its pool and scales are the
+    XLA read's) nor of a family with a read of its own."""
+    reqs = _requests(model, 4, seed=11)
+    eng = ServingEngine(model, ServingConfig(
+        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32))
+    _serve(eng, reqs)
+    stats = eng.stats()
+    assert stats["row_read"] == dict.fromkeys(
+        ("prefill", "decode", "verify"), "kernel")
+    assert stats["paged_attention"] is False
+    rows = stats["kv_kernel_rows"]
+    assert 0 < rows and stats["kv_gathered_tokens"] >= rows * 2
+    quant = ServingEngine(model, ServingConfig(
+        max_lanes=3, block_size=2, prefill_chunk=4, max_seq_len=32,
+        kv_int8=True, spec=False))
+    _serve(quant, reqs)
+    assert quant.stats()["row_read"] == {"prefill": "xla", "decode": "xla"}
+    assert quant.stats()["kv_kernel_rows"] == 0
+    latent = ServingEngine(_tiny_latent(), ServingConfig(
+        max_lanes=2, block_size=2, prefill_chunk=4, max_seq_len=16,
+        spec=False))
+    assert set(latent.stats()["row_read"].values()) == {"xla"}
 
 
 def _tiny_latent():

@@ -29,10 +29,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def _row_reads(form, g, i32, geom, slot):
+def _row_reads(form, g, i32, geom, slot, tiled=True):
     """(static kwargs by program, read operands by program) of a family
     whose programs read their lanes' live rows: ``form(kind)`` its ``(W,
-    tile)``; ``slot``: the prefill chunk is told its state slot too."""
+    tile)``; ``slot``: the prefill chunk is told its state slot too;
+    ``tiled``: the programs run the rows ``tile`` at a time themselves
+    (not the families whose read is the row kernel)."""
     from paddle_tpu.serving.engine import fit_rows
 
     L, _, C, K, M = geom
@@ -41,7 +43,7 @@ def _row_reads(form, g, i32, geom, slot):
                                ("prefill", 1, C)):
         w, tile, cap = fit_rows(form(kind), lanes, M)
         reads[kind] = (i32(cap, 2 + w), i32(lanes, width))
-        statics[kind] = {"cfg": g, "tile": tile}
+        statics[kind] = {"cfg": g, "tile": tile} if tiled else {"cfg": g}
     if slot:
         reads["prefill"] += (i32(1),)
     return statics, reads
@@ -99,7 +101,7 @@ def _hybrid(arch, cfg, layers, s, sds, i32, geom):
 
     statics, reads = _row_reads(
         lambda kind: (fam.ROW_BLOCKS, fam.PREFILL_TILE if kind == "prefill"
-                      else fam.ROW_TILE), g, i32, geom, True)
+                      else fam.ROW_TILE), g, i32, geom, True, tiled=False)
     return fam, statics, pools, reads
 
 
@@ -150,7 +152,8 @@ def _window(arch, cfg, layers, s, sds, i32, geom):
              sds((len(fam.ACC),), jnp.int32),
              *(sds((L, R, swa * g.head_dim)) for _ in range(n_win)),
              *(sds((L, R, swa * g.v_head_dim)) for _ in range(n_win)))
-    statics, reads = _row_reads(fam.read_form, g, i32, geom, True)
+    statics, reads = _row_reads(fam.read_form, g, i32, geom, True,
+                                tiled=False)
     return fam, statics, pools, reads
 
 
