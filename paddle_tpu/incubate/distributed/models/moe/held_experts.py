@@ -7,7 +7,11 @@ per token (e.g. 8); THIS chip holds ``n_held`` of them, from
 the part of the result that its own experts give, and leaves out what
 the absent ones would add — what an expert-parallel rank computes before
 its exchange. On one chip it runs without that exchange; nothing here
-stands in for the absent chips.
+stands in for the absent chips. Where NONE is absent (``n_held ==
+router_experts`` from ``first_held`` 0: a chip that holds the whole
+layer, ``models/conv_moe.py``) nothing is left out and the result is the
+layer's: every assignment is held, so the held-mask is all true and the
+sort puts no assignment past the last group.
 
 Against the older :class:`.moe_layer.MoELayer` (GShard top-1/top-2,
 dense ``[T, E, C]`` dispatch, capacity dropping, un-gated experts): no
@@ -48,10 +52,11 @@ def swiglu(x, w_gate_up, w_down):
             * up) @ w_down
 
 
-def route_top_k(u, w_router, top_k, scaling, bias=None):
+def route_top_k(u, w_router, top_k, scaling, bias=None, eps=1e-20):
     """Sigmoid-scored top-k over ALL the router's experts, in float32 as
     the published gate computes it: ``s = sigmoid(float32(u) W_r)``, the
-    ``top_k`` largest, ``g = s_top / (sum(s_top) + 1e-20) * scaling``.
+    ``top_k`` largest, ``g = s_top / (sum(s_top) + eps) * scaling``
+    (``eps``: the published gate's own, 1e-20 in most, 1e-6 in some).
     With a per-expert selection ``bias`` [E] the ``top_k`` largest of ``s
     + bias`` are chosen and the gates are still their ``s``: the bias
     chooses, it does not weigh.
@@ -65,7 +70,7 @@ def route_top_k(u, w_router, top_k, scaling, bias=None):
         else:
             _, top_i = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
             top_s = jnp.take_along_axis(s, top_i, axis=-1)
-        g = top_s / (jnp.sum(top_s, -1, keepdims=True) + 1e-20) * scaling
+        g = top_s / (jnp.sum(top_s, -1, keepdims=True) + eps) * scaling
         return top_i.astype(jnp.int32), g
 
 
@@ -138,15 +143,17 @@ def held_experts(u, idx, g, w_gate_up, w_down, first_held, valid=None):
         return y.astype(u.dtype), counts
 
 
-def sparse_expert_block(u, p, *, top_k, scaling, first_held, valid=None):
+def sparse_expert_block(u, p, *, top_k, scaling, first_held, valid=None,
+                        eps=1e-20):
     """The whole expert layer on normed tokens ``u`` [T, H]: route, the
     held experts' share, plus the shared expert (whole, on every chip)
     where the layer has one. ``p``: ``router`` [H, E], ``experts_gate_up``,
     ``experts_down``, ``shared_gate_up`` and ``shared_down`` (a layer
     without a shared expert has neither leaf) and, where the router has
-    one, its selection bias ``router_bias`` [E]. Returns (y, counts)."""
+    one, its selection bias ``router_bias`` [E]; ``eps`` is
+    ``route_top_k``'s. Returns (y, counts)."""
     idx, g = route_top_k(u, p["router"], top_k, scaling,
-                         p.get("router_bias"))
+                         p.get("router_bias"), eps)
     y, counts = held_experts(u, idx, g, p["experts_gate_up"],
                              p["experts_down"], first_held, valid)
     if "shared_gate_up" in p:
@@ -161,7 +168,8 @@ class HeldExperts(Layer):
     ``[n_held, in, out]``; ``n_shared`` shared experts are one SwiGLU of
     ``n_shared * width`` (``n_shared=0``: a layer without one, and without
     its two leaves). ``selection_bias`` adds the router's per-expert
-    ``router_bias`` (born zero; ``route_top_k`` says what it does).
+    ``router_bias`` (born zero; ``route_top_k`` says what it does, and
+    what ``eps`` is).
     ``forward`` returns the output; the call's per-held-expert assignment
     counts are left on ``last_counts``."""
 
@@ -170,7 +178,7 @@ class HeldExperts(Layer):
 
     def __init__(self, hidden, width, router_experts, n_held, first_held=0,
                  top_k=8, n_shared=1, scaling=1.0, dtype="float32",
-                 init_std=0.02, selection_bias=False):
+                 init_std=0.02, selection_bias=False, eps=1e-20):
         super().__init__(dtype=dtype)  # parameters are born in it
         if not 0 <= first_held <= router_experts - n_held:
             raise ValueError(
@@ -178,7 +186,7 @@ class HeldExperts(Layer):
                 f"among the router's {router_experts}")
         if top_k > router_experts:
             raise ValueError(f"top_k {top_k} > {router_experts} experts")
-        self.top_k, self.scaling = top_k, float(scaling)
+        self.top_k, self.scaling, self.eps = top_k, float(scaling), eps
         self.first_held, self.n_held = first_held, n_held
         init = I.Normal(std=init_std)
         self.router = self.create_parameter(
@@ -211,7 +219,7 @@ class HeldExperts(Layer):
             y, counts = sparse_expert_block(
                 xa.reshape(-1, shape[-1]), dict(zip(self._NAMES, ws)),
                 top_k=self.top_k, scaling=self.scaling,
-                first_held=self.first_held)
+                first_held=self.first_held, eps=self.eps)
             return y.reshape(xa.shape), counts
 
         y, counts = apply("held_experts", kernel,

@@ -2,12 +2,13 @@
 the benchmark configs in BASELINE.md name Llama, BERT, ResNet, ERNIE —
 they live in-tree here so the framework is benchmarkable standalone)."""
 from . import (  # noqa: F401
-    bert, ernie, generation, hybrid_ssm, latent_moe, linear_latent_moe,
-    llama, window_moe,
+    bert, conv_moe, ernie, generation, hybrid_ssm, latent_moe,
+    linear_latent_moe, llama, window_moe,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertForMaskedLM, BertForSequenceClassification, BertModel,
 )
+from .conv_moe import ConvMoEConfig, ConvMoEForCausalLM  # noqa: F401
 from .ernie import (  # noqa: F401
     ErnieConfig, ErnieForPretraining, ErnieForPretrainingPipe,
     ErnieForSequenceClassification, ErnieModel,
@@ -34,6 +35,7 @@ __all__ = [
     "linear_latent_moe", "LinearLatentMoEConfig",
     "LinearLatentMoEForCausalLM",
     "window_moe", "WindowMoEConfig", "WindowMoEForCausalLM",
+    "conv_moe", "ConvMoEConfig", "ConvMoEForCausalLM",
     "ernie", "ErnieConfig", "ErnieModel", "ErnieForPretraining",
     "ErnieForPretrainingPipe", "ErnieForSequenceClassification",
 ]

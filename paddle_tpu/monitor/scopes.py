@@ -44,7 +44,7 @@ __all__ = ["GROUPS", "SCOPES", "PLUMBING", "UNSCOPED", "path_of", "group_of",
 GROUPS = {
     "attn": "attn", "mla": "attn",
     "mlp": "ffn", "moe": "ffn",
-    "ssm": "state", "kda": "state",
+    "ssm": "state", "kda": "state", "sconv": "state",
     "norm": "norm",
     "embed": "head", "head": "head", "sample": "head", "spec": "head",
     "acc": "head",
@@ -62,6 +62,7 @@ SCOPES = {
             "out_proj"),
     "kda": ("proj", "conv", "gates", "state_update", "gate_norm",
             "out_proj"),
+    "sconv": ("in_proj", "conv", "out_proj"),
     "norm": (), "embed": (), "head": (), "sample": (), "spec": (),
     "acc": (),
 }

@@ -12,7 +12,8 @@ hold and not every slot of every lane's table (every family since PR
 ``None``: none is left, ROADMAP C). A family that also keeps state per
 LANE (``lane_state``: a recurrent state and conv tail, the hybrid
 state-space family's and the linear-attention family's; a ring of a
-window layer's last keys, the window-attention family's) has its one-lane
+window layer's last keys, the window-attention family's; a bare conv
+tail, the short-convolution family's) has its one-lane
 prefill chunk told which lane the request holds, whichever form its read
 takes. Three compiled programs serve the whole lifetime:
 
@@ -51,9 +52,9 @@ takes in a layer, how the weights are collected — are the model's
 FAMILY's (``serving/families``: the dense grouped-query decoder whose
 outputs are token-identical to per-request ``generate()`` calls, the
 latent-attention sparse-expert decoder, the hybrid state-space /
-attention decoder, the linear-attention and the window-attention
-sparse-expert decoders); this module is what every family shares and
-names no architecture.
+attention decoder, the linear-attention, the window-attention and the
+short-convolution sparse-expert decoders); this module is what every
+family shares and names no architecture.
 
 Reference lineage: the static-graph serving surface this replaces is
 `paddle_infer.Predictor` (`paddle/fluid/inference/api/
@@ -854,7 +855,13 @@ class ServingEngine:
           ``R >= W + k`` (``k`` = ``spec_k``), and the next accepted
           write to that slot comes before the band reaches it. A masked
           position (pad of a short draft, an idle lane) is not written.
-          The family checks the inequality when it sizes its rings."""
+          The family checks the inequality when it sizes its rings.
+        - LANE-indexed, a bare CONV TAIL (the last ``L - 1`` inputs of a
+          short convolution: the short-convolution family): the verify
+          program keeps each layer's ``[tail | k+1 positions]`` window
+          until it has the lane's acceptance, then sets the tail to the
+          window's rows that end at the last kept position; an idle
+          lane's tail is set to itself."""
         L, K = self.config.max_lanes, self.config.spec_k
         with self._phase("pack", "pack_s") as ph:
             cur = np.zeros((L,), np.int32)

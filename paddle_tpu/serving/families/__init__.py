@@ -28,9 +28,11 @@ and asks the model's *family* for the three things that differ:
 Two more kinds of state than (a) may live in a family, both told to the
 engine by attributes: ``lane_state`` — besides its token-indexed pools the
 family keeps pools indexed by LANE (``[layers, lanes, ...]``: a recurrent
-state, a conv tail, or a WINDOW of the lane's own K/V — a ring of a
-window-attention layer's last positions, ``families/window_moe.py``;
-``lane_pool_bytes(pools)`` their size, 0 elsewhere).
+state with its conv tail; a BARE conv tail and nothing else — the last
+``L - 1`` inputs of a short convolution, ``families/conv_moe.py``; or a
+WINDOW of the lane's own K/V — a ring of a window-attention layer's last
+positions, ``families/window_moe.py``; ``lane_pool_bytes(pools)`` their
+size, 0 elsewhere).
 Decode and verify index them by the batch row; the one-lane prefill chunk
 is told its request's lane, the STATE SLOT, as the last entry of its read
 operand — ``(rows, wblk, slot [1])`` in the rows form, ``(table, slot
@@ -44,7 +46,13 @@ slot ``p mod R``) under a window of ``W`` positions and ``k`` drafts a
 round that is an inequality, not an update: with ``R >= W + k`` what a
 rejected draft wrote reads, to every later query, as a position outside
 the band, and is overwritten before the band reaches it (the family
-takes ``R >= W + k + 1``). ``prefix_reuse`` —
+takes ``R >= W + k + 1``). For a bare conv tail it is a choice of rows:
+the verify program holds each conv layer's ``[tail | k+1 positions]``
+window until the head has given the lane's ``n_keep`` (its pending token
+and its accepted drafts; 0 for an idle lane) and sets the tail to the
+window's rows ``n_keep .. n_keep + L - 2`` — the rows that end at the
+last kept position, the lane's own tail where nothing is kept — so a
+rejected position is in no tail. ``prefix_reuse`` —
 False where a request cannot start from a prefix's blocks alone (it
 would need the recurrent state, or the ring, at that boundary): the
 engine then has the scheduler acquire none, and ``stats()`` says so.

@@ -1151,3 +1151,80 @@ def test_window_program_never_copies_a_pool(topo, window_engine, kind,
               if re.search(rf"= ({pools})\S* copy\(", n)]
     assert not copies, "\n".join(copies[:4])
     assert len([n for n in names if re.match(r"%gmm[\.\d]* = ", n)]) == 4
+
+
+# -- the short-convolution / grouped-query family's programs --------------------
+
+# lanes; the hidden size (2048: a tail of 2 x 2048 a conv layer), the head
+# size (64) and the key/value heads (8: a pool row of 512) are the
+# published ones: what decides the layouts of the pools and the tails
+_CONV_LANES, _CONV_TABLE = 8, 144  # 9 rows of 16 blocks a lane
+_CONV_KV = (1, 2049, 16, 8 * 64)
+_CONV_TAILS = (3, _CONV_LANES, 2 * 2048)
+
+
+@pytest.fixture(scope="module")
+def conv_engine():
+    """A conv layer over a dense SwiGLU, then conv / attention / conv over
+    expert layers that hold every expert, bf16, built on the CPU for its
+    shapes."""
+    from paddle_tpu.models import ConvMoEConfig, ConvMoEForCausalLM
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    model = ConvMoEForCausalLM(ConvMoEConfig(
+        vocab_size=512, hidden_size=2048, intermediate_size=256,
+        moe_intermediate_size=128, num_hidden_layers=4,
+        layer_types=["conv", "conv", "full_attention", "conv"],
+        num_dense_layers=1, num_attention_heads=32, num_key_value_heads=8,
+        num_experts=8, num_experts_per_tok=4, initializer_range=0.0,
+        dtype="bfloat16"))
+    model.eval()
+    return ServingEngine(model, ServingConfig(
+        max_lanes=_CONV_LANES, block_size=16, num_blocks=_CONV_KV[1],
+        prefill_chunk=32, max_seq_len=_CONV_TABLE * 16))
+
+
+@pytest.mark.parametrize("kind,chunk", _programs())
+def test_conv_program_never_copies_a_pool(topo, conv_engine, kind, chunk,
+                                          monkeypatch):
+    """Device state of two kinds by LAYER TYPE, none of which a program
+    call may copy: the attention layer's K and V pools (8 heads x 64
+    merged: 4 lane tiles) and ONE pool of tails by (conv layer, lane), 2 x
+    2048 numbers a row. Every one is donated and written where it lies: a
+    plain round shifts a layer's tails, a verify round sets them from the
+    window it kept until the head, the prefill chunk (told its lane beside
+    its rows) updates its lane's row. The attention layer reads its live
+    rows in ONE call of the row kernel, with ``attn/rows`` in its
+    metadata; the expert products are the grouped-matmul kernel."""
+    import paddle_tpu.framework.device as device
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    eng = conv_engine
+    assert (eng._pools[0].shape, eng._pools[1].shape) == (_CONV_KV,) * 2
+    assert eng._pools[3].shape == _CONV_TAILS and len(eng._pools) == 4
+    compiled = _compiled_program(topo, eng, kind, chunk)
+    state = [p for i, p in enumerate(eng._pools) if i != 2]
+    # donation holds: both pools and the tails come back in their buffers
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= sum(p.nbytes for p in state)
+    names = _program_names(compiled, loops=True)
+    reads = [n for n in names if "row_attention" in n
+             and 'custom_call_target="tpu_custom_call"' in n]
+    assert len(reads) == 1 and "attn/rows" in reads[0], reads
+    lanes = 1 if kind == "prefill" else _CONV_LANES
+    _holds_rows_not_tables("\n".join(names), eng, kind, lanes, _CONV_TABLE,
+                           _CONV_KV[3], a_tile=False)
+    pools = "|".join(rf"\w+\[{_dims(s)}\]" for s in (_CONV_KV, _CONV_TAILS))
+    copies = [n[:200] for n in names
+              if re.search(rf"= ({pools})\S* copy\(", n)]
+    assert not copies, "\n".join(copies[:4])
+    # the tails are written under the convolution's own scope (what the
+    # compiler adds is a prefetch of the small pool into fast memory and
+    # back, ``copy-start`` / ``copy-done``: no change of layout)
+    writes = [n for n in names
+              if re.match(rf"%\S+ = \w+\[{_dims(_CONV_TAILS)}\]", n)
+              and not re.search(r"[\s)](parameter|get-tuple-element|"
+                                r"copy-start|copy-done)\(", n)]
+    assert writes and all("sconv/conv" in n for n in writes), \
+        [n[:200] for n in writes if "sconv/conv" not in n]
+    assert len([n for n in names if re.match(r"%gmm[\.\d]* = ", n)]) == 6

@@ -21,7 +21,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KINDS = ("decode", "verify", "prefill")
 
 
-# -- the five families at the sizes their own tests use ------------------------
+# -- the six families at the sizes their own tests use -------------------------
 
 def _dense():
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
@@ -98,9 +98,22 @@ def _window():
                        max_seq_len=96)
 
 
+def _conv():
+    from paddle_tpu.models import ConvMoEConfig, ConvMoEForCausalLM
+
+    model = ConvMoEForCausalLM(ConvMoEConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=80,
+        moe_intermediate_size=24, num_hidden_layers=5,
+        layer_types=["conv", "conv", "conv", "full_attention", "conv"],
+        num_dense_layers=1, num_attention_heads=8, num_key_value_heads=2,
+        num_experts=16, num_experts_per_tok=4))
+    return model, dict(max_lanes=3, block_size=4, prefill_chunk=8,
+                       max_seq_len=96)
+
+
 FAMILIES = {"dense_gqa": _dense, "latent_moe": _latent,
             "hybrid_ssm": _hybrid, "linear_latent_moe": _linear,
-            "window_moe": _window}
+            "window_moe": _window, "conv_moe": _conv}
 
 
 def _engine(family):

@@ -1,7 +1,7 @@
 """Compile a serving cell's three step programs — the latent-attention
 sparse-expert family's, the hybrid state-space family's, the
-linear-attention family's or the window-attention family's, by the
-configuration's ``arch`` — at the configuration's real sizes for ONE chip
+linear-attention family's, the window-attention family's or the
+short-convolution family's, by the configuration's ``arch`` — at the configuration's real sizes for ONE chip
 of a described ``v5e:2x2`` — no chip attached, nothing runs — and print
 what each needs of the device's memory. By hand, before chip calls:
 
@@ -157,8 +157,33 @@ def _window(arch, cfg, layers, s, sds, i32, geom):
     return fam, statics, pools, reads
 
 
+def _conv(arch, cfg, layers, s, sds, i32, geom):
+    """The same of ``serving/families/conv_moe.py``: K and V pools by
+    block for the attention layers and ONE pool of tails by (conv layer,
+    LANE); the dense family's live-rows read (the prefill chunk's with
+    its lane)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import ConvMoEConfig
+    from paddle_tpu.serving.families import conv_moe as fam
+
+    L, B, _, _, _ = geom
+    g = ConvMoEConfig(**arch.config_kwargs(
+        cfg, layers, s["max_seq_len"])).static()
+    n_conv = sum(k == "conv" for k in g.layer_types)
+    kv = sds((layers - n_conv, s["num_blocks"], B,
+              g.num_key_value_heads * g.head_dim))
+    pools = (kv, kv, sds((len(fam.ACC),), jnp.int32),
+             sds((n_conv, L, (g.conv_L_cache - 1) * g.hidden_size)))
+    statics, reads = _row_reads(
+        lambda kind: (fam.ROW_BLOCKS, fam.PREFILL_TILE if kind == "prefill"
+                      else fam.ROW_TILE), g, i32, geom, True, tiled=False)
+    return fam, statics, pools, reads
+
+
 FAMILIES = {"mla_moe": _latent, "hybrid_ssm": _hybrid,
-            "kda_mla_moe": _linear, "swa_gqa_moe": _window}
+            "kda_mla_moe": _linear, "swa_gqa_moe": _window,
+            "conv_gqa_moe": _conv}
 
 
 def main():
