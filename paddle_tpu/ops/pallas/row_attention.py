@@ -23,7 +23,15 @@ so no gathered tile, no float32 copy of K/V, no relayout of 64- or 192-wide
 heads and no score tensor reaches HBM. The GRID IS BOUNDED BY THE LIVE ROWS
 (a dynamic grid bound, counted from ``rows`` by the caller's program): no
 grid step is walked for a pad row, and a call's cost follows what the lanes
-hold.
+hold. A call whose lanes each bring more than ``_Q_TILE_ROWS`` query rows a
+KV head (a 512-position prefill chunk at group 16) walks its live rows once
+a QUERY TILE — a leading grid axis over tiles of at most that many rows,
+each with its own copy pipeline, statistics and output block, so K/V is
+read once a tile (4 x 21 MB a full layer of an 8k context at 512
+positions) — and what one grid step holds in VMEM is bounded by the tile,
+not by the call's width. Which form a call takes is its SHAPE's:
+every round, and every chunk of up to 128 positions at group 16 (512 at
+group 4), is one tile and the one-axis grid.
 
 **Arithmetic** (``_attend_lanes`` is the definition; the XLA read it
 replaces rounded the same operands the same way on the chip, PERF.md
@@ -44,16 +52,31 @@ the flat last axis, at lane offsets that are multiples of 64. The heads'
 chains (product, max, exp, sum, product) are independent and a round's few
 query rows a head make each chain latency, not work: they are written a
 phase at a time over the heads, so that the scheduler overlaps them (head
-after head the same kernel was 1.2-2x slower on the chip). Query rows go
-through in chunks of ``_Q_ROWS`` (a prefill chunk of 128 positions at
-group 16 is 2,048 rows a KV head), ``_TOGETHER_ROWS`` of them side by side.
+after head the same kernel was 1.2-2x slower on the chip). A grid step's
+query rows (its tile's: at most ``_Q_TILE_ROWS`` a KV head, position-major,
+so a tile is a run of whole positions) go through in chunks of ``_Q_ROWS``,
+``_TOGETHER_ROWS`` of them side by side.
 
-**VMEM** at the served geometries (MiMo full layers: 4 KV heads x 192 / 128,
-5 positions x group 16): K and V buffers 2 x (256 x 768 + 256 x 512) x 2 B =
-1.3 MB, queries and output blocks double-buffered under 1 MB, running
-statistics padded to 128 lanes 0.5 MB; the prefill chunk: queries 2 x 4 MB,
-statistics 8 MB, accumulator and output 8 MB. ``vmem_limit_bytes`` states
-64 MiB of the chip's 128.
+**VMEM** follows the query rows ONE GRID STEP holds (queries and output
+double-buffered, the running max and sum, the float32 accumulator, the
+positions), so it is bounded by the query tile and not by the call. What
+the chip's compiler takes at the served geometries, by bisecting
+``vmem_limit_bytes`` for a described v5e with the call INSIDE a program
+(its queries a product's result and its output a product's operand, which
+the compiler may keep in VMEM too): a round of 5 positions at MiMo's full
+layers (4 KV heads x 192 / 128, group 16) — K and V buffers 2 x (256 x
+768 + 256 x 512) x 2 B = 1.3 MB, queries, output and statistics under 2
+MB; a 128-position chunk there (2,048 rows a KV head, one tile) compiles
+from 15 MiB, its family's 512-position chunk in four tiles from 27 MiB;
+LFM2's and granite's 8 KV heads x 64 at group 4 bring 2,048 rows at 512
+positions: one tile, from 26 MiB. As ONE tile of 8,192 rows MiMo's 512
+positions are REFUSED under the stated limit: the family's prefill program
+then wants 170 MB of VMEM (the kernel handed its operands as parameters
+of their own compiles alone from 51 MiB, and ran so on the chip 3.5%
+faster than four tiles: what a program can hold is what counts).
+``vmem_limit_bytes`` states 64 MiB of the chip's 128;
+``tests/test_chip_compile.py`` compiles every family's round and chunk
+under it for a described v5e, and holds the one-tile refusal.
 """
 from __future__ import annotations
 
@@ -77,14 +100,31 @@ _VMEM_LIMIT = 64 << 20
 # (PERF.md section 6, PR 39)
 _Q_ROWS = 128
 _TOGETHER_ROWS = 512
+# Query rows of one KV head that one grid step may hold (module docstring,
+# "VMEM"): a lane's rows beyond it go a tile a walk of the live rows. The
+# widest that was served before a call could be wider (128 positions at
+# group 16), so every call of up to that many rows is the kernel it was.
+_Q_TILE_ROWS = 2048
 _NEG = -1e30
+
+
+def _query_tile(M, bound):
+    """The query rows (of ``M`` a KV head) one grid step holds: all of them
+    up to ``bound``, else the largest whole number of sublane tiles (8
+    rows) that divides ``M`` under it; ``M`` where nothing divides."""
+    if M <= bound:
+        return M
+    return max((c for c in range(8, bound + 1, 8) if M % c == 0),
+               default=M)
 
 
 def _kernel(lay_ref, lane_ref, first_ref, blk_ref, pos_ref, q_ref, k_hbm,
             v_hbm, _, o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
-            W, B, nkv, d, dv, scale, window):
-    """One grid step: live row ``r`` of the lane ``lane_ref[r]``."""
-    r, n = pl.program_id(0), pl.num_programs(0)
+            W, B, nkv, d, dv, scale, window, rows_axis):
+    """One grid step: live row ``r`` of the lane ``lane_ref[r]`` (against
+    one tile of that lane's query rows where the grid has a leading axis
+    of query tiles: ``rows_axis`` 1)."""
+    r, n = pl.program_id(rows_axis), pl.num_programs(rows_axis)
     lay, lane = lay_ref[0], lane_ref[r]
     M, S = q_ref.shape[2], W * B
     slot = r % 2
@@ -184,22 +224,27 @@ def row_attention(q, pos, rows, kpool, vpool, layer, nkv, scale,
     return _rows(q, pos, rows, kpool, vpool,
                  jnp.asarray(layer, jnp.int32).reshape(1), nkv=nkv,
                  scale=float(scale), window=int(sliding_window),
+                 q_tile=_query_tile(q.shape[1] * (q.shape[2] // nkv),
+                                    _Q_TILE_ROWS),
                  interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("nkv", "scale", "window",
-                                             "interpret"))
-def _rows(q, pos, rows, kpool, vpool, layer, *, nkv, scale, window,
+                                             "q_tile", "interpret"))
+def _rows(q, pos, rows, kpool, vpool, layer, *, nkv, scale, window, q_tile,
           interpret):
     """``row_attention`` behind one trace a program: a program's layers
-    differ in ``layer`` alone, which is data."""
+    differ in ``layer`` alone, which is data. ``q_tile``: the query rows a
+    KV head that one grid step holds (:func:`_query_tile`)."""
     b, s, nh, d = q.shape
     g = nh // nkv
     B = kpool.shape[2]
     dv = vpool.shape[3] // nkv
     W = rows.shape[1] - 2
     M = s * g
+    T = q_tile
     assert kpool.shape[3] == nkv * d, (kpool.shape, nkv, d)
+    assert M % T == 0, (M, T)
     # a KV head's queries as rows, position-major: [b, nkv, s x g, d]
     qh = q.reshape(b, s, nkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, nkv, M, d)
@@ -207,31 +252,47 @@ def _rows(q, pos, rows, kpool, vpool, layer, *, nkv, scale, window,
     lane = rows[:, 0]
     n_live = jnp.sum(lane >= 0, dtype=jnp.int32)
 
-    def by_lane(*block):
-        return pl.BlockSpec((1, *block), lambda r, lay, ln, *_: (
-            ln[r], *(0,) * len(block)))
+    # one tile: the grid is the live rows alone, as it was before a call
+    # could be wider than a tile; more: a leading axis of query tiles, each
+    # walking the live rows (the query-row axis of a block: ``rows_at``)
+    tiled = T < M
+    grid = (M // T, n_live) if tiled else (n_live,)
+
+    def by_lane(*block, rows_at):
+        """The block of the lane that grid row ``r`` answers to (``ln``,
+        the second prefetched operand) and, along the block's query-row
+        axis ``rows_at``, of the grid's tile."""
+        def index(*ids):
+            at_grid, ln = ids[:len(grid)], ids[len(grid) + 1]
+            at = [0] * len(block)
+            at[rows_at] = at_grid[0] if tiled else 0
+            return (ln[at_grid[-1]], *at)
+
+        return pl.BlockSpec((1, *block), index)
 
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         functools.partial(_kernel, W=W, B=B, nkv=nkv, d=d, dv=dv,
-                          scale=scale, window=window),
+                          scale=scale, window=window,
+                          rows_axis=len(grid) - 1),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(n_live,),
-            in_specs=[by_lane(M, 1), by_lane(nkv, M, d), anywhere, anywhere,
+            num_scalar_prefetch=4, grid=grid,
+            in_specs=[by_lane(T, 1, rows_at=0),
+                      by_lane(nkv, T, d, rows_at=1), anywhere, anywhere,
                       anywhere],
-            out_specs=by_lane(nkv, M, dv),
+            out_specs=by_lane(nkv, T, dv, rows_at=1),
             scratch_shapes=[
                 pltpu.VMEM((2, W * B, nkv * d), kpool.dtype),
                 pltpu.VMEM((2, W * B, nkv * dv), vpool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((nkv, M, 1), F32), pltpu.VMEM((nkv, M, 1), F32),
-                pltpu.VMEM((nkv, M, dv), F32)]),
+                pltpu.VMEM((nkv, T, 1), F32), pltpu.VMEM((nkv, T, 1), F32),
+                pltpu.VMEM((nkv, T, dv), F32)]),
         out_shape=jax.ShapeDtypeStruct((b, nkv, M, dv), q.dtype),
         # a lane no row answers to is no grid step's: it reads the zeros
         # the output's buffer came in with (operand 8: after the 4 prefetched)
         input_output_aliases={8: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=_VMEM_LIMIT),
         name="row_attention",
         interpret=interpret,

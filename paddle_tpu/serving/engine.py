@@ -141,12 +141,15 @@ class ServingConfig:
     - ``num_blocks`` (``PT_SERVE_BLOCKS``): pool size incl. the reserved
       null block; default sizes every lane for ``max_seq_len`` (no
       preemption pressure — shrink it to trade HBM for requeues).
-    - ``prefill_chunk`` (``PT_SERVE_PREFILL_CHUNK``; default
-      :data:`PREFILL_CHUNK`, 128): prefill program width; prompts enter
-      in ceil(len/chunk) calls, and a call reads every weight whatever
-      its width, so the default is as wide as that read pays for on the
-      chip (PERF.md section 6, PR 32). Left unset (``None`` here) the
-      engine fits the default to its geometry — whole blocks, never past
+    - ``prefill_chunk`` (``PT_SERVE_PREFILL_CHUNK``; default: the
+      model's FAMILY's ``prefill_chunk`` — 512 for the window-attention
+      and the short-convolution families, whose call reads every held
+      expert —, :data:`PREFILL_CHUNK`, 128, for a family that names
+      none): prefill program width; prompts enter in ceil(len/chunk)
+      calls, and a call reads every weight whatever its width, so the
+      default is as wide as that read pays for on the chip (PERF.md
+      section 6, PR 32 and PR 42). Left unset (``None`` here) the engine
+      fits the default to its geometry — whole blocks, never past
       ``max_seq_len`` rounded down to whole blocks
       (:func:`default_prefill_chunk`; ``ServingEngine.prefill_chunk`` is
       the width in use); a width given here or in the environment is
@@ -219,18 +222,21 @@ class ServingConfig:
                 raise ValueError(f"{name} must be >= 1, got {v}")
 
 
-# The prefill call's default width, chosen on the chip (PERF.md section 6,
-# PR 32): a call reads all the weights to push its tokens, and up to about
-# this width it costs what a 32-token call costs in every family.
+# The prefill call's default width for a family that names none of its own
+# (``prefill_chunk`` on the family object), chosen on the chip (PERF.md
+# section 6, PR 32): a call reads all the weights to push its tokens, and
+# up to about this width it costs what a 32-token call costs in the dense,
+# hybrid and latent families.
 PREFILL_CHUNK = 128
 
 
-def default_prefill_chunk(max_seq_len, block_size):
-    """:data:`PREFILL_CHUNK` fitted to an engine's geometry: whole blocks
-    (at least one), and no wider than ``max_seq_len`` rounded down to
-    whole blocks — a model that serves 48 tokens gets a 48-token call,
-    not 80 positions of padding in every one."""
-    cap = min(PREFILL_CHUNK, max_seq_len) // block_size
+def default_prefill_chunk(max_seq_len, block_size, width=PREFILL_CHUNK):
+    """``width`` (a family's ``prefill_chunk``, or :data:`PREFILL_CHUNK`)
+    fitted to an engine's geometry: whole blocks (at least one), and no
+    wider than ``max_seq_len`` rounded down to whole blocks — a model that
+    serves 48 tokens gets a 48-token call, not 80 positions of padding in
+    every one."""
+    cap = min(width, max_seq_len) // block_size
     return max(cap, 1) * block_size
 
 
@@ -309,8 +315,11 @@ class ServingEngine:
                                or fam.max_position_embeddings)
         self.blocks_per_lane = blocks_needed(self.max_seq_len,
                                              cfg.block_size)
+        # the width is the family's (what its call reads against what a
+        # token uses is its own layers'), unless the deployer gave one
         self.prefill_chunk = int(cfg.prefill_chunk or default_prefill_chunk(
-            self.max_seq_len, cfg.block_size))
+            self.max_seq_len, cfg.block_size,
+            getattr(fam, "prefill_chunk", PREFILL_CHUNK)))
         num_blocks = int(cfg.num_blocks
                          or cfg.max_lanes * self.blocks_per_lane + 1)
         # the device state every step program threads through (the

@@ -57,6 +57,16 @@ False where a request cannot start from a prefix's blocks alone (it
 would need the recurrent state, or the ring, at that boundary): the
 engine then has the scheduler acquire none, and ``stats()`` says so.
 
+``prefill_chunk`` — the width of the family's prefill call where the
+engine's default (``engine.PREFILL_CHUNK``, 128) is not its own: what a
+call reads against what a position uses is the family's layers', so a
+family whose call reads every held expert for a few of them a position
+says 512 (``families/window_moe.py``, ``families/conv_moe.py``). The
+engine fits it to whole blocks under ``max_seq_len``
+(``engine.default_prefill_chunk``); ``ServingConfig.prefill_chunk`` /
+``PT_SERVE_PREFILL_CHUNK`` given win. A family without the attribute
+takes the engine's.
+
 ``row_read`` — ``"kernel"`` where the family's programs read their live
 rows through ``ops/pallas/row_attention.py`` (the engine then bills
 ``kv_kernel_rows``, and ``stats()["row_read"]`` says so); a family without

@@ -76,6 +76,18 @@ CONV_ACC = ("conv_slot_resets", "spec_rolled_back_tokens",
             "moe_round_experts_hit")
 ACC = MOE_ACC + CONV_ACC
 N_POOLS = 4  # K pool, V pool, the accumulator, the tails
+# The prefill call's width (``ConvMoEFamily.prefill_chunk``; the engine
+# fits it to whole blocks under ``max_seq_len``, and a width a deployer
+# gives wins): ``families/window_moe.PREFILL_CHUNK``'s arithmetic — a
+# call's FLOPs meet its bytes at ~240 positions x (weights read / weights
+# a position uses). This family holds EVERY expert and a position uses its
+# top-k: at the served cut (LFM2-24B-A2B, top-4 of 64) a call reads 5.2 G
+# weights for the 0.5 G a position uses, FLOPs under bytes until ~2,300
+# positions, and a 128-position call was 16 ms of which 14.5 read experts
+# for 8 rows each. 512, the widest measured (PERF.md section 6, PR 42: the
+# sweep 128 / 256 / 512); not wider: a call holds every decoding lane for
+# its length (ROADMAP A2).
+PREFILL_CHUNK = 512
 
 
 def _bump(acc, **by):
@@ -274,6 +286,7 @@ class ConvMoEFamily:
     """See ``families/__init__.py`` for what the engine asks of it."""
 
     name = "conv_moe"
+    prefill_chunk = PREFILL_CHUNK
     lane_state = True
     prefix_reuse = False
     row_read = "kernel"  # the attention layers' live rows: row_attention

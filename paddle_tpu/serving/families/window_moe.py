@@ -88,6 +88,22 @@ ROW_BLOCKS = 16
 ROW_TILE = 16
 PREFILL_TILE = 4
 RING_TILE = 16  # a ring is whole 16-row tiles of the model dtype
+# The prefill call's width (``WindowMoEFamily.prefill_chunk``; the engine
+# fits it to whole blocks under ``max_seq_len``, and a width a deployer
+# gives wins). A call reads every weight the chip holds whatever its width,
+# and on the v5e a weight's 2 FLOP a position meet its 2 bytes at ~240
+# positions (197 TFLOP/s over 819 GB/s), so a call's FLOPs meet its bytes
+# at ~240 x (weights read / weights a position uses). A dense stack uses
+# all it reads: ~240, and the dense call costs +27% at 256 (PERF.md section
+# 6, PR 32: 128 for a family that says nothing). Here a call reads every
+# HELD expert and a position uses its top-k of ALL the experts — at the
+# served cut (MiMo-V2.5, 16 of 256 held, top-8: half a held expert a layer)
+# 3.4 G weights read for 0.9 G used: ~900 positions — and the prompts are
+# long, so the calls of a prompt, each a read of every weight, are what the
+# width buys down. 512, the widest measured (PERF.md section 6, PR 42: the
+# sweep 128 / 256 / 512); a call also holds every decoding lane for its
+# length (ROADMAP A2), so not wider.
+PREFILL_CHUNK = 512
 
 # the device accumulator's slots after the expert layer's: prefill chunks
 # that started a lane's rings empty (position 0); drafted positions whose
@@ -151,20 +167,34 @@ def ring_round(q, k, v, pos, valid, rk, rv, lp, cfg):
 def ring_chunk(q, k, v, pos, start, n_real, slot, rk, rv, lp, cfg):
     """A window layer in one lane's prefill chunk at ``pos`` [1, C] =
     ``start ..``: the queries over the lane's ring as the positions before
-    ``start`` left it, plus the chunk's own keys; then the ring takes the
+    ``start`` left it, plus the chunk's own keys (a block of queries at a
+    time against the keys its band can reach); then the ring takes the
     chunk's last real positions (``n_real`` of the C are real). Returns
     (att [1, C, H x dv], rk, rv)."""
-    C = pos.shape[1]
+    C, T = pos.shape[1], cfg.sliding_window
     R, g = rk.shape[1], cfg.swa_num_key_value_heads
     with jax.named_scope("attn/window"):
         old_k = jax.lax.dynamic_slice_in_dim(rk, slot, 1)    # [1, R, ..]
         old_v = jax.lax.dynamic_slice_in_dim(rv, slot, 1)
         at = jnp.concatenate([held_positions(start - 1, R), pos[0]])
+        keys = jnp.concatenate([_heads(old_k, g), k], 1)[0]  # [R + C, ..]
+        vals = jnp.concatenate([_heads(old_v, g), v], 1)[0]
+        # queries in blocks of ``w`` positions, block j against the R + w
+        # keys that end with its own: a chunk of several whole windows a
+        # window at a time — the ring and block 0 for the first; a later
+        # block's band starts inside the chunk (R >= T - 1 keys back at
+        # most), so scores are [C, R + T], not [C, R + C] —, any other
+        # chunk as its one block
+        w = T if C > T and C % T == 0 else C
+
+        def blocks(a):  # [R + C, ..] -> [C // w, R + w, ..]
+            return jnp.stack([a[j:j + R + w] for j in range(0, C, w)])
+
+        q_at = pos.reshape(-1, w)
         # (pads among the chunk's keys lie after every real query)
-        vis = M.band_mask(pos[0][:, None], at[None, :], cfg.sliding_window)
-        att = M.attend(q, jnp.concatenate([_heads(old_k, g), k], 1),
-                       jnp.concatenate([_heads(old_v, g), v], 1),
-                       vis[None], lp["sink"])
+        vis = M.band_mask(q_at[..., None], blocks(at)[:, None, :], T)
+        att = M.attend(q.reshape(-1, w, *q.shape[2:]), blocks(keys),
+                       blocks(vals), vis, lp["sink"]).reshape(1, C, -1)
         # slot s takes the latest real position that falls in it, if any
         takes = held_positions(start + n_real - 1, R)
         idx = jnp.clip(takes - start, 0, C - 1)
@@ -347,6 +377,7 @@ class WindowMoEFamily:
     """See ``families/__init__.py`` for what the engine asks of it."""
 
     name = "window_moe"
+    prefill_chunk = PREFILL_CHUNK
     lane_state = True
     prefix_reuse = False
     row_read = "kernel"  # the full layers' live rows: row_attention

@@ -258,13 +258,17 @@ def _program_names(compiled, loops=False):
     return out
 
 
-def _programs():
+def _programs(family_width=None):
     """Every step program, the prefill chunk at the width the tests'
-    engines pin (32) and at the engine's default."""
+    engines pin (32), at the engine's default and — a family that names a
+    width of its own (``prefill_chunk``) — at the family's."""
+    own = [] if family_width is None else [
+        pytest.param("prefill", family_width, id="prefill_family")]
     return [pytest.param("decode", None, id="decode"),
             pytest.param("verify", None, id="verify"),
             pytest.param("prefill", None, id="prefill"),
-            pytest.param("prefill", PREFILL_CHUNK, id="prefill_default")]
+            pytest.param("prefill", PREFILL_CHUNK, id="prefill_default"),
+            *own]
 
 
 def _results_shaped(text, dims):
@@ -346,45 +350,99 @@ def test_row_read_compiles_at_served_geometries(
 
 
 # the benchmark cells' full-attention geometries: (heads, kv heads, head
-# dim, value dim, lanes) — Mistral-7B's, granite-4.0-h's (a head is half a
-# 128-lane tile), MiMo-V2.5's full layers (192 is no multiple of 128, and
-# values narrower than keys)
-_ROW_READ_AT = {"mistral_g4_d128": (32, 8, 128, 128, 32),
-                "granite_g4_d64": (32, 8, 64, 64, 64),
-                "mimo_g16_d192_dv128": (64, 4, 192, 128, 64)}
+# dim, value dim, lanes, the prefill widths served: the family's own, and
+# the engine's where a deployer may still give it) — Mistral-7B's,
+# granite-4.0-h's (a head is half a 128-lane tile), MiMo-V2.5's full
+# layers (192 is no multiple of 128, values narrower than keys; its
+# family's 512 positions at group 16 are FOUR query tiles of the kernel's
+# grid), LFM2's (granite's heads at its family's 512: 2,048 rows, one tile)
+_ROW_READ_AT = {
+    "mistral_g4_d128": (32, 8, 128, 128, 32, (PREFILL_CHUNK,)),
+    "granite_g4_d64": (32, 8, 64, 64, 64, (PREFILL_CHUNK,)),
+    "mimo_g16_d192_dv128": (64, 4, 192, 128, 64, (PREFILL_CHUNK, 256, 512)),
+    "lfm2_g4_d64": (32, 8, 64, 64, 64, (512,))}
 
 
-@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
-@pytest.mark.parametrize("geometry", sorted(_ROW_READ_AT))
-def test_row_kernel_compiles_at_the_cells_geometries(topo, geometry, kind):
-    """The live-rows read at the served head geometries and lanes, a
-    plain round's, a verify round's and a prefill chunk's queries: the
-    fused kernel (``ops/pallas/row_attention.py``) compiles for the chip
-    — head slices at lane offsets of 64 and 192, 2,048 query rows a KV
-    head in MiMo's chunk — and the program holds no gathered tile ``[T, W
-    x B, kv_heads, d]`` (float32 or not, heads merged or not) and no value
-    of one layer's pool shape."""
-    from paddle_tpu.ops.pallas.row_attention import row_attention
+def test_the_compiled_prefill_widths_are_the_families():
+    from paddle_tpu.serving.families import conv_moe, window_moe
 
-    nh, nkv, d, dv, lanes = _ROW_READ_AT[geometry]
-    layers, nb, B, W, rows_a_lane = 2, 2049, 16, 16, 6
-    b, s = {"decode": (lanes, 1), "verify": (lanes, 5),
-            "prefill": (1, PREFILL_CHUNK)}[kind]
+    assert _ROW_READ_AT["mimo_g16_d192_dv128"][5][-1] \
+        == window_moe.WindowMoEFamily.prefill_chunk
+    assert _ROW_READ_AT["lfm2_g4_d64"][5] \
+        == (conv_moe.ConvMoEFamily.prefill_chunk,)
+
+
+def _row_read_in_a_program(sd, row_attention, q_shape, nkv, dv, rows, pool):
+    """The kernel's call lowered with its queries a product's result and
+    its output a product's operand, as a family's program has them.
+    Handed the kernel as parameters of their own the compiler places them
+    otherwise, and a call that NO program can hold compiles alone (PR 42:
+    MiMo's 512 positions as one query tile, 170 MB of VMEM in a program)."""
+    b, s, nh, d = q_shape
+
+    def read(x, w, o, pos, rows, kpool, vpool, li):
+        q = (x @ w).reshape(b, s, nh, d)
+        out = row_attention(q, pos, rows, kpool, vpool, li, nkv, d ** -0.5)
+        return out.reshape(b * s, nh * dv) @ o
+
+    return jax.jit(read).lower(
+        sd(jnp.bfloat16, b * s, 4096), sd(jnp.bfloat16, 4096, nh * d),
+        sd(jnp.bfloat16, nh * dv, 4096), sd(jnp.int32, b, s),
+        sd(jnp.int32, *rows), sd(jnp.bfloat16, *pool, nkv * d),
+        sd(jnp.bfloat16, *pool, nkv * dv), sd(jnp.int32))
+
+
+def test_one_query_tile_cannot_hold_the_widest_chunk(topo, monkeypatch):
+    """Why the kernel's grid has its axis of query tiles: MiMo's 512
+    positions at group 16 (8,192 query rows a KV head) in ONE grid step
+    are refused by the chip's compiler inside a program."""
+    from paddle_tpu.ops.pallas import row_attention as RA
+
+    nh, nkv, d, dv, _, widths = _ROW_READ_AT["mimo_g16_d192_dv128"]
+    monkeypatch.setattr(RA, "_Q_TILE_ROWS", max(widths) * (nh // nkv))
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def sd(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def read(q, pos, rows, kpool, vpool, li):
-        return row_attention(q, pos, rows, kpool, vpool, li, nkv, d ** -0.5)
+    with _kernels_compiled():
+        lowered = _row_read_in_a_program(
+            sd, RA.row_attention, (1, max(widths), nh, d), nkv, dv,
+            (6, 18), (2, 2049, 16))
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
+
+
+@pytest.mark.parametrize("geometry,kind", [
+    (g, k) for g, at in sorted(_ROW_READ_AT.items())
+    for k in ("decode", "verify", *(f"prefill{w}" for w in at[5]))])
+def test_row_kernel_compiles_at_the_cells_geometries(topo, geometry, kind):
+    """The live-rows read at the served head geometries and lanes, a
+    plain round's, a verify round's and a prefill chunk's queries at
+    every width its family serves: the fused kernel
+    (``ops/pallas/row_attention.py``) compiles for the chip under its
+    stated ``vmem_limit_bytes`` inside a program (its queries a
+    product's result) — head slices at lane offsets of 64 and
+    192, 2,048 query rows a KV head a grid step in MiMo's chunks (the 512
+    positions of its family's call in four query tiles) and in LFM2's —
+    and the program holds no gathered tile ``[T, W x B, kv_heads, d]``
+    (float32 or not, heads merged or not) and no value of one layer's
+    pool shape."""
+    from paddle_tpu.ops.pallas.row_attention import row_attention
+
+    nh, nkv, d, dv, lanes, _ = _ROW_READ_AT[geometry]
+    layers, nb, B, W, rows_a_lane = 2, 2049, 16, 16, 6
+    b, s = {"decode": (lanes, 1), "verify": (lanes, 5)}.get(
+        kind) or (1, int(kind[len("prefill"):]))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     with _kernels_compiled():
-        text = jax.jit(read).lower(
-            sd(jnp.bfloat16, b, s, nh, d), sd(jnp.int32, b, s),
-            sd(jnp.int32, b * rows_a_lane, 2 + W),
-            sd(jnp.bfloat16, layers, nb, B, nkv * d),
-            sd(jnp.bfloat16, layers, nb, B, nkv * dv),
-            sd(jnp.int32)).compile().as_text()
+        text = _row_read_in_a_program(
+            sd, row_attention, (b, s, nh, d), nkv, dv,
+            (b * rows_a_lane, 2 + W), (layers, nb, B)).compile().as_text()
     assert "tpu_custom_call" in text and "row_attention" in text
     for dims in (rf"\d+,{W * B},({nkv},)?\d+", rf"{nb},{B},\d+"):
         lines = _results_shaped(text, dims)
@@ -1110,7 +1168,7 @@ def window_engine():
         prefill_chunk=32, max_seq_len=_WINDOW_TABLE * 16))
 
 
-@pytest.mark.parametrize("kind,chunk", _programs())
+@pytest.mark.parametrize("kind,chunk", _programs(512))
 def test_window_program_never_copies_a_pool(topo, window_engine, kind,
                                             chunk, monkeypatch):
     """Device state of two kinds by LAYER TYPE, none of which a program
@@ -1184,7 +1242,7 @@ def conv_engine():
         prefill_chunk=32, max_seq_len=_CONV_TABLE * 16))
 
 
-@pytest.mark.parametrize("kind,chunk", _programs())
+@pytest.mark.parametrize("kind,chunk", _programs(512))
 def test_conv_program_never_copies_a_pool(topo, conv_engine, kind, chunk,
                                           monkeypatch):
     """Device state of two kinds by LAYER TYPE, none of which a program

@@ -347,8 +347,8 @@ def tails(eng, lane=0):
     return np.asarray(eng._pools[3][:, lane]).reshape(-1)
 
 
-@pytest.mark.parametrize("chunk", [8, 12, 128],
-                         ids=["chunk8", "chunk12", "chunk128"])
+@pytest.mark.parametrize("chunk", [8, 12, 40, 128],
+                         ids=["chunk8", "chunk12", "chunk40", "chunk128"])
 def test_chunked_prefill_and_plain_decode_equal_the_full_forward(
         ref, model, chunk):
     """Prompts of 1 and 2 tokens (shorter than the tail), of 8, 16 and 24
@@ -358,7 +358,9 @@ def test_chunked_prefill_and_plain_decode_equal_the_full_forward(
     longer ones, decoded with speculation off: every served token is the
     reference's first choice to within ``TOL`` at its position. At 128
     every prompt is ONE padded call, of which the last 2 real rows are
-    kept."""
+    kept; at 40 (a width no prompt length here divides, as the served
+    512) the 70-token prompt is a FULL wide call and then a part-padded
+    one whose tail is taken at its 30 real tokens."""
     eng = engine(model, spec=False, prefill_chunk=chunk)
     work = prompts(3) + [np.arange(n, dtype=np.int32) * 7 % 251
                          for n in (1, 2, 3, 8, 9, 10, 11, 16, 17, 18, 19,
@@ -489,7 +491,8 @@ def test_a_reused_lane_gives_what_a_fresh_engine_gives(ref, model):
     assert eng.stats()["conv_slot_resets"] == 2
 
 
-@pytest.mark.parametrize("chunk", [8, 128], ids=["chunk8", "chunk128"])
+@pytest.mark.parametrize("chunk", [8, 40, 128],
+                         ids=["chunk8", "chunk40", "chunk128"])
 def test_a_preempted_request_resumes_token_identically(model, chunk):
     """A pool too small for three growing requests: the newest is
     preempted, its lane handed on, and its re-admission's prefill

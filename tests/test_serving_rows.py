@@ -144,6 +144,91 @@ def test_kernel_read_is_the_full_table_read(case, s, geometry):
     assert (got[~held] == 0).all()  # idle lanes read 0, not NaN
 
 
+# the query-tiled grid (a call of more query rows a KV head than one grid
+# step may hold: PERF.md section 6, PR 42), with the bound lowered so that
+# tiny shapes cross it. lens / s / W as in _CASES; bound: query rows a KV
+# head a tile; upto: the slots the rows cover (a prefill chunk's: its real
+# tokens' end), real: the fed positions compared (a chunk's pads read what
+# no one reads)
+_TILED = {
+    # 12 positions at group 2 in tiles of 4 over 6 rows of 8 slots: the
+    # first two tiles' positions (30-37) end below the last row's first
+    # slot (40), wholly masked to them
+    "one_lane_several_rows": dict(lens=[30], s=12, W=2, bound=8),
+    "lanes_and_an_idle_one": dict(lens=[20, 0, 9], s=8, W=2, bound=8),
+    # a chunk at 24 of 16 fed positions, 9 of them real: rows to slot 33
+    "part_padded_chunk": dict(lens=[24], s=16, W=2, bound=8, upto=33,
+                              real=9),
+    "one_block_rows": dict(lens=[17, 5], s=8, W=1, bound=8),
+    "window": dict(lens=[30], s=12, W=2, bound=8, window=6),
+    "window_wider_than_a_row": dict(lens=[26, 13], s=8, W=2, bound=8,
+                                    window=19),
+}
+
+
+@pytest.mark.parametrize("geometry", ["tiny", *sorted(_GEOMETRIES)])
+@pytest.mark.parametrize("case", sorted(_TILED))
+def test_query_tiled_kernel_read_is_the_full_table_read(
+        case, geometry, monkeypatch):
+    """A lane's query rows beyond the bound go a tile a walk of the live
+    rows — a leading grid axis, each tile with its own copy pipeline,
+    running softmax and output block: equal to the read over every lane's
+    whole table, at the served head geometries too."""
+    from paddle_tpu.ops.pallas import row_attention as RA
+
+    nkv, g, d, dv = (NKV, G, D, D) if geometry == "tiny" \
+        else _GEOMETRIES[geometry]
+    c = _TILED[case]
+    lens, s, window = c["lens"], c["s"], c.get("window", 0)
+    bound = c["bound"] * g // G  # the same positions a tile at any group
+    monkeypatch.setattr(RA, "_Q_TILE_ROWS", bound)
+    assert RA._query_tile(s * g, bound) == bound < s * g  # several tiles
+    rng = np.random.RandomState(len(case))
+    L = len(lens)
+    nb = 1 + L * M
+    kp = jnp.asarray(rng.randn(nb, B, nkv, d).astype(np.float32))
+    vp = jnp.asarray(rng.randn(nb, B, nkv, dv).astype(np.float32))
+    tables = rng.permutation(np.arange(1, nb)).reshape(L, M).astype(np.int32)
+    q = jnp.asarray(rng.randn(L, s, nkv * g, d).astype(np.float32))
+    pos = jnp.asarray(np.asarray(lens)[:, None] + np.arange(s)[None, :],
+                      jnp.int32)
+    want = np.asarray(E._attend_lanes(
+        q, kp[tables].reshape(L, M * B, nkv, d),
+        vp[tables].reshape(L, M * B, nkv, dv), pos, nkv * g, nkv,
+        sliding_window=window))
+    w, _, cap = fit_rows((c["W"], 4), L, M)
+    rows, _, n, _ = pack_rows(
+        [(i, list(tables[i]), lens[i], c.get("upto", lens[i] + s))
+         for i in range(L) if lens[i]], L, s, B, w, cap)
+    assert n > L  # a lane of several rows
+    got = np.asarray(_kernel_read(q, pos, rows, kp, vp, nkv, window))
+    assert got.shape == (L, s, nkv * g, dv)
+    held = np.asarray(lens) > 0
+    real = c.get("real", s)
+    np.testing.assert_allclose(got[held, :real], want[held, :real],
+                               rtol=2e-5, atol=2e-6)
+    assert np.isfinite(got).all()
+    assert (got[~held] == 0).all()  # idle lanes read 0, not NaN
+
+
+@pytest.mark.parametrize("rows,bound,want", [
+    (80, 2048, 80), (2048, 2048, 2048),  # a round, a 128-position chunk at
+    #                                      group 16, 512 at group 4: one tile
+    (8192, 2048, 2048), (4096, 2048, 2048),  # 512 / 256 positions, group 16
+    (2560, 2048, 1280), (24, 8, 8),
+    (36, 8, 36), (2 * 1031, 2048, 2 * 1031),  # nothing divides: one tile
+])
+def test_the_query_tile_is_the_shapes(rows, bound, want):
+    """Every call of up to the bound is one tile (the kernel it was before
+    a call could be wider); a wider one takes the largest whole number of
+    8-row sublane tiles under the bound that divides its rows."""
+    from paddle_tpu.ops.pallas.row_attention import _Q_TILE_ROWS, _query_tile
+
+    assert _Q_TILE_ROWS == 2048
+    assert _query_tile(rows, bound) == want
+    assert rows % want == 0
+
+
 def test_the_kernel_walks_no_pad_row():
     """Pad rows (lane -1, after the live ones) are no grid step of the
     kernel's: pointing them at a block of NaN moves nothing, and neither
@@ -622,3 +707,67 @@ def test_a_latent_row_above_its_lanes_positions_weighs_nothing():
     np.testing.assert_array_equal(
         _latent_rows_read(pool.at[dead].set(3e4), *args),
         _latent_rows_read(pool, *args))
+
+def _kernel_call(b, s, nh, nkv, d, dv):
+    """The traced ``pallas_call`` of one read: (grid, operand blocks,
+    grid semantics)."""
+    import jax
+
+    from paddle_tpu.ops.pallas.row_attention import row_attention
+
+    def sd(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    (eqn,) = calls(jax.make_jaxpr(
+        lambda q, pos, rows, kp, vp: row_attention(
+            q, pos, rows, kp, vp, 0, nkv, d ** -0.5))(
+        sd(jnp.bfloat16, b, s, nh, d), sd(jnp.int32, b, s),
+        sd(jnp.int32, 6 * b, 18), sd(jnp.bfloat16, 2, 65, 16, nkv * d),
+        sd(jnp.bfloat16, 2, 65, 16, nkv * dv)).jaxpr)
+    at = eqn.params["grid_mapping"]
+    blocks = [tuple(getattr(n, "block_size", n) for n in m.block_shape)
+              for m in at.block_mappings]
+    return (len(at.grid), blocks,
+            eqn.params["compiler_params"]["mosaic_tpu"].dimension_semantics)
+
+
+# (lanes, positions) of every call a cell serves, by head geometry: a plain
+# and a verify round of 64 lanes (32: Mistral's), the family's prefill chunk
+_SERVED_CALLS = [
+    (geometry, b, s)
+    for geometry, lanes, width in (("g4_d128", 32, 128), ("g4_d64", 64, 128),
+                                   ("g4_d64", 64, 512),
+                                   ("g16_d192_dv128", 64, 128))
+    for b, s in ((lanes, 1), (lanes, 5), (1, width))]
+
+
+@pytest.mark.parametrize("geometry,b,s", sorted(set(_SERVED_CALLS)))
+def test_a_call_within_the_bound_is_the_one_axis_kernel(geometry, b, s):
+    """Every call the cells served before a call could be wider (and
+    LFM2's 512 at group 4) is traced to the kernel call it was: the grid
+    the live rows alone, each block a lane's WHOLE query rows — so the
+    cells that keep their width run the program they ran (PERF.md section
+    6, PR 42: read here, not from compiled text by eye)."""
+    nkv, g, d, dv = _GEOMETRIES[geometry]
+    M = s * g
+    axes, blocks, semantics = _kernel_call(b, s, nkv * g, nkv, d, dv)
+    assert axes == 1 and semantics == ("arbitrary",)
+    assert blocks[:2] == [(1, M, 1), (1, nkv, M, d)]
+    assert blocks[-1] == (1, nkv, M, dv)
+
+
+def test_the_widest_served_chunk_is_four_query_tiles():
+    """MiMo's 512 positions at group 16: a leading grid axis of four tiles
+    of the 2,048 rows a 128-position chunk brought."""
+    nkv, g, d, dv = _GEOMETRIES["g16_d192_dv128"]
+    axes, blocks, semantics = _kernel_call(1, 512, nkv * g, nkv, d, dv)
+    assert axes == 2 and semantics == ("arbitrary", "arbitrary")
+    assert blocks[:2] == [(1, 2048, 1), (1, nkv, 2048, d)]
+    assert blocks[-1] == (1, nkv, 2048, dv)

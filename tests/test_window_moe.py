@@ -327,8 +327,8 @@ def rings(eng, lane=0):
                            for p in eng._pools[3:]])
 
 
-@pytest.mark.parametrize("chunk", [8, 12, 128],
-                         ids=["chunk8", "chunk12", "chunk128"])
+@pytest.mark.parametrize("chunk", [8, 12, 40, 128],
+                         ids=["chunk8", "chunk12", "chunk40", "chunk128"])
 def test_chunked_prefill_and_plain_decode_equal_the_full_forward(
         ref, model, chunk):
     """Prompts shorter than, equal to and several times the chunk, the
@@ -337,7 +337,11 @@ def test_chunked_prefill_and_plain_decode_equal_the_full_forward(
     position. Chunks of 8 put every chunk boundary inside a band; chunks
     of 12 leave the ring's slots out of step with the chunks; at 128
     every prompt is ONE padded call wider than the ring, of which the
-    last 16 real positions are kept."""
+    last 16 real positions are kept; at 40 — two and a half rings, five
+    windows, a width no prompt length here divides, as the served 512 is
+    over a ring of 144 — a long prompt is a FULL wide call and then a
+    part-padded one that starts from the ring the first left, and both
+    go through the band a window of queries at a time."""
     eng = engine(model, spec=False, prefill_chunk=chunk)
     assert eng.stats()["win_ring_len"] == 16
     work = prompts(5) + [np.arange(8, dtype=np.int32),
@@ -436,7 +440,8 @@ def test_a_reused_lane_gives_what_a_fresh_engine_gives(ref, model):
     assert eng.stats()["win_slot_resets"] == 2
 
 
-@pytest.mark.parametrize("chunk", [8, 128], ids=["chunk8", "chunk128"])
+@pytest.mark.parametrize("chunk", [8, 40, 128],
+                         ids=["chunk8", "chunk40", "chunk128"])
 def test_a_preempted_request_resumes_token_identically(model, chunk):
     """A pool too small for three growing requests: the newest is
     preempted, its lane handed on, and its re-admission's prefill

@@ -181,9 +181,13 @@ def _conv(arch, cfg, layers, s, sds, i32, geom):
     return fam, statics, pools, reads
 
 
-FAMILIES = {"mla_moe": _latent, "hybrid_ssm": _hybrid,
-            "kda_mla_moe": _linear, "swa_gqa_moe": _window,
-            "conv_gqa_moe": _conv}
+# architecture -> (its family's module under serving/families, the
+# function above that describes its pools and reads)
+FAMILIES = {"mla_moe": ("latent_moe", _latent),
+            "hybrid_ssm": ("hybrid_ssm", _hybrid),
+            "kda_mla_moe": ("linear_latent_moe", _linear),
+            "swa_gqa_moe": ("window_moe", _window),
+            "conv_gqa_moe": ("conv_moe", _conv)}
 
 
 def main():
@@ -201,7 +205,8 @@ def main():
 
     import paddle_tpu.framework.device as device
     from paddle_tpu.serving import ServingConfig
-    from paddle_tpu.serving.engine import default_prefill_chunk
+    from paddle_tpu.serving.engine import (PREFILL_CHUNK,
+                                           default_prefill_chunk)
 
     # steer the code's ONE rule for "am I on the chip" here, in the
     # script: the described chip gets the real kernels, not interpret mode
@@ -231,13 +236,22 @@ def main():
                        max_seq_len=s["max_seq_len"],
                        num_blocks=s["num_blocks"])
     L, B, K = sc.max_lanes, sc.block_size, sc.spec_k
-    C = sc.prefill_chunk or default_prefill_chunk(s["max_seq_len"], B)
+    module, describe = FAMILIES[cfg["arch"]]
+    # the width the engine would take: the family object's own, as
+    # ``ServingEngine.__init__`` reads it (a family module exports its
+    # family class alone)
+    families = importlib.import_module(
+        "paddle_tpu.serving.families." + module)
+    (family,) = families.__all__
+    C = sc.prefill_chunk or default_prefill_chunk(
+        s["max_seq_len"], B, getattr(getattr(families, family),
+                                     "prefill_chunk", PREFILL_CHUNK))
     M = -(-s["max_seq_len"] // B)
 
     def i32(*shape):
         return sds(shape, jnp.int32)
 
-    fam, statics, pools, reads = FAMILIES[cfg["arch"]](
+    fam, statics, pools, reads = describe(
         arch, cfg, layers, s, sds, i32, (L, B, C, K, M))
     programs = {
         "decode": (fam._decode_step, (reads["decode"], i32(L), i32(L))),
