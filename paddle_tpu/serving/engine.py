@@ -44,6 +44,13 @@ takes. Three compiled programs serve the whole lifetime:
   verify program itself, which takes up the pending token and the
   accepted drafts and nothing else (:meth:`ServingEngine._verify_round`).
 
+A call's operands — where its lanes' K/V lies, and the lengths, tokens
+and limits above — are all ``int32`` and of static shape, and reach the
+device as ONE array in ONE transfer (:class:`OperandLayout`; the compiled
+program, :func:`packed_program` around the family's function, cuts it
+apart by static slices): a transfer costs the host 0.2 ms whatever its
+size, with the chip idle (PERF.md section 6, PR 43).
+
 All three compile through :func:`paddle_tpu.jit.exec_cache.get_or_compile`
 (keyed on generation config, param avals, pool geometry, lane count and
 mesh), so a warm ``PT_EXEC_CACHE`` server start pays zero fresh XLA
@@ -92,9 +99,11 @@ from __future__ import annotations
 
 import collections
 import functools
+import math
 import os
 import sys
 import time
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -255,7 +264,9 @@ def pack_rows(items, lanes, width, block, w, cap):
     """The live-rows read operand of one program call (the kernel
     ``ops/pallas/row_attention.py`` and the families' ``_attend_rows``
     read it), as numpy. ``items``: per
-    occupied lane ``(lane, blocks, first, upto)`` — its block list, the
+    occupied lane ``(lane, blocks, first, upto)`` — its block list (a
+    list, or an ``int32`` array of it: ``scheduler.BlockList.ids``, which
+    is copied where a list is converted), the
     position of its first token this call, and the slots ``[0, upto)``
     its queries may see. Returns ``(rows [cap, 2 + w], wblk [lanes,
     width], live rows, live blocks)``: each lane's blocks below ``upto``
@@ -275,12 +286,13 @@ def pack_rows(items, lanes, width, block, w, cap):
         counts.append(-(-nb // w))
         n += counts[-1]
         live += nb
+        # block by block, not position by position: block lo + k holds
+        # the call's positions [(lo + k) * block - first, + block)
         lo = first // block
-        seg = blocks[lo:(first + width - 1) // block + 1]
-        for j in range(width):
-            k = (first + j) // block - lo
-            if k < len(seg):
-                wblk[lane, j] = seg[k]
+        at = lo * block - first
+        for b in blocks[lo:(first + width - 1) // block + 1]:
+            wblk[lane, max(at, 0):at + block] = b
+            at += block
     rows = np.empty((cap, 2 + w), np.int32)
     rows[:n, 0] = np.repeat(owners, counts)
     rows[n:, 0] = -1
@@ -290,6 +302,78 @@ def pack_rows(items, lanes, width, block, w, cap):
     rows[n:, 1] = 0
     rows[:, 2:] = ids.reshape(cap, w)
     return rows, wblk, n, live
+
+
+class OperandLayout(typing.NamedTuple):
+    """Where each operand of a step-program call lies in the call's ONE
+    packed ``int32`` vector. A program's operands — its read operand
+    (:meth:`ServingEngine._pack_read`) and its kind's own (lengths and
+    tokens; a chunk, its start, the context length, the last index) — are
+    all ``int32``, their shapes static, and a transfer costs the host the
+    same whatever its size (PERF.md section 6, PR 28 and PR 43): they go
+    up end to end in one array (:meth:`pack`), and the compiled program
+    cuts it apart by static slices (:meth:`unpack`,
+    :func:`packed_program`). Built once an engine and kind, from the
+    operands' shapes (:meth:`of`); hashable, so two engines of one
+    geometry share a traced program."""
+
+    tree: typing.Any   # the operands' pytree structure
+    cuts: tuple        # per leaf: (offset, shape)
+    size: int          # the packed vector's length
+
+    @classmethod
+    def of(cls, spec):
+        """The layout of operands shaped like ``spec`` (a pytree of
+        ``jax.ShapeDtypeStruct``, in the order the program takes them)."""
+        leaves, tree = jax.tree_util.tree_flatten(spec)
+        cuts, n = [], 0
+        for leaf in leaves:
+            assert leaf.dtype == jnp.int32, leaf
+            cuts.append((n, tuple(leaf.shape)))
+            n += math.prod(leaf.shape)
+        return cls(tree, tuple(cuts), n)
+
+    def pack(self, operands) -> np.ndarray:
+        """``operands`` (numpy; a scalar may be a plain int) laid end to
+        end. A FRESH buffer a call: prefill calls are enqueued without a
+        sync, and the CPU backend may alias host memory, so a reused
+        buffer would be overwritten under a program in flight."""
+        leaves = jax.tree_util.tree_leaves(operands)
+        if [np.shape(a) for a in leaves] != [c[1] for c in self.cuts]:
+            raise ValueError(
+                f"operands shaped {[np.shape(a) for a in leaves]} do not "
+                f"fit the program's {[c[1] for c in self.cuts]}")
+        return np.concatenate([np.ravel(a) for a in leaves], dtype=np.int32)
+
+    def unpack(self, packed):
+        """Inside the program: the operands back out of ``packed``, by
+        static slices, in the structure the family's function takes."""
+        return self.tree.unflatten([
+            packed[lo] if shape == () else jax.lax.slice(
+                packed, (lo,), (lo + math.prod(shape),)).reshape(shape)
+            for lo, shape in self.cuts])
+
+
+@functools.lru_cache(maxsize=None)
+def packed_program(fn, layout):
+    """A family's step function ``fn(params, *pools, read, *operands,
+    **static)`` as the engine compiles it: ``(params, *pools, packed,
+    **static)`` — params and pools where they were (``donate_argnums``
+    holds), the operands as ONE ``int32`` vector laid out by ``layout``.
+    It wears ``fn``'s name: the compiled module (``jit__decode_step``),
+    the registry of ``monitor/scopes.py`` and every scope path
+    (``jit(_decode_step)/...``) are keyed by it. The slices run under
+    ``embed`` (the fed tokens and positions: group ``head``). Cached, so
+    engines of one geometry trace a program once."""
+    def program(params, *args, **static):
+        *pools, packed = args
+        with jax.named_scope("embed"):
+            operands = layout.unpack(packed)
+        return fn(params, *pools, *operands, **static)
+
+    program.__name__ = fn.__name__
+    program.__qualname__ = fn.__qualname__
+    return program
 
 
 # -- the engine ---------------------------------------------------------------
@@ -346,6 +430,8 @@ class ServingEngine:
         self._prefill_exec = None
         self._decode_exec = None
         self._verify_exec = None
+        # kind -> OperandLayout of the program's one packed operand
+        self._layouts: dict = {}
         # speculative decoding (docs/SERVING.md): active iff configured
         # on AND k > 0; the drafter slot is pluggable (a draft model
         # would implement Drafter.propose) — default prompt-lookup
@@ -402,6 +488,10 @@ class ServingEngine:
             "kv_read_tokens": 0, "kv_gathered_tokens": 0,
             "kv_dense_read_tokens": 0, "kv_kernel_rows": 0,
             "kv_quant_writes": 0, "kv_quant_tokens": 0,
+            # arrays (and their bytes) handed to the device by step-program
+            # calls: one a call (_operand), so operand_uploads ==
+            # decode_steps + verify_steps + prefill_chunks
+            "operand_uploads": 0, "operand_upload_bytes": 0,
             # wall seconds per phase of step() (monitor/spans.Phase):
             # they telescope to step_s up to the statements between
             # them; dispatch_s + fetch_s is a round's launch-to-tokens
@@ -457,7 +547,6 @@ class ServingEngine:
 
         cfgv, fam = self.config, self._family
         L, M, C = cfgv.max_lanes, self.blocks_per_lane, self.prefill_chunk
-        i32 = jnp.int32
         # donation halves pool HBM traffic; XLA:CPU can't donate these
         # and would warn per call. Which operands churn write-for-write
         # with the cache (int8 mode: the scale pools too) is the family's
@@ -473,45 +562,35 @@ class ServingEngine:
             return {"kind": kind, **fam_key, "donate": donate,
                     "mesh": exec_cache.mesh_spec(), **extra}
 
-        def jitted(kind):
-            fn, static = fam.program(kind)
-            kw = {"static_argnames": tuple(static)}
-            if donate:
-                kw["donate_argnums"] = fam.donate_argnums
-            return jax.jit(fn, **kw), static
-
         def extra(static):  # what of a program's statics its key names
             return {k: v for k, v in static.items() if k != "cfg"}
 
-        dec, dstatic = jitted("decode")
-        self._decode_exec = exec_cache.get_or_compile(
-            key("serving_decode", lanes=L, m=M, **extra(dstatic)),
-            lambda: dec.lower(
-                self._params, *pools, self._read_spec("decode", L, 1),
-                jax.ShapeDtypeStruct((L,), i32),
-                jax.ShapeDtypeStruct((L,), i32), **dstatic),
-            label="serving/decode")
-        pre, pstatic = jitted("prefill")
-        scal = jax.ShapeDtypeStruct((), i32)
-        self._prefill_exec = exec_cache.get_or_compile(
-            key("serving_prefill", m=M, chunk=C, **extra(pstatic)),
-            lambda: pre.lower(
-                self._params, *pools, self._read_spec("prefill", 1, C),
-                jax.ShapeDtypeStruct((1, C), i32),
-                scal, scal, scal, **pstatic),
-            label="serving/prefill")
+        def build(kind, **geometry):
+            """Program ``kind`` (``geometry``: what its key names): the
+            family's function behind ONE packed operand
+            (:func:`packed_program`), laid out by :meth:`_layout`."""
+            fn, static = fam.program(kind)
+            layout = self._layout(kind)
+            kw = {"static_argnames": tuple(static)}
+            if donate:
+                kw["donate_argnums"] = fam.donate_argnums
+            program = jax.jit(packed_program(fn, layout), **kw)
+            # the key names the operands' form: an executable serialized
+            # for the tuple of arrays is never loaded for the packed one
+            return exec_cache.get_or_compile(
+                key("serving_" + kind, operands=("packed", layout.size),
+                    **geometry, **extra(static)),
+                lambda: program.lower(
+                    self._params, *pools,
+                    jax.ShapeDtypeStruct((layout.size,), jnp.int32),
+                    **static),
+                label="serving/" + kind)
+
+        self._decode_exec = build("decode", lanes=L, m=M)
+        self._prefill_exec = build("prefill", m=M, chunk=C)
         if self.spec_active:
-            S = self.config.spec_k + 1
-            ver, vstatic = jitted("verify")
-            self._verify_exec = exec_cache.get_or_compile(
-                key("serving_verify", lanes=L, m=M, k=self.config.spec_k,
-                    **extra(vstatic)),
-                lambda: ver.lower(
-                    self._params, *pools, self._read_spec("verify", L, S),
-                    jax.ShapeDtypeStruct((L,), i32),
-                    jax.ShapeDtypeStruct((L, S), i32),
-                    jax.ShapeDtypeStruct((L,), i32), **vstatic),
-                label="serving/verify")
+            self._verify_exec = build("verify", lanes=L, m=M,
+                                      k=cfgv.spec_k)
 
     # -- the step loop -------------------------------------------------------
 
@@ -632,6 +711,24 @@ class ServingEngine:
             spec += (jax.ShapeDtypeStruct((1,), i32),)
         return spec
 
+    def _layout(self, kind) -> OperandLayout:
+        """Where program ``kind``'s operands lie in its ONE packed
+        operand: the read operand (:meth:`_read_spec`), then the kind's
+        own — ``cur``, ``last`` | ``cur``, ``toks``, ``wlim`` | ``chunk``,
+        ``start``, ``ctx``, ``last_idx``. Derived once a kind."""
+        if kind not in self._layouts:
+            L, C = self.config.max_lanes, self.prefill_chunk
+            S = self.config.spec_k + 1
+            lanes, width, own = {
+                "decode": (L, 1, ((L,), (L,))),
+                "verify": (L, S, ((L,), (L, S), (L,))),
+                "prefill": (1, C, ((1, C), (), (), ()))}[kind]
+            self._layouts[kind] = OperandLayout.of(
+                (self._read_spec(kind, lanes, width),
+                 *(jax.ShapeDtypeStruct(shape, jnp.int32)
+                   for shape in own)))
+        return self._layouts[kind]
+
     def _pack_read(self, kind, lanes, width, items, ph=None, slot=None):
         """Program ``kind``'s read operand for one call, as numpy, in
         the form its family takes: LIVE ROWS ``(rows, wblk)``
@@ -707,12 +804,11 @@ class ServingEngine:
                 # the chunk sees its lane's slots below its own end
                 read = self._pack_read(
                     "prefill", 1, C,
-                    [(0, req.blocks, start, min(start + C, ctx))],
+                    [(0, req.blocks.ids, start, min(start + C, ctx))],
                     slot=req.lane)
                 tok, *self._pools = self._prefill_exec(
-                    self._params, *self._pools, *jax.device_put(
-                        (read, chunk, np.int32(start), np.int32(ctx),
-                         np.int32(last_idx))))
+                    self._params, *self._pools, self._operand(
+                        "prefill", read, chunk, start, ctx, last_idx))
                 nchunks += 1
                 if sp is not None:
                     # enqueue wall only (no per-chunk host sync — the one
@@ -818,13 +914,24 @@ class ServingEngine:
             drafts[id(req)] = d
         return drafts
 
-    def _launch(self, kind, program, operands, lanes):
+    def _operand(self, kind, *operands):
+        """One call's operands (numpy, as program ``kind``'s family takes
+        them after the pools) as the ONE array the compiled program
+        takes: laid end to end (:class:`OperandLayout`). It is handed to
+        the executable as numpy: the call itself makes the one transfer,
+        0.19 ms of host time sooner than a ``jax.device_put`` before it
+        (PERF.md section 6, PR 43)."""
+        packed = self._layout(kind).pack(operands)
+        self.counters["operand_uploads"] += 1
+        self.counters["operand_upload_bytes"] += packed.nbytes
+        return packed
+
+    def _launch(self, kind, program, packed, lanes):
         """Run the round's program over the pools and fetch its tokens:
         returns them as numpy with the stamp of the fetch's end — the
         round's ONE host sync, and every lane's attribution mark."""
         with self._phase("dispatch", "dispatch_s", kind=kind, lanes=lanes):
-            out, *self._pools = program(self._params, *self._pools,
-                                        *operands)
+            out, *self._pools = program(self._params, *self._pools, packed)
         with self._phase("token_fetch", "fetch_s") as fetch:
             out = np.asarray(out)
         # a family's own counters ride on the fetched array
@@ -886,12 +993,12 @@ class ServingEngine:
                 wlim[req.lane] = req.pool_len + 1 + d.size
                 # rejected positions sit above pool_len in lane-private
                 # blocks: the read covers the pending token and the draft
-                items.append((req.lane, req.blocks, req.pool_len,
+                items.append((req.lane, req.blocks.ids, req.pool_len,
                               req.pool_len + 1 + int(d.size)))
-            operands = jax.device_put(
-                (self._pack_read("verify", L, K + 1, items, ph), cur, toks,
-                 wlim))
-        preds, now = self._launch("verify", self._verify_exec, operands,
+            packed = self._operand(
+                "verify", self._pack_read("verify", L, K + 1, items, ph),
+                cur, toks, wlim)
+        preds, now = self._launch("verify", self._verify_exec, packed,
                                   len(act))
         preds = preds.reshape(L, K + 1)
         with self._phase("emit", "emit_s") as ph:
@@ -975,10 +1082,10 @@ class ServingEngine:
                 last[req.lane] = req.output[-1]
             read = self._pack_read(
                 "decode", L, 1,
-                [(r.lane, r.blocks, r.pool_len, r.pool_len + 1)
+                [(r.lane, r.blocks.ids, r.pool_len, r.pool_len + 1)
                  for r in act], ph)
-            operands = jax.device_put((read, cur, last))
-        toks, now = self._launch("decode", self._decode_exec, operands,
+            packed = self._operand("decode", read, cur, last)
+        toks, now = self._launch("decode", self._decode_exec, packed,
                                  len(act))
         with self._phase("emit", "emit_s") as ph:
             if _spans is not None:

@@ -23,7 +23,15 @@ and asks the model's *family* for the three things that differ:
     time, and each fed token's write block: ``(rows [R, 2 + W], wblk
     [lanes, width])`` (``engine.pack_rows``), every family's read since
     PR 35; ``None`` — a ``[lanes, M]`` block table, which no family
-    takes any more (ROADMAP C14).
+    takes any more (ROADMAP C14). That is the signature of the family's
+    ``fn``, and it stays: tests and tools call it as it is. What the
+    ENGINE compiles takes ``(params, *pools, packed, **static)`` — the
+    read operand and the kind's own operands, all ``int32``, laid end to
+    end in ONE vector that goes up in one transfer and is cut apart by
+    static slices before ``fn`` is called (``engine.packed_program``,
+    under ``fn``'s own name; ``engine.OperandLayout`` owns the layout,
+    which ``ServingEngine._layout`` derives from ``_read_spec`` and the
+    kind's own operands' shapes).
 
 Two more kinds of state than (a) may live in a family, both told to the
 engine by attributes: ``lane_state`` — besides its token-indexed pools the

@@ -583,7 +583,8 @@ class RouterEngine:
         "spec_proposed_tokens", "spec_accepted_tokens",
         "spec_bonus_tokens", "prefix_hit_tokens", "prefix_miss_tokens",
         "kv_read_tokens", "kv_gathered_tokens", "kv_dense_read_tokens",
-        "kv_kernel_rows", "step_s", "admit_s",
+        "kv_kernel_rows", "operand_uploads", "operand_upload_bytes",
+        "step_s", "admit_s",
         "prefill_s", "first_fetch_s", "grow_s", "draft_s", "pack_s",
         "dispatch_s", "fetch_s", "emit_s",
         "decode_rounds", "free_blocks", "allocatable_blocks",

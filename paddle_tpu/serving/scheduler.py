@@ -76,6 +76,48 @@ FINISHED = "finished"
 _auto_id = itertools.count()
 
 
+class BlockList(list):
+    """A running request's block ids: the list the scheduler grows and
+    trims and every reader reads, which also keeps an ``int32`` array of
+    itself (:attr:`ids`) for the engine's operand packing — a round's
+    ``pack_rows`` copies arrays, where converting every lane's list cost
+    42 ns a live block a round on the chip's host (0.55 ms a round at
+    64 lanes of 3.3k tokens: PERF.md section 6, PR 43). It changes at its
+    TAIL only — ``extend`` and ``del blocks[n:]`` — and says so: any other
+    mutation raises, where it would have left the array behind."""
+
+    __slots__ = ("_ids",)
+
+    def __init__(self, blocks=()):
+        super().__init__(blocks)
+        self._ids = np.array(self, np.int32)
+
+    @property
+    def ids(self) -> np.ndarray:
+        """The block ids as an ``int32`` array (a view: copy to keep)."""
+        return self._ids[:len(self)]
+
+    def extend(self, blocks) -> None:
+        n = len(self)
+        super().extend(blocks)
+        if len(self) > self._ids.size:  # room for twice what is held
+            self._ids = np.resize(self._ids, 2 * len(self))
+        self._ids[n:len(self)] = self[n:]
+
+    def __delitem__(self, at) -> None:
+        if not (isinstance(at, slice) and at.stop is None
+                and at.step is None and at.start is not None):
+            self._refuse()
+        super().__delitem__(at)
+
+    def _refuse(self, *args, **kw):
+        raise TypeError("a BlockList changes at its tail only: extend() "
+                        "and del blocks[n:]")
+
+    append = insert = pop = remove = sort = reverse = clear = _refuse
+    __setitem__ = __iadd__ = __imul__ = _refuse
+
+
 class Request:
     """One generation request and its full lifecycle state.
 
@@ -123,7 +165,7 @@ class Request:
                              else int(eos_token_id))
         self.state = WAITING
         self.output: list = []
-        self.blocks: list = []
+        self.blocks = BlockList()
         self.lane = None
         # tokens whose K/V sit in the pool (= prefilled context while
         # running; the pending output token is NOT yet written)
@@ -294,7 +336,7 @@ class FCFSScheduler:
                 self.pool.free(hits, req)  # back to the cold LRU
                 break  # runners will free blocks as they finish
             self.waiting.popleft()
-            req.blocks = hits + blocks
+            req.blocks = BlockList(hits + blocks)
             req.lane = lane
             req.state = RUNNING
             req.pool_len = 0  # set by the engine's prefill
@@ -450,7 +492,7 @@ class FCFSScheduler:
         arrival order)."""
         freed = len(req.blocks)
         self.pool.free(req.blocks, req)
-        req.blocks = []
+        req.blocks = BlockList()
         lane = req.lane
         self.lanes[req.lane] = None
         req.lane = None
@@ -484,7 +526,7 @@ class FCFSScheduler:
         """Reclaim a finished lane: KV blocks and the lane slot return to
         the pool immediately (the eviction the admission loop feeds on)."""
         self.pool.free(req.blocks, req)
-        req.blocks = []
+        req.blocks = BlockList()
         self.lanes[req.lane] = None
         req.lane = None
         req.state = FINISHED

@@ -750,6 +750,46 @@ def test_dense_program_fusions_carry_a_group(topo, engines, kv_int8, kind):
 
 
 @pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_the_packed_program_compiles_for_the_chip(topo, engines,
+                                                  hybrid_engine, family,
+                                                  kind):
+    """What the ENGINE compiles (``engine.packed_program``: the family's
+    function behind ONE packed int32 operand) for the described chip: the
+    row kernel takes its scalar-prefetch ``rows`` from a static slice of
+    that vector, the module keeps the function's name, and the slices
+    that are instructions of their own fall to ``embed`` — none of the
+    operand's readers is left without a group."""
+    from paddle_tpu.serving.engine import packed_program
+    from test_program_scopes import users_of_the_packed_operand
+
+    eng = engines[False] if family == "dense" else hybrid_engine
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    layout = eng._layout(kind)
+    fn, static = eng._family.program(kind)
+    with _kernels_compiled():
+        text = jax.jit(
+            packed_program(fn, layout), static_argnames=tuple(static),
+            donate_argnums=eng._family.donate_argnums,
+        ).lower(*jax.tree_util.tree_map(spec, (eng._params, *eng._pools)),
+                jax.ShapeDtypeStruct((layout.size,), jnp.int32,
+                                     sharding=one_chip),
+                **static).compile().as_text()
+    module, table, users = users_of_the_packed_operand(text, layout.size)
+    assert module == "jit_" + fn.__name__
+    assert "tpu_custom_call" in text  # the row kernel (and the state's)
+    assert users
+    for name in users:
+        assert table[name][1] and table[name][4] != "none", \
+            (name, table[name])
+    assert any(row[0].startswith("embed") for row in table.values())
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify", "prefill"])
 def test_latent_program_fusions_carry_a_group(topo, latent_engine, kind,
                                               monkeypatch):
     bare, fusions = _fusions_without_a_group(

@@ -365,6 +365,51 @@ def test_the_registry_outlives_the_programs():
     assert scopes.stale_programs() == 0
 
 
+def users_of_the_packed_operand(text, size):
+    """A compiled program's module name, its scope table, and the ENTRY
+    instructions that read its one packed ``s32[size]`` parameter."""
+    module, table = scopes.parse(text)
+    entry = text[text.index("\nENTRY "):].split("\n}")[0]
+    (packed,) = re.findall(
+        rf"%?([\w.\-]+) = s32\[{size}\]\S* parameter\(", entry)
+    users = [m.group(1) for m in map(scopes._INSTR.match, entry.splitlines())
+             if m and m.group(1) != packed and packed in
+             scopes._OPERAND.findall(m.group(2).split(", metadata=")[0])]
+    return module, table, users
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_the_engines_programs_keep_their_names_and_scope_their_unpack(
+        family, monkeypatch):
+    """What the ENGINE compiles is the family's function behind one packed
+    operand (``engine.packed_program``): it keeps the function's name —
+    three modules, three registry entries, each under its
+    ``serving/<kind>`` label — and the slices that cut the operand apart
+    fall to a declared scope, none to "unscoped"."""
+    monkeypatch.setattr(scopes, "_programs", {})
+    eng = _engine(family)
+    jax.clear_caches()
+    with exec_cache._fresh_compile():
+        eng.warmup()
+    progs = scopes.compiled()
+    assert {m: p["label"] for m, p in progs.items()} == {
+        "jit__decode_step": "serving/decode",
+        "jit__verify_step": "serving/verify",
+        "jit__prefill_chunk": "serving/prefill"}
+    assert scopes.stale_programs() == 0
+    for kind, exe in (("decode", eng._decode_exec),
+                      ("verify", eng._verify_exec),
+                      ("prefill", eng._prefill_exec)):
+        module, table, users = users_of_the_packed_operand(
+            exe.compiled.as_text(), eng._layout(kind).size)
+        assert users, module
+        for name in users:
+            assert table[name][1] and table[name][4] != "none", \
+                (module, name, table[name])
+        stray = [n for n, row in table.items() if row[4] == "none"]
+        assert len(stray) <= LOOP_BOOKKEEPING, (module, stray)
+
+
 def test_the_train_steps_program_is_recorded_too(monkeypatch):
     """Both compile sites go through ``exec_cache.get_or_compile``: the
     hook there records ``TrainStep``'s program at no further code. A
