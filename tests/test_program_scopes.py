@@ -5,6 +5,7 @@ outlives the programs, and the benchmark's reduction of a device trace by
 scope (``benchmarks/chip/chiplib/devscopes.py``) puts op time where it
 belongs."""
 import contextlib
+import hashlib
 import os
 import re
 import sys
@@ -124,9 +125,10 @@ def _engine(family):
     return eng
 
 
-def _program_text(eng, kind):
-    """Program ``kind`` as ``ServingEngine._ensure_compiled`` lowers it,
-    compiled here and now (no cache between the caller and the trace)."""
+def _program_texts(eng, kind):
+    """Program ``kind`` as ``ServingEngine._ensure_compiled`` lowers it:
+    (its lowered text, without debug info; its text compiled here and now
+    — no cache between the caller and the trace)."""
     def spec(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
@@ -146,17 +148,21 @@ def _program_text(eng, kind):
     # program WITHOUT its metadata, so a hit hands back the names of
     # whichever tree compiled it first (tests/conftest.py turns it on)
     with exec_cache._fresh_compile():
-        return jax.jit(fn, static_argnames=tuple(static)).lower(
-            *pools, eng._read_spec(kind, lanes, width), *rest,
-            **static).compile().as_text()
+        lowered = jax.jit(fn, static_argnames=tuple(static)).lower(
+            *pools, eng._read_spec(kind, lanes, width), *rest, **static)
+        return lowered.as_text(), lowered.compile().as_text()
+
+
+def _program_text(eng, kind):
+    return _program_texts(eng, kind)[1]
 
 
 @pytest.fixture(scope="module", params=list(FAMILIES))
 def texts(request):
     """{kind: (text with the scopes on, text with ``jax.named_scope`` a
-    null context)} of one family's three programs."""
+    null context, the lowered text)} of one family's three programs."""
     eng = _engine(request.param)
-    on = {kind: _program_text(eng, kind) for kind in KINDS}
+    both = {kind: _program_texts(eng, kind) for kind in KINDS}
     real = jax.named_scope
     jax.named_scope = lambda name: contextlib.nullcontext()
     try:
@@ -164,7 +170,8 @@ def texts(request):
     finally:
         jax.named_scope = real
         jax.clear_caches()
-    return {kind: (on[kind], off[kind]) for kind in KINDS}
+    return {kind: (both[kind][1], off[kind], both[kind][0])
+            for kind in KINDS}
 
 
 # a layer loop's own bookkeeping belongs to no layer: a scan's counter, its
@@ -223,10 +230,61 @@ def _instructions(text):
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_a_scope_changes_no_instruction(texts, kind):
-    on, off = texts[kind]
+    on, off, _ = texts[kind]
     assert any(row[4] == "own" for row in scopes.parse(on)[1].values())
     assert not any(row[0] for row in scopes.parse(off)[1].values())
     assert _instructions(on) == _instructions(off)
+
+
+# sha256 (first 16 hex) of (a) each program's lowered text, without debug
+# info, as ``tests/test_latent_moe.py`` takes the dense family's (JAX
+# 0.9.0, matmul precision "highest" as tests/conftest.py sets it), and (b)
+# the SORTED (opcode, scope path) pairs of ``scopes.parse``'s table of the
+# compiled program: which scope every instruction falls to, with no
+# instruction name, file name or line number in it — sorted, because XLA
+# writes a module's computations in an order that differs from one compile
+# of the same tree to the next (two runs on PR 43's tree agreed on the
+# pairs and on no program's order of them). (b) is what the
+# benchmark's ``dev_*_ms_per_round`` metrics and the ledger's ``breakdown``
+# split device time by, and (a) does not see a ``jax.named_scope`` that
+# moved. PR 44 read them on its parent (PR 43's tree) before it moved the
+# families' common part to ``serving/families/common.py``: a refactor that
+# keeps each program's operations, their order and their scopes keeps
+# both. A change to a program changes its pins: read them again when that
+# is meant. So does another JAX (a), or another XLA CPU compiler (b).
+_PINS = {
+    ("dense_gqa", "decode"): ("17fda45b37bfd089", "bd105bf6f3a6258a"),
+    ("dense_gqa", "verify"): ("16d6e470633adb07", "2328434dfb89c058"),
+    ("dense_gqa", "prefill"): ("d735efcc21819e63", "920060828733a600"),
+    ("latent_moe", "decode"): ("57d3748667c7a817", "a6ae92ea95014d29"),
+    ("latent_moe", "verify"): ("8050058a179dc62d", "bedba116a028b9e0"),
+    ("latent_moe", "prefill"): ("5f33fa374ff85032", "6cea0999f151e133"),
+    ("hybrid_ssm", "decode"): ("d822b946bbebeb42", "4bac2dab009fa262"),
+    ("hybrid_ssm", "verify"): ("a6321a93dd2cc162", "67f460d199d1d81e"),
+    ("hybrid_ssm", "prefill"): ("9148517617aa9927", "5da29c8360572f25"),
+    ("linear_latent_moe", "decode"): ("b3d18f0204db935c", "a543232f15be898b"),
+    ("linear_latent_moe", "verify"): ("f27670955ae8ca0f", "758e6cdbb68c9460"),
+    ("linear_latent_moe", "prefill"): ("4a5ced12674ef990", "e9650b85c4e44a1d"),
+    ("window_moe", "decode"): ("74fd0ded846c1d55", "9ce4de4981844279"),
+    ("window_moe", "verify"): ("ae41f6cb315ed683", "05435ab5ad1119db"),
+    ("window_moe", "prefill"): ("50b4f006d2675fc0", "850003b71665693e"),
+    ("conv_moe", "decode"): ("c6397bf50adc653e", "514328ed7f578eb6"),
+    ("conv_moe", "verify"): ("785c6b47cb6bd03a", "a6903243907b2b6f"),
+    ("conv_moe", "prefill"): ("45cbc60c29394027", "3f8b6ab9f91799a7"),
+}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_programs_are_the_ones_pinned(texts, kind, request):
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the pins were read under JAX 0.9.0")
+    family = request.node.callspec.params["texts"]
+    compiled, _, lowered = texts[kind]
+    pairs = sorted((row[3], row[0])
+                   for row in scopes.parse(compiled)[1].values())
+    got = tuple(hashlib.sha256(t.encode()).hexdigest()[:16]
+                for t in (lowered, repr(pairs)))
+    assert got == _PINS[family, kind], (family, kind, got)
 
 
 # -- the scope path ---------------------------------------------------------------
