@@ -4,18 +4,16 @@ The millions-of-users path (ROADMAP item 1): requests of unequal prompt
 and output lengths share ONE compiled decode step — which blocks a lane
 holds and its valid length are runtime *data*, so admission, eviction,
 and growth never retrace. Every program call is told where its lanes'
-K/V lies in the form its family takes (``read_form``): the lanes' LIVE
-ROWS — each lane's block list cut into rows of a few blocks, all lanes'
-rows end to end (:func:`pack_rows`), so a call gathers what the lanes
-hold and not every slot of every lane's table (every family since PR
-35) — or a ``[lanes, M]`` block table (a family whose ``read_form`` is
-``None``: none is left, ROADMAP C). A family that also keeps state per
+K/V lies by the lanes' LIVE ROWS — each lane's block list cut into rows
+of a few blocks (the family's ``read_form``), all lanes' rows end to end
+(:func:`pack_rows`), so a call gathers what the lanes hold and not every
+slot of every lane's table. A family that also keeps state per
 LANE (``lane_state``: a recurrent state and conv tail, the hybrid
 state-space family's and the linear-attention family's; a ring of a
 window layer's last keys, the window-attention family's; a bare conv
 tail, the short-convolution family's) has its one-lane
-prefill chunk told which lane the request holds, whichever form its read
-takes. Three compiled programs serve the whole lifetime:
+prefill chunk told which lane the request holds. Three compiled programs
+serve the whole lifetime:
 
 - **prefill chunk** ``[1, C]``: one lane's context enters the pool C
   tokens at a time (padded tail chunks write only below the context
@@ -115,7 +113,7 @@ from ..monitor import _register as _monitor_register
 from ..monitor import blackbox as _blackbox
 from ..monitor import live as _live_telemetry
 from ..monitor.spans import Phase
-from .families import family_for
+from .families import PREFILL_CHUNK, family_for
 from .kv_cache import BlockPool, blocks_needed
 from .scheduler import RUNNING, FCFSScheduler, Request
 from .speculative import LaneContext, NgramDrafter
@@ -229,14 +227,6 @@ class ServingConfig:
             v = getattr(self, name)
             if v is not None and v < 1:
                 raise ValueError(f"{name} must be >= 1, got {v}")
-
-
-# The prefill call's default width for a family that names none of its own
-# (``prefill_chunk`` on the family object), chosen on the chip (PERF.md
-# section 6, PR 32): a call reads all the weights to push its tokens, and
-# up to about this width it costs what a 32-token call costs in the dense,
-# hybrid and latent families.
-PREFILL_CHUNK = 128
 
 
 def default_prefill_chunk(max_seq_len, block_size, width=PREFILL_CHUNK):
@@ -403,7 +393,7 @@ class ServingEngine:
         # token uses is its own layers'), unless the deployer gave one
         self.prefill_chunk = int(cfg.prefill_chunk or default_prefill_chunk(
             self.max_seq_len, cfg.block_size,
-            getattr(fam, "prefill_chunk", PREFILL_CHUNK)))
+            fam.prefill_chunk))
         num_blocks = int(cfg.num_blocks
                          or cfg.max_lanes * self.blocks_per_lane + 1)
         # the device state every step program threads through (the
@@ -452,8 +442,8 @@ class ServingEngine:
         # truth; independent of the monitor like exec_cache._stats).
         # Per program call (rounds and prefill chunks): kv_read_tokens
         # the LIVE tokens its lanes hold, kv_gathered_tokens the slots
-        # it gathers (rows run x row width; a table-form program: its
-        # whole tables), kv_dense_read_tokens what a gather of every
+        # it gathers (rows run x row width), kv_dense_read_tokens what
+        # a gather of every
         # lane's whole table reads (idle lanes' too: the full-table
         # read this engine had gathered those). gathered / read is the
         # read's amplification, gathered / dense the share of the table
@@ -678,17 +668,16 @@ class ServingEngine:
 
     def _rows_form(self, kind, lanes):
         """:func:`fit_rows` of program ``kind``'s live-rows operand at
-        ``lanes`` lanes, or ``None``: the program takes a block table
-        (no family's does since the latent families read rows too)."""
-        form = self._family.read_form(kind)
-        return form and fit_rows(form, lanes, self.blocks_per_lane)
+        ``lanes`` lanes."""
+        return fit_rows(self._family.read_form(kind), lanes,
+                        self.blocks_per_lane)
 
     @property
     def _row_read(self):
         """``"kernel"`` where the family's programs read their live rows
         through ``ops/pallas/row_attention.py``; ``"xla"`` otherwise
         (the int8 pool; a family with a read of its own)."""
-        return getattr(self._family, "row_read", "xla")
+        return self._family.row_read
 
     def _tells_slot(self, kind):
         """A ``lane_state`` family's one-lane prefill chunk is told the
@@ -699,15 +688,10 @@ class ServingEngine:
         """Shapes of program ``kind``'s read operand (:meth:`_pack_read`)
         at ``lanes`` lanes of ``width`` positions."""
         i32 = jnp.int32
-        form = self._rows_form(kind, lanes)
-        slot = self._tells_slot(kind)
-        if form is None:
-            table = jax.ShapeDtypeStruct((lanes, self.blocks_per_lane), i32)
-            return (table, jax.ShapeDtypeStruct((1,), i32)) if slot \
-                else table
-        spec = (jax.ShapeDtypeStruct((form[2], 2 + form[0]), i32),
+        w, _, cap = self._rows_form(kind, lanes)
+        spec = (jax.ShapeDtypeStruct((cap, 2 + w), i32),
                 jax.ShapeDtypeStruct((lanes, width), i32))
-        if slot:
+        if self._tells_slot(kind):
             spec += (jax.ShapeDtypeStruct((1,), i32),)
         return spec
 
@@ -730,30 +714,17 @@ class ServingEngine:
         return self._layouts[kind]
 
     def _pack_read(self, kind, lanes, width, items, ph=None, slot=None):
-        """Program ``kind``'s read operand for one call, as numpy, in
-        the form its family takes: LIVE ROWS ``(rows, wblk)``
-        (:func:`pack_rows`; ``items`` as there), or — the families whose
-        ``read_form`` is ``None`` — a block TABLE ``[lanes, M]`` (every
-        lane's whole list, null-padded). A ``lane_state`` family's
-        prefill chunk gets ``slot [1]`` as a last entry, ``(rows, wblk,
-        slot)`` or ``(table, slot)``: the lane its request holds (the
+        """Program ``kind``'s read operand for one call, as numpy: LIVE
+        ROWS ``(rows, wblk)`` (:func:`pack_rows`; ``items`` as there). A
+        ``lane_state`` family's prefill chunk gets ``slot [1]`` as a last
+        entry, ``(rows, wblk, slot)``: the lane its request holds (the
         chunk runs as lane 0 of a one-lane call). Bills the call to the
         three ``kv_*`` read counters."""
         B, M = self.config.block_size, self.blocks_per_lane
         c = self.counters
         c["kv_read_tokens"] += sum(it[3] for it in items)
         c["kv_dense_read_tokens"] += lanes * M * B
-        form = self._rows_form(kind, lanes)
-        to_slot = self._tells_slot(kind)
-        if form is None:
-            tables = np.zeros((lanes, M), np.int32)
-            for lane, blocks, _, _ in items:
-                tables[lane, :len(blocks)] = blocks
-            c["kv_gathered_tokens"] += lanes * M * B
-            if to_slot:
-                return tables, np.asarray([slot], np.int32)
-            return tables
-        w, tile, cap = form
+        w, tile, cap = self._rows_form(kind, lanes)
         rows, wblk, n, live = pack_rows(items, lanes, width, B, w, cap)
         # the kernel's grid is the live rows; the XLA read runs whole tiles
         by_kernel = n if self._row_read == "kernel" else 0
@@ -763,7 +734,7 @@ class ServingEngine:
         if ph is not None and _spans is not None:
             ph.args.update(rows=n, live_blocks=live,
                            kv_kernel_rows=by_kernel)
-        if to_slot:
+        if self._tells_slot(kind):
             return rows, wblk, np.asarray([slot], np.int32)
         return rows, wblk
 

@@ -18,12 +18,10 @@ and asks the model's *family* for the three things that differ:
     operands are the engine's (``[1, C]`` chunk, start, context length,
     last index | ``[L]`` lengths, ``[L]`` tokens | lengths, ``[L, k+1]``
     tokens, ``[L]`` write limits), and ``read`` says where the lanes' K/V
-    lies, in the form ``read_form(kind)`` names: ``(W, tile)`` — the
-    lanes' live rows of ``W`` blocks, which the program runs ``tile`` at a
-    time, and each fed token's write block: ``(rows [R, 2 + W], wblk
-    [lanes, width])`` (``engine.pack_rows``), every family's read since
-    PR 35; ``None`` — a ``[lanes, M]`` block table, which no family
-    takes any more (ROADMAP C14). That is the signature of the family's
+    lies: the lanes' live rows of ``W`` blocks, which the program runs
+    ``tile`` at a time (``read_form(kind)`` names ``(W, tile)``), and each
+    fed token's write block: ``(rows [R, 2 + W], wblk [lanes, width])``
+    (``engine.pack_rows``). That is the signature of the family's
     ``fn``, and it stays: tests and tools call it as it is. What the
     ENGINE compiles takes ``(params, *pools, packed, **static)`` — the
     read operand and the kind's own operands, all ``int32``, laid end to
@@ -43,24 +41,13 @@ positions, ``families/window_moe.py``; ``lane_pool_bytes(pools)`` their
 size, 0 elsewhere).
 Decode and verify index them by the batch row; the one-lane prefill chunk
 is told its request's lane, the STATE SLOT, as the last entry of its read
-operand — ``(rows, wblk, slot [1])`` in the rows form, ``(table, slot
-[1])`` where the family reads by block table (``read_form`` ``None``) —
-and a chunk at position 0 starts the slot from zero (a ring: empty), so
-an admitted or re-admitted request never sees its predecessor's. Such a
-family's verify program owes the engine the rollback contract of
-``ServingEngine._verify_round``: a masked position is the identity on
-the family's lane state. For a ring of ``R`` slots (position ``p`` in
-slot ``p mod R``) under a window of ``W`` positions and ``k`` drafts a
-round that is an inequality, not an update: with ``R >= W + k`` what a
-rejected draft wrote reads, to every later query, as a position outside
-the band, and is overwritten before the band reaches it (the family
-takes ``R >= W + k + 1``). For a bare conv tail it is a choice of rows:
-the verify program holds each conv layer's ``[tail | k+1 positions]``
-window until the head has given the lane's ``n_keep`` (its pending token
-and its accepted drafts; 0 for an idle lane) and sets the tail to the
-window's rows ``n_keep .. n_keep + L - 2`` — the rows that end at the
-last kept position, the lane's own tail where nothing is kept — so a
-rejected position is in no tail. ``prefix_reuse`` —
+operand — ``(rows, wblk, slot [1])`` — and a chunk at position 0 starts
+the slot from zero (a ring: empty), so an admitted or re-admitted request
+never sees its predecessor's. Such a family's verify program owes the
+engine the rollback contract of ``ServingEngine._verify_round``: a masked
+position is the identity on the family's lane state (``common.py`` states
+it, beside the rules that keep it: ``_carried``, ``_keeps``,
+``_take_rows``). ``prefix_reuse`` —
 False where a request cannot start from a prefix's blocks alone (it
 would need the recurrent state, or the ring, at that boundary): the
 engine then has the scheduler acquire none, and ``stats()`` says so.
@@ -72,13 +59,13 @@ family whose call reads every held expert for a few of them a position
 says 512 (``families/window_moe.py``, ``families/conv_moe.py``). The
 engine fits it to whole blocks under ``max_seq_len``
 (``engine.default_prefill_chunk``); ``ServingConfig.prefill_chunk`` /
-``PT_SERVE_PREFILL_CHUNK`` given win. A family without the attribute
-takes the engine's.
+``PT_SERVE_PREFILL_CHUNK`` given win. ``common.Family``'s is that
+default.
 
 ``row_read`` — ``"kernel"`` where the family's programs read their live
 rows through ``ops/pallas/row_attention.py`` (the engine then bills
-``kv_kernel_rows``, and ``stats()["row_read"]`` says so); a family without
-the attribute reads them itself (``"xla"``).
+``kv_kernel_rows``, and ``stats()["row_read"]`` says so); ``"xla"``
+(``common.Family``'s) where a family reads them itself.
 
 Plus ``absorb(out, counters)``: the round's ONE fetched array goes
 through it — a family that rides its own counters on that array strips
@@ -87,21 +74,30 @@ tokens; ``exec_key(pools)`` and ``stats()``.
 
 A model names its family by a ``serving_family(serving_config)`` method;
 one without it is the dense grouped-query decoder the engine began with.
+
+**A new family** is a module here with a class that inherits
+``common.Family`` and writes what is its own: its pools (``make_pools``,
+``kv_pool_bytes``, ``lane_pool_bytes`` if it keeps any by lane,
+``donate_argnums``), its three step programs (``_prefill_chunk``,
+``_decode_step``, ``_verify_step``, named in ``programs``) with its
+``read_form``, and its ``stats``. It inherits the attributes' defaults,
+``program``, ``exec_key``, ``absorb`` with the counters' bookkeeping, the
+collected ``params`` and the one refusal of ``kv_int8`` / ``int8_weights``;
+and its programs are built from ``common.py``'s parts — ``write_slots``
+and ``paged_attention`` for a grouped-query layer on the block pool,
+``greedy_head``, the expert accumulator (``MOE_ACC``, ``expert_counts``,
+``bump``, ``_out``), the verify round's ``accept`` and the lane-state
+rules. It imports no other family.
 """
 from __future__ import annotations
 
-
-def absorb_accumulator(out, names, seen, counters):
-    """For a family that rides a device accumulator on the round's fetched
-    vector (its last ``len(names)`` entries, int32, running totals): add
-    what each slot grew by since the last fetch to ``counters`` (modulo
-    2^32; ``seen`` holds the last totals) and return the tokens."""
-    n = len(names)
-    for i, name in enumerate(names):
-        now = int(out[out.size - n + i])
-        counters[name] += (now - seen[i]) & 0xFFFFFFFF
-        seen[i] = now
-    return out[:out.size - n]
+# The prefill call's width for a family that names none of its own
+# (``common.Family.prefill_chunk``; the engine imports it as
+# ``engine.PREFILL_CHUNK``), chosen on the chip (PERF.md section 6, PR 32):
+# a call reads all the weights to push its tokens, and up to about this
+# width it costs what a 32-token call costs in the dense, hybrid and latent
+# families.
+PREFILL_CHUNK = 128
 
 
 def family_for(model, serving_config):

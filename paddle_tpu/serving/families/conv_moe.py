@@ -7,8 +7,9 @@ kinds by LAYER TYPE, of which the second is nearly nothing.
   4 lane tiles, the heads merged into the last axis so that the pools' own
   layout is row-major and no call copies them — ``families/hybrid_ssm.py``
   says what the unmerged form cost), written by (layer, block, offset) with
-  the null-block redirect and read by the dense family's live-rows read
-  (the fused kernel ``ops/pallas/row_attention.py``), the q/k head norms
+  the null-block redirect and read over the lanes' live rows
+  (``common.paged_attention``: the fused kernel
+  ``ops/pallas/row_attention.py``), the q/k head norms
   and the rotary embedding in front of it. A conv layer takes NOTHING in
   the block pool.
 - **By LANE, the CONV layers**: ONE pool ``[conv layers, lanes, (L - 1) x
@@ -37,8 +38,8 @@ kinds by LAYER TYPE, of which the second is nearly nothing.
     ``_accept`` rule) — then sets the tail to rows ``n_keep .. n_keep + L -
     2`` of the window: the rows that end at the last kept position. A
     rejected position's ``g`` is in no tail; an idle lane (``n_keep`` 0)
-    gets its own tail back. ``hybrid_ssm._keeps`` / ``_take_rows`` /
-    ``_carried`` do exactly this for that family's tail and are shared.
+    gets its own tail back (``common._keeps`` / ``_take_rows`` /
+    ``_carried``: the rules every ``lane_state`` family's tail follows).
 - **No prefix reuse** (``prefix_reuse`` False): a prefix hit hands over
   block-aligned K/V of the attention layers, and the conv layers would need
   their tails as they stood at that boundary, which nothing keeps. It is
@@ -47,7 +48,7 @@ kinds by LAYER TYPE, of which the second is nearly nothing.
 - **Weights once**: ``params`` references the model's arrays; each
   program is a Python loop over the layers. The head is the embedding.
 - **Counters** ride on the round's token array (the latent family's way):
-  the expert layer's four, then ``CONV_ACC``.
+  the expert layer's four (``common.MOE_ACC``), then ``CONV_ACC``.
 
 ``kv_int8`` and ``int8_weights`` raise ``UnimplementedError``.
 """
@@ -56,14 +57,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...framework.errors import UnimplementedError
 from ...models import conv_moe as M
 from ...models.generation import _rms
-from ...ops.pallas.row_attention import row_attention
-from . import absorb_accumulator
-from .dense_gqa import PREFILL_TILE, ROW_BLOCKS, ROW_TILE
-from .hybrid_ssm import _carried, _keeps, _take_rows
-from .latent_moe import ACC as MOE_ACC
-from .latent_moe import _out, expert_counts
+from .common import (
+    MOE_ACC, PREFILL_TILE, ROW_BLOCKS, ROW_TILE, Family, _carried, _keeps,
+    _out, _take_rows, accept, bump, expert_counts, greedy_head, lane_tails,
+    paged_attention, rolled_back, write_slots,
+)
 
 __all__ = ["ConvMoEFamily"]
 
@@ -90,31 +91,14 @@ N_POOLS = 4  # K pool, V pool, the accumulator, the tails
 PREFILL_CHUNK = 512
 
 
-def _bump(acc, **by):
-    with jax.named_scope("acc"):
-        return acc.at[len(MOE_ACC):].add(jnp.stack(
-            [jnp.asarray(by.get(n, 0), jnp.int32) for n in CONV_ACC]))
-
-
-def _tails(tpool, ci, cfg):
-    """Conv layer ``ci``'s tails as ``[lanes, L - 1, hidden]``."""
-    return tpool[ci].reshape(tpool.shape[1], cfg.conv_L_cache - 1, -1)
-
-
 def _attention(u, lp, ai, kpool, vpool, rows, pos, blk, off, cfg):
-    """An attention layer against the block pool: write the fed tokens'
-    K/V by (layer, block, offset), then the dense family's live-rows read.
+    """An attention layer against the block pool (``paged_attention``).
     Returns (out [b, s, hidden], kpool, vpool)."""
-    b, s = pos.shape
-    g, d = cfg.num_key_value_heads, cfg.head_dim
-    q, k, v = M.attention_qkv(u, lp, pos, cfg)
-    with jax.named_scope("attn/kv_write"):
-        kpool = kpool.at[ai, blk, off].set(k.reshape(b, s, g * d))
-        vpool = vpool.at[ai, blk, off].set(v.reshape(b, s, g * d))
-    with jax.named_scope("attn/rows"):  # from the STACKED pools
-        out = row_attention(q, pos, rows, kpool, vpool, ai, g, d ** -0.5)
+    att, kpool, vpool = paged_attention(
+        *M.attention_qkv(u, lp, pos, cfg), ai, kpool, vpool, rows, pos, blk,
+        off, cfg.num_key_value_heads, cfg.head_dim ** -0.5)
     with jax.named_scope("attn/out"):
-        return out.reshape(b, s, -1) @ lp["o"], kpool, vpool
+        return att.reshape(*pos.shape, -1) @ lp["o"], kpool, vpool
 
 
 def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
@@ -130,10 +114,8 @@ def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
     with scope("embed"):
         x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
     rows, wblk = read
-    with scope("attn/kv_write"):
-        ok = pos < wlimit[:, None]
-        blk = jnp.where(ok, wblk, 0)
-        off = jnp.where(ok, pos % kpool.shape[2], 0)
+    blk, off = write_slots(wblk, pos, wlimit, kpool.shape[2],
+                           "attn/kv_write")
     with scope("acc"):
         n_valid = jnp.sum(valid, dtype=jnp.int32)
         hit = jnp.int32(0)
@@ -162,14 +144,6 @@ def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
                     n_valid, counts, cfg.num_experts_per_tok))
                 hit = hit + jnp.sum(counts > 0, dtype=jnp.int32)
     return x, kpool, vpool, acc, hit
-
-
-def _picks(x, params, cfg):
-    with jax.named_scope("head"):
-        x = _rms(x, params["norm"], cfg.norm_eps)
-        logits = (x @ params["embed"].T).astype(jnp.float32)
-    with jax.named_scope("sample"):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _prefill_chunk(params, kpool, vpool, acc, tpool, read, ids, start,
@@ -206,11 +180,12 @@ def _prefill_chunk(params, kpool, vpool, acc, tpool, read, ids, start,
     x, kpool, vpool, acc, _ = _stack(
         params, ids, pos, jnp.reshape(ctx_len, (1,)), real, read, kpool,
         vpool, acc, cfg, conv)
-    acc = _bump(acc, conv_slot_resets=fresh)
+    acc = bump(acc, CONV_ACC, len(MOE_ACC), conv_slot_resets=fresh)
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
                                          keepdims=False)
-    return _out(_picks(h, params, cfg), acc), kpool, vpool, acc, tails[0]
+    picks = greedy_head(h, params, cfg.norm_eps)
+    return _out(picks, acc), kpool, vpool, acc, tails[0]
 
 
 def _decode_step(params, kpool, vpool, acc, tpool, read, cur_len, last_tok,
@@ -227,7 +202,8 @@ def _decode_step(params, kpool, vpool, acc, tpool, read, cur_len, last_tok,
 
     def conv(ci, g):
         with jax.named_scope("sconv/conv"):
-            window = jnp.concatenate([_tails(tails[0], ci, cfg), g], axis=1)
+            window = jnp.concatenate(
+                [lane_tails(tails[0], ci, cfg.conv_L_cache), g], axis=1)
             tails[0] = tails[0].at[ci].set(
                 window[:, 1:].reshape(window.shape[0], -1))
         return window
@@ -235,10 +211,11 @@ def _decode_step(params, kpool, vpool, acc, tpool, read, cur_len, last_tok,
     x, kpool, vpool, acc, n_hit = _stack(
         params, last_tok[:, None], pos, cur_len + 1, live, read, kpool,
         vpool, acc, cfg, conv)
-    acc = _bump(acc, moe_round_experts_hit=n_hit)
+    acc = bump(acc, CONV_ACC, len(MOE_ACC), moe_round_experts_hit=n_hit)
     with jax.named_scope("head"):
         x = x[:, -1]
-    return _out(_picks(x, params, cfg), acc), kpool, vpool, acc, tails[0]
+    picks = greedy_head(x, params, cfg.norm_eps)
+    return _out(picks, acc), kpool, vpool, acc, tails[0]
 
 
 def _verify_step(params, kpool, vpool, acc, tpool, read, cur_len, toks,
@@ -257,35 +234,34 @@ def _verify_step(params, kpool, vpool, acc, tpool, read, cur_len, toks,
 
     def conv(ci, g):
         with jax.named_scope("sconv/conv"):
-            windows.append(
-                jnp.concatenate([_tails(tpool, ci, cfg), g], axis=1))
+            windows.append(jnp.concatenate(
+                [lane_tails(tpool, ci, cfg.conv_L_cache), g], axis=1))
         return windows[-1]
 
     x, kpool, vpool, acc, n_hit = _stack(
         params, toks, pos, wlimit, valid, read, kpool, vpool, acc, cfg, conv)
-    picks = _picks(x, params, cfg)
-    with jax.named_scope("spec"):  # engine._accept's rule
-        n_draft = wlimit - cur_len - 1                  # -1: an idle lane
-        hit = (picks[:, :-1] == toks[:, 1:]) \
-            & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
-        accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1),
-                           axis=1)
-        live = n_draft >= 0
+    picks = greedy_head(x, params, cfg.norm_eps)
+    live, n_draft, accepted = accept(picks, toks, cur_len, wlimit)
+    with jax.named_scope("spec"):
         n_keep = _keeps(live, accepted)
-        rolled = jnp.sum(jnp.where(live, n_draft - accepted, 0))
+        rolled = rolled_back(live, n_draft, accepted)
     with jax.named_scope("sconv/conv"):
         for ci, window in enumerate(windows):
             tpool = tpool.at[ci].set(_take_rows(window, n_keep, K1).reshape(
                 window.shape[0], -1))
-    acc = _bump(acc, moe_round_experts_hit=n_hit,
-                spec_rolled_back_tokens=rolled)
+    acc = bump(acc, CONV_ACC, len(MOE_ACC), moe_round_experts_hit=n_hit,
+               spec_rolled_back_tokens=rolled)
     return _out(picks, acc), kpool, vpool, acc, tpool
 
 
-class ConvMoEFamily:
+class ConvMoEFamily(Family):
     """See ``families/__init__.py`` for what the engine asks of it."""
 
     name = "conv_moe"
+    title = "the short-convolution family"
+    ACC = ACC
+    programs = {"prefill": _prefill_chunk, "decode": _decode_step,
+                "verify": _verify_step}
     prefill_chunk = PREFILL_CHUNK
     lane_state = True
     prefix_reuse = False
@@ -299,22 +275,12 @@ class ConvMoEFamily:
         "(ROADMAP B-m4)")
 
     def __init__(self, model, config):
-        from ...framework.errors import UnimplementedError
-
-        for flag, why in (
-                (config.kv_int8, "kv_int8: the int8 scale pools pair with "
-                 "[.., kv_heads, head_dim] pools, and these merge the "
-                 "heads into the last axis"),
-                (config.int8_weights, "int8_weights: the pack would be a "
-                 "second copy of the weights")):
-            if flag:
-                raise UnimplementedError(
-                    f"the short-convolution family does not serve with "
-                    f"{why}")
+        self.refuse(config, {
+            "kv_int8": "the int8 scale pools pair with [.., kv_heads, "
+            "head_dim] pools, and these merge the heads into the last "
+            "axis"})
+        super().__init__(model, config)
         c = model.config
-        self.gcfg = c.static()
-        self.max_position_embeddings = c.max_position_embeddings
-        self.lanes = config.max_lanes
         self.n_conv = sum(k == M.CONV for k in c.layer_types)
         self.n_attn = c.num_hidden_layers - self.n_conv
         if not self.n_attn:
@@ -322,13 +288,6 @@ class ConvMoEFamily:
                 "a stack with no attention layer has no K/V pool: the "
                 "engine's block pool would manage nothing")
         self.donate_argnums = tuple(range(1, 1 + N_POOLS))
-        # the model's own arrays: ONE copy of the weights on the device
-        self.params = {
-            "embed": model.embed._data, "norm": model.norm._data,
-            "layers": tuple({k: p._data for k, p in blk.leaves().items()}
-                            for blk in model.layers)}
-        self.counters = dict.fromkeys(ACC, 0)
-        self._seen = [0] * len(ACC)
 
     def make_pools(self, num_blocks, block_size):
         """(K pool, V pool by (attention layer, block, offset), the
@@ -349,28 +308,10 @@ class ConvMoEFamily:
         return int(pools[3].nbytes)
 
     def read_form(self, kind):
-        """The dense family's live rows ``(W, tile)`` (the kernel's grid is
+        """The paged layer's live rows ``(W, tile)`` (the kernel's grid is
         the live rows: ``tile`` only rounds the operand's length);
         ``lane_state`` adds the request's lane to the prefill chunk's."""
         return ROW_BLOCKS, PREFILL_TILE if kind == "prefill" else ROW_TILE
-
-    def program(self, kind):
-        return {"prefill": _prefill_chunk, "decode": _decode_step,
-                "verify": _verify_step}[kind], {"cfg": self.gcfg}
-
-    def exec_key(self, pools):
-        from ...jit import exec_cache
-
-        return {"family": self.name, "gen_cfg": self.gcfg._key(),
-                "params": [exec_cache.array_spec(a) for a in
-                           jax.tree_util.tree_leaves(self.params)],
-                "pools": [(tuple(int(x) for x in p.shape), str(p.dtype))
-                          for p in pools]}
-
-    def absorb(self, out, counters):
-        """Strip the accumulator (the expert layer's slots and the
-        tails') off the fetched vector into ``counters``."""
-        return absorb_accumulator(out, ACC, self._seen, counters)
 
     def stats(self):
         g = self.gcfg

@@ -13,8 +13,10 @@ nothing else of any table, so a call's cost follows the live blocks, not
 dtype:
 
 - **bf16 pools: the fused kernel** ``ops/pallas/row_attention.py`` (PR 39;
-  the hybrid state-space and the window-attention families' attention
-  layers call it too): one call a layer whose grid is the live rows; it
+  behind ``common.paged_attention``, the grouped-query layer on the paged
+  pool that the hybrid state-space, the window-attention and the
+  short-convolution families' attention layers call too): one call a
+  layer whose grid is the live rows; it
   copies a row's blocks out of the stacked pool into fast memory by
   (layer, block) id, scores them against the lane's queries with the
   operands as stored (bfloat16 on the chip, float32 products), masks, and
@@ -43,19 +45,11 @@ import numpy as np
 from ...models.generation import (
     _GenCfg, _collect_params, _mm, _rms, _rope_at,
 )
-from ...ops.pallas.row_attention import row_attention
+from .common import (
+    PREFILL_TILE, ROW_BLOCKS, ROW_TILE, Family, paged_attention, write_slots,
+)
 
 __all__ = ["DenseGQAFamily"]
-
-# The K/V read's constants, chosen on the chip (PERF.md section 6, PR 28):
-# a row is ROW_BLOCKS blocks of one lane (wider rows make a 5-position
-# verify call cheaper, narrower ones pad a lane's last row less), and a
-# program runs its live rows ROW_TILE at a time (a tile costs ~9 us a
-# layer to start and pads a call by half of itself on average); the
-# prefill chunk, all rows one lane's: PREFILL_TILE.
-ROW_BLOCKS = 16
-ROW_TILE = 16
-PREFILL_TILE = 4
 
 
 # -- compiled phases ----------------------------------------------------------
@@ -198,11 +192,8 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
     scope = jax.named_scope  # the scopes: monitor/scopes.py
     with scope("embed"):
         x = params["embed"][ids].astype(dt)
-    rows, blk = read
-    with scope("attn/kv_write"):
-        ok = pos < wlimit[:, None]
-        blk = jnp.where(ok, blk, 0)
-        off = jnp.where(ok, pos % B, 0)
+    rows, wblk = read
+    blk, off = write_slots(wblk, pos, wlimit, B, "attn/kv_write")
     n_layers = params["ln1"].shape[0]
 
     def body(carry, li):
@@ -231,8 +222,9 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
         # pool, before every gather (PERF.md section 6, PR 25). The pool's
         # dtype says which read: the bf16 pool keeps the heads merged
         # into its last axis and the kernel copies a row's blocks out of
-        # it; the int8 pool and its scales are indexed by the (layer,
-        # block) pair and dequantized a gathered tile at a time
+        # it (``common.paged_attention``); the int8 pool and its scales
+        # are indexed by the (layer, block) pair and dequantized a
+        # gathered tile at a time
         if quant:
             from ...quantization import dequantize_kv, quantize_kv
 
@@ -255,13 +247,9 @@ def _pool_forward(params, kpool, vpool, kscale, vscale, read, ids,
                 out = _attend_rows(q, pos, rows, gather, tile, nkv,
                                    sliding_window=cfg.sliding_window)
         else:
-            with scope("attn/kv_write"):
-                kp = kp.at[li, blk, off].set(k.reshape(b, s, nkv * d))
-                vp = vp.at[li, blk, off].set(v.reshape(b, s, nkv * d))
-            with scope("attn/rows"):
-                out = row_attention(q, pos, rows, kp, vp, li, nkv,
-                                    d ** -0.5,
-                                    sliding_window=cfg.sliding_window)
+            out, kp, vp = paged_attention(
+                q, k, v, li, kp, vp, rows, pos, blk, off, nkv, d ** -0.5,
+                sliding_window=cfg.sliding_window)
         with scope("attn/out"):
             x = x + _mm(out.reshape(b, s, nh * d), layer_p["o"])
         h2 = _rms(x, layer_p["ln2"], cfg.rms_norm_eps)
@@ -355,13 +343,14 @@ def _verify_step(params, kpool, vpool, kscale, vscale, read, cur_len,
 
 # -- the family ---------------------------------------------------------------
 
-class DenseGQAFamily:
-    """What :class:`~paddle_tpu.serving.ServingEngine` asks a model's
-    family for (see ``families/__init__.py``)."""
+class DenseGQAFamily(Family):
+    """See ``families/__init__.py`` for what the engine asks of it. The one
+    family that packs its weights (``_collect_params``: stacked per leaf,
+    int8 on request) and serves an int8 pool, so its ``__init__`` and its
+    ``exec_key`` are its own."""
 
     name = "dense_gqa"
-    lane_state = False   # every pool is indexed by (layer, block, offset)
-    prefix_reuse = True  # a prefix's K/V blocks are all a new request needs
+    tiled = True  # the int8 pool's XLA read runs its rows ``tile`` at a time
 
     def __init__(self, model, config):
         if getattr(model.config, "moe_num_experts", 0) > 1:
@@ -377,6 +366,7 @@ class DenseGQAFamily:
         self.layers = self.params["ln1"].shape[0]
         self.max_position_embeddings = model.config.max_position_embeddings
         self.donate_argnums = (1, 2, 3, 4) if config.kv_int8 else (1, 2)
+        self.counters = {}  # nothing beyond the engine's own
 
     def make_pools(self, num_blocks, block_size):
         """(kpool, vpool, kscale, vscale). int8 mode: paired per-position
@@ -402,9 +392,6 @@ class DenseGQAFamily:
     def kv_pool_bytes(self, pools):
         return int(sum(a.nbytes for a in pools if a is not None))
 
-    def lane_pool_bytes(self, pools):
-        return 0
-
     def read_form(self, kind):
         """How program ``kind`` is told where its lanes' K/V lies
         (``ServingEngine._pack_read`` builds it): ``(W, tile)`` — live
@@ -418,14 +405,12 @@ class DenseGQAFamily:
         read (``_attend_rows``)."""
         return "xla" if self.config.kv_int8 else "kernel"
 
-    def program(self, kind):
-        """(function, static keyword arguments) of one step program."""
-        fn = {"prefill": _prefill_chunk, "decode": _decode_step,
-              "verify": _verify_step}[kind]
-        return fn, {"cfg": self.gcfg, "tile": self.read_form(kind)[1]}
+    programs = {"prefill": _prefill_chunk, "decode": _decode_step,
+                "verify": _verify_step}
 
     def exec_key(self, pools):
-        """The family's part of an exec-cache key."""
+        """The family's part of an exec-cache key: its own, which names
+        the one pool's form and marks the int8 mode."""
         from ...jit import exec_cache
 
         kpool, kscale = pools[0], pools[2]
@@ -442,12 +427,6 @@ class DenseGQAFamily:
             k["scale"] = (tuple(int(x) for x in kscale.shape),
                           str(kscale.dtype))
         return k
-
-    counters = {}  # nothing beyond the engine's own
-
-    def absorb(self, out, counters):
-        """The round's fetched output IS its tokens."""
-        return out
 
     def stats(self):
         return {}

@@ -3,11 +3,12 @@ the serving engine sees it: TWO kinds of cache in one family.
 
 - **By token**: a K pool and a V pool ``[attention layers, blocks, block,
   kv_heads x head_dim]`` for the few attention layers, written by
-  (layer, block, offset) with the null-block redirect and read by the
-  dense family's live-rows read (the fused kernel
+  (layer, block, offset) with the null-block redirect and read over the
+  lanes' live rows (``common.paged_attention``: the fused kernel
   ``ops/pallas/row_attention.py``; no rotary embedding here, and the
-  model's score multiplier is the read's ``scale``). A pool that ends in ``[kv_heads, head_dim]`` with a
-  head of 64, half a 128-lane tile, the TPU compiler lays out
+  model's score multiplier is the read's ``scale``). A pool that ends in
+  ``[kv_heads, head_dim]`` with a head of 64, half a 128-lane tile, the
+  TPU compiler lays out
   blocks-minor, and every program call then copied both pools in and out
   (4 x 285 MB; the latent family's finding, PERF.md section 6, PR 27).
   With the heads merged into the last axis (512 = 4 lane tiles) the
@@ -90,12 +91,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...framework.errors import UnimplementedError
 from ...models import hybrid_ssm as M
 from ...models.generation import _rms
 from ...ops.pallas import ssm_state
-from ...ops.pallas.row_attention import row_attention
-from . import absorb_accumulator
-from .dense_gqa import PREFILL_TILE, ROW_BLOCKS, ROW_TILE
+from .common import (
+    PREFILL_TILE, ROW_BLOCKS, ROW_TILE, Family, _carried, _keeps, _out,
+    _take_rows, accept, bump, greedy_head, lane_tails, paged_attention,
+    rolled_back, write_slots,
+)
 
 __all__ = ["HybridSSMFamily"]
 
@@ -112,52 +116,16 @@ ACC = ("ssm_state_passes", "ssm_lane_rounds", "ssm_state_lane_moves",
        "ssm_deferred_positions")
 
 
-def _bump(acc, **by):
-    with jax.named_scope("acc"):
-        return acc + jnp.stack([jnp.asarray(by.get(n, 0), jnp.int32)
-                                for n in ACC])
-
-
-def _attention(u, lp, ai, kpool, vpool, read, pos, blk, off, cfg):
-    """An attention layer's mixer against the block pool: write the fed
-    tokens' K/V by (layer, block, offset), then the dense family's
-    live-rows read. Returns (out [b, s, hidden], kpool, vpool)."""
+def _attention(u, lp, ai, kpool, vpool, rows, pos, blk, off, cfg):
+    """An attention layer's mixer against the block pool
+    (``paged_attention``; the model states its own scale). Returns (out
+    [b, s, hidden], kpool, vpool)."""
     b, s, _ = u.shape
-    nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                  cfg.head_dim)
-    q, k, v = M.attention_qkv(u, lp, cfg)
-    with jax.named_scope("attn/kv_write"):
-        kpool = kpool.at[ai, blk, off].set(k.reshape(b, s, nkv * d))
-        vpool = vpool.at[ai, blk, off].set(v.reshape(b, s, nkv * d))
-    with jax.named_scope("attn/rows"):  # the model states its own scale
-        out = row_attention(q, pos, read[0], kpool, vpool, ai, nkv,
-                            cfg.attention_multiplier)
+    att, kpool, vpool = paged_attention(
+        *M.attention_qkv(u, lp, cfg), ai, kpool, vpool, rows, pos, blk, off,
+        cfg.num_key_value_heads, cfg.attention_multiplier)
     with jax.named_scope("attn/out"):
-        return out.reshape(b, s, nh * d) @ lp["o"], kpool, vpool
-
-
-def _carried(fresh, kept):
-    """What a prefill chunk starts from: what the slot kept, or zero
-    where the chunk is its request's first."""
-    return jnp.where(fresh, 0, kept)
-
-
-def _keeps(live, accepted):
-    """How many of a verify round's positions a lane's state and conv
-    tail take up: its pending token and its accepted drafts; none where
-    the lane is idle."""
-    return jnp.where(live, 1 + accepted, 0)
-
-
-def _tail(cpool, si, cfg):
-    """Layer ``si``'s conv tails as ``[lanes, d_conv - 1, channels]``."""
-    return cpool[si].reshape(cpool.shape[1], cfg.mamba_d_conv - 1, -1)
-
-
-def _take_rows(window, first, n):
-    """``window[b, first[b] : first[b] + n]`` for every row ``b``."""
-    idx = first[:, None] + jnp.arange(n)[None, :]
-    return jnp.take_along_axis(window, idx[:, :, None], axis=1)
+        return att.reshape(b, s, -1) @ lp["o"], kpool, vpool
 
 
 def _stack(params, ids, pos, wlimit, read, kpool, vpool, cfg, ssm):
@@ -165,16 +133,14 @@ def _stack(params, ids, pos, wlimit, read, kpool, vpool, cfg, ssm):
     layers against the block pool here, each state-space layer through
     ``ssm(si, u, lp) -> mix`` (the program's own: what it does with the
     lane-indexed pools differs by program). Returns (x, kpool, vpool)."""
-    B = kpool.shape[2]
     eps, rm = cfg.rms_norm_eps, cfg.residual_multiplier
     dt = jnp.dtype(cfg.dtype)
     scope = jax.named_scope  # the scopes: monitor/scopes.py
     with scope("embed"):
         x = (params["embed"][ids] * cfg.embedding_multiplier).astype(dt)
-    with scope("attn/kv_write"):
-        ok = pos < wlimit[:, None]
-        blk = jnp.where(ok, read[1], 0)
-        off = jnp.where(ok, pos % B, 0)
+    rows, wblk = read[:2]
+    blk, off = write_slots(wblk, pos, wlimit, kpool.shape[2],
+                           "attn/kv_write")
     si = ai = 0
     for kind, lp in zip(cfg.layer_types, params["layers"]):
         u = _rms(x, lp["ln_in"], eps)
@@ -183,7 +149,7 @@ def _stack(params, ids, pos, wlimit, read, kpool, vpool, cfg, ssm):
             si += 1
             out = "ssm/out_proj"
         else:
-            mix, kpool, vpool = _attention(u, lp, ai, kpool, vpool, read,
+            mix, kpool, vpool = _attention(u, lp, ai, kpool, vpool, rows,
                                            pos, blk, off, cfg)
             ai += 1
             out = "attn/out"
@@ -196,17 +162,8 @@ def _stack(params, ids, pos, wlimit, read, kpool, vpool, cfg, ssm):
 
 
 def _picks(x, params, cfg):
-    with jax.named_scope("head"):
-        x = _rms(x, params["norm"], cfg.rms_norm_eps)
-        logits = (x @ params["embed"].T).astype(F32) / cfg.logits_scaling
-    with jax.named_scope("sample"):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
-def _out(picks, acc):
-    """A program's fetched vector: its picks, then the accumulator."""
-    with jax.named_scope("acc"):
-        return jnp.concatenate([picks.reshape(-1), acc])
+    """The tied head, the logits over the model's ``logits_scaling``."""
+    return greedy_head(x, params, cfg.rms_norm_eps, cfg.logits_scaling)
 
 
 N_POOLS = 6  # the pools before the arrays a state-space layer
@@ -310,7 +267,7 @@ def _prefill_chunk(params, *args, cfg):
 
     x, kpool, vpool = _stack(params, ids, pos, jnp.reshape(ctx_len, (1,)),
                              read, kpool, vpool, cfg, ssm)
-    acc = _bump(acc, ssm_slot_resets=fresh)
+    acc = bump(acc, ACC, 0, ssm_slot_resets=fresh)
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
                                          keepdims=False)
@@ -339,8 +296,8 @@ def _decode_step(params, *args, cfg):
     def ssm(si, u, lp):
         z, xBC, dt_raw = M.ssm_project(u, lp, cfg)
         with jax.named_scope("ssm/conv"):
-            window = jnp.concatenate([_tail(conv[0], si, cfg), xBC],
-                                     axis=1)
+            window = jnp.concatenate(
+                [lane_tails(conv[0], si, cfg.mamba_d_conv), xBC], axis=1)
         c = M.ssm_conv(window, lp, cfg)
         _, Bm, Cm, dt, A = M.ssm_inputs(c, dt_raw, lp, cfg)
         with jax.named_scope("ssm/state_update"):
@@ -365,7 +322,7 @@ def _decode_step(params, *args, cfg):
                   ssm_state_lane_moves=2 * jnp.sum(live),
                   ssm_deferred_positions=jnp.sum(
                       jnp.where(live, n_owed, 0)))
-    acc = _bump(acc, ssm_state_passes=1, **by)
+    acc = bump(acc, ACC, 0, ssm_state_passes=1, **by)
     with jax.named_scope("head"):
         x = x[:, -1]
     return (_out(_picks(x, params, cfg), acc), kpool, vpool, conv[0], acc,
@@ -399,7 +356,8 @@ def _verify_step(params, *args, cfg):
     def ssm(si, u, lp):
         z, xBC, dt_raw = M.ssm_project(u, lp, cfg)
         with jax.named_scope("ssm/conv"):
-            window = jnp.concatenate([_tail(cpool, si, cfg), xBC], axis=1)
+            window = jnp.concatenate(
+                [lane_tails(cpool, si, cfg.mamba_d_conv), xBC], axis=1)
         windows.append(window)
         c = M.ssm_conv(window, lp, cfg)
         _, Bm, Cm, dt, A = M.ssm_inputs(c, dt_raw, lp, cfg)
@@ -418,15 +376,8 @@ def _verify_step(params, *args, cfg):
     x, kpool, vpool = _stack(params, toks, pos, wlimit, read, kpool, vpool,
                              cfg, ssm)
     picks = _picks(x, params, cfg)
-    # a lane keeps its pending token and the longest prefix of its draft
-    # that equals the program's own picks (engine._accept's rule)
+    live, n_draft, accepted = accept(picks, toks, cur_len, wlimit)
     with jax.named_scope("spec"):
-        n_draft = wlimit - cur_len - 1                  # -1: an idle lane
-        hit = (picks[:, :-1] == toks[:, 1:]) \
-            & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
-        accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1),
-                           axis=1)
-        live = n_draft >= 0
         n_keep = _keeps(live, accepted)
     with jax.named_scope("ssm/state_update"):
         for si, window in enumerate(windows):
@@ -437,17 +388,21 @@ def _verify_step(params, *args, cfg):
                   ssm_state_lane_moves=2 * jnp.sum(live),
                   ssm_deferred_positions=jnp.sum(
                       jnp.where(live, n_owed, 0)),
-                  spec_rolled_back_tokens=jnp.sum(
-                      jnp.where(live, n_draft - accepted, 0)))
-    acc = _bump(acc, ssm_state_passes=1, **by)
+                  spec_rolled_back_tokens=rolled_back(live, n_draft,
+                                                      accepted))
+    acc = bump(acc, ACC, 0, ssm_state_passes=1, **by)
     return (_out(picks, acc), kpool, vpool, cpool, acc, *left,
             n_keep.astype(n_owed.dtype), *states, *pend_x)
 
 
-class HybridSSMFamily:
+class HybridSSMFamily(Family):
     """See ``families/__init__.py`` for what the engine asks of it."""
 
     name = "hybrid_ssm"
+    title = "the hybrid state-space family"
+    ACC = ACC
+    programs = {"prefill": _prefill_chunk, "decode": _decode_step,
+                "verify": _verify_step}
     lane_state = True
     prefix_reuse = False
     row_read = "kernel"  # the attention layers' live rows: row_attention
@@ -457,22 +412,11 @@ class HybridSSMFamily:
         "boundary, which nothing snapshots yet (ROADMAP B-m4)")
 
     def __init__(self, model, config):
-        from ...framework.errors import UnimplementedError
-
-        for flag, why in (
-                (config.kv_int8, "kv_int8: most of its device state is the "
-                 "float32 recurrent state, which the int8 K/V scale pools "
-                 "do not cover"),
-                (config.int8_weights, "int8_weights: the pack would be a "
-                 "second copy of the weights")):
-            if flag:
-                raise UnimplementedError(
-                    f"the hybrid state-space family does not serve with "
-                    f"{why}")
+        self.refuse(config, {
+            "kv_int8": "most of its device state is the float32 recurrent "
+            "state, which the int8 K/V scale pools do not cover"})
+        super().__init__(model, config)
         c = model.config
-        self.gcfg = c.static()
-        self.max_position_embeddings = c.max_position_embeddings
-        self.lanes = config.max_lanes
         self.n_ssm = sum(k == M.SSM for k in c.layer_types)
         self.n_attn = c.num_hidden_layers - self.n_ssm
         self.donate_argnums = tuple(
@@ -482,13 +426,6 @@ class HybridSSMFamily:
             raise UnimplementedError(
                 "a stack with no attention layer has no K/V pool: the "
                 "engine's block pool would manage nothing")
-        # the model's own arrays: ONE copy of the weights on the device
-        self.params = {
-            "embed": model.embed._data, "norm": model.norm._data,
-            "layers": tuple({k: p._data for k, p in blk.leaves().items()}
-                            for blk in model.layers)}
-        self.counters = dict.fromkeys(ACC, 0)
-        self._seen = [0] * len(ACC)
 
     def make_pools(self, num_blocks, block_size):
         """(K pool, V pool, conv pool, the counters' device accumulator,
@@ -528,29 +465,10 @@ class HybridSSMFamily:
         return int(pools[2].nbytes + sum(p.nbytes for p in pools[4:]))
 
     def read_form(self, kind):
-        """The dense family's live rows ``(W, tile)`` (the kernel's grid is
+        """The paged layer's live rows ``(W, tile)`` (the kernel's grid is
         the live rows: ``tile`` only rounds the operand's length);
         ``lane_state`` adds the request's lane to the prefill chunk's."""
         return ROW_BLOCKS, PREFILL_TILE if kind == "prefill" else ROW_TILE
-
-    def program(self, kind):
-        fn = {"prefill": _prefill_chunk, "decode": _decode_step,
-              "verify": _verify_step}[kind]
-        return fn, {"cfg": self.gcfg}
-
-    def exec_key(self, pools):
-        from ...jit import exec_cache
-
-        return {"family": self.name, "gen_cfg": self.gcfg._key(),
-                "params": [exec_cache.array_spec(a) for a in
-                           jax.tree_util.tree_leaves(self.params)],
-                "pools": [(tuple(int(x) for x in p.shape), str(p.dtype))
-                          for p in (*pools[:N_POOLS + 1], pools[-1])],
-                "state_arrays": self.n_ssm}
-
-    def absorb(self, out, counters):
-        """Strip the accumulator off the fetched vector into ``counters``."""
-        return absorb_accumulator(out, ACC, self._seen, counters)
 
     def stats(self):
         g = self.gcfg

@@ -49,7 +49,8 @@ from ...models.generation import _rms
 from ...models.latent_moe import (
     absorb_query, latent_qkv, mlp_block, unabsorb_output,
 )
-from . import absorb_accumulator
+from .common import MOE_ACC as ACC  # the accumulator: the expert layers'
+from .common import Family, _out, expert_counts, greedy_head, write_slots
 
 __all__ = ["LatentMoEFamily"]
 
@@ -77,10 +78,6 @@ PREFILL_TILE = 4
 # 9.8, PERF.md section 6, PR 32). The matmuls run at the call's width; the
 # attention at what the call was fed.
 QUERY_TILE = 32
-
-# the device accumulator's slots, in the order the programs fill them
-ACC = ("moe_assignments", "moe_assignments_held", "moe_expert_calls",
-       "moe_load_max_sum")
 
 
 def _attend_rows(qq, pos, rows, gather, tile, lp, cfg):
@@ -174,16 +171,6 @@ def _attend_tiles(qq, pos, n_tiles, attend, width):
         0, n_tiles, one, jnp.zeros((*qq.shape[:2], width), qq.dtype))
 
 
-def write_slots(wblk, pos, wlimit, block):
-    """Where the fed positions ``pos`` [b, s] are written: (block, offset)
-    [b, s] — ``wblk`` the block each position falls in (the host's lookup
-    in the lane's block list), positions >= ``wlimit[b]`` redirected to
-    null block 0."""
-    with jax.named_scope("mla/kv_write"):
-        ok = pos < wlimit[:, None]
-        return jnp.where(ok, wblk, 0), jnp.where(ok, pos % block, 0)
-
-
 def attend_pool(u, lp, li, pool, rows, pos, blk, off, cfg, tile,
                 n_tiles=None, rope=True):
     """Latent layer ``li``'s attention on normed ``u`` [b, s, h] against
@@ -242,7 +229,7 @@ def _pool_forward(params, pool, acc, read, ids, pos, wlimit, valid, cfg,
     with scope("embed"):
         x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
     rows, wblk = read
-    blk, off = write_slots(wblk, pos, wlimit, pool.shape[2])
+    blk, off = write_slots(wblk, pos, wlimit, pool.shape[2], "mla/kv_write")
     with scope("acc"):
         n_valid = jnp.sum(valid, dtype=jnp.int32)
     for li, lp in enumerate(params["layers"]):
@@ -272,27 +259,6 @@ def read_form(kind):
     return ROW_BLOCKS, PREFILL_TILE if kind == "prefill" else ROW_TILE
 
 
-def expert_counts(n_valid, counts, top_k):
-    """What one expert-layer call adds to the accumulator's ``ACC``."""
-    with jax.named_scope("acc"):
-        return jnp.stack([n_valid * top_k, jnp.sum(counts), jnp.int32(1),
-                          jnp.max(counts)])
-
-
-def _head(x, params, cfg):
-    with jax.named_scope("head"):
-        x = _rms(x, params["norm"], cfg.rms_norm_eps)
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
-    with jax.named_scope("sample"):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
-def _out(picks, acc):
-    """A program's fetched vector: its picks, then the accumulator."""
-    with jax.named_scope("acc"):
-        return jnp.concatenate([picks.reshape(-1), acc])
-
-
 def _prefill_chunk(params, pool, acc, read, ids, start, ctx_len, last_idx,
                    *, cfg, tile):
     """One lane's prefill chunk ``ids`` [1, C] at [start, start + C),
@@ -310,7 +276,7 @@ def _prefill_chunk(params, pool, acc, read, ids, start, ctx_len, last_idx,
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
                                          keepdims=False)
-    return _out(_head(h, params, cfg), acc), pool, acc
+    return _out(greedy_head(h, params, cfg.rms_norm_eps), acc), pool, acc
 
 
 def _decode_step(params, pool, acc, read, cur_len, last_tok, *, cfg, tile):
@@ -324,7 +290,7 @@ def _decode_step(params, pool, acc, read, cur_len, last_tok, *, cfg, tile):
     x, pool, acc = _pool_forward(params, pool, acc, read, *fed, cfg, tile)
     with jax.named_scope("head"):
         x = x[:, -1]
-    return _out(_head(x, params, cfg), acc), pool, acc
+    return _out(greedy_head(x, params, cfg.rms_norm_eps), acc), pool, acc
 
 
 def _verify_step(params, pool, acc, read, cur_len, toks, wlimit, *, cfg,
@@ -339,43 +305,30 @@ def _verify_step(params, pool, acc, read, cur_len, toks, wlimit, *, cfg,
         valid = pos < wlimit[:, None]
     x, pool, acc = _pool_forward(params, pool, acc, read, toks, pos,
                                  wlimit, valid, cfg, tile)
-    return _out(_head(x, params, cfg), acc), pool, acc
+    return _out(greedy_head(x, params, cfg.rms_norm_eps), acc), pool, acc
 
 
-class LatentMoEFamily:
+class LatentMoEFamily(Family):
     """See ``families/__init__.py`` for what the engine asks of it."""
 
     name = "latent_moe"
+    title = "the latent-attention family"
+    ACC = ACC
+    programs = {"prefill": _prefill_chunk, "decode": _decode_step,
+                "verify": _verify_step}
+    tiled = True  # ``_attend_rows`` runs its rows ``tile`` at a time
     donate_argnums = (1, 2)
-    lane_state = False   # the one pool is indexed by (layer, block, offset)
-    prefix_reuse = True  # a prefix's latent blocks are all a request needs
 
     def __init__(self, model, config):
-        from ...framework.errors import UnimplementedError
-
-        for flag, why in (
-                (config.kv_int8, "kv_int8: the int8 scale pools are "
-                 "[.., kv_heads] beside [.., kv_heads, head_dim] pools"),
-                (config.int8_weights, "int8_weights: the pack would be a "
-                 "second copy of the weights")):
-            if flag:
-                raise UnimplementedError(
-                    f"the latent-attention family does not serve with "
-                    f"{why}; its cache is one [layers, blocks, block, "
-                    f"{model.config.latent_width}] latent pool")
         c = model.config
-        self.gcfg = c.static()
+        self.refuse(config, {
+            "kv_int8": "the int8 scale pools are [.., kv_heads] beside "
+            "[.., kv_heads, head_dim] pools"},
+            tail=f"; its cache is one [layers, blocks, block, "
+            f"{c.latent_width}] latent pool")
+        super().__init__(model, config)
         self.layers = c.num_hidden_layers
-        self.max_position_embeddings = c.max_position_embeddings
         self._width = c.latent_width
-        # the model's own arrays: ONE copy of the weights on the device
-        self.params = {
-            "embed": model.embed._data, "norm": model.norm._data,
-            "lm_head": model.lm_head._data,
-            "layers": tuple({k: p._data for k, p in blk.leaves().items()}
-                            for blk in model.layers)}
-        self.counters = dict.fromkeys(ACC, 0)
-        self._seen = [0] * len(ACC)
 
     def make_pools(self, num_blocks, block_size):
         """(latent pool, the counters' device accumulator). The pool's
@@ -395,32 +348,11 @@ class LatentMoEFamily:
     def kv_pool_bytes(self, pools):
         return int(pools[0].nbytes)
 
-    def lane_pool_bytes(self, pools):
-        return 0
-
     def read_form(self, kind):
         """How program ``kind`` is told where its lanes' entries lie
         (``ServingEngine._pack_read`` builds it): ``(W, tile)`` — live
         rows of ``W`` blocks, run ``tile`` at a time."""
         return read_form(kind)
-
-    def program(self, kind):
-        return {"prefill": _prefill_chunk, "decode": _decode_step,
-                "verify": _verify_step}[kind], {
-            "cfg": self.gcfg, "tile": read_form(kind)[1]}
-
-    def exec_key(self, pools):
-        from ...jit import exec_cache
-
-        return {"family": self.name, "gen_cfg": self.gcfg._key(),
-                "params": [exec_cache.array_spec(a) for a in
-                           jax.tree_util.tree_leaves(self.params)],
-                "pool": (tuple(int(x) for x in pools[0].shape),
-                         str(pools[0].dtype))}
-
-    def absorb(self, out, counters):
-        """Strip the accumulator off the fetched vector into ``counters``."""
-        return absorb_accumulator(out, ACC, self._seen, counters)
 
     def stats(self):
         itemsize = jnp.dtype(self.gcfg.dtype).itemsize

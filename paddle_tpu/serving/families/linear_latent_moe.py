@@ -6,7 +6,11 @@ kinds of device state in one family.
   ``families/latent_moe.py`` — laid out, written and read by ITS functions
   (the lanes' live rows gathered a tile at a time from the stacked pool,
   the absorbed attention row by row: ``attend_pool``), for the few
-  latent-attention layers only; no position embedding.
+  latent-attention layers only; no position embedding. This is the one
+  import of a family by a family: the latent layer (``attend_pool``,
+  ``chunk_tiles``, ``read_form`` with the constants tests steer, the
+  pool's ``LANES``) stays where its family is and is served from there,
+  not copied into ``common.py``, which holds no latent arithmetic.
 - **By LANE, float32**: the delta rule's matrix state, ``[lanes, heads, d,
   d]`` (key x value) a linear-attention layer — ONE ARRAY A LAYER, not one
   stacked pool (``families/hybrid_ssm.py`` says what a stacked one cost) —
@@ -50,7 +54,7 @@ position 0 starts from ZERO state and tail.
   program is a Python loop over the layers with three bodies (linear
   attention + dense, linear attention + experts, latent + experts).
 - **Counters** ride on the round's token array: the expert layer's four
-  (the latent family's ``ACC``), the state's five and the rounds' count
+  (``common.MOE_ACC``), the state's five and the rounds' count
   of held experts hit (``LIN_ACC``), one accumulator.
 
 ``kv_int8`` and ``int8_weights`` raise ``UnimplementedError``.
@@ -60,15 +64,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...framework.errors import UnimplementedError
 from ...models import linear_latent_moe as M
 from ...models.generation import _rms
-from . import absorb_accumulator
-from .hybrid_ssm import _carried, _keeps, _take_rows
-from .latent_moe import ACC as MOE_ACC
-from .latent_moe import (
-    LANES, _head, _out, attend_pool, chunk_tiles, expert_counts, read_form,
-    write_slots,
+from .common import (
+    MOE_ACC, Family, _carried, _keeps, _out, _take_rows, accept, bump,
+    expert_counts, greedy_head, lane_tails, rolled_back, write_slots,
 )
+from .latent_moe import LANES, attend_pool, chunk_tiles, read_form
 
 __all__ = ["LinearLatentMoEFamily"]
 
@@ -90,17 +93,6 @@ LIN_ACC = ("lin_state_passes", "lin_lane_rounds", "lin_state_lane_moves",
 ACC = MOE_ACC + LIN_ACC
 
 
-def _bump(acc, **by):
-    with jax.named_scope("acc"):
-        return acc.at[len(MOE_ACC):].add(jnp.stack(
-            [jnp.asarray(by.get(n, 0), jnp.int32) for n in LIN_ACC]))
-
-
-def _tail(cpool, ki, cfg):
-    """Layer ``ki``'s conv tails as ``[lanes, K - 1, channels]``."""
-    return cpool[ki].reshape(cpool.shape[1], cfg.kda_taps - 1, -1)
-
-
 def _heads_first(a):
     """[b, T, H, ...] <-> [b, H, T, ...]."""
     return jnp.swapaxes(a, 1, 2)
@@ -120,7 +112,7 @@ def _stack(params, ids, pos, wlimit, valid, read, pool, acc, cfg, kda, tile,
     with scope("embed"):
         x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
     rows, wblk = read
-    blk, off = write_slots(wblk, pos, wlimit, pool.shape[2])
+    blk, off = write_slots(wblk, pos, wlimit, pool.shape[2], "mla/kv_write")
     with scope("acc"):
         n_valid = jnp.sum(valid, dtype=jnp.int32)
         hit = jnp.int32(0)
@@ -211,11 +203,12 @@ def _prefill_chunk(params, *args, cfg, tile):
     x, pool, acc, _ = _stack(
         params, ids, pos, jnp.reshape(ctx_len, (1,)), real, read, pool,
         acc, cfg, kda, tile, n_tiles=chunk_tiles(C, start, ctx_len))
-    acc = _bump(acc, lin_slot_resets=fresh)
+    acc = bump(acc, LIN_ACC, len(MOE_ACC), lin_slot_resets=fresh)
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
                                          keepdims=False)
-    return _out(_head(h, params, cfg), acc), pool, acc, conv[0], *states
+    picks = greedy_head(h, params, cfg.rms_norm_eps)
+    return _out(picks, acc), pool, acc, conv[0], *states
 
 
 def _decode_step(params, *args, cfg, tile):
@@ -232,8 +225,8 @@ def _decode_step(params, *args, cfg, tile):
     def kda(ki, u, lp):
         raw = M.kda_project(u, lp, cfg)
         with jax.named_scope("kda/conv"):
-            window = jnp.concatenate([_tail(conv[0], ki, cfg), raw],
-                                     axis=1)
+            window = jnp.concatenate(
+                [lane_tails(conv[0], ki, cfg.kda_taps), raw], axis=1)
         q, k, v = M.kda_conv(window, lp, cfg)
         g, beta = M.kda_gates(u, lp, cfg)
         with jax.named_scope("kda/state_update"):
@@ -252,10 +245,12 @@ def _decode_step(params, *args, cfg, tile):
     with jax.named_scope("acc"):
         n = jnp.sum(live)
         by = dict(lin_lane_rounds=n, lin_state_lane_moves=2 * n)
-    acc = _bump(acc, lin_state_passes=1, moe_round_experts_hit=n_hit, **by)
+    acc = bump(acc, LIN_ACC, len(MOE_ACC), lin_state_passes=1,
+               moe_round_experts_hit=n_hit, **by)
     with jax.named_scope("head"):
         x = x[:, -1]
-    return _out(_head(x, params, cfg), acc), pool, acc, conv[0], *states
+    picks = greedy_head(x, params, cfg.rms_norm_eps)
+    return _out(picks, acc), pool, acc, conv[0], *states
 
 
 def _verify_step(params, *args, cfg, tile):
@@ -278,7 +273,8 @@ def _verify_step(params, *args, cfg, tile):
     def kda(ki, u, lp):
         raw = M.kda_project(u, lp, cfg)
         with jax.named_scope("kda/conv"):
-            window = jnp.concatenate([_tail(cpool, ki, cfg), raw], axis=1)
+            window = jnp.concatenate(
+                [lane_tails(cpool, ki, cfg.kda_taps), raw], axis=1)
         q, k, v = M.kda_conv(window, lp, cfg)
         with jax.named_scope("kda/conv"):
             q, k, v = (_heads_first(a) for a in (q, k, v))
@@ -293,16 +289,9 @@ def _verify_step(params, *args, cfg, tile):
 
     x, pool, acc, n_hit = _stack(params, toks, pos, wlimit, valid, read,
                                  pool, acc, cfg, kda, tile)
-    picks = _head(x, params, cfg)
-    # a lane keeps its pending token and the longest prefix of its draft
-    # that equals the program's own picks (engine._accept's rule)
+    picks = greedy_head(x, params, cfg.rms_norm_eps)
+    live, n_draft, accepted = accept(picks, toks, cur_len, wlimit)
     with jax.named_scope("spec"):
-        n_draft = wlimit - cur_len - 1                  # -1: an idle lane
-        hit = (picks[:, :-1] == toks[:, 1:]) \
-            & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
-        accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1),
-                           axis=1)
-        live = n_draft >= 0
         n_keep = _keeps(live, accepted)
         keep = (jnp.arange(S1)[None, :]
                 < n_keep[:, None])[:, None, :, None]
@@ -316,16 +305,22 @@ def _verify_step(params, *args, cfg, tile):
     with jax.named_scope("acc"):
         by = dict(lin_lane_rounds=jnp.sum(live),
                   lin_state_lane_moves=3 * jnp.sum(live),
-                  spec_rolled_back_tokens=jnp.sum(
-                      jnp.where(live, n_draft - accepted, 0)))
-    acc = _bump(acc, lin_state_passes=2, moe_round_experts_hit=n_hit, **by)
+                  spec_rolled_back_tokens=rolled_back(live, n_draft,
+                                                      accepted))
+    acc = bump(acc, LIN_ACC, len(MOE_ACC), lin_state_passes=2,
+               moe_round_experts_hit=n_hit, **by)
     return _out(picks, acc), pool, acc, cpool, *states
 
 
-class LinearLatentMoEFamily:
+class LinearLatentMoEFamily(Family):
     """See ``families/__init__.py`` for what the engine asks of it."""
 
     name = "linear_latent_moe"
+    title = "the linear-attention family"
+    ACC = ACC
+    programs = {"prefill": _prefill_chunk, "decode": _decode_step,
+                "verify": _verify_step}
+    tiled = True  # the latent layers' read runs its rows ``tile`` at a time
     lane_state = True
     prefix_reuse = False
     prefix_reuse_why = (
@@ -335,22 +330,12 @@ class LinearLatentMoEFamily:
         "B-m4)")
 
     def __init__(self, model, config):
-        from ...framework.errors import UnimplementedError
-
-        for flag, why in (
-                (config.kv_int8, "kv_int8: most of its device state is the "
-                 "float32 recurrent state, and the int8 scale pools are "
-                 "[.., kv_heads] beside [.., kv_heads, head_dim] pools"),
-                (config.int8_weights, "int8_weights: the pack would be a "
-                 "second copy of the weights")):
-            if flag:
-                raise UnimplementedError(
-                    f"the linear-attention family does not serve with "
-                    f"{why}")
+        self.refuse(config, {
+            "kv_int8": "most of its device state is the float32 recurrent "
+            "state, and the int8 scale pools are [.., kv_heads] beside "
+            "[.., kv_heads, head_dim] pools"})
+        super().__init__(model, config)
         c = model.config
-        self.gcfg = c.static()
-        self.max_position_embeddings = c.max_position_embeddings
-        self.lanes = config.max_lanes
         self.n_kda = sum(k == M.KDA for k in c.layer_kinds)
         self.n_latent = c.num_hidden_layers - self.n_kda
         self._width = c.latent_width
@@ -359,14 +344,6 @@ class LinearLatentMoEFamily:
             raise UnimplementedError(
                 "a stack with no latent-attention layer has no block pool: "
                 "the engine's block pool would manage nothing")
-        # the model's own arrays: ONE copy of the weights on the device
-        self.params = {
-            "embed": model.embed._data, "norm": model.norm._data,
-            "lm_head": model.lm_head._data,
-            "layers": tuple({k: p._data for k, p in blk.leaves().items()}
-                            for blk in model.layers)}
-        self.counters = dict.fromkeys(ACC, 0)
-        self._seen = [0] * len(ACC)
 
     def make_pools(self, num_blocks, block_size):
         """(latent pool by (latent layer, block, offset), the counters'
@@ -394,25 +371,6 @@ class LinearLatentMoEFamily:
         their lanes' live rows through its functions; ``lane_state`` adds
         the request's lane to the prefill chunk's operand."""
         return read_form(kind)
-
-    def program(self, kind):
-        return {"prefill": _prefill_chunk, "decode": _decode_step,
-                "verify": _verify_step}[kind], {
-            "cfg": self.gcfg, "tile": read_form(kind)[1]}
-
-    def exec_key(self, pools):
-        from ...jit import exec_cache
-
-        return {"family": self.name, "gen_cfg": self.gcfg._key(),
-                "params": [exec_cache.array_spec(a) for a in
-                           jax.tree_util.tree_leaves(self.params)],
-                "pools": [(tuple(int(x) for x in p.shape), str(p.dtype))
-                          for p in pools[:4]], "state_arrays": self.n_kda}
-
-    def absorb(self, out, counters):
-        """Strip the accumulator (the expert layer's slots and the
-        state's) off the fetched vector into ``counters``."""
-        return absorb_accumulator(out, ACC, self._seen, counters)
 
     def stats(self):
         g = self.gcfg

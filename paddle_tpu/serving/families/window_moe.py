@@ -8,8 +8,8 @@ kinds by LAYER TYPE.
   axis, the heads merged into it (4 x 192 = 6 and 4 x 128 = 4 lane tiles:
   the pools' own layout is row-major and no call copies them,
   ``families/hybrid_ssm.py`` says what the unmerged form cost) — written by
-  (layer, block, offset) with the null-block redirect and read by the
-  dense family's live-rows read: a call's
+  (layer, block, offset) with the null-block redirect and read over the
+  lanes' live rows (``common.paged_attention``): a call's
   cost follows the live blocks (the fused kernel
   ``ops/pallas/row_attention.py``, which copies a row's blocks out of the
   stacked pools itself). A window layer takes NOTHING in the block pool.
@@ -63,7 +63,7 @@ kinds by LAYER TYPE.
 - **Weights once**: ``params`` references the model's arrays; each
   program is a Python loop over the layers.
 - **Counters** ride on the round's token array (the latent family's way):
-  the expert layer's four, then ``WIN_ACC``.
+  the expert layer's four (``common.MOE_ACC``), then ``WIN_ACC``.
 
 ``kv_int8`` and ``int8_weights`` raise ``UnimplementedError``.
 """
@@ -72,21 +72,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...framework.errors import UnimplementedError
 from ...models import window_moe as M
 from ...models.generation import _rms
-from ...ops.pallas.row_attention import row_attention
-from . import absorb_accumulator
-from .latent_moe import ACC as MOE_ACC
-from .latent_moe import _out, expert_counts
+from .common import (
+    MOE_ACC, PREFILL_TILE, ROW_BLOCKS, ROW_TILE, Family, _out, accept, bump,
+    expert_counts, greedy_head, paged_attention, rolled_back, write_slots,
+)
 
 __all__ = ["WindowMoEFamily"]
 
-# The full layers' read (the dense family's, PERF.md section 6, PR 28): a
-# row is ROW_BLOCKS blocks of one lane; a round program runs its live rows
-# ROW_TILE at a time, the prefill chunk (all rows one lane's) PREFILL_TILE.
-ROW_BLOCKS = 16
-ROW_TILE = 16
-PREFILL_TILE = 4
 RING_TILE = 16  # a ring is whole 16-row tiles of the model dtype
 # The prefill call's width (``WindowMoEFamily.prefill_chunk``; the engine
 # fits it to whole blocks under ``max_seq_len``, and a width a deployer
@@ -133,12 +128,6 @@ def held_positions(t, R):
     position ``t`` [...]: ``[..., R]``, negative where the slot is empty."""
     t = t[..., None]
     return t - (t - jnp.arange(R, dtype=t.dtype)) % R
-
-
-def _bump(acc, **by):
-    with jax.named_scope("acc"):
-        return acc.at[len(MOE_ACC):].add(jnp.stack(
-            [jnp.asarray(by.get(n, 0), jnp.int32) for n in WIN_ACC]))
 
 
 def _heads(a, g):
@@ -209,20 +198,6 @@ def ring_chunk(q, k, v, pos, start, n_real, slot, rk, rv, lp, cfg):
     return att, rk, rv
 
 
-def _full_attention(q, k, v, ai, kpool, vpool, rows, pos, blk, off, cfg):
-    """A full layer against the block pool: write the fed tokens' K/V by
-    (layer, block, offset), then the dense family's live-rows read.
-    Returns (att [b, s, H x dv], kpool, vpool)."""
-    b, s = pos.shape
-    g, dk, dv = cfg.num_key_value_heads, cfg.head_dim, cfg.v_head_dim
-    with jax.named_scope("attn/kv_write"):
-        kpool = kpool.at[ai, blk, off].set(k.reshape(b, s, g * dk))
-        vpool = vpool.at[ai, blk, off].set(v.reshape(b, s, g * dv))
-    with jax.named_scope("attn/rows"):  # from the STACKED pools
-        out = row_attention(q, pos, rows, kpool, vpool, ai, g, dk ** -0.5)
-    return out.reshape(b, s, -1), kpool, vpool
-
-
 def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
            window):
     """The layer stack over ``ids`` [b, s] at positions ``pos``: full
@@ -236,10 +211,8 @@ def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
     with scope("embed"):
         x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
     rows, wblk = read
-    with scope("attn/kv_write"):
-        ok = pos < wlimit[:, None]
-        blk = jnp.where(ok, wblk, 0)
-        off = jnp.where(ok, pos % kpool.shape[2], 0)
+    blk, off = write_slots(wblk, pos, wlimit, kpool.shape[2],
+                           "attn/kv_write")
     with scope("acc"):
         n_valid = jnp.sum(valid, dtype=jnp.int32)
         hit = jnp.int32(0)
@@ -250,8 +223,10 @@ def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
             att = window(wi, q, k, v, lp)
             wi += 1
         else:
-            att, kpool, vpool = _full_attention(
-                q, k, v, ai, kpool, vpool, rows, pos, blk, off, cfg)
+            att, kpool, vpool = paged_attention(
+                q, k, v, ai, kpool, vpool, rows, pos, blk, off,
+                cfg.num_key_value_heads, cfg.head_dim ** -0.5)
+            att = att.reshape(*pos.shape, -1)
             ai += 1
         with scope("attn/out"):
             x = x + att @ lp["o"]
@@ -265,14 +240,6 @@ def _stack(params, ids, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
                     n_valid, counts, cfg.num_experts_per_tok))
                 hit = hit + jnp.sum(counts > 0, dtype=jnp.int32)
     return x, kpool, vpool, acc, hit
-
-
-def _head(x, params, cfg):
-    with jax.named_scope("head"):
-        x = _rms(x, params["norm"], cfg.layernorm_epsilon)
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
-    with jax.named_scope("sample"):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _unpack(args, cfg):
@@ -309,11 +276,12 @@ def _prefill_chunk(params, *args, cfg):
     x, kpool, vpool, acc, _ = _stack(
         params, ids, pos, jnp.reshape(ctx_len, (1,)), real, read, kpool,
         vpool, acc, cfg, window)
-    acc = _bump(acc, win_slot_resets=start == 0)
+    acc = bump(acc, WIN_ACC, len(MOE_ACC), win_slot_resets=start == 0)
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
                                          keepdims=False)
-    return _out(_head(h, params, cfg), acc), kpool, vpool, acc, *rks, *rvs
+    picks = greedy_head(h, params, cfg.layernorm_epsilon)
+    return _out(picks, acc), kpool, vpool, acc, *rks, *rvs
 
 
 def _decode_step(params, *args, cfg):
@@ -334,10 +302,11 @@ def _decode_step(params, *args, cfg):
     x, kpool, vpool, acc, n_hit = _stack(
         params, last_tok[:, None], pos, cur_len + 1, live, read, kpool,
         vpool, acc, cfg, window)
-    acc = _bump(acc, moe_round_experts_hit=n_hit)
+    acc = bump(acc, WIN_ACC, len(MOE_ACC), moe_round_experts_hit=n_hit)
     with jax.named_scope("head"):
         x = x[:, -1]
-    return _out(_head(x, params, cfg), acc), kpool, vpool, acc, *rks, *rvs
+    picks = greedy_head(x, params, cfg.layernorm_epsilon)
+    return _out(picks, acc), kpool, vpool, acc, *rks, *rvs
 
 
 def _verify_step(params, *args, cfg):
@@ -360,23 +329,23 @@ def _verify_step(params, *args, cfg):
     x, kpool, vpool, acc, n_hit = _stack(
         params, toks, pos, wlimit, valid, read, kpool, vpool, acc, cfg,
         window)
-    picks = _head(x, params, cfg)
-    with jax.named_scope("spec"):  # engine._accept's rule, to count by
-        n_draft = wlimit - cur_len - 1                  # -1: an idle lane
-        hit = (picks[:, :-1] == toks[:, 1:]) \
-            & (jnp.arange(S1 - 1)[None, :] < n_draft[:, None])
-        accepted = jnp.sum(jnp.cumprod(hit.astype(jnp.int32), axis=1),
-                           axis=1)
-        rolled = jnp.sum(jnp.where(n_draft >= 0, n_draft - accepted, 0))
-    acc = _bump(acc, moe_round_experts_hit=n_hit,
-                spec_rolled_back_tokens=rolled)
+    picks = greedy_head(x, params, cfg.layernorm_epsilon)
+    counted = accept(picks, toks, cur_len, wlimit)  # to count by
+    with jax.named_scope("spec"):
+        rolled = rolled_back(*counted)
+    acc = bump(acc, WIN_ACC, len(MOE_ACC), moe_round_experts_hit=n_hit,
+               spec_rolled_back_tokens=rolled)
     return _out(picks, acc), kpool, vpool, acc, *rks, *rvs
 
 
-class WindowMoEFamily:
+class WindowMoEFamily(Family):
     """See ``families/__init__.py`` for what the engine asks of it."""
 
     name = "window_moe"
+    title = "the window-attention family"
+    ACC = ACC
+    programs = {"prefill": _prefill_chunk, "decode": _decode_step,
+                "verify": _verify_step}
     prefill_chunk = PREFILL_CHUNK
     lane_state = True
     prefix_reuse = False
@@ -388,22 +357,11 @@ class WindowMoEFamily:
         "(ROADMAP B-m2)")
 
     def __init__(self, model, config):
-        from ...framework.errors import UnimplementedError
-
-        for flag, why in (
-                (config.kv_int8, "kv_int8: the int8 scale pools pair with "
-                 "[.., kv_heads, head_dim] pools of one width, and the "
-                 "rings have none"),
-                (config.int8_weights, "int8_weights: the pack would be a "
-                 "second copy of the weights")):
-            if flag:
-                raise UnimplementedError(
-                    f"the window-attention family does not serve with "
-                    f"{why}")
+        self.refuse(config, {
+            "kv_int8": "the int8 scale pools pair with [.., kv_heads, "
+            "head_dim] pools of one width, and the rings have none"})
+        super().__init__(model, config)
         c = model.config
-        self.gcfg = c.static()
-        self.max_position_embeddings = c.max_position_embeddings
-        self.lanes = config.max_lanes
         self.n_window = sum(c.hybrid_layer_pattern)
         self.n_full = c.num_hidden_layers - self.n_window
         if not self.n_full:
@@ -421,14 +379,6 @@ class WindowMoEFamily:
                 f"sliding_window + spec_k + 1 = "
                 f"{c.sliding_window + spec_k + 1}")
         self.donate_argnums = tuple(range(1, 4 + 2 * self.n_window))
-        # the model's own arrays: ONE copy of the weights on the device
-        self.params = {
-            "embed": model.embed._data, "norm": model.norm._data,
-            "lm_head": model.lm_head._data,
-            "layers": tuple({k: p._data for k, p in blk.leaves().items()}
-                            for blk in model.layers)}
-        self.counters = dict.fromkeys(ACC, 0)
-        self._seen = [0] * len(ACC)
 
     def make_pools(self, num_blocks, block_size):
         """(K pool and V pool by (full layer, block, offset), the
@@ -458,24 +408,6 @@ class WindowMoEFamily:
         ``lane_state`` adds the request's lane to the prefill chunk's
         operand."""
         return read_form(kind)
-
-    def program(self, kind):
-        return {"prefill": _prefill_chunk, "decode": _decode_step,
-                "verify": _verify_step}[kind], {"cfg": self.gcfg}
-
-    def exec_key(self, pools):
-        from ...jit import exec_cache
-
-        return {"family": self.name, "gen_cfg": self.gcfg._key(),
-                "params": [exec_cache.array_spec(a) for a in
-                           jax.tree_util.tree_leaves(self.params)],
-                "pools": [(tuple(int(x) for x in p.shape), str(p.dtype))
-                          for p in pools[:4]], "rings": 2 * self.n_window}
-
-    def absorb(self, out, counters):
-        """Strip the accumulator (the expert layer's slots and the
-        rings') off the fetched vector into ``counters``."""
-        return absorb_accumulator(out, ACC, self._seen, counters)
 
     def stats(self):
         g = self.gcfg

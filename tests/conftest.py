@@ -29,7 +29,15 @@ import pytest  # noqa: E402
 # <repo>/.jax_cache
 from paddle_tpu.utils.xla_cache import enable_compilation_cache  # noqa: E402
 
-enable_compilation_cache()
+_cache_dir = enable_compilation_cache()
+# ... and one directory a pytest-xdist worker: six workers writing one
+# directory lost a different token-identity case each run, and two
+# builders lost a worker to a segmentation fault inside the cache (ROADMAP
+# C10). A serial run keeps the directory above.
+if os.environ.get("PYTEST_XDIST_WORKER"):
+    _cache_dir = os.path.join(_cache_dir, os.environ["PYTEST_XDIST_WORKER"])
+    os.makedirs(_cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", _cache_dir)
 
 # blackbox postmortems off by default under pytest: many tests raise
 # engine/NaN errors ON PURPOSE (often with the monitor enabled), and each

@@ -496,40 +496,6 @@ def _tiny_latent():
     return model
 
 
-@pytest.mark.parametrize("slot", [False, True], ids=["table", "table_slot"])
-def test_a_table_form_program_bills_its_whole_table(monkeypatch, slot):
-    """The engine's TABLE form (a family whose ``read_form`` is ``None``;
-    since PR 35 no family's is — ROADMAP C): the operand is every lane's
-    whole ``[lanes, M]`` list, null-padded (a ``lane_state`` family's
-    prefill chunk: with its slot), and gathered == the table in every
-    kind."""
-    eng = ServingEngine(_tiny_latent(), ServingConfig(
-        max_lanes=2, block_size=2, prefill_chunk=4, max_seq_len=16,
-        spec=False))
-    fam = eng._family
-    monkeypatch.setattr(type(fam), "read_form", lambda self, kind: None)
-    monkeypatch.setattr(type(fam), "lane_state", slot)
-    assert eng._rows_form("decode", 2) is None
-    assert eng._rows_form("prefill", 1) is None
-    M = eng.blocks_per_lane
-    got = eng._pack_read("decode", 2, 1, [(1, [5, 3, 7], 5, 6)])
-    want = np.zeros((2, M), np.int32)
-    want[1, :3] = [5, 3, 7]
-    np.testing.assert_array_equal(got, want)
-    assert eng._read_spec("decode", 2, 1).shape == (2, M)
-    got = eng._pack_read("prefill", 1, 4, [(0, [4, 9], 0, 3)], slot=1)
-    spec = eng._read_spec("prefill", 1, 4)
-    if slot:
-        (got, at), (spec, at_spec) = got, spec
-        assert at.tolist() == [1] and at_spec.shape == (1,)
-    assert got.tolist() == [[4, 9] + [0] * (M - 2)]
-    assert spec.shape == (1, M)
-    c = eng.counters
-    assert c["kv_read_tokens"] == 6 + 3
-    assert c["kv_gathered_tokens"] == c["kv_dense_read_tokens"] \
-        == (2 + 1) * M * 2
-
-
 def test_the_latent_family_bills_its_live_rows():
     """The latent family reads by rows (PR 35): what its programs gather
     follows what the lanes hold, in every kind."""
