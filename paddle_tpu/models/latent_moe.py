@@ -232,15 +232,15 @@ def absorb_query(q_nope, q_rope, lp, cfg, stored):
     return jnp.concatenate([q_lat, q_rope, pad], axis=-1)
 
 
-def unabsorb_output(o_lat, lp, cfg, dtype=None):
+def unabsorb_output(o_lat, lp, cfg):
     """``kv_b``'s value half applied to the per-head weighted sums of the
-    latent ``o_lat`` [b, s, nh, dc]. Returns [b, s, nh * dv] in ``dtype``
-    (``o_lat``'s own unless given)."""
+    latent ``o_lat`` [b, s, nh, dc]. Returns [b, s, nh * dv] in
+    ``o_lat``'s dtype."""
     b, s, nh, _ = o_lat.shape
     _, w_v = _kvb_halves(lp, cfg)
     out = jnp.einsum("bshc,chd->bshd", o_lat, w_v,
                      preferred_element_type=jnp.float32)
-    return out.astype(dtype or o_lat.dtype).reshape(
+    return out.astype(o_lat.dtype).reshape(
         b, s, nh * cfg.v_head_dim)
 
 
@@ -251,8 +251,9 @@ def attend_absorbed(q_nope, q_rope, cache, vis, lp, cfg):
     work is ``2 * nh * (2 dc + dr)`` FLOP on ``dc + dr`` numbers read.
     Matmuls in the model dtype with float32 accumulation, float32
     softmax. The serving programs run this arithmetic over the rows
-    their lanes hold (``serving/families/latent_moe.py:_attend_rows``,
-    held to this function by tests/test_serving_rows.py)."""
+    their lanes hold (``serving/families/latent_moe.py:attend_pool``:
+    the row kernel's one-pool form, held to this function by
+    tests/test_serving_rows.py)."""
     dc = cfg.kv_lora_rank
     f32 = jnp.float32
     dt = q_nope.dtype

@@ -1,7 +1,8 @@
 """The live-rows K/V read as one fused call a layer: the serving families'
 ``attn/rows`` scope wherever the pool is not int8
-(``serving/families/{dense_gqa,hybrid_ssm,window_moe}.py``; PERF.md section
-6, PR 39).
+(``serving/families/{dense_gqa,hybrid_ssm,window_moe,conv_moe}.py``;
+PERF.md section 6, PR 39) and the latent families' ``mla/attend``
+(``serving/families/latent_moe.py``, "One pool" below; PR 47).
 
 **Contract.** ``row_attention(q, pos, rows, kpool, vpool, layer, nkv, ...)``
 is ``dense_gqa._attend_lanes`` over LIVE ROWS: ``rows`` [R, 2 + W] int32 is
@@ -33,6 +34,20 @@ not by the call's width. Which form a call takes is its SHAPE's:
 every round, and every chunk of up to 128 positions at group 16 (512 at
 group 4), is one tile and the one-axis grid.
 
+**One pool.** ``vpool`` None and ``dv`` say that ONE pool holds a slot's
+key and its value: the value is the first ``dv`` numbers of its head's
+key. That is the latent pool under the absorbed query — ``[layers, blocks,
+block, 640]``, a slot the 512-wide normed latent and the 64-wide rotary
+key padded to whole lane tiles; ``nkv`` 1, ``d`` the stored width, ``dv``
+``kv_lora_rank`` — and the kernel then keeps no value buffer and makes no
+second copy: ``p x V`` reads ``kbuf[slot, :, :dv]``. Everything else
+(grid, scalar prefetch, the two-buffer copy pipeline, the bound on live
+rows, the fold, the query tiles) is the same code. One shared head brings
+a verify round ``positions x heads`` = 640 query rows a lane (a K/V
+head's group brings 4-80): its chunks of ``_Q_ROWS`` go through ``chunk``
+``_SIDE_ROWS`` rows side by side, as several KV heads' do ("Layout"); a
+128-position prefill chunk's 16,384 rows eight query tiles.
+
 **Arithmetic** (``_attend_lanes`` is the definition; the XLA read it
 replaces rounded the same operands the same way on the chip, PERF.md
 section 6, PR 39): ``q``, K and V enter the matrix unit as stored (bfloat16
@@ -59,7 +74,10 @@ so a tile is a run of whole positions) go through in chunks of ``_Q_ROWS``,
 
 **VMEM** follows the query rows ONE GRID STEP holds (queries and output
 double-buffered, the running max and sum, the float32 accumulator, the
-positions), so it is bounded by the query tile and not by the call. What
+positions), so it is bounded by the query tile and not by the call (the
+latent round: queries 640 x 640 and output 640 x 512 bfloat16 twice, 3
+MB, one buffer of 2 x 256 x 640, the accumulator 1.3 MB; its chunk's
+2,048-row tile ~17 MB). What
 the chip's compiler takes at the served geometries, by bisecting
 ``vmem_limit_bytes`` for a described v5e with the call INSIDE a program
 (its queries a product's result and its output a product's operand, which
@@ -100,6 +118,13 @@ _VMEM_LIMIT = 64 << 20
 # (PERF.md section 6, PR 39)
 _Q_ROWS = 128
 _TOGETHER_ROWS = 512
+# ... and the rows of ONE KV head's chunks scored side by side where the
+# heads do not fill ``_TOGETHER_ROWS`` — the latent pool's one shared head
+# brings a round 640 query rows a lane: all five chunks of 128 at once
+# read 0.72 ms a layer on the chip where two of 320 one after another
+# read 0.87 and one product of 640 rows 0.78 (PERF.md section 6, PR 47).
+# No K/V family's served call has room for a second chunk under it.
+_SIDE_ROWS = 640
 # Query rows of one KV head that one grid step may hold (module docstring,
 # "VMEM"): a lane's rows beyond it go a tile a walk of the live rows. The
 # widest that was served before a call could be wider (128 positions at
@@ -118,12 +143,21 @@ def _query_tile(M, bound):
                default=M)
 
 
-def _kernel(lay_ref, lane_ref, first_ref, blk_ref, pos_ref, q_ref, k_hbm,
-            v_hbm, _, o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
+def _kernel(lay_ref, lane_ref, first_ref, blk_ref, pos_ref, q_ref, *refs,
             W, B, nkv, d, dv, scale, window, rows_axis):
     """One grid step: live row ``r`` of the lane ``lane_ref[r]`` (against
     one tile of that lane's query rows where the grid has a leading axis
-    of query tiles: ``rows_axis`` 1)."""
+    of query tiles: ``rows_axis`` 1). ``refs``: the pools (K and V, or
+    the ONE pool a slot's key and value both lie in: the value is then
+    the first ``dv`` numbers of its head's key), the output's zeros and
+    the output, a VMEM buffer a pool, then the semaphores and the fold's
+    scratch."""
+    n = (len(refs) - 6) // 2  # pools: K and V, or the one with both
+    pools, (_, o_ref), bufs = refs[:n], refs[n:n + 2], refs[n + 2:2 * n + 2]
+    sem, m_scr, l_scr, acc_scr = refs[2 * n + 2:]
+    copied = tuple(zip(pools, bufs))
+    kbuf, vbuf = bufs[0], bufs[-1]
+    vd = dv if n == 2 else d  # from one head's values to the next's
     r, n = pl.program_id(rows_axis), pl.num_programs(rows_axis)
     lay, lane = lay_ref[0], lane_ref[r]
     M, S = q_ref.shape[2], W * B
@@ -136,7 +170,7 @@ def _kernel(lay_ref, lane_ref, first_ref, blk_ref, pos_ref, q_ref, k_hbm,
         def block(j, _):
             b = blk_ref[row * W + j]
             at = pl.ds(pl.multiple_of(j * B, B), B)
-            for i, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+            for i, (pool, buf) in enumerate(copied):
                 copy = pltpu.make_async_copy(
                     pool.at[lay, b], buf.at[into, at], sem.at[i, into])
                 copy.start() if start else copy.wait()
@@ -167,45 +201,62 @@ def _kernel(lay_ref, lane_ref, first_ref, blk_ref, pos_ref, q_ref, k_hbm,
     # (the widest chunk that divides the rows: a chunk width x group)
     step = max(c for c in range(1, min(M, _Q_ROWS) + 1) if M % c == 0)
     together = max(1, min(nkv, _TOGETHER_ROWS // step))
+    # where the KV heads leave room (ONE head: the latent pool), as many
+    # of a head's chunks side by side as divide them
+    chunks = M // step
+    side = max(c for c in range(1, chunks + 1) if chunks % c == 0
+               and c * together * step <= max(_SIDE_ROWS, together * step))
 
-    def chunk(hs, c0):
-        """Query rows ``c0 .. c0 + step`` of the KV heads ``hs`` against
-        the row's slots, a phase at a time over the heads: their chains
-        (product, max, exp, sum, product) are independent, and written
-        side by side the scheduler overlaps them."""
-        at = pl.ds(c0, step)
-        held = first + jax.lax.broadcasted_iota(jnp.int32, (step, S), 1)
-        p_own = pos_ref[0, at, :]                            # [step, 1]
-        vis = held <= p_own
-        if window > 0:
-            vis = jnp.logical_and(vis, held > p_own - window)
+    def chunk(hs, c0s):
+        """Query rows ``c0 .. c0 + step``, for each ``c0`` of ``c0s``, of
+        the KV heads ``hs`` against the row's slots, a phase at a time
+        over the chunks and heads: their chains (product, max, exp, sum,
+        product) are independent, and written side by side the scheduler
+        overlaps them."""
+        ats = [pl.ds(c0, step) for c0 in c0s]
+        viss = []
+        for at in ats:
+            held = first + jax.lax.broadcasted_iota(jnp.int32, (step, S), 1)
+            p_own = pos_ref[0, at, :]                        # [step, 1]
+            vis = held <= p_own
+            if window > 0:
+                vis = jnp.logical_and(vis, held > p_own - window)
+            viss.append(vis)
+        each = [(at, vis, h) for at, vis in zip(ats, viss) for h in hs]
         ss = [jax.lax.dot_general(
             q_ref[0, h, at, :], kbuf[slot, :, h * d:(h + 1) * d],
             (((1,), (1,)), ((), ())), preferred_element_type=F32)
-            for h in hs]                                     # [step, S]
-        ss = [jnp.where(vis, s * scale, _NEG) for s in ss]
-        m_old = [m_scr[h, at, :] for h in hs]
+            for at, _, h in each]                            # [step, S]
+        ss = [jnp.where(vis, s * scale, _NEG)
+              for s, (_, vis, _) in zip(ss, each)]
+        m_old = [m_scr[h, at, :] for at, _, h in each]
         m_new = [jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                  for m, s in zip(m_old, ss)]
         ps = [jnp.exp(s - m) for s, m in zip(ss, m_new)]
         keep = [jnp.exp(a - b) for a, b in zip(m_old, m_new)]
         pv = [jnp.dot(p.astype(vbuf.dtype),
-                      vbuf[slot, :, h * dv:(h + 1) * dv],
-                      preferred_element_type=F32) for h, p in zip(hs, ps)]
-        for i, h in enumerate(hs):
+                      vbuf[slot, :, h * vd:h * vd + dv],
+                      preferred_element_type=F32)
+              for (_, _, h), p in zip(each, ps)]
+        for i, (at, _, h) in enumerate(each):
             m_scr[h, at, :] = m_new[i]
             l_scr[h, at, :] = l_scr[h, at, :] * keep[i] + jnp.sum(
                 ps[i], axis=-1, keepdims=True)
             acc_scr[h, at, :] = acc_scr[h, at, :] * keep[i] + pv[i]
 
+    def chunks_at(c, hs):
+        """Loop step ``c``'s ``side`` chunks of the heads ``hs``."""
+        c0 = pl.multiple_of(c * (side * step), step)
+        chunk(hs, [c0, *(c0 + i * step for i in range(1, side))])
+
     for h0 in range(0, nkv, together):
         hs = range(h0, min(h0 + together, nkv))
-        if step == M:
-            chunk(hs, 0)
+        if side == chunks:
+            chunk(hs, [i * step for i in range(side)])
         else:
             jax.lax.fori_loop(
-                0, M // step, lambda c, _, hs=hs: chunk(
-                    hs, pl.multiple_of(c * step, step)), None)
+                0, chunks // side,
+                lambda c, _, hs=hs: chunks_at(c, hs), None)
 
     @pl.when(closes)
     def _():
@@ -215,35 +266,41 @@ def _kernel(lay_ref, lane_ref, first_ref, blk_ref, pos_ref, q_ref, k_hbm,
 
 
 def row_attention(q, pos, rows, kpool, vpool, layer, nkv, scale,
-                  sliding_window=0):
+                  sliding_window=0, dv=None):
     """``q`` [b, s, heads, d], ``pos`` [b, s], ``rows`` [R, 2 + W] (module
     docstring), ``kpool`` [layers, blocks, block, nkv x d], ``vpool`` [..,
-    nkv x dv], ``layer`` a number or a traced scalar. Returns [b, s, heads,
+    nkv x dv], ``layer`` a number or a traced scalar. ``vpool`` None: a
+    slot's value is the first ``dv`` numbers of its head's key (the
+    latent pool: module docstring, "One pool"). Returns [b, s, heads,
     dv] in ``q``'s dtype."""
     search.note_engaged("row_attention")  # pallas/engaged/..., at trace
-    return _rows(q, pos, rows, kpool, vpool,
+    pools = (kpool,) if vpool is None else (kpool, vpool)
+    return _rows(q, pos, rows, pools,
                  jnp.asarray(layer, jnp.int32).reshape(1), nkv=nkv,
+                 dv=int(dv) if vpool is None else vpool.shape[3] // nkv,
                  scale=float(scale), window=int(sliding_window),
                  q_tile=_query_tile(q.shape[1] * (q.shape[2] // nkv),
                                     _Q_TILE_ROWS),
                  interpret=not on_tpu())
 
 
-@functools.partial(jax.jit, static_argnames=("nkv", "scale", "window",
+@functools.partial(jax.jit, static_argnames=("nkv", "dv", "scale", "window",
                                              "q_tile", "interpret"))
-def _rows(q, pos, rows, kpool, vpool, layer, *, nkv, scale, window, q_tile,
+def _rows(q, pos, rows, pools, layer, *, nkv, dv, scale, window, q_tile,
           interpret):
     """``row_attention`` behind one trace a program: a program's layers
-    differ in ``layer`` alone, which is data. ``q_tile``: the query rows a
-    KV head that one grid step holds (:func:`_query_tile`)."""
+    differ in ``layer`` alone, which is data. ``pools``: (K, V), or the
+    one pool that holds both; ``q_tile``: the query rows a KV head that
+    one grid step holds (:func:`_query_tile`)."""
     b, s, nh, d = q.shape
     g = nh // nkv
+    kpool = pools[0]
     B = kpool.shape[2]
-    dv = vpool.shape[3] // nkv
     W = rows.shape[1] - 2
     M = s * g
     T = q_tile
     assert kpool.shape[3] == nkv * d, (kpool.shape, nkv, d)
+    assert dv <= d or len(pools) == 2, (dv, d)
     assert M % T == 0, (M, T)
     # a KV head's queries as rows, position-major: [b, nkv, s x g, d]
     qh = q.reshape(b, s, nkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
@@ -278,25 +335,26 @@ def _rows(q, pos, rows, kpool, vpool, layer, *, nkv, scale, window, q_tile,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=grid,
             in_specs=[by_lane(T, 1, rows_at=0),
-                      by_lane(nkv, T, d, rows_at=1), anywhere, anywhere,
-                      anywhere],
+                      by_lane(nkv, T, d, rows_at=1),
+                      *(anywhere for _ in pools), anywhere],
             out_specs=by_lane(nkv, T, dv, rows_at=1),
             scratch_shapes=[
-                pltpu.VMEM((2, W * B, nkv * d), kpool.dtype),
-                pltpu.VMEM((2, W * B, nkv * dv), vpool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                *(pltpu.VMEM((2, W * B, p.shape[3]), p.dtype)
+                  for p in pools),
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.VMEM((nkv, T, 1), F32), pltpu.VMEM((nkv, T, 1), F32),
                 pltpu.VMEM((nkv, T, dv), F32)]),
         out_shape=jax.ShapeDtypeStruct((b, nkv, M, dv), q.dtype),
         # a lane no row answers to is no grid step's: it reads the zeros
-        # the output's buffer came in with (operand 8: after the 4 prefetched)
-        input_output_aliases={8: 0},
+        # the output's buffer came in with (the last operand, after the 4
+        # prefetched, the positions, the queries and the pools)
+        input_output_aliases={6 + len(pools): 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=_VMEM_LIMIT),
         name="row_attention",
         interpret=interpret,
-    )(layer, lane, rows[:, 1], rows[:, 2:].reshape(-1), posq, qh, kpool,
-      vpool, jnp.zeros((b, nkv, M, dv), q.dtype))
+    )(layer, lane, rows[:, 1], rows[:, 2:].reshape(-1), posq, qh, *pools,
+      jnp.zeros((b, nkv, M, dv), q.dtype))
     return out.reshape(b, nkv, s, g, dv).transpose(0, 2, 1, 3, 4).reshape(
         b, s, nh, dv)

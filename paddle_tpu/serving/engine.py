@@ -252,7 +252,7 @@ def fit_rows(form, lanes, blocks_per_lane):
 
 def pack_rows(items, lanes, width, block, w, cap):
     """The live-rows read operand of one program call (the kernel
-    ``ops/pallas/row_attention.py`` and the families' ``_attend_rows``
+    ``ops/pallas/row_attention.py`` and the int8 pool's ``_attend_rows``
     read it), as numpy. ``items``: per
     occupied lane ``(lane, blocks, first, upto)`` — its block list (a
     list, or an ``int32`` array of it: ``scheduler.BlockList.ids``, which

@@ -18,8 +18,9 @@ and asks the model's *family* for the three things that differ:
     operands are the engine's (``[1, C]`` chunk, start, context length,
     last index | ``[L]`` lengths, ``[L]`` tokens | lengths, ``[L, k+1]``
     tokens, ``[L]`` write limits), and ``read`` says where the lanes' K/V
-    lies: the lanes' live rows of ``W`` blocks, which the program runs
-    ``tile`` at a time (``read_form(kind)`` names ``(W, tile)``), and each
+    lies: the lanes' live rows of ``W`` blocks, their count rounded up
+    to whole ``tile``s (``read_form(kind)`` names ``(W, tile)``; the int8
+    pool's XLA read runs them a tile at a time), and each
     fed token's write block: ``(rows [R, 2 + W], wblk [lanes, width])``
     (``engine.pack_rows``). That is the signature of the family's
     ``fn``, and it stays: tests and tools call it as it is. What the
@@ -64,8 +65,9 @@ default.
 
 ``row_read`` — ``"kernel"`` where the family's programs read their live
 rows through ``ops/pallas/row_attention.py`` (the engine then bills
-``kv_kernel_rows``, and ``stats()["row_read"]`` says so); ``"xla"``
-(``common.Family``'s) where a family reads them itself.
+``kv_kernel_rows``, and ``stats()["row_read"]`` says so: every family's
+bf16 programs); ``"xla"`` (``common.Family``'s) where a family reads them
+itself (the dense family's int8 pool).
 
 Plus ``absorb(out, counters)``: the round's ONE fetched array goes
 through it — a family that rides its own counters on that array strips

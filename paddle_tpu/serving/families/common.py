@@ -30,7 +30,7 @@ from . import PREFILL_CHUNK
 # kernel's grid is the live rows: there ``tile`` only rounds the operand's
 # length); the prefill chunk, all rows one lane's: PREFILL_TILE. A family
 # imports them BY VALUE and its ``read_form`` reads its own module's (the
-# latent read's constants are ``latent_moe``'s).
+# latent read's row width is ``latent_moe``'s).
 ROW_BLOCKS = 16
 ROW_TILE = 16
 PREFILL_TILE = 4

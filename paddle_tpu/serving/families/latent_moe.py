@@ -7,21 +7,25 @@ as the serving engine sees it.
   B in bf16 at the published widths, 640 as stored, where 128 full heads
   of K and V would take 81,920 B); no V pool. Written by (layer, block,
   offset) with the null-block redirect.
-- **The read follows what the lanes hold** (``_attend_rows``; PERF.md
-  section 6, PR 35): the engine's ``pack`` phase cuts each running lane's
-  block list into rows of ``ROW_BLOCKS`` blocks and lays all lanes' rows
-  end to end (``engine.pack_rows``, the dense family's operand); a
-  program gathers the live rows a tile at a time from the stacked pool by
-  (layer, block), attends row by row and recombines per lane as one
-  softmax. No program gathers a table slot that holds nothing, so a
-  call's cost follows the live tokens, not ``max_seq_len``.
+- **The read follows what the lanes hold** (PERF.md section 6, PR 35 and
+  PR 47): the engine's ``pack`` phase cuts each running lane's block list
+  into rows of ``ROW_BLOCKS`` blocks and lays all lanes' rows end to end
+  (``engine.pack_rows``, the dense family's operand); a latent layer's
+  read is ONE call of the fused kernel ``ops/pallas/row_attention.py``,
+  whose grid is the live rows: it copies a row's blocks out of the
+  stacked pool by (layer, block) into fast memory itself and folds a
+  lane's rows into one softmax there, so no gathered tile, no score
+  tensor and no row's weighted sum is a value of a program, and a call's
+  cost follows the live tokens, not ``max_seq_len``.
 - **Attention** reads the latent directly, ``kv_b`` absorbed
   (``models/latent_moe.attend_absorbed``'s arithmetic, which stays the
   definition the row read is held to: tests/test_serving_rows.py), in
   all three programs: at a 32-token chunk the up-projection of a whole
   block table costs ~13x the absorbed scores (PERF.md section 6, PR 27).
-  A prefill chunk wider than ``QUERY_TILE`` attends its positions a tile
-  at a time and skips the tiles that are all pad (below).
+  Under the absorbed query the pool is ONE shared KV head whose value is
+  the first ``kv_lora_rank`` numbers of its key: a round's 5 x 128 query
+  rows a lane go through the kernel as one head's, a prefill chunk's
+  128 x 128 a query tile at a time.
 - **Weights once**: ``params`` is a tuple of per-layer dicts whose leaves
   ARE the model's arrays, and each program is a Python loop over the
   layers (a dense layer followed by expert layers cannot be one scan
@@ -39,183 +43,58 @@ second copy.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ...models.generation import _rms
 from ...models.latent_moe import (
     absorb_query, latent_qkv, mlp_block, unabsorb_output,
 )
+from ...ops.pallas.row_attention import row_attention
 from .common import MOE_ACC as ACC  # the accumulator: the expert layers'
-from .common import Family, _out, expert_counts, greedy_head, write_slots
+from .common import (
+    PREFILL_TILE, ROW_TILE, Family, _out, expert_counts, greedy_head,
+    write_slots,
+)
 
 __all__ = ["LatentMoEFamily"]
 
 LANES = 128  # the TPU's lane tile: the pool's last axis is padded to it
 
-# The latent read's constants, chosen on the chip (PERF.md section 6, PR
-# 35: single verify calls at the published widths, 64 lanes): a row is
-# ROW_BLOCKS blocks of one lane (16 read 27.3 ms a call where 20, 24 and
-# 28 read 30.4, 28.8 and 30.1 and the whole table 47.9), and a round
-# program runs its live rows ROW_TILE at a time — as many rows as the
-# benchmark's deployments have lanes, which is also what its reader of
-# this attention's device time finds the operations by (PERF.md section 7
-# (r): a debt); the prefill chunk, all rows one lane's: PREFILL_TILE (4
-# read 13.0-14.7 ms a 128-wide call, 2 and 8 more).
+# A row of the latent read is ROW_BLOCKS blocks of one lane: one grid step
+# of the kernel (256 slots: 328 KB of cache against a round's 640 query
+# rows a lane). Chosen on the chip (PERF.md section 6, PR 47); the kernel's
+# grid is the live rows, so ``common.ROW_TILE`` / ``PREFILL_TILE`` only
+# round the operand's length.
 ROW_BLOCKS = 16
-ROW_TILE = 64
-PREFILL_TILE = 4
-
-# A prefill chunk attends QUERY_TILE of its positions at a time, under a
-# loop that runs the tiles holding a real token: the absorbed attention's
-# float32 scores are [positions, heads, a tile of rows' slots], written,
-# reduced and read again, which the chunk's weight reads do not amortise
-# (0.08 ms a position over a whole table at the published widths: a
-# 128-wide call on a 20-token prompt cost 18.6 ms where a 32-wide one cost
-# 9.8, PERF.md section 6, PR 32). The matmuls run at the call's width; the
-# attention at what the call was fed.
-QUERY_TILE = 32
 
 
-def _attend_rows(qq, pos, rows, gather, tile, lp, cfg):
-    """``models/latent_moe.attend_absorbed`` after ``absorb_query``, over
-    LIVE ROWS: what each lane holds, cut into rows of ``W`` blocks, and
-    nothing else of its table. ``qq`` [b, s, heads, stored] the absorbed
-    queries, ``pos`` [b, s]; ``rows`` [R, 2 + W] int32 as
-    ``engine.pack_rows`` lays them (lane, -1 a pad row; the position of
-    the row's first slot; its ``W`` block ids); ``gather(blocks [T, W])``
-    returns those blocks' entries as ``[T, W * B, stored]``.
-
-    Rows run ``tile`` at a time under a device-side loop whose trip count
-    is data (the live rows, counted here): per row the scores of its
-    lane's queries against its slots (model dtype, float32 accumulation),
-    the mask ``first + slot <= pos``, the row's max, its sum of
-    exponentials, its weighted sum of the latent and that through ``W_v``
-    (``unabsorb_output``, kept in float32); per tile those are folded into
-    each lane's running max / sum / output as a softmax over the union of
-    the lane's slots (``dense_gqa._attend_rows``' fold: rescaled by
-    ``exp(row_max - lane_max)``, added up by lane). ``W_v`` goes before
-    the fold because the fold's operands are float32 and a row's weighted
-    sum is ``kv_lora_rank`` wide a head where its output is
-    ``v_head_dim``: folded first, the float32 ``[rows, positions, heads,
-    512]`` tensor's round trips were 10 of a verify call's 34.6 ms at the
-    published widths (PERF.md section 6, PR 35). A masked slot weighs
-    exp(-1e30 - max) = 0 exactly; a lane with no row reads 0. Returns
-    [b, s, heads * dv] in the queries' dtype."""
-    b, s, nh, _ = qq.shape
-    dc, dv = cfg.kv_lora_rank, cfg.v_head_dim
-    f32, dt = jnp.float32, qq.dtype
-    scale = np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
-    lane, first, blocks = rows[:, 0], rows[:, 1], rows[:, 2:]
-    tile = min(tile, rows.shape[0])  # engine.fit_rows: fewer rows, one tile
-    assert rows.shape[0] % tile == 0, (rows.shape, tile)
-
-    def one_tile(t, carry):
-        m, l, o = carry  # [b, s, nh] twice, [b, s, nh, dv]
-        r0 = t * tile
-        ln = jax.lax.dynamic_slice_in_dim(lane, r0, tile)
-        own = jnp.maximum(ln, 0)
-        cache = gather(jax.lax.dynamic_slice_in_dim(blocks, r0, tile))
-        at = jax.lax.dynamic_slice_in_dim(first, r0, tile)[:, None, None] \
-            + jnp.arange(cache.shape[1])[None, None, :]     # [T, 1, S]
-        vis = at <= pos[own][:, :, None]                    # [T, s, S]
-        scores = jnp.einsum("tshe,tle->tshl", qq[own], cache,
-                            preferred_element_type=f32) / scale
-        scores = jnp.where(vis[:, :, None, :], scores, -1e30)
-        rm = jnp.max(scores, axis=-1)                       # [T, s, nh]
-        p = jnp.exp(scores - rm[..., None])
-        rl = jnp.sum(p, axis=-1)
-        ro = jnp.einsum("tshl,tlc->tshc", p.astype(dt), cache[..., :dc],
-                        preferred_element_type=f32).astype(dt)
-        rv = unabsorb_output(ro, lp, cfg, f32).reshape(tile, s, nh, dv)
-        mine = ln[None, :] == jnp.arange(b)[:, None]        # [b, T]
-        m_new = jnp.maximum(m, jnp.max(
-            jnp.where(mine[:, :, None, None], rm[None], -1e30), axis=1))
-        # (a pad row may outscore lane 0's max: its weight is 0, not inf)
-        w = jnp.exp(jnp.where((ln >= 0)[:, None, None],
-                              rm - m_new[own], -1e30))
-        keep = jnp.exp(m - m_new)
-        hot = mine.astype(f32)
-        exact = jax.lax.Precision.HIGHEST  # the one-hot sum is a sum
-        l = l * keep + jnp.einsum("bt,tsh->bsh", hot, rl * w,
-                                  precision=exact)
-        o = o * keep[..., None] + jnp.einsum(
-            "bt,tshd->bshd", hot, rv * w[..., None], precision=exact)
-        return m_new, l, o
-
-    n_tiles = (jnp.sum(lane >= 0, dtype=jnp.int32) + tile - 1) // tile
-    _, l, o = jax.lax.fori_loop(
-        0, n_tiles, one_tile,
-        (jnp.full((b, s, nh), -1e30, f32), jnp.zeros((b, s, nh), f32),
-         jnp.zeros((b, s, nh, dv), f32)))
-    out = o / jnp.where(l > 0, l, 1.0)[..., None]
-    return out.astype(dt).reshape(b, s, nh * dv)
-
-
-def _attend_tiles(qq, pos, n_tiles, attend, width):
-    """``attend(qq, pos)`` [b, s, ``width``] over the first ``n_tiles``
-    (data) tiles of ``QUERY_TILE`` positions; the positions past them
-    read 0."""
-    def one(t, out):
-        def cut(a):
-            return jax.lax.dynamic_slice_in_dim(a, t * QUERY_TILE,
-                                                QUERY_TILE, axis=1)
-
-        return jax.lax.dynamic_update_slice_in_dim(
-            out, attend(cut(qq), cut(pos)), t * QUERY_TILE, axis=1)
-
-    return jax.lax.fori_loop(
-        0, n_tiles, one, jnp.zeros((*qq.shape[:2], width), qq.dtype))
-
-
-def attend_pool(u, lp, li, pool, rows, pos, blk, off, cfg, tile,
-                n_tiles=None, rope=True):
+def attend_pool(u, lp, li, pool, rows, pos, blk, off, cfg, rope=True):
     """Latent layer ``li``'s attention on normed ``u`` [b, s, h] against
     the block pool: write each token's cache entry at (``li``, ``blk``,
-    ``off``), then attend over the lanes' LIVE ROWS (``_attend_rows``),
-    gathered ``tile`` at a time from the stacked pool — every position
-    at once, or (``n_tiles``, the prefill chunk's) the tiles of
-    ``QUERY_TILE`` positions that hold a real token. Returns (att [b, s,
-    heads x v], pool)."""
-    nb, B, W = pool.shape[1:]
+    ``off``), then attend over the lanes' LIVE ROWS — ONE call of the
+    fused kernel, which copies a row's blocks out of the stacked pool by
+    (layer, block) itself: under the absorbed query the pool is ONE
+    shared KV head as wide as an entry is stored, whose value is the
+    entry's first ``kv_lora_rank`` numbers (no value pool), masked
+    ``first + slot <= pos``; the scores' scale is the published head's.
+    ``W_v`` (``unabsorb_output``) runs once, on the folded sums. Returns
+    (att [b, s, heads x v], pool)."""
+    W = pool.shape[3]
     q_nope, q_rope, entry = latent_qkv(u, lp, pos, cfg, rope)
     with jax.named_scope("mla/kv_write"):
         pool = pool.at[li, blk, off].set(
             jnp.pad(entry, ((0, 0), (0, 0), (0, W - entry.shape[-1]))))
-
-    def gather(blocks):
-        # from the STACKED pool, by (layer, block): pool[li] would make
-        # the TPU materialise the layer's whole pool first
-        return pool.reshape(-1, B, W)[blocks + li * nb].reshape(
-            blocks.shape[0], -1, W)
-
-    attend = functools.partial(_attend_rows, rows=rows, gather=gather,
-                               tile=tile, lp=lp, cfg=cfg)
     with jax.named_scope("mla/attend"):
         qq = absorb_query(q_nope, q_rope, lp, cfg, W)
-        if n_tiles is None:
-            return attend(qq, pos), pool
-        return _attend_tiles(
-            qq, pos, n_tiles, attend,
-            cfg.num_attention_heads * cfg.v_head_dim), pool
+        o_lat = row_attention(
+            qq, pos, rows, pool, None, li, 1,
+            (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
+            dv=cfg.kv_lora_rank)
+        return unabsorb_output(o_lat, lp, cfg), pool
 
 
-def chunk_tiles(C, start, ctx_len):
-    """How many query tiles of a ``C``-wide prefill chunk at ``start``
-    hold a real token; ``None`` where the chunk is not several whole
-    tiles (it then attends all its positions at once)."""
-    if C > QUERY_TILE and C % QUERY_TILE == 0:
-        with jax.named_scope("mla/attend"):
-            return (jnp.clip(ctx_len - start, 0, C) + QUERY_TILE - 1) \
-                // QUERY_TILE
-    return None
-
-
-def _pool_forward(params, pool, acc, read, ids, pos, wlimit, valid, cfg,
-                  tile, n_tiles=None):
+def _pool_forward(params, pool, acc, read, ids, pos, wlimit, valid, cfg):
     """Forward ``ids`` [b, s] at absolute positions ``pos`` [b, s] against
     the latent block pool: per layer, write each token's cache entry into
     its lane's block at ``pos`` (positions >= ``wlimit[b]`` go to null
@@ -234,7 +113,7 @@ def _pool_forward(params, pool, acc, read, ids, pos, wlimit, valid, cfg,
         n_valid = jnp.sum(valid, dtype=jnp.int32)
     for li, lp in enumerate(params["layers"]):
         att, pool = attend_pool(_rms(x, lp["ln_in"], eps), lp, li, pool,
-                                rows, pos, blk, off, cfg, tile, n_tiles)
+                                rows, pos, blk, off, cfg)
         with scope("mla/out"):
             att = att @ lp["o"]
         att = _rms(att, lp["ln_attn_out"], eps)
@@ -260,26 +139,24 @@ def read_form(kind):
 
 
 def _prefill_chunk(params, pool, acc, read, ids, start, ctx_len, last_idx,
-                   *, cfg, tile):
+                   *, cfg):
     """One lane's prefill chunk ``ids`` [1, C] at [start, start + C),
     ``read`` its lane's rows live up to the chunk's end; greedy-samples
     at ``last_idx``. Returns ([token, *acc], pool, acc)."""
     C = ids.shape[1]
     with jax.named_scope("embed"):  # the fed positions
         pos = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
-    # a chunk of several whole query tiles attends the fed ones alone
-    n_tiles = chunk_tiles(C, start, ctx_len)
     with jax.named_scope("embed"):  # ... how far they go, which are real
         fed = jnp.reshape(ctx_len, (1,)), pos < ctx_len
     x, pool, acc = _pool_forward(params, pool, acc, read, ids, pos, *fed,
-                                 cfg, tile, n_tiles=n_tiles)
+                                 cfg)
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
                                          keepdims=False)
     return _out(greedy_head(h, params, cfg.rms_norm_eps), acc), pool, acc
 
 
-def _decode_step(params, pool, acc, read, cur_len, last_tok, *, cfg, tile):
+def _decode_step(params, pool, acc, read, cur_len, last_tok, *, cfg):
     """Every lane feeds its pending token at ``cur_len`` (write, then
     attend) and greedy-samples the next; idle lanes (``cur_len`` 0, no
     row) write to the null block and count for nothing. Returns ([L
@@ -287,14 +164,13 @@ def _decode_step(params, pool, acc, read, cur_len, last_tok, *, cfg, tile):
     with jax.named_scope("embed"):  # the fed tokens, where, which are real
         fed = (last_tok[:, None], cur_len[:, None], cur_len + 1,
                (cur_len > 0)[:, None])
-    x, pool, acc = _pool_forward(params, pool, acc, read, *fed, cfg, tile)
+    x, pool, acc = _pool_forward(params, pool, acc, read, *fed, cfg)
     with jax.named_scope("head"):
         x = x[:, -1]
     return _out(greedy_head(x, params, cfg.rms_norm_eps), acc), pool, acc
 
 
-def _verify_step(params, pool, acc, read, cur_len, toks, wlimit, *, cfg,
-                 tile):
+def _verify_step(params, pool, acc, read, cur_len, toks, wlimit, *, cfg):
     """``toks`` [L, k+1]: each lane's pending token and its draft at
     ``cur_len + j``; writes at positions >= ``wlimit[b]`` go to the null
     block. Returns ([L * (k+1) greedy picks row-major, *acc], pool,
@@ -304,7 +180,7 @@ def _verify_step(params, pool, acc, read, cur_len, toks, wlimit, *, cfg,
         pos = cur_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
         valid = pos < wlimit[:, None]
     x, pool, acc = _pool_forward(params, pool, acc, read, toks, pos,
-                                 wlimit, valid, cfg, tile)
+                                 wlimit, valid, cfg)
     return _out(greedy_head(x, params, cfg.rms_norm_eps), acc), pool, acc
 
 
@@ -316,7 +192,7 @@ class LatentMoEFamily(Family):
     ACC = ACC
     programs = {"prefill": _prefill_chunk, "decode": _decode_step,
                 "verify": _verify_step}
-    tiled = True  # ``_attend_rows`` runs its rows ``tile`` at a time
+    row_read = "kernel"  # the latent layers' live rows: row_attention
     donate_argnums = (1, 2)
 
     def __init__(self, model, config):
@@ -351,7 +227,7 @@ class LatentMoEFamily(Family):
     def read_form(self, kind):
         """How program ``kind`` is told where its lanes' entries lie
         (``ServingEngine._pack_read`` builds it): ``(W, tile)`` — live
-        rows of ``W`` blocks, run ``tile`` at a time."""
+        rows of ``W`` blocks, the operand's length in whole ``tile``s."""
         return read_form(kind)
 
     def stats(self):

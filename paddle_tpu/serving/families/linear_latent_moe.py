@@ -4,11 +4,11 @@ kinds of device state in one family.
 
 - **By token**: the latent pool ``[latent layers, blocks, block, 640]`` of
   ``families/latent_moe.py`` — laid out, written and read by ITS functions
-  (the lanes' live rows gathered a tile at a time from the stacked pool,
-  the absorbed attention row by row: ``attend_pool``), for the few
+  (the lanes' live rows through the fused row kernel, the absorbed
+  attention as one shared KV head: ``attend_pool``), for the few
   latent-attention layers only; no position embedding. This is the one
   import of a family by a family: the latent layer (``attend_pool``,
-  ``chunk_tiles``, ``read_form`` with the constants tests steer, the
+  ``read_form`` with the constants tests steer, the
   pool's ``LANES``) stays where its family is and is served from there,
   not copied into ``common.py``, which holds no latent arithmetic.
 - **By LANE, float32**: the delta rule's matrix state, ``[lanes, heads, d,
@@ -71,7 +71,7 @@ from .common import (
     MOE_ACC, Family, _carried, _keeps, _out, _take_rows, accept, bump,
     expert_counts, greedy_head, lane_tails, rolled_back, write_slots,
 )
-from .latent_moe import LANES, attend_pool, chunk_tiles, read_form
+from .latent_moe import LANES, attend_pool, read_form
 
 __all__ = ["LinearLatentMoEFamily"]
 
@@ -98,12 +98,11 @@ def _heads_first(a):
     return jnp.swapaxes(a, 1, 2)
 
 
-def _stack(params, ids, pos, wlimit, valid, read, pool, acc, cfg, kda, tile,
-           n_tiles=None):
+def _stack(params, ids, pos, wlimit, valid, read, pool, acc, cfg, kda):
     """The layer stack over ``ids`` [b, s] at positions ``pos``: latent
     layers against the block pool here (``read`` = the engine's live rows
-    and the fed positions' blocks, run ``tile`` rows at a time: the latent
-    family's ``attend_pool``), each linear-attention layer
+    and the fed positions' blocks: the latent family's ``attend_pool``),
+    each linear-attention layer
     through ``kda(ki, u, lp) -> mix`` (the program's own: what it does
     with the lane-indexed pools differs by program). Returns (x, pool,
     acc, the held experts hit summed over the expert layers)."""
@@ -125,7 +124,7 @@ def _stack(params, ids, pos, wlimit, valid, read, pool, acc, cfg, kda, tile,
             out = "kda/out_proj"
         else:
             att, pool = attend_pool(u, lp, ai, pool, rows, pos, blk, off,
-                                    cfg, tile, n_tiles, rope=False)
+                                    cfg, rope=False)
             ai += 1
             out = "mla/out"
             with scope(out):
@@ -152,7 +151,7 @@ def _unpack(args, cfg):
     return (*args[:3], list(args[3:3 + n]), args[3 + n:])
 
 
-def _prefill_chunk(params, *args, cfg, tile):
+def _prefill_chunk(params, *args, cfg):
     """One request's prefill chunk ``ids`` [1, C] at [start, start + C),
     ``read`` = (its lane's rows live up to the chunk's end, the fed
     positions' blocks, ``slot`` [1]: the lane it holds).
@@ -202,7 +201,7 @@ def _prefill_chunk(params, *args, cfg, tile):
 
     x, pool, acc, _ = _stack(
         params, ids, pos, jnp.reshape(ctx_len, (1,)), real, read, pool,
-        acc, cfg, kda, tile, n_tiles=chunk_tiles(C, start, ctx_len))
+        acc, cfg, kda)
     acc = bump(acc, LIN_ACC, len(MOE_ACC), lin_slot_resets=fresh)
     with jax.named_scope("head"):
         h = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1,
@@ -211,7 +210,7 @@ def _prefill_chunk(params, *args, cfg, tile):
     return _out(picks, acc), pool, acc, conv[0], *states
 
 
-def _decode_step(params, *args, cfg, tile):
+def _decode_step(params, *args, cfg):
     """Every lane feeds its pending token at ``cur_len``: the latent
     entry written then attended, each lane's state advanced one position
     and its conv tail shifted by one row, in place. Idle lanes
@@ -240,8 +239,7 @@ def _decode_step(params, *args, cfg, tile):
         live = cur_len > 0
         fed = (last_tok[:, None], cur_len[:, None], cur_len + 1,
                live[:, None])
-    x, pool, acc, n_hit = _stack(params, *fed, read, pool, acc, cfg, kda,
-                                 tile)
+    x, pool, acc, n_hit = _stack(params, *fed, read, pool, acc, cfg, kda)
     with jax.named_scope("acc"):
         n = jnp.sum(live)
         by = dict(lin_lane_rounds=n, lin_state_lane_moves=2 * n)
@@ -253,7 +251,7 @@ def _decode_step(params, *args, cfg, tile):
     return _out(picks, acc), pool, acc, conv[0], *states
 
 
-def _verify_step(params, *args, cfg, tile):
+def _verify_step(params, *args, cfg):
     """``toks`` [L, k+1]: each lane's pending token and its draft at
     ``cur_len + j``; positions >= ``wlimit[b]`` are pad. The forward
     reads every linear-attention layer's state once and writes none;
@@ -288,7 +286,7 @@ def _verify_step(params, *args, cfg, tile):
         return M.kda_gate_out(o, u, lp, cfg)
 
     x, pool, acc, n_hit = _stack(params, toks, pos, wlimit, valid, read,
-                                 pool, acc, cfg, kda, tile)
+                                 pool, acc, cfg, kda)
     picks = greedy_head(x, params, cfg.rms_norm_eps)
     live, n_draft, accepted = accept(picks, toks, cur_len, wlimit)
     with jax.named_scope("spec"):
@@ -320,7 +318,7 @@ class LinearLatentMoEFamily(Family):
     ACC = ACC
     programs = {"prefill": _prefill_chunk, "decode": _decode_step,
                 "verify": _verify_step}
-    tiled = True  # the latent layers' read runs its rows ``tile`` at a time
+    row_read = "kernel"  # the latent layers' live rows: row_attention
     lane_state = True
     prefix_reuse = False
     prefix_reuse_why = (

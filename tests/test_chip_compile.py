@@ -355,12 +355,18 @@ def test_row_read_compiles_at_served_geometries(
 # granite-4.0-h's (a head is half a 128-lane tile), MiMo-V2.5's full
 # layers (192 is no multiple of 128, values narrower than keys; its
 # family's 512 positions at group 16 are FOUR query tiles of the kernel's
-# grid), LFM2's (granite's heads at its family's 512: 2,048 rows, one tile)
+# grid), LFM2's (granite's heads at its family's 512: 2,048 rows, one tile),
+# and the two latent cells' (KV heads 0: ONE pool, one shared head as wide
+# as an entry is stored, its value the entry's first 512 numbers — 640 /
+# 160 query rows a lane a verify round, a 128-position chunk 16,384 /
+# 4,096 rows in eight / two query tiles: PERF.md section 6, PR 47)
 _ROW_READ_AT = {
     "mistral_g4_d128": (32, 8, 128, 128, 32, (PREFILL_CHUNK,)),
     "granite_g4_d64": (32, 8, 64, 64, 64, (PREFILL_CHUNK,)),
     "mimo_g16_d192_dv128": (64, 4, 192, 128, 64, (PREFILL_CHUNK, 256, 512)),
-    "lfm2_g4_d64": (32, 8, 64, 64, 64, (512,))}
+    "lfm2_g4_d64": (32, 8, 64, 64, 64, (512,)),
+    "openpangu_latent_h128_d640": (128, 0, 640, 512, 64, (PREFILL_CHUNK,)),
+    "kimi_latent_h32_d640": (32, 0, 640, 512, 64, (PREFILL_CHUNK,))}
 
 
 def test_the_compiled_prefill_widths_are_the_families():
@@ -377,19 +383,22 @@ def _row_read_in_a_program(sd, row_attention, q_shape, nkv, dv, rows, pool):
     its output a product's operand, as a family's program has them.
     Handed the kernel as parameters of their own the compiler places them
     otherwise, and a call that NO program can hold compiles alone (PR 42:
-    MiMo's 512 positions as one query tile, 170 MB of VMEM in a program)."""
+    MiMo's 512 positions as one query tile, 170 MB of VMEM in a program).
+    ``nkv`` 0: the latent form — one pool, no value pool."""
     b, s, nh, d = q_shape
 
-    def read(x, w, o, pos, rows, kpool, vpool, li):
+    def read(x, w, o, pos, rows, li, kpool, vpool=None):
         q = (x @ w).reshape(b, s, nh, d)
-        out = row_attention(q, pos, rows, kpool, vpool, li, nkv, d ** -0.5)
+        out = row_attention(q, pos, rows, kpool, vpool, li, max(nkv, 1),
+                            d ** -0.5, dv=dv)
         return out.reshape(b * s, nh * dv) @ o
 
     return jax.jit(read).lower(
         sd(jnp.bfloat16, b * s, 4096), sd(jnp.bfloat16, 4096, nh * d),
         sd(jnp.bfloat16, nh * dv, 4096), sd(jnp.int32, b, s),
-        sd(jnp.int32, *rows), sd(jnp.bfloat16, *pool, nkv * d),
-        sd(jnp.bfloat16, *pool, nkv * dv), sd(jnp.int32))
+        sd(jnp.int32, *rows), sd(jnp.int32),
+        sd(jnp.bfloat16, *pool, max(nkv, 1) * d),
+        *([sd(jnp.bfloat16, *pool, nkv * dv)] if nkv else []))
 
 
 def test_one_query_tile_cannot_hold_the_widest_chunk(topo, monkeypatch):
@@ -424,7 +433,9 @@ def test_row_kernel_compiles_at_the_cells_geometries(topo, geometry, kind):
     stated ``vmem_limit_bytes`` inside a program (its queries a
     product's result) — head slices at lane offsets of 64 and
     192, 2,048 query rows a KV head a grid step in MiMo's chunks (the 512
-    positions of its family's call in four query tiles) and in LFM2's —
+    positions of its family's call in four query tiles) and in LFM2's,
+    the latent pool's one head of 640 (160) query rows a lane with its
+    values out of the key's buffer —
     and the program holds no gathered tile ``[T, W x B, kv_heads, d]``
     (float32 or not, heads merged or not) and no value of one layer's
     pool shape."""
@@ -444,7 +455,7 @@ def test_row_kernel_compiles_at_the_cells_geometries(topo, geometry, kind):
             sd, row_attention, (b, s, nh, d), nkv, dv,
             (b * rows_a_lane, 2 + W), (layers, nb, B)).compile().as_text()
     assert "tpu_custom_call" in text and "row_attention" in text
-    for dims in (rf"\d+,{W * B},({nkv},)?\d+", rf"{nb},{B},\d+"):
+    for dims in (rf"\d+,{W * B},({nkv},)?\d+(,1)?", rf"{nb},{B},\d+"):
         lines = _results_shaped(text, dims)
         assert not lines, "\n".join(lines[:6])
 
@@ -487,11 +498,9 @@ def test_engine_program_never_holds_every_lanes_table(
 _LATENT_POOL = (3, 2049, 16, 640)
 
 
-# lanes, blocks a lane: the benchmark cell's 64 lanes, so that a round's
-# tile of ``ROW_TILE`` rows has as many rows as there are lanes (what the
-# benchmark's reader finds the attention by), and 5 rows of 16 blocks a
-# lane: rounds run 320 rows in tiles of 64 and a chunk 5 in tiles of 4, so
-# no tile is a whole table. No width is 256 (a row's slots)
+# lanes, blocks a lane: the benchmark cell's 64 lanes, and 5 rows of 16
+# blocks a lane (rounds are handed 320 rows, a chunk 5: the operand's
+# length is no whole table). No width is 256 (a row's slots)
 _LATENT_LANES, _LATENT_TABLE = 64, 80
 
 
@@ -540,11 +549,11 @@ def test_latent_program_never_copies_its_pool(topo, latent_engine, kind,
     into row-major and back, every call (2 x 1.95 ms at the benchmark's
     0.57 GB: PERF.md section 6, PR 27; with width 576 here this test
     finds both copies). The family pads the entry to 640, whose own
-    layout is row-major: no instruction is a copy of the pool — in the
-    entry computation or in a loop that gathers a tile of rows from it —,
-    the stacked pool is what the gather reads (no one layer's pool is
-    produced either), and the expert products are the grouped-matmul
-    kernel, not its interpreter."""
+    layout is row-major: no instruction is a copy of the pool, the
+    stacked pool is what the row kernel is handed (no one layer's pool
+    is produced either: ``layer`` is a number the kernel is told), and
+    the expert products are the grouped-matmul kernel, not its
+    interpreter."""
     assert latent_engine._pools[0].shape == _LATENT_POOL
     compiled = _latent_program(topo, latent_engine, kind, monkeypatch, chunk)
     text = compiled.as_text()
@@ -557,9 +566,12 @@ def test_latent_program_never_copies_its_pool(topo, latent_engine, kind,
                  if re.search(rf"= \w+\[{nb},{block},{width}\]", ln)]
     assert not one_layer, "\n".join(one_layer[:4])
     assert re.search(whole, text)
-    # the loops' instructions were among those lines
-    assert any("/while/body/" in n
-               for n in _program_names(compiled, loops=True))
+    # the read: one Mosaic kernel call a latent layer, handed the pool
+    reads = [ln for ln in text.splitlines()
+             if re.search(r"%row_attention[\.\d]* = [^\n]*custom_call_target="
+                          r"\"tpu_custom_call\"", ln)]
+    assert len(reads) == layers and all(
+        re.search(whole, ln.split("custom-call(", 1)[1]) for ln in reads)
     # two grouped products an expert layer, as Mosaic kernels
     assert len(re.findall(r"%gmm[\.\d]* = [^\n]*custom_call_target="
                           r"\"tpu_custom_call\"", text)) == 4
@@ -588,12 +600,15 @@ def _holds_rows_not_tables(text, eng, kind, lanes, table, width,
 @pytest.mark.parametrize("kind,chunk", _programs())
 def test_latent_program_never_holds_every_lanes_table(
         topo, latent_engine, kind, chunk, monkeypatch):
-    """The latent read gathers the rows the lanes hold, a tile at a time
-    (PERF.md section 6, PR 35), as the dense family's does."""
+    """The latent read follows the rows the lanes hold (PERF.md section
+    6, PR 35), and since PR 47 the kernel copies a row's blocks into
+    fast memory itself: no value of a program is shaped like every
+    lane's whole table, nor like a tile of gathered rows."""
     lanes = 1 if kind == "prefill" else _LATENT_LANES
     _holds_rows_not_tables(
         _latent_program_text(topo, latent_engine, kind, monkeypatch, chunk),
-        latent_engine, kind, lanes, _LATENT_TABLE, _LATENT_POOL[3])
+        latent_engine, kind, lanes, _LATENT_TABLE, _LATENT_POOL[3],
+        a_tile=False)
 
 
 def _mla_reader():
@@ -613,88 +628,56 @@ def _mla_reader():
     return reader
 
 
-# what the reader is to pick, a latent layer: the operation's role, by the
-# ``jax.named_scope`` path in its metadata (which the trace does not hold)
-_MLA_ROLES = {
-    "the row gather": r"= bf16\[\d+,16,640\].*mla/attend/while/body/gather",
-    "the scores": r"mla/attend/while/body/tshe,tle->tshl",
-    "the weighted sum": r"mla/attend/while/body/tshl,tlc->tshc",
-    "a row's sum through W_v": r"mla/attend/while/body/bshc,chd->bshd",
-    "the per-head latent queries": r"mla/attend/bshd,chd->bshc",
-}
 _OPERATION = re.compile(r"[\s)](fusion|copy|custom-call|gather|scatter|"
                         r"convolution|reduce|dynamic-update-slice)\(")
 
 
 @pytest.mark.parametrize("kind", ["decode", "verify"])
-def test_latent_attention_reader_picks_the_cache_not_the_new_entries(
+def test_latent_round_reads_each_layer_in_one_kernel_call(
         topo, latent_engine, kind, monkeypatch):
-    """The benchmark's ``mla_attend_roofline`` picks operations by the
-    shapes in their instruction text (the device trace's events are named
-    by it), taking the gathered cache for the ``[lanes, slots, 640]``
-    with the most slots. It once read the round's k + 1 NEW entries for
-    the cache and so timed the write path (review of PR 27). Since PR 35
-    the cache is gathered a tile of ``ROW_TILE`` rows at a time under a
-    loop: the reader finds it because a tile has as many rows as the
-    served engine has lanes (PERF.md section 7 (r)). Held here to the
-    compiled programs' own instructions, the loop bodies' among them: the
-    slots are a ROW's (16 blocks of 16), and what is picked is, by role,
-    each layer's row gather, score product (the softmax's reductions
-    fused into it and into the weighted sum), weighted sum, that sum
-    through ``W_v`` and the per-head latent queries — and nothing of the
-    expert layer, the query path, the cache write or the head."""
+    """What is true of a latent round program since PR 47: under
+    ``mla/attend`` a layer's read is ONE call of the row kernel (its
+    queries the absorbed ones, one shared head: ``[lanes, 1, positions x
+    heads, 640]``; its output the folded sums ``[.., 512]``, which go
+    through ``W_v`` once), the scope holds no loop, and the program's text
+    holds no gathered row tile ``[rows x blocks, block, 640]`` / ``[rows, slots,
+    640]`` and no float32 tensor over a row's slots ``[.., slots]`` — the
+    scores, their statistics and the weighted sums stay in the kernel's
+    fast memory. (Until PR 47 this test held the benchmark's
+    ``mla_attend_roofline`` reader to the XLA read's operations by role;
+    what that reader picks now is PERF.md section 7 (r).)"""
     from paddle_tpu.serving.families import latent_moe as fam
 
-    reader = _mla_reader()
-    names = _program_names(
-        _latent_program(topo, latent_engine, kind, monkeypatch), loops=True)
-    lanes, block = _LATENT_LANES, _LATENT_POOL[2]
-    assert fam.ROW_TILE == lanes  # what the reader hangs on
-    slots = fam.ROW_BLOCKS * block
-    m = {"kv_lora_rank": 512, "qk_rope_head_dim": 64,
-         "num_attention_heads": 4}
-    picked = reader.pattern(names, lanes, m)
-    assert rf"\[{lanes},{slots},640" in picked, picked
-    assert re.escape(f"[{lanes * fam.ROW_BLOCKS},{block},640]") in picked
-    hit = [n for n in names if re.search(picked, n)
-           and _OPERATION.search(n)]
-    layers = _LATENT_POOL[0]
-    for role, rx in _MLA_ROLES.items():
-        got = [n for n in hit if re.search(rx, n)]
-        assert len(got) == layers, (role, [n[:160] for n in got])
-    # the float32 scores over a row's slots, one product a layer
-    scores = (rf"= \(?(f32\[[\d,]*\]\S* )?f32\[{lanes},(\d+,)*{slots}\]"
-              r"\S* fusion")
-    assert sum(bool(re.search(scores, n)) for n in hit) >= layers, hit
-    stray = [n[:200] for n in hit if 'op_name="' in n
-             and "mla/attend" not in n]
-    assert not stray, stray
-    # what of the loop's body the reader does NOT pick: the fold by lane
-    # (its float32 ``[lanes, positions, heads, dv]`` operands carry no
-    # shape the reader knows: PERF.md section 7 (r)), the per-row
-    # statistics and the index arithmetic — nothing as wide as a row's
-    # slots, the latent or a stored entry
-    left = [n for n in names if "mla/attend/while/body" in n
-            and _OPERATION.search(n) and n not in hit]
-    assert sum("bt,tshd->bshd" in n for n in left) == layers
-    wide = [n[:200] for n in left
-            if re.search(r"\[(\d+,)*(256|512|640)(,1)?\]", n)]
-    assert not wide, wide
-    # a trace holds the prefill chunk's events too, and the pattern is
-    # made from all of its names: with a chunk of the default width
-    # among them (``[1, W, heads, 640]`` queries, ``[4, 32, heads,
-    # slots]`` scores of its own row tile) the round's picks are the
-    # same operations
-    wide = _program_names(_latent_program(
-        topo, latent_engine, "prefill", monkeypatch, PREFILL_CHUNK),
-        loops=True)
-    assert any(re.search(rf"\[1,{PREFILL_CHUNK},", n) for n in wide)
-    with_chunk = reader.pattern(names + wide, lanes, m)
-    assert [n for n in names if re.search(with_chunk, n)
-            and _OPERATION.search(n)] == hit
-    # no cache among the names (the parent's programs): nothing to read
-    assert reader.pattern([n for n in names if "640" not in n
-                           and "576" not in n], lanes, m) is None
+    compiled = _latent_program(topo, latent_engine, kind, monkeypatch)
+    names = _program_names(compiled, loops=True)
+    layers, _, block, width = _LATENT_POOL
+    lanes, slots = _LATENT_LANES, fam.ROW_BLOCKS * block
+    s = 1 if kind == "decode" else latent_engine.config.spec_k + 1
+    heads, dc = 4, 512
+    reads = [n for n in names if "row_attention" in n
+             and 'custom_call_target="tpu_custom_call"' in n]
+    assert len(reads) == layers, [n[:160] for n in reads]
+    for n in reads:
+        assert re.search(r'op_name="[^"]*mla/attend/', n), n[:300]
+        assert re.match(rf"%row_attention[\.\d]* = bf16\[{lanes},1,"
+                        rf"{s * heads},{dc}\]", n), n[:160]
+        assert f"bf16[{lanes},1,{s * heads},{width}]" in n, n[:400]
+        assert f"bf16[{_dims(_LATENT_POOL)}]" in n  # the stacked pool
+    assert not any("mla/attend" in n and "while" in n for n in names)
+    text = compiled.as_text()
+    for dims in (rf"\d+,{block},{width}",           # [T x W, block, 640]
+                 rf"\d+,{slots},{width}(,1)?"):     # [T, slots, 640]
+        lines = [ln for ln in _results_shaped(text, dims)
+                 if not re.search(rf"= \w+\[{layers},", ln)]
+        assert not lines, "\n".join(lines[:6])
+    scores = re.compile(rf"f32\[(\d+,)+{slots}\]")
+    held = [ln.strip()[:200] for ln in text.splitlines()
+            if scores.search(ln)]
+    assert not held, "\n".join(held[:6])
+    # everything else under the scope is the absorbed query and ``W_v``
+    under = [n for n in names if "mla/attend" in n and _OPERATION.search(n)
+             and n not in reads]
+    assert under and all("while" not in n for n in under)
 
 
 @pytest.mark.parametrize("heads,width", [(128, 7680), (32, 2304)],
@@ -1054,8 +1037,8 @@ def test_state_kernel_compiles_at_the_served_sizes(topo, monkeypatch):
 _LINEAR_LANES, _LINEAR_HEADS = 8, 2
 _LINEAR_STATE = (_LINEAR_LANES, _LINEAR_HEADS, 128, 128)
 _LINEAR_POOL = (1, 2049, 16, 640)
-_LINEAR_TABLE = 144  # blocks a lane: 9 rows of 16, so rounds run 72 rows
-#                      in tiles of 64 and a chunk 9 in tiles of 4
+_LINEAR_TABLE = 144  # blocks a lane: 9 rows of 16 (rounds are handed 72
+#                      rows, a chunk 9: more than one ``tile`` of either)
 
 
 @pytest.fixture(scope="module")
@@ -1104,20 +1087,22 @@ def test_linear_program_never_copies_a_pool(topo, linear_engine, kind,
     copy: the latent family's padded pool (for the latent layers alone),
     a conv pool with a lane's 3 rows side by side, one float32 state
     array a linear-attention layer. The prefill chunk (told its state
-    slot beside its lane's rows) too, at both widths; the loops that
-    gather a tile of rows from the latent pool are read too. And the
-    latent read gathers rows, never every lane's whole table (PERF.md
-    section 6, PR 35)."""
+    slot beside its lane's rows) too, at both widths. And the latent
+    layer reads its live rows in ONE call of the row kernel under
+    ``mla/attend``: no value shaped like every lane's whole table, nor
+    like a tile of gathered rows (PERF.md section 6, PR 35 and PR 47)."""
     eng = linear_engine
     assert eng._pools[0].shape == _LINEAR_POOL
     assert all(p.shape == _LINEAR_STATE for p in eng._pools[3:])
     names = _linear_program_names(topo, eng, kind, monkeypatch, chunk,
                                   loops=True)
-    assert any("/while/body/" in n for n in names)
+    reads = [n for n in names if "row_attention" in n
+             and 'custom_call_target="tpu_custom_call"' in n]
+    assert len(reads) == 1 and "mla/attend" in reads[0], reads
     _holds_rows_not_tables(
         "\n".join(names), eng, kind,
         1 if kind == "prefill" else _LINEAR_LANES, _LINEAR_TABLE,
-        _LINEAR_POOL[3])
+        _LINEAR_POOL[3], a_tile=False)
     pools = "|".join(rf"\w+\[{_dims(p.shape)}\]" for p in
                      (eng._pools[0], eng._pools[2], eng._pools[3]))
     copies = [n[:200] for n in names

@@ -2,11 +2,13 @@
 latent-attention family and the linear-attention family (whose latent
 layers read through the same functions) held to their plain references
 with the read's constants steered small, so that tiny engines cut a lane
-into several rows over several tiles — decode, verify with rejected
-drafts, a prefill chunk of several ``QUERY_TILE``s, a shared prefix
-block, preempt-and-recompute, an idle lane, pad rows in the last tile.
+into several rows over several tiles of the operand — decode, verify
+with rejected drafts, a prefill chunk wider than what it is fed, a
+shared prefix block, preempt-and-recompute, an idle lane, pad rows after
+the live ones. Since PR 47 the rows go through the fused kernel
+(``ops/pallas/row_attention.py``'s one-pool form, interpret mode here).
 
-``_attend_rows`` itself against the model's ``attend_absorbed`` over
+The layer's read itself against the model's ``attend_absorbed`` over
 whole tables: tests/test_serving_rows.py (e). The helpers (tiny models,
 seeded weights, the references) are the families' own test modules'.
 """
@@ -27,10 +29,10 @@ ref_linear = KT.ref
 @pytest.fixture(params=[(2, 2, 1), (1, 3, 2), (3, 64, 4)],
                 ids=["W2_tile2_p1", "W1_tile3_p2", "W3_one_tile"])
 def small_rows(request, monkeypatch):
-    """Rows of W blocks, run a few at a time (a prefill chunk: ``p``):
-    the engines below then run 2-10 rows a lane over several tiles; the
-    last case every live row in ONE tile, as the served constants do at
-    these sizes."""
+    """Rows of W blocks, the operand's length in whole tiles of a few
+    (a prefill chunk's: ``p``): the engines below then hand the kernel
+    2-10 rows a lane over several tiles; the last case every live row in
+    ONE tile."""
     w, tile, ptile = request.param
     monkeypatch.setattr(fam, "ROW_BLOCKS", w)
     monkeypatch.setattr(fam, "ROW_TILE", tile)
@@ -62,8 +64,8 @@ def test_latent_engine_reads_rows(ref_latent, latent_model, small_rows,
     drafter proposes for — accepted and rejected drafts —, a short one;
     4 requests on 3 lanes, so lanes idle at the end; a pool of 14 blocks,
     so one is preempted and recomputed) against the reference's full
-    forward. At chunk 128 a call is four ``QUERY_TILE``s of which the fed
-    ones are attended, and its pad runs past ``max_seq_len``."""
+    forward. At chunk 128 a call is wider than any prompt is long, and
+    its pad runs past ``max_seq_len``."""
     eng, handles = LT._serve(latent_model, LT._traffic(), num_blocks=15,
                              prefill_chunk=chunk)
     _several_tiles(eng, small_rows, 3)
@@ -73,11 +75,15 @@ def test_latent_engine_reads_rows(ref_latent, latent_model, small_rows,
     assert c["prefix_hit_tokens"] >= 48
     assert c["preemptions"] >= 1
     assert max(LT._served_gaps(ref_latent, latent_model, handles)) < LT.TOL
-    # what the programs gathered follows what the lanes held (one tile
-    # of 4 rows x 3 blocks a lane: more than a 10-block table)
+    # what the programs read follows what the lanes held (the kernel
+    # walks the live rows: whole rows, so no less than the live tokens)
     assert c["kv_read_tokens"] <= c["kv_gathered_tokens"]
     if small_rows[1] < 64:
         assert c["kv_gathered_tokens"] < c["kv_dense_read_tokens"]
+    # every kind's rows went through the kernel, and were billed so
+    assert set(eng.stats()["row_read"].values()) == {"kernel"}
+    assert c["kv_gathered_tokens"] == c["kv_kernel_rows"] * small_rows[0] \
+        * eng.config.block_size
     eng.scheduler.pool.check_invariant()
 
 
@@ -124,6 +130,10 @@ def test_linear_engine_prefill_and_decode_read_rows(
     assert c["kv_read_tokens"] <= c["kv_gathered_tokens"]
     if small_rows[1] < 64:
         assert c["kv_gathered_tokens"] < c["kv_dense_read_tokens"]
+    # every kind's rows went through the kernel, and were billed so
+    assert set(eng.stats()["row_read"].values()) == {"kernel"}
+    assert c["kv_gathered_tokens"] == c["kv_kernel_rows"] * small_rows[0] \
+        * eng.config.block_size
 
 
 def test_linear_engine_verify_and_preemption_read_rows(linear_model,

@@ -251,20 +251,23 @@ def test_a_scope_changes_no_instruction(texts, kind):
 # families' common part to ``serving/families/common.py``: a refactor that
 # keeps each program's operations, their order and their scopes keeps
 # both. A change to a program changes its pins: read them again when that
-# is meant. So does another JAX (a), or another XLA CPU compiler (b).
+# is meant. So does another JAX (a), or another XLA CPU compiler (b). PR 47
+# read the six of ``latent_moe`` and ``linear_latent_moe`` again (their
+# read became a call of the row kernel) and left the other twelve as they
+# were: the K/V families' kernel is traced to the text it was.
 _PINS = {
     ("dense_gqa", "decode"): ("17fda45b37bfd089", "bd105bf6f3a6258a"),
     ("dense_gqa", "verify"): ("16d6e470633adb07", "2328434dfb89c058"),
     ("dense_gqa", "prefill"): ("d735efcc21819e63", "920060828733a600"),
-    ("latent_moe", "decode"): ("57d3748667c7a817", "a6ae92ea95014d29"),
-    ("latent_moe", "verify"): ("8050058a179dc62d", "bedba116a028b9e0"),
-    ("latent_moe", "prefill"): ("5f33fa374ff85032", "6cea0999f151e133"),
+    ("latent_moe", "decode"): ("74316d22779a5326", "249a00a00fd232a7"),
+    ("latent_moe", "verify"): ("7ae84f42f54b2605", "9818ef8f96572d63"),
+    ("latent_moe", "prefill"): ("d8cdb221e9c3085f", "f8251b60435fb351"),
     ("hybrid_ssm", "decode"): ("d822b946bbebeb42", "4bac2dab009fa262"),
     ("hybrid_ssm", "verify"): ("a6321a93dd2cc162", "67f460d199d1d81e"),
     ("hybrid_ssm", "prefill"): ("9148517617aa9927", "5da29c8360572f25"),
-    ("linear_latent_moe", "decode"): ("b3d18f0204db935c", "a543232f15be898b"),
-    ("linear_latent_moe", "verify"): ("f27670955ae8ca0f", "758e6cdbb68c9460"),
-    ("linear_latent_moe", "prefill"): ("4a5ced12674ef990", "e9650b85c4e44a1d"),
+    ("linear_latent_moe", "decode"): ("660c04db11042d91", "828dc9886d158b3c"),
+    ("linear_latent_moe", "verify"): ("a12fe5f80afc9ea4", "e5d25f90a9daa183"),
+    ("linear_latent_moe", "prefill"): ("1a0a289f5db9263f", "b76eea53328e675a"),
     ("window_moe", "decode"): ("74fd0ded846c1d55", "9ce4de4981844279"),
     ("window_moe", "verify"): ("ae41f6cb315ed683", "05435ab5ad1119db"),
     ("window_moe", "prefill"): ("50b4f006d2675fc0", "850003b71665693e"),

@@ -479,7 +479,7 @@ def test_stats_say_who_reads_the_rows(model):
     latent = ServingEngine(_tiny_latent(), ServingConfig(
         max_lanes=2, block_size=2, prefill_chunk=4, max_seq_len=16,
         spec=False))
-    assert set(latent.stats()["row_read"].values()) == {"xla"}
+    assert set(latent.stats()["row_read"].values()) == {"kernel"}
 
 
 def _tiny_latent():
@@ -502,8 +502,9 @@ def test_the_latent_family_bills_its_live_rows():
     eng = ServingEngine(_tiny_latent(), ServingConfig(
         max_lanes=4, block_size=2, prefill_chunk=4, max_seq_len=1024,
         spec=False))
-    # rows of 16 blocks: 32 a lane, run 64 (a prefill chunk: 4) at a time
-    assert eng._rows_form("decode", 4) == (16, 64, 128)
+    # rows of 16 blocks: 32 a lane, the operand's length in whole 16s (a
+    # prefill chunk's: 4s)
+    assert eng._rows_form("decode", 4) == (16, 16, 128)
     assert eng._rows_form("prefill", 1) == (16, 4, 32)
     _serve(eng, _requests(eng.model, 3, seed=10, hi=8, new=(3, 6)))
     c = eng.counters
@@ -620,16 +621,21 @@ def _latent_operands(lens, s, seed):
     return jnp.asarray(pool), tables, q_nope, q_rope, lp, pos
 
 
-def _latent_rows_read(pool, rows, w, tile, q_nope, q_rope, lp, pos):
+def _latent_rows_read(pool, rows, q_nope, q_rope, lp, pos):
+    """The latent layer's read as ``families/latent_moe.attend_pool``
+    makes it: the absorbed queries through the fused kernel as ONE shared
+    KV head over layer 1 of a stacked pool whose layer 0 is poison — a
+    slot's value the first ``DC`` numbers of its key, no value pool —
+    then ``W_v`` once on the folded sums."""
     from paddle_tpu.models import latent_moe as model
-    from paddle_tpu.serving.families import latent_moe as fam
-
-    def gather(blocks):
-        return pool[blocks].reshape(blocks.shape[0], w * B, STORED)
 
     qq = model.absorb_query(q_nope, q_rope, lp, _LatentCfg, STORED)
-    return np.asarray(fam._attend_rows(
-        qq, pos, jnp.asarray(rows), gather, tile, lp, _LatentCfg))
+    o_lat = row_attention(
+        qq, pos, jnp.asarray(rows),
+        jnp.stack([jnp.full_like(pool, jnp.nan), pool]), None, 1, 1,
+        (DN + DR) ** -0.5, dv=DC)
+    assert o_lat.shape == (*qq.shape[:3], DC)
+    return np.asarray(model.unabsorb_output(o_lat, lp, _LatentCfg))
 
 
 _LATENT_CASES = sorted(c for c in _CASES if "window" not in c)
@@ -638,7 +644,8 @@ _LATENT_CASES = sorted(c for c in _CASES if "window" not in c)
 @pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify"])
 @pytest.mark.parametrize("case", _LATENT_CASES)
 def test_latent_rows_read_is_the_absorbed_table_read(case, s):
-    """``families/latent_moe._attend_rows`` against the model's own
+    """The latent read (the kernel's one-pool form, PR 47; the XLA
+    ``_attend_rows`` of PR 35 before it) against the model's own
     ``attend_absorbed`` over every lane's whole gathered table."""
     from paddle_tpu.models import latent_moe as model
 
@@ -654,7 +661,7 @@ def test_latent_rows_read_is_the_absorbed_table_read(case, s):
     rows, _, n, _ = pack_rows(
         [(i, list(tables[i]), lens[i], lens[i] + s)
          for i in range(L) if lens[i]], L, s, B, w, cap)
-    got = _latent_rows_read(pool, rows, w, tile, q_nope, q_rope, lp, pos)
+    got = _latent_rows_read(pool, rows, q_nope, q_rope, lp, pos)
     held = np.asarray(lens) > 0
     np.testing.assert_allclose(got[held], want[held], rtol=2e-5, atol=2e-6)
     assert (got[~held] == 0).all()  # idle lanes read 0, not NaN
@@ -669,10 +676,106 @@ def test_a_latent_row_above_its_lanes_positions_weighs_nothing():
     rows, *_ = pack_rows([(0, list(tables[0]), lens[0], M * B)], 1, s, B,
                          w, cap)
     dead = tables[0, 6:]  # slots 24.. lie above positions 21-22
-    args = (rows, w, tile, q_nope, q_rope, lp, pos)
+    args = (rows, q_nope, q_rope, lp, pos)
     np.testing.assert_array_equal(
         _latent_rows_read(pool.at[dead].set(3e4), *args),
         _latent_rows_read(pool, *args))
+
+
+# the latent layer itself (``families/latent_moe.attend_pool``: the new
+# entries written, the absorbed query, the kernel's one-pool form, ``W_v``
+# once) against the model's definition over every lane's whole table.
+# lens: tokens a lane holds (0: idle, no row); s: positions fed a lane (a
+# round's 1 or 5, a prefill chunk's); W, tile: the rows operand's form;
+# steer: the kernel's constants made small, so that tiny shapes go several
+# chunks side by side, under a loop, and over several query tiles
+_LAYER = {
+    "round_ragged": dict(lens=[5, 17, 30, 2], s=5, W=2, tile=4),
+    "plain_round": dict(lens=[15, 23, 7, 40], s=1, W=2, tile=3),
+    # lane 1's rows (3) start in the first tile of 2 rows and end in the
+    # second; the last tile holds pad rows; lanes 0 and 3 hold nothing
+    "rows_over_a_tile_boundary": dict(lens=[0, 19, 9, 0], s=5, W=2, tile=2),
+    "contexts_end_inside_a_block": dict(lens=[1, 6, 13], s=5, W=3, tile=4),
+    "chunks_side_by_side": dict(lens=[11, 29], s=5, W=2, tile=4,
+                                steer=dict(_Q_ROWS=5, _SIDE_ROWS=10)),
+    "chunks_under_a_loop": dict(lens=[11, 29], s=5, W=2, tile=4,
+                                steer=dict(_Q_ROWS=5, _SIDE_ROWS=5)),
+    "a_chunk": dict(lens=[24], s=16, W=2, tile=4),
+    "a_chunk_in_query_tiles": dict(
+        lens=[24], s=16, W=2, tile=4,
+        steer=dict(_Q_TILE_ROWS=32, _Q_ROWS=8, _SIDE_ROWS=16)),
+    "a_part_padded_chunk": dict(lens=[24], s=16, W=2, tile=4, real=9,
+                                steer=dict(_Q_TILE_ROWS=32)),
+}
+
+
+@pytest.mark.parametrize("rope", [True, False], ids=["rope", "nope"])
+@pytest.mark.parametrize("heads", [8, 4], ids=["heads8", "heads4"])
+@pytest.mark.parametrize("case", sorted(_LAYER))
+def test_the_latent_layer_reads_through_the_kernel(case, heads, rope,
+                                                   monkeypatch):
+    """128 and 32 heads cut to 8 and 4 (a head group is the whole head
+    count: one shared KV head), with and without the rotary embedding."""
+    from paddle_tpu.models import latent_moe as model
+    from paddle_tpu.ops.pallas import row_attention as RA
+    from paddle_tpu.serving.families import latent_moe as fam
+    from paddle_tpu.serving.families.common import write_slots
+
+    class cfg:
+        kv_lora_rank, qk_rope_head_dim, qk_nope_head_dim = 32, 8, 16
+        v_head_dim, num_attention_heads = 12, heads
+        rms_norm_eps, rope_theta = 1e-5, 1e4
+
+    c = _LAYER[case]
+    for name, value in c.get("steer", {}).items():
+        monkeypatch.setattr(RA, name, value)
+    lens, s, L = c["lens"], c["s"], len(c["lens"])
+    real = c.get("real", s)
+    rng = np.random.RandomState(len(case) + heads)
+    hid, dc, dr = 24, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    dq = cfg.qk_nope_head_dim + dr
+
+    def leaf(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.3)
+
+    lp = {"q": leaf(hid, heads * dq), "kv_a": leaf(hid, dc + dr),
+          "kv_norm": 1 + leaf(dc),
+          "kv_b": leaf(dc, heads * (cfg.qk_nope_head_dim + cfg.v_head_dim))}
+    nb = 1 + L * M
+    pool = np.zeros((2, nb, B, fam.LANES), np.float32)
+    pool[1, ..., :dc + dr] = rng.randn(nb, B, dc + dr)
+    pool[0] = np.nan  # the other layer's: never read
+    tables = rng.permutation(np.arange(1, nb)).reshape(L, M).astype(np.int32)
+    u = leaf(L, s, hid)
+    pos = jnp.asarray(np.asarray(lens)[:, None] + np.arange(s)[None, :],
+                      jnp.int32)
+    live = [i for i in range(L) if lens[i]]
+    w, _, cap = fit_rows((c["W"], c["tile"]), L, M)
+    rows, wblk, n, _ = pack_rows(
+        [(i, list(tables[i]), lens[i], lens[i] + real) for i in live],
+        L, s, B, w, cap)
+    assert n < cap or case == "a_chunk"  # pad rows after the live ones
+    wlimit = jnp.asarray([lens[i] + real if lens[i] else 0
+                          for i in range(L)], jnp.int32)
+    blk, off = write_slots(jnp.asarray(wblk), pos, wlimit, B,
+                           "mla/kv_write")
+    got, after = fam.attend_pool(u, lp, 1, jnp.asarray(pool),
+                                 jnp.asarray(rows), pos, blk, off, cfg,
+                                 rope=rope)
+    assert got.shape == (L, s, heads * cfg.v_head_dim)
+
+    q_nope, q_rope, _ = model.latent_qkv(u, lp, pos, cfg, rope)
+    vis = jnp.arange(M * B)[None, None, :] <= pos[:, :, None]
+    want = np.asarray(model.attend_absorbed(
+        q_nope, q_rope, after[1][tables].reshape(L, M * B, fam.LANES), vis,
+        lp, cfg))
+    got = np.asarray(got)
+    np.testing.assert_allclose(got[live, :real], want[live, :real],
+                               rtol=3e-5, atol=3e-6)
+    assert np.isfinite(got).all()
+    idle = [i for i in range(L) if not lens[i]]
+    assert (got[idle] == 0).all()  # a lane with no row reads 0, not NaN
+
 
 def _kernel_call(b, s, nh, nkv, d, dv):
     """The traced ``pallas_call`` of one read: (grid, operand blocks,

@@ -29,21 +29,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def _row_reads(form, g, i32, geom, slot, tiled=True):
+def _row_reads(form, g, i32, geom, slot):
     """(static kwargs by program, read operands by program) of a family
-    whose programs read their lanes' live rows: ``form(kind)`` its ``(W,
-    tile)``; ``slot``: the prefill chunk is told its state slot too;
-    ``tiled``: the programs run the rows ``tile`` at a time themselves
-    (not the families whose read is the row kernel)."""
+    whose programs read their lanes' live rows through the row kernel:
+    ``form(kind)`` its ``(W, tile)``; ``slot``: the prefill chunk is told
+    its state slot too."""
     from paddle_tpu.serving.engine import fit_rows
 
     L, _, C, K, M = geom
     reads, statics = {}, {}
     for kind, lanes, width in (("decode", L, 1), ("verify", L, K + 1),
                                ("prefill", 1, C)):
-        w, tile, cap = fit_rows(form(kind), lanes, M)
+        w, _, cap = fit_rows(form(kind), lanes, M)
         reads[kind] = (i32(cap, 2 + w), i32(lanes, width))
-        statics[kind] = {"cfg": g, "tile": tile} if tiled else {"cfg": g}
+        statics[kind] = {"cfg": g}
     if slot:
         reads["prefill"] += (i32(1),)
     return statics, reads
@@ -101,7 +100,7 @@ def _hybrid(arch, cfg, layers, s, sds, i32, geom):
 
     statics, reads = _row_reads(
         lambda kind: (fam.ROW_BLOCKS, fam.PREFILL_TILE if kind == "prefill"
-                      else fam.ROW_TILE), g, i32, geom, True, tiled=False)
+                      else fam.ROW_TILE), g, i32, geom, True)
     return fam, statics, pools, reads
 
 
@@ -152,8 +151,7 @@ def _window(arch, cfg, layers, s, sds, i32, geom):
              sds((len(fam.ACC),), jnp.int32),
              *(sds((L, R, swa * g.head_dim)) for _ in range(n_win)),
              *(sds((L, R, swa * g.v_head_dim)) for _ in range(n_win)))
-    statics, reads = _row_reads(fam.read_form, g, i32, geom, True,
-                                tiled=False)
+    statics, reads = _row_reads(fam.read_form, g, i32, geom, True)
     return fam, statics, pools, reads
 
 
@@ -177,7 +175,7 @@ def _conv(arch, cfg, layers, s, sds, i32, geom):
              sds((n_conv, L, (g.conv_L_cache - 1) * g.hidden_size)))
     statics, reads = _row_reads(
         lambda kind: (fam.ROW_BLOCKS, fam.PREFILL_TILE if kind == "prefill"
-                      else fam.ROW_TILE), g, i32, geom, True, tiled=False)
+                      else fam.ROW_TILE), g, i32, geom, True)
     return fam, statics, pools, reads
 
 
