@@ -1093,7 +1093,7 @@ def test_linear_program_never_copies_a_pool(topo, linear_engine, kind,
     like a tile of gathered rows (PERF.md section 6, PR 35 and PR 47)."""
     eng = linear_engine
     assert eng._pools[0].shape == _LINEAR_POOL
-    assert all(p.shape == _LINEAR_STATE for p in eng._pools[3:])
+    assert all(p.shape == _LINEAR_STATE for p in eng._pools[3:-2])
     names = _linear_program_names(topo, eng, kind, monkeypatch, chunk,
                                   loops=True)
     reads = [n for n in names if "row_attention" in n
@@ -1117,12 +1117,15 @@ def test_kda_update_reader_picks_the_state_and_nothing_else(
         topo, linear_engine, kind, monkeypatch):
     """The benchmark's ``kda_update_roofline`` picks operations by the
     state's shape in their instruction text. Held here to the compiled
-    programs' own instructions: TWO fusions a layer a round — a plain
-    round's one read for both products with the old state (a
-    multi-output reduction) and its read-and-write update; a verify
-    round's one product for the k+1 positions' outputs and its one-pass
-    update of what was accepted — nothing of the latent layer, the expert
-    layers or the head."""
+    programs' own instructions: ONE a linear-attention layer in a plain
+    round and in a verify round — the state kernel's call under
+    ``kda/state_update``, with the state aliased in and out (one buffer,
+    read once and written once) — nothing of the latent layer, the expert
+    layers or the head. No fusion goes over the state's shape (before PR
+    48: two a layer a round — a read for the products with the old state,
+    then the update) and no ``[lanes, heads, k+1, k+1]`` value is left
+    (the chunked form's 5 x 5 algebra: it runs inside the kernel, on
+    ``[heads, d]`` vectors)."""
     import importlib.util
     import sys
 
@@ -1140,25 +1143,71 @@ def test_kda_update_reader_picks_the_state_and_nothing_else(
     m = {"linear_attn_config": {"num_heads": _LINEAR_HEADS,
                                 "head_dim": 128}}
     picked = reader.pattern(names, _LINEAR_LANES, m)
-    assert rf"f32\[{_dims(_LINEAR_STATE)}\]" in picked.replace("\\,", ",")
-    hit = [n for n in names if re.search(picked, n)
-           and re.search(r"[\s)]fusion\(", n)]
-    assert len(hit) == 2 * 2, [n[:200] for n in hit]
-    assert all("kda/state_update" in n for n in hit), \
-        [n[:200] for n in hit if "kda/state_update" not in n]
-    # the state is written once a layer: one fusion's result has its shape
     state = rf"f32\[{_dims(_LINEAR_STATE)}\]"
-    assert len([n for n in hit if re.match(rf"%\S+ = {state}", n)]) == 2
+    assert picked.replace("\\,", ",") == state  # that shape and no other
+    hit = [n for n in names if re.search(picked, n) and _WORK.search(n)]
+    assert len(hit) == 2, [n[:200] for n in hit]
+    for n in hit:
+        assert re.search(r"[\s)]custom-call\(", n) \
+            and 'custom_call_target="tpu_custom_call"' in n, n[:200]
+        assert "kda/state_update" in n and "kda_state_round" in n, n[:300]
+        # the call's second result IS its last operand's buffer
+        assert re.match(rf"%\S+ = \(f32\[[\d,]+\]\S*, {state}", n), n[:200]
+        aliased = re.search(
+            r"output_to_operand_aliasing=\{\{1\}: \((\d+), \{\}\)", n)
+        operands = re.findall(r"%[\w.\-]+", n[n.index("custom-call("):n.index(
+            "), custom_call_target")])
+        assert aliased and int(aliased.group(1)) == len(operands) - 1, \
+            n[:400]
+    # nothing else over the state's shape, in any reshape of it
+    assert _slab_sized(names, _LINEAR_STATE) == hit
+    # the chunked form's T x T algebra is nowhere in the program
+    T = linear_engine.config.spec_k + 1
+    square = rf"f32\[{_LINEAR_LANES},{_LINEAR_HEADS},{T},{T}[\],]"
+    assert not [n[:200] for n in names if re.search(square, n)]
     # a trace holds the prefill chunk's events too: the round's picks are
     # the same with a chunk of the default width among the names
     wide = _linear_program_names(topo, linear_engine, "prefill",
                                  monkeypatch, PREFILL_CHUNK)
     with_chunk = reader.pattern(names + wide, _LINEAR_LANES, m)
     assert [n for n in names if re.search(with_chunk, n)
-            and re.search(r"[\s)]fusion\(", n)] == hit
+            and _WORK.search(n)] == hit
     # a program without a state (the parent's): nothing to read
     assert reader.pattern([n for n in names if ",128,128]" not in n],
                           _LINEAR_LANES, m) is None
+
+
+def test_kda_state_kernel_compiles_at_the_served_sizes(topo, monkeypatch):
+    """The delta rule's state kernel at the benchmark cell's sizes — 64
+    lanes, 32 heads of 128 x 128, layer 19 of 20, a verify round's 5 owed
+    and 5 read positions and a plain round's 5 owed, 1 read and 1 own —
+    compiled by Mosaic for the described chip: the 2 MB block a lane fits
+    its stated VMEM, the strided stores into a head's tiles, the
+    in-kernel transposes and the float32 products on the matrix unit
+    lower, and state and pending pool are aliased (no second 134 MB
+    buffer: temporaries 0)."""
+    import paddle_tpu.framework.device as device
+    from paddle_tpu.ops.pallas import kda_state
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, H, d, T, layers = 64, 32, 128, 5, 20
+    for reads, own in ((T, False), (1, True)):
+        def call(S, pend, n, q, k, v, g, beta):
+            return kda_state.state_round(S, pend, layers - 1, n, q, k, v,
+                                         g, beta, own=own)
+
+        compiled = jax.jit(call, donate_argnums=(0, 1)[:2 - own]).lower(
+            sd(L, H, d, d), sd(*kda_state.pending_shape(layers, L, T, H, d)),
+            sd(L, dtype=jnp.int32), *(sd(reads, L, H, d),) * 4,
+            sd(L, reads, H)).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 # -- the window-attention / full-attention family's programs --------------------

@@ -27,7 +27,9 @@ from paddle_tpu.models import (
     LinearLatentMoEConfig, LinearLatentMoEForCausalLM, generate,
     linear_latent_moe as M,
 )
+from paddle_tpu.ops.pallas import kda_state
 from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.serving.families import linear_latent_moe as family
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 2e-4
@@ -230,6 +232,90 @@ def test_a_masked_position_is_the_identity_on_the_state():
     np.testing.assert_allclose(S6, S4, rtol=1e-6, atol=1e-6)
 
 
+def _masked_steps(q, k, v, g, beta, S, n):
+    """``kda_step`` position by position, a row's state advanced over its
+    first ``n[row]`` positions alone; (every position's output, S)."""
+    outs = []
+    for t in range(q.shape[1]):
+        S2, o = M.kda_step(S, q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t])
+        S = jnp.where((t < n)[:, None, None, None], S2, S)
+        outs.append(o)
+    return jnp.stack(outs, 1), S
+
+
+def _state_round(S, pend, layer, n, q, k, v, g, beta, own):
+    """The kernel on ``[b, T, ..]`` arrays, as the family calls it (it
+    takes and gives positions first)."""
+    return family._round(S, pend, layer, n, (q, k, v, g), beta, own)
+
+
+def _left_pending(S0, seed, layers=3, layer=1):
+    """A verify round's call on ``S0``: (its inputs, the pending pool it
+    leaves — ``layer``'s entries of ``layers``, the others ones)."""
+    owed = _kda_inputs(5, seed=seed, b=3)[:5]
+    ones = jnp.ones(kda_state.pending_shape(layers, 3, 5, *S0.shape[1:3]),
+                    jnp.float32)
+    o, S, pend = _state_round(S0, ones, layer, jnp.zeros((3,), int), *owed,
+                              own=False)
+    # nothing was owed: the state is as it was; the round's outputs are
+    # the recurrence's; the other layers' entries are untouched
+    assert (np.asarray(S) == np.asarray(S0)).all()
+    want, _ = _masked_steps(*owed, S0, jnp.full((3,), 5))
+    np.testing.assert_allclose(o, want, rtol=1e-4, atol=1e-5)
+    assert (np.asarray(pend[0]) == 1).all() and (np.asarray(pend[2])
+                                                  == 1).all()
+    return owed, pend
+
+
+@pytest.mark.parametrize("n_keep", range(6))
+@pytest.mark.parametrize("reads", [1, 5])
+def test_state_kernel_equals_the_step_recurrence(reads, n_keep):
+    """5 owed positions of which a row keeps ``n_keep`` (its neighbours
+    another number), then ``reads`` positions of this round: the state
+    the kernel writes is ``kda_step`` over the kept positions, its outputs
+    the recurrence's from there, position by position. With one read (a
+    plain round) the round's own position is applied in the same call;
+    with five (a verify round) none is, and they are left pending."""
+    S0 = _kda_inputs(1, seed=50 + n_keep, b=3)[5]
+    owed, pend = _left_pending(S0, seed=10 * reads + n_keep)
+    n = jnp.asarray([n_keep, (n_keep + 2) % 6, 5 - n_keep])
+    _, S_c = _masked_steps(*owed, S0, n)
+    now = _kda_inputs(reads, seed=99 - n_keep, b=3)[:5]
+    want, S_own = _masked_steps(*now, S_c, jnp.full((3,), reads))
+    o, S, *left = _state_round(S0, pend, 1, n, *now, own=reads == 1)
+    np.testing.assert_allclose(o, want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(S, S_own if reads == 1 else S_c, rtol=1e-4,
+                               atol=1e-5)
+    assert np.abs(np.asarray(S_c - S0)).max() > 1e-2
+    if reads == 5:  # what the round leaves is what ITS commit needs
+        k, g, u = (left[0][1, :, i] for i in range(3))
+        assert (np.asarray(k) == np.asarray(now[1])).all()
+        assert (np.asarray(g) == np.asarray(now[3])).all()
+        hf = lambda a: jnp.swapaxes(a, 1, 2)  # noqa: E731
+        np.testing.assert_allclose(
+            M.kda_apply(S_c, hf(k), hf(g), hf(u)), S_own, rtol=1e-4,
+            atol=1e-5)
+
+
+@pytest.mark.parametrize("reads", [1, 5])
+def test_state_kernel_with_nothing_owed_is_the_identity(reads):
+    """A lane that owes nothing gets its state back BIT FOR BIT, whatever
+    the pending entries hold — a verify round's rejected drafts, a
+    finished request's last round — and reads from exactly that state."""
+    S0 = _kda_inputs(1, seed=60 + reads, b=3)[5]
+    _, pend = _left_pending(S0, seed=reads)
+    now = _kda_inputs(5, seed=70, b=3)[:5]
+    none = jnp.zeros((3,), jnp.int32)
+    o, S, _ = _state_round(S0, pend, 1, none, *now, own=False)
+    assert (np.asarray(S) == np.asarray(S0)).all()
+    o2, S2, _ = _state_round(S0, 7 * pend[:, :, :, ::-1] + 1, 1, none,
+                             *now, own=False)
+    assert (np.asarray(S2) == np.asarray(S0)).all()
+    assert (np.asarray(o2) == np.asarray(o)).all()
+    want, _ = _masked_steps(*now, S0, jnp.full((3,), 5))
+    np.testing.assert_allclose(o, want, rtol=1e-4, atol=1e-5)
+
+
 def test_the_selection_bias_chooses_and_does_not_weigh():
     rng = np.random.default_rng(4)
     u = jnp.asarray(rng.normal(0, 1, (9, 16)), jnp.float32)
@@ -385,10 +471,25 @@ class Oracle:
         return d[:k]
 
 
-def lane_state(eng, lane=0):
-    """(state [linear-attention layers, H, d, d], conv tail) of a lane."""
-    n = len(eng._pools) - 3
-    return (np.stack([np.asarray(s[lane]) for s in eng._pools[3:]]),
+def lane_owes(eng, lane=0):
+    return int(eng._pools[-1][lane])
+
+
+def lane_state(eng, lane=0, owed=True):
+    """(state [linear-attention layers, H, d, d], conv tail) of a lane:
+    what its linear-attention layers HOLD — each layer's array entry with
+    the positions the lane still owes applied (``owed=False``: the entry
+    alone), by the one-pass update under the program's mask."""
+    *states, pend, _ = eng._pools[3:]
+    n = len(states)
+    S = jnp.stack([s[lane] for s in states])
+    if owed:
+        keep = (jnp.arange(pend.shape[3])
+                < lane_owes(eng, lane))[None, None, :, None]
+        k, g, u = (jnp.swapaxes(pend[:, lane, i], 1, 2) for i in range(3))
+        S = M.kda_apply(S, k, jnp.where(keep, g, 0.0),
+                        jnp.where(keep, u, 0.0))
+    return (np.asarray(S),
             np.asarray(eng._pools[2][:, lane]).reshape(n, 3, -1))
 
 
@@ -425,7 +526,8 @@ def test_rejected_drafts_leave_no_trace_in_the_state(model, plain_run, a):
     them — and equal plain decoding's after as many tokens up to the
     order of summation (the round reads the state once, in the chunked
     form over its positions, which is not the step recurrence bit for
-    bit). Every later token is plain decoding's, and the engine's
+    bit; ``lane_state``: the lane's array entry WITH the positions it
+    still owes). Every later token is plain decoding's, and the engine's
     acceptance and the program's agree."""
     prompt, truth, states = plain_run
     seq = np.concatenate([prompt, truth])
@@ -481,6 +583,38 @@ def test_a_reused_lane_gives_what_a_fresh_engine_gives(model):
     for got, want in zip(lane_state(eng, 0), lane_state(fresh, 0)):
         assert (got == want).all()
     assert eng.stats()["lin_slot_resets"] == 2
+
+
+def test_a_prefilling_lane_never_carries_its_predecessors_pending_round(
+        model, plain_run):
+    """One lane, two requests. The first one's LAST round is a verify
+    round, so it finishes owing its state 4 positions, which nothing
+    applies; the second's first chunk zeroes the lane's count with the
+    slot, its rounds start from what its prefill left, and it is served
+    what a fresh engine serves."""
+    prompt, truth, _ = plain_run
+    seq = np.concatenate([prompt, truth])
+    second = prompts(1, seed=23)[0]
+    eng = engine(model, Oracle(seq, prompt.size + 6, 3, 0), max_lanes=1,
+                 spec_k=K)
+    first = eng.submit(prompt, max_new_tokens=10)
+    eng.run()
+    assert first.output == list(truth[:10])
+    assert eng.counters["verify_steps"] == 1 and lane_owes(eng) == 4
+    r2 = eng.submit(second, max_new_tokens=10)
+    eng.step()  # its prefill, and nothing else yet
+    assert lane_owes(eng) == 0
+    fresh = engine(model, max_lanes=1, spec=False)
+    f2 = fresh.submit(second, max_new_tokens=10)
+    fresh.step()
+    for got, want in zip(lane_state(eng, owed=False),
+                         lane_state(fresh, owed=False)):
+        assert (got == want).all()
+    eng.run()
+    fresh.run()
+    assert r2.output == f2.output
+    # the predecessor's 4 positions were never committed
+    assert eng.stats()["lin_deferred_positions"] == 0
 
 
 @pytest.mark.parametrize("chunk", [8, 128], ids=["chunk8", "chunk128"])
@@ -564,7 +698,12 @@ def test_speculation_is_token_identical_to_plain_decoding(model):
     assert st["spec_rolled_back_tokens"] \
         == st["spec_proposed_tokens"] - st["spec_accepted_tokens"] > 0
     assert st["lin_state_passes"] \
-        == st["decode_steps"] + 2 * st["verify_steps"]
+        == st["decode_steps"] + st["verify_steps"]
+    assert st["lin_state_lane_moves"] == 2 * st["lin_lane_rounds"]
+    # every accepted position entered its lane's state a call late, but
+    # for the last round's of a request that then finished
+    kept = st["spec_accepted_tokens"] + st["verify_steps"]
+    assert 0 < st["lin_deferred_positions"] <= kept * GEOM["max_lanes"]
 
 
 def test_stats_tell_pools_by_kind(model):
@@ -577,7 +716,11 @@ def test_stats_tell_pools_by_kind(model):
     assert st["family"] == "linear_latent_moe"
     assert st["lin_state_bytes_per_lane"] == state
     assert st["lin_conv_bytes_per_lane"] == tail
-    assert st["lane_pool_bytes"] == GEOM["max_lanes"] * (state + tail)
+    # a verify round's k+1 positions' keys, log-decays, pseudo-values
+    # (float32) a layer, and the lane's count
+    owed = n_kda * 3 * (eng.config.spec_k + 1) * c.kda_width * 4 + 4
+    assert st["lin_pending_bytes_per_lane"] == owed
+    assert st["lane_pool_bytes"] == GEOM["max_lanes"] * (state + tail + owed)
     assert st["latent_kv_bytes_per_token"] == (32 + 8) * 4
     blocks = eng.scheduler.pool.num_blocks
     # ONE latent layer's entries, padded to a whole 128-lane tile

@@ -254,7 +254,10 @@ def test_a_scope_changes_no_instruction(texts, kind):
 # is meant. So does another JAX (a), or another XLA CPU compiler (b). PR 47
 # read the six of ``latent_moe`` and ``linear_latent_moe`` again (their
 # read became a call of the row kernel) and left the other twelve as they
-# were: the K/V families' kernel is traced to the text it was.
+# were: the K/V families' kernel is traced to the text it was. PR 48 read
+# the three of ``linear_latent_moe`` again (its rounds' state path became a
+# call of the state kernel a layer, its chunk zeroes a pending count) and
+# no other family's.
 _PINS = {
     ("dense_gqa", "decode"): ("17fda45b37bfd089", "bd105bf6f3a6258a"),
     ("dense_gqa", "verify"): ("16d6e470633adb07", "2328434dfb89c058"),
@@ -265,9 +268,9 @@ _PINS = {
     ("hybrid_ssm", "decode"): ("d822b946bbebeb42", "4bac2dab009fa262"),
     ("hybrid_ssm", "verify"): ("a6321a93dd2cc162", "67f460d199d1d81e"),
     ("hybrid_ssm", "prefill"): ("9148517617aa9927", "5da29c8360572f25"),
-    ("linear_latent_moe", "decode"): ("660c04db11042d91", "828dc9886d158b3c"),
-    ("linear_latent_moe", "verify"): ("a12fe5f80afc9ea4", "e5d25f90a9daa183"),
-    ("linear_latent_moe", "prefill"): ("1a0a289f5db9263f", "b76eea53328e675a"),
+    ("linear_latent_moe", "decode"): ("c75990560645e1e6", "8d3fc91cf900f7b7"),
+    ("linear_latent_moe", "verify"): ("d3743f505309ddd3", "fac33b182adbadf0"),
+    ("linear_latent_moe", "prefill"): ("85c9032de192465e", "827bdda560a782f8"),
     ("window_moe", "decode"): ("74fd0ded846c1d55", "9ce4de4981844279"),
     ("window_moe", "verify"): ("ae41f6cb315ed683", "05435ab5ad1119db"),
     ("window_moe", "prefill"): ("50b4f006d2675fc0", "850003b71665693e"),

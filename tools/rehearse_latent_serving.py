@@ -106,16 +106,17 @@ def _hybrid(arch, cfg, layers, s, sds, i32, geom):
 
 def _linear(arch, cfg, layers, s, sds, i32, geom):
     """The same of ``serving/families/linear_latent_moe.py``: the latent
-    family's padded pool for the latent layers, a conv pool and one
-    float32 state array a linear-attention layer by LANE, the lanes'
-    live rows for every program (the prefill chunk's with its state
-    slot)."""
+    family's padded pool for the latent layers, a conv pool, one
+    float32 state array a linear-attention layer by LANE, the pending
+    pool with the lanes' counts, the lanes' live rows for every program
+    (the prefill chunk's with its state slot)."""
     import jax.numpy as jnp
 
     from paddle_tpu.models import LinearLatentMoEConfig
+    from paddle_tpu.ops.pallas import kda_state
     from paddle_tpu.serving.families import linear_latent_moe as fam
 
-    L, B, _, _, M = geom
+    L, B, _, K, M = geom
     g = LinearLatentMoEConfig(**arch.config_kwargs(
         cfg, layers, s["max_seq_len"])).static()
     n_kda = sum(k == "kda" for k in g.layer_kinds)
@@ -125,7 +126,10 @@ def _linear(arch, cfg, layers, s, sds, i32, geom):
              sds((len(fam.ACC),), jnp.int32),
              sds((n_kda, L, (g.kda_taps - 1) * 3 * g.kda_width)),
              *(sds((L, g.kda_heads, g.kda_head_dim, g.kda_head_dim),
-                   jnp.float32) for _ in range(n_kda)))
+                   jnp.float32) for _ in range(n_kda)),
+             sds(kda_state.pending_shape(n_kda, L, K + 1, g.kda_heads,
+                                         g.kda_head_dim), jnp.float32),
+             sds((L,), jnp.int32))
     statics, reads = _row_reads(fam.read_form, g, i32, geom, True)
     return fam, statics, pools, reads
 
